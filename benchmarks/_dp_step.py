@@ -1,14 +1,66 @@
 """Shared data-parallel train-step construction for the benchmark scripts.
 
-One definition of the measured program (model apply + loss + grad +
-DistributedOptimizer update + cross-replica BatchNorm averaging, jitted as
-a shard_map over the data axis) so `bench.py` and
-`benchmarks/scaling_bench.py` cannot drift apart — the reference keeps its
-protocol in one script per framework for the same reason
+One definition of each measured program (model apply + loss + grad +
+DistributedOptimizer update, jitted as a shard_map over the data axis) so
+`bench.py`, `benchmarks/scaling_bench.py`, `benchmarks/lm_bench.py` and
+`chip_smoke.py` cannot drift apart — the reference keeps its protocol in
+one script per framework for the same reason
 (``examples/pytorch_synthetic_benchmark.py:37-110``).
 """
 
 from __future__ import annotations
+
+
+def _init_on_mesh(make, mesh, *specs):
+    """Run ``make()`` as ONE jitted program on ``mesh``'s devices (an
+    un-jitted flax init is hundreds of small compiles on a TPU), its
+    outputs laid out by ``specs`` — i.e. already as the step wants them."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    return jax.jit(make, out_shardings=tuple(
+        NamedSharding(mesh, spec) for spec in specs))()
+
+
+def synthesize_image_job(model, mesh, global_batch: int, side: int,
+                         num_classes: int, axis_name: str = "data"):
+    """``(images, labels, variables)`` for ``make_dp_train_step``: one
+    fixed synthetic batch split on ``axis_name`` and ``model``'s freshly
+    initialised variables replicated, all from fixed seeds."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    def make():
+        rng = jax.random.PRNGKey(0)
+        return (jax.random.normal(rng, (global_batch, side, side, 3),
+                                  jnp.float32),
+                jax.random.randint(rng, (global_batch,), 0, num_classes),
+                model.init(jax.random.PRNGKey(1),
+                           jnp.zeros((2, side, side, 3), jnp.float32)))
+
+    return _init_on_mesh(make, mesh, P(axis_name), P(axis_name), P())
+
+
+def synthesize_lm_job(model, mesh, global_batch: int, seq_len: int,
+                      axis_name: str = "data"):
+    """``(tokens, variables)`` for ``make_lm_train_step``: one fixed batch
+    of random tokens split on ``axis_name`` and ``model``'s variables
+    replicated. Parameter shapes depend on neither the attention backend
+    nor the sequence length, so the init runs dense attention on a short
+    input."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    def make():
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(0), (global_batch, seq_len), 0,
+            model.vocab_size, dtype=jnp.int32)
+        return tokens, model.clone(attention="dense").init(
+            jax.random.PRNGKey(1), jnp.zeros((2, 8), jnp.int32))
+
+    return _init_on_mesh(make, mesh, P(axis_name), P())
 
 
 def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
@@ -17,9 +69,10 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
     """Build the jitted DP train step over ``mesh``'s ``axis_name``.
 
     Returns ``step(params, opt_state, batch_stats, x, y) -> (params,
-    opt_state, batch_stats)`` with x/y sharded on the data axis and
-    everything else replicated. Models without BatchNorm pass
-    ``batch_stats={}`` through unchanged.
+    opt_state, batch_stats, loss)`` with x/y sharded on the data axis and
+    everything else replicated; ``loss`` is the cross-replica mean of the
+    loss the update was computed from (the last batch's, when scanning).
+    Models without BatchNorm pass ``batch_stats={}`` through unchanged.
 
     ``scan_batches > 1`` wraps the step body in ``lax.scan`` so ONE
     dispatched call executes N batches back to back on device (same
@@ -65,29 +118,62 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
         return loss, updated.get("batch_stats", {})
 
     def train_step(params, opt_state, batch_stats, x, y):
-        (_, new_stats), grads = jax.value_and_grad(
+        (loss, new_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch_stats, x, y)
         updates, opt_state = opt.update(grads, opt_state, params)
         # cross-replica BN statistics averaging (per-replica stats would be
         # rank-varying; the reference averages metrics the same way)
         new_stats = jax.tree_util.tree_map(
             lambda s: jax.lax.pmean(s, axis_name), new_stats)
-        return optax.apply_updates(params, updates), opt_state, new_stats
+        return (optax.apply_updates(params, updates), opt_state, new_stats,
+                jax.lax.pmean(loss, axis_name))
 
     if scan_batches > 1:
         single = train_step
 
         def train_step(params, opt_state, batch_stats, x, y):  # noqa: F811
             def body(carry, _):
-                return single(*carry, x, y), None
+                *carry, loss = single(*carry, x, y)
+                return tuple(carry), loss
 
-            carry, _ = jax.lax.scan(body, (params, opt_state, batch_stats),
-                                    None, length=scan_batches)
-            return carry
+            carry, losses = jax.lax.scan(
+                body, (params, opt_state, batch_stats), None,
+                length=scan_batches)
+            return (*carry, losses[-1])
 
     return jax.jit(
         shard_map(train_step, mesh=mesh,
                   in_specs=(P(), P(), P(), P(axis_name), P(axis_name)),
-                  out_specs=(P(), P(), P()),
+                  out_specs=(P(), P(), P(), P()),
                   check_vma=not (hierarchical or explicit_grad_reduce)),
         donate_argnums=(0, 1, 2) if donate else ())
+
+
+def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
+    """Build the jitted DP language-model train step over ``mesh``.
+
+    Returns ``step(params, opt_state, tokens) -> (params, opt_state,
+    loss)`` with tokens ``[global_batch, seq]`` sharded on the data axis,
+    params and optimizer state replicated and donated, and ``loss`` the
+    cross-replica mean next-token cross entropy."""
+    import jax
+    import optax
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.models import lm_loss
+
+    def train_step(params, opt_state, tokens):
+        def loss_fn(p):
+            return lm_loss(model.apply({"params": p}, tokens), tokens)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, axis_name))
+
+    return jax.jit(
+        shard_map(train_step, mesh=mesh,
+                  in_specs=(P(), P(), P(axis_name)),
+                  out_specs=(P(), P(), P())),
+        donate_argnums=(0, 1))

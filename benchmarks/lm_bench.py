@@ -14,12 +14,15 @@ donated buffers, AOT-compiled).
 
 Defaults are GPT-2-small-shaped (12 layers, 12 heads, d_model 768,
 d_ff 3072, seq 1024, vocab 32768) with the Pallas flash-attention kernel
-(``--attention dense`` for the XLA-fused baseline; the kernel
-auto-interprets off-TPU so CPU CI drives the identical code path).
+(``--attention dense`` for the XLA-fused baseline; the kernel runs in the
+Pallas interpreter on the CPU backend so CPU CI drives the identical code
+path).
 
-Prints ONE JSON line like bench.py, metric
-``transformer_lm_tokens_per_sec_per_device`` (vs_baseline null — the
-reference publishes no LM figure).
+One process, on the device it measures, like bench.py: it finds a TPU or
+exits non-zero unless ``HOROVOD_BENCH_PLATFORM=cpu`` asks for a CPU run.
+Prints ONE JSON line, metric ``transformer_lm_tokens_per_sec_per_device``
+(vs_baseline null — the reference publishes no LM figure), stamped with
+``platform`` / ``device_kind`` / ``n_devices``.
 """
 
 from __future__ import annotations
@@ -53,132 +56,60 @@ def _parse_args(argv=None):
     parser.add_argument("--num-warmup-batches", type=int, default=10)
     parser.add_argument("--num-batches-per-iter", type=int, default=10)
     parser.add_argument("--num-iters", type=int, default=10)
-    parser.add_argument("--warm-init-cache", action="store_true",
-                        default=False,
-                        help="build this config's host-init cache entry "
-                             "on CPU and exit before any accelerator "
-                             "contact (see bench.py --warm-init-cache)")
-    parser.add_argument("--warm-devices", type=int, default=1,
-                        help="device count the warmed entry targets "
-                             "(see bench.py --warm-devices)")
     return parser.parse_args(argv)
-
-
-def _init_cache_path(args, global_batch) -> str:
-    """Host-init cache entry for this LM config (shared policy:
-    ``core.platform.init_cache_path``; this file is hashed in).
-    Deliberately NOT keyed by ``--attention``/``--remat``: params come
-    from a dense-clone init and tokens depend only on (batch, seq,
-    vocab), so flash and dense share one entry."""
-    from horovod_tpu.core.platform import init_cache_path
-
-    cfg = (f"lm_{args.num_layers}x{args.num_heads}_d{args.d_model}"
-           f"_ff{args.d_ff}_v{args.vocab_size}_s{args.seq_len}"
-           f"_gb{global_batch}")
-    return init_cache_path(cfg, extra_sources=[os.path.abspath(__file__)])
 
 
 def main() -> None:
     args = _parse_args()
 
-    if args.warm_init_cache:
-        os.environ.setdefault("HOROVOD_BENCH_PLATFORM", "cpu")
-
-    import jax
-
-    platform_pin = os.environ.get("HOROVOD_BENCH_PLATFORM")
-    if platform_pin:
-        jax.config.update("jax_platforms", platform_pin)
     from bench import (
         _add_mfu_fields,
+        _bench_device,
+        _device_stamp,
         _git_head as _git_sha,
         _log as log,
         _maybe_dump_hlo,
         _maybe_profile_one_batch,
-        _setup_accelerator_cache,
         _step_flops_of,
     )
 
-    _setup_accelerator_cache(jax)
-    import jax.numpy as jnp
+    device = _bench_device()
+    import jax
     import optax
 
-    import horovod_tpu as hvd  # first: installs the jax compat aliases
+    import horovod_tpu as hvd
+    from benchmarks._dp_step import make_lm_train_step, synthesize_lm_job
+    from horovod_tpu.core.platform import setup_compile_cache
+    from horovod_tpu.models import TransformerLM
 
-    from jax import shard_map
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from horovod_tpu.core.platform import host_init_cached, init_on_host_cpu
-    from horovod_tpu.models import TransformerLM, lm_loss
-
+    setup_compile_cache()
     hvd.init()
-    n_dev = hvd.local_device_count()
     mesh = hvd.parallel.data_parallel_mesh()
+    n_dev = mesh.size
     log(f"TransformerLM: {args.num_layers}L/{args.num_heads}H/"
         f"d{args.d_model}/ff{args.d_ff}, vocab {args.vocab_size}, "
         f"seq {args.seq_len}, batch {args.batch_size}/device, "
         f"attention={args.attention}, devices: {n_dev} "
-        f"({jax.devices()[0].platform})")
+        f"({device.platform}, {device.device_kind})")
 
     model = TransformerLM(
         vocab_size=args.vocab_size, num_layers=args.num_layers,
         num_heads=args.num_heads, d_model=args.d_model, d_ff=args.d_ff,
         max_seq_len=args.seq_len, attention=args.attention,
         remat=args.remat)
-    # see bench.py: warm mode sizes arrays for the --warm-devices target
-    # topology, not the host backend it happens to run on
-    global_batch = args.batch_size * (args.warm_devices
-                                      if args.warm_init_cache else n_dev)
+    global_batch = args.batch_size * n_dev
 
-    def synthesize_and_init():
-        rng = jax.random.PRNGKey(0)
-        tokens = jax.random.randint(
-            rng, (global_batch, args.seq_len), 0, args.vocab_size,
-            dtype=jnp.int32)
-        # init with dense attention on tiny tokens: the pallas kernel's
-        # shapes are irrelevant to parameter shapes, and interpreting it
-        # on the host init backend would be minutes of wasted work
-        init_model = model.clone(attention="dense")
-        variables = init_model.init(jax.random.PRNGKey(1), tokens[:2, :8])
-        return tokens, variables
-
-    cache_path = _init_cache_path(args, global_batch)
-    if args.warm_init_cache:
-        host_init_cached(cache_path, synthesize_and_init, log=log)
-        log("init cache warmed; exiting without accelerator contact")
-        return
-
-    placed = init_on_host_cpu(
-        lambda: host_init_cached(cache_path, synthesize_and_init, log=log),
-        (NamedSharding(mesh, P("data")), NamedSharding(mesh, P())),
-        log=log)
-    if placed is not None:
-        tokens, variables = placed
-    else:
-        log("host-CPU init/placement unavailable (see warning above); "
-            "initializing on device")
-        tokens, variables = synthesize_and_init()
+    # synthetic tokens + model init, on the mesh the step will use
+    tokens, variables = synthesize_lm_job(model, mesh, global_batch,
+                                          args.seq_len)
     params = variables["params"]
     log("model initialized")
 
     opt = hvd.DistributedOptimizer(
         optax.adamw(3e-4, weight_decay=0.01), axis_name="data")
-    opt_state = opt.init(params)
+    opt_state = jax.jit(opt.init)(params)
     params = hvd.broadcast_parameters(params, root_rank=0)
-
-    def train_step(params, opt_state, tokens):
-        def f(p):
-            return lm_loss(model.apply({"params": p}, tokens), tokens)
-
-        loss, grads = jax.value_and_grad(f)(params)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return (optax.apply_updates(params, updates), opt_state,
-                jax.lax.pmean(loss, "data"))
-
-    step = jax.jit(
-        shard_map(train_step, mesh=mesh,
-                  in_specs=(P(), P(), P("data")),
-                  out_specs=(P(), P(), P())),
-        donate_argnums=(0, 1))
+    step = make_lm_train_step(model, opt, mesh, axis_name="data")
 
     log("Compiling LM train step (AOT)...")
     compiled = step.lower(params, opt_state, tokens).compile()
@@ -222,17 +153,15 @@ def main() -> None:
         "value": round(per_device, 1),
         "unit": "tokens/s",
         "vs_baseline": None,  # the reference publishes no LM figure
-        "live": True,
         "attention": args.attention,
         "seq_len": args.seq_len,
         "batch_size": args.batch_size,
-        "n_devices": n_dev,
-        "captured_at": round(time.time(), 1),
+        **_device_stamp(device, n_dev),
         "git_sha": _git_sha(),
     }
     # steps/s, not tokens/s: step_flops is the whole per-device step
     _add_mfu_fields(result, step_flops, mean / tokens_per_batch,
-                    jax.devices()[0], log)
+                    device, log)
     print(json.dumps(result))
     hvd.shutdown()
 

@@ -51,7 +51,9 @@ def _measure() -> None:
 
     import horovod_tpu as hvd
     from benchmarks._dp_step import make_dp_train_step
+    from horovod_tpu.core.platform import setup_compile_cache
 
+    setup_compile_cache()
     model_name = os.environ["SCALING_MODEL"]
     batch = int(os.environ["SCALING_BATCH"])
     iters = int(os.environ["SCALING_ITERS"])
@@ -95,15 +97,15 @@ def _measure() -> None:
     step = make_dp_train_step(model, opt, mesh, axis_name="data")
 
     for _ in range(2):  # warmup / compile
-        params, opt_state, batch_stats = step(params, opt_state,
-                                              batch_stats, x, y)
+        params, opt_state, batch_stats, _ = step(params, opt_state,
+                                                 batch_stats, x, y)
     jax.block_until_ready(params)
     rates = []
     for _ in range(iters):
         t0 = time.perf_counter()
         for _ in range(bpi):
-            params, opt_state, batch_stats = step(params, opt_state,
-                                                  batch_stats, x, y)
+            params, opt_state, batch_stats, _ = step(
+                params, opt_state, batch_stats, x, y)
         jax.block_until_ready(params)
         rates.append(global_batch * bpi / (time.perf_counter() - t0))
     print(json.dumps({"devices": n, "img_per_s": float(np.mean(rates))}))

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import horovod_tpu.core.jax_compat  # noqa: F401 - jax.shard_map shim on older JAX
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 

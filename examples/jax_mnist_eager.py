@@ -60,6 +60,7 @@ def main() -> None:
     compression = hvd.Compression.lookup(args.compression)
 
     hvd.init()
+    print(f"rank {hvd.rank()}/{hvd.size()} on {jax.devices()}", flush=True)
 
     model = MnistCNN()
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1)))

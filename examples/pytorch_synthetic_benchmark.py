@@ -84,8 +84,7 @@ def main() -> None:
     parser.add_argument("--num-iters", type=int, default=3)
     parser.add_argument("--json", action="store_true",
                         help="emit one self-describing JSON result line "
-                             "(the bench.py capture protocol) so the chip "
-                             "watcher can record this run with provenance")
+                             "(the bench.py protocol)")
     args = parser.parse_args()
 
     hvd.init()
@@ -133,10 +132,10 @@ def main() -> None:
     log(f"Total img/sec on {hvd.size()} rank(s): "
         f"{mean * hvd.size():.1f} +- {conf * hvd.size():.1f}")
     if args.json and hvd.rank() == 0:
-        # Same self-describing capture line as bench.py: the watcher files
-        # this under torch_synthetic.json; model compute is torch-CPU (torch
-        # has no TPU backend in this image) — what the entry measures is the
-        # eager hook→engine→data-plane path, so the plane is stamped in.
+        # Same self-describing result line as bench.py; model compute is
+        # torch-CPU (torch has no TPU backend in this image) — what the
+        # entry measures is the eager hook→engine→data-plane path, so the
+        # plane is stamped in.
         import json
 
         from horovod_tpu.core.provenance import git_head_sha
@@ -147,13 +146,11 @@ def main() -> None:
             "value": round(float(mean), 2),
             "unit": "img/s",
             "vs_baseline": None,
-            "live": True,
             "front_end": "torch",
             "data_plane": os.environ.get("HOROVOD_DATA_PLANE", "auto"),
             "batch_size": args.batch_size,
             "image_size": args.image_size,
             "n_ranks": hvd.size(),
-            "captured_at": round(time.time(), 1),
             "git_sha": sha,
         }), flush=True)
     hvd.shutdown()

@@ -22,19 +22,10 @@ Typical use, mirroring the reference README:
 
 import os as _os
 
-# Bridge JAX API drift (jax.shard_map / check_vma / lax.axis_size on
-# older pinned releases) before anything — including test modules that do
-# `from jax import shard_map` after importing this package — touches jax.
-from .core import jax_compat as _jax_compat
-
-_jax_compat.install()
-del _jax_compat
-
-# HOROVOD_PLATFORM: pin the JAX platform before ANY backend starts (the
-# env var JAX_PLATFORMS alone is insufficient on TPU images whose plugin
-# prepends itself to the list). Applied at import so launcher-spawned
-# workers — which import this package before their first device query —
-# are steered without code changes; see docs/running.md.
+# HOROVOD_PLATFORM: pin the JAX platform before ANY backend starts.
+# Applied at import so launcher-spawned workers — which import this
+# package before their first device query — are steered without code
+# changes; see docs/running.md.
 from .core import config as _config
 
 _platform = _os.environ.get(_config.HOROVOD_PLATFORM)
@@ -42,19 +33,12 @@ if _platform:
     import jax as _jax
 
     _jax.config.update("jax_platforms", _platform)
-    try:  # diagnose the one case the pin cannot fix: a live backend.
-        # backends_are_initialized() is the purpose-built passive query
-        # (jax.config itself uses it to validate late config changes);
-        # there is no fully-public equivalent that doesn't itself
-        # initialize a backend.
-        _live = _jax._src.xla_bridge.backends_are_initialized()
-    except Exception as _exc:  # noqa: BLE001 - probe moved in a future JAX
-        from .core.logging import LOG as _LOG
-
-        _LOG.debug("HOROVOD_PLATFORM late-backend probe unavailable "
-                   f"({_exc!r}); cannot warn if the pin came too late")
-        del _LOG
-        _live = False
+    # Diagnose the one case the pin cannot fix: a live backend.
+    # backends_are_initialized() is the purpose-built passive query
+    # (jax.config itself uses it to validate late config changes); there
+    # is no fully-public equivalent that doesn't itself initialize a
+    # backend.
+    _live = _jax._src.xla_bridge.backends_are_initialized()
     if _live:
         import warnings as _warnings
 

@@ -4,8 +4,9 @@ The reference binds C++ to Python through per-framework FFI (TF custom op
 loading, torch pybind11/cffi, mxnet ctypes — SURVEY L2/L3). This build has
 one framework-agnostic shared library and one binding mechanism: ctypes on
 an ``extern "C"`` API (pybind11 is not in the image, per the environment
-contract). The library is rebuilt on demand when sources are newer than the
-binary — the role setup.py's extension builders play in the reference.
+contract). The library is rebuilt on demand when the digest of its sources
+differs from the one stored beside the binary — the role setup.py's
+extension builders play in the reference.
 
 Exports:
 * ``NativeNegotiator`` — drop-in for ``ops.controller.Negotiator``
@@ -17,6 +18,7 @@ Exports:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import subprocess
@@ -26,6 +28,7 @@ from typing import List, Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "build", "libhtpu_core.so")
+_DIGEST_PATH = _LIB_PATH + ".sources.sha256"
 _SOURCES = ("negotiator.cc", "autotune.cc", "timeline_writer.cc",
             "controller_service.cc", "negotiator_core.h", "sha256.h",
             "Makefile")
@@ -47,19 +50,36 @@ def _build_locked() -> None:
         fcntl.flock(lock_fh, fcntl.LOCK_EX)
         try:
             if _needs_build():
-                subprocess.run(["make", "-C", _DIR], check=True,
+                # -B: make compares mtimes, which say nothing about a
+                # library that arrived with a copy of the tree
+                subprocess.run(["make", "-B", "-C", _DIR], check=True,
                                capture_output=True, text=True, timeout=120)
+                with open(_DIGEST_PATH, "w", encoding="utf-8") as fh:
+                    fh.write(_sources_digest())
         finally:
             fcntl.flock(lock_fh, fcntl.LOCK_UN)
 
 
+def _sources_digest() -> str:
+    digest = hashlib.sha256()
+    for src in _SOURCES:
+        digest.update(src.encode())
+        with open(os.path.join(_DIR, src), "rb") as fh:
+            digest.update(fh.read())
+    return digest.hexdigest()
+
+
 def _needs_build() -> bool:
+    """True unless the library was built from exactly these sources. Keyed
+    on content, not mtimes: a ``build/`` directory carried along with a
+    copy of the tree has arbitrary timestamps."""
     if not os.path.exists(_LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, src)) > lib_mtime
-        for src in _SOURCES if os.path.exists(os.path.join(_DIR, src)))
+    try:
+        with open(_DIGEST_PATH, encoding="utf-8") as fh:
+            return fh.read() != _sources_digest()
+    except FileNotFoundError:
+        return True
 
 
 def _load():

@@ -144,12 +144,12 @@ HOROVOD_SUBCOORD_PORT = "HOROVOD_SUBCOORD_PORT"
 HOROVOD_SUBCOORD_FD = "HOROVOD_SUBCOORD_FD"
 HOROVOD_SECRET_KEY = "HOROVOD_SECRET_KEY"
 HOROVOD_START_TIMEOUT = "HOROVOD_START_TIMEOUT"
-# Force the JAX platform ("cpu", "tpu", ...) before any backend starts.
-# An env var (JAX_PLATFORMS) is NOT enough on TPU images whose plugin
-# prepends itself to the platform list, so ``import horovod_tpu`` applies
-# this via jax.config. The debug analog of the reference running an MPI
-# job with CUDA_VISIBLE_DEVICES= hidden: the same launcher command line
-# can be steered onto CPU for debugging (docs/running.md).
+# Force the JAX platform ("cpu", "tpu", ...) before any backend starts:
+# ``import horovod_tpu`` applies it via jax.config, which also works after
+# jax itself was imported (JAX_PLATFORMS is only read at that import). The
+# debug analog of the reference running an MPI job with
+# CUDA_VISIBLE_DEVICES= hidden: the same launcher command line can be
+# steered onto CPU for debugging (docs/running.md).
 HOROVOD_PLATFORM = "HOROVOD_PLATFORM"
 # Launcher: set to "0" to stop the launcher from pinning one TPU chip per
 # local rank (TPU_VISIBLE_DEVICES et al.) when a host runs several slots.
@@ -462,9 +462,6 @@ HOROVOD_NATIVE_CONTROLLER = "HOROVOD_NATIVE_CONTROLLER"
 # Interface the rank-0 controller service binds (default loopback);
 # multi-host worlds set the DCN-reachable address (docs/running.md).
 HOROVOD_CONTROLLER_BIND = "HOROVOD_CONTROLLER_BIND"
-# bench.py warm-init cache (docs/benchmarks.md): "0" disables, unset/"1"
-# the default repo-local directory, anything else a custom directory.
-HOROVOD_BENCH_INIT_CACHE = "HOROVOD_BENCH_INIT_CACHE"
 # Runtime lock witness (docs/analysis.md): "1" wraps the engine's /
 # controller's / registry's locks so tests record the ACTUAL acquisition
 # order into a global held-before graph and raise LockInversionError on

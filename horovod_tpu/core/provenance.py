@@ -1,7 +1,7 @@
-"""Capture-provenance helpers shared by ``bench.py`` and the example
-benchmarks: every self-describing measurement line stamps the revision it
-was measured on, so the wedge-fallback path can tell (and report) when a
-capture predates perf-relevant commits — a time bound alone cannot.
+"""Provenance helpers shared by ``bench.py`` and the other benchmarks:
+every result line stamps the revision it was measured on (``None`` where
+the tree is not a git checkout), and a parent that relays a child's
+one-line result parses it tolerantly.
 """
 
 from __future__ import annotations

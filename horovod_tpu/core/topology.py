@@ -147,12 +147,13 @@ def _discover_full(use_jax: bool = True) -> Topology:
         cross_rank = int(env.get(_config.HOROVOD_CROSS_RANK, rank // max(local_size, 1)))
         cross_size = int(env.get(_config.HOROVOD_CROSS_SIZE, size // max(local_size, 1)))
         if use_jax and env.get(_config.HOROVOD_DATA_PLANE) != "host":
-            local_devices = _local_devices_safe()
+            import jax
+
+            local_devices = jax.local_device_count()
         else:
             # Host-plane worlds (numpy-over-TCP; the torch/TF front-ends'
             # CPU deployment) never touch accelerators: one rank == one
-            # device, and querying JAX here would needlessly initialize —
-            # and on a machine with a wedged/slow TPU plugin, hang — a
+            # device, and querying JAX here would needlessly initialize a
             # backend the job will not use.
             local_devices = 1
         return Topology(
@@ -184,12 +185,3 @@ def _discover_full(use_jax: bool = True) -> Topology:
         cross_size=1, local_device_count=1, global_device_count=1,
         hostname=hostname,
     )
-
-
-def _local_devices_safe() -> int:
-    try:
-        import jax
-
-        return jax.local_device_count()
-    except Exception:  # pragma: no cover - jax missing/broken
-        return 1

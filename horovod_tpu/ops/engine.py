@@ -566,6 +566,17 @@ class Engine:
                 from .xla_plane import XlaDataPlane
 
                 self._plane = XlaDataPlane(topo)
+            if topo.world_rank == 0:
+                # at default verbosity: which plane carries the bytes is
+                # the first thing to know about a multi-rank run, and
+                # "auto" decides it from the JAX process world
+                LOG.warning(
+                    "eager data plane for %d ranks: %s "
+                    "(HOROVOD_DATA_PLANE=%s)", self._size,
+                    "xla — compiled collectives over the device mesh"
+                    if self._plane is not None else
+                    "host — numpy buffers over the controller's TCP wire",
+                    cfg.data_plane)
             secret = default_secret()
             port = int(os.environ.get(_config.HOROVOD_CONTROLLER_PORT, "0"))
             addr = os.environ.get(_config.HOROVOD_CONTROLLER_ADDR, "127.0.0.1")

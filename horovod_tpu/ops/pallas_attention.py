@@ -21,8 +21,18 @@ saves only O(T) per-row logsumexp statistics, and two further kernels
 recompute P = exp(S - lse) blockwise to produce dQ and dK/dV without ever
 materializing the [T, T] matrix.
 
-``interpret=True`` (automatic off-TPU) runs the same kernels through the
-Pallas interpreter, which is how the CPU test suite validates them.
+Per-row statistics (logsumexp, delta) cross the kernel boundary in
+layouts whose last two block dims tile on the TPU: a trailing 128-lane
+axis (``[B*H, T, 128]``, value repeated along lanes) where a kernel needs
+them as a column against ``[block_q, block_k]`` scores, and a lane-dense
+row (``[B*H, 1, T]``) where the dK/dV kernel works on transposed scores.
+That kernel computes ``S^T = K Q^T`` directly, so every matmul in this
+file is a plain or transposed-RHS product — none contracts dim 0 of its
+left operand.
+
+``interpret=True`` (automatic on the CPU backend only) runs the same
+kernels through the Pallas interpreter, which is how the CPU test suite
+validates them. On any other platform the kernels compile through Mosaic.
 """
 
 from __future__ import annotations
@@ -37,15 +47,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
+_LANES = 128  # TPU vreg lane count: the trailing axis of column statistics
 
 
-def _causal_mask(s, q_pos0, k_pos0, block_q, block_k):
-    """Mask future positions of a [block_q, block_k] score block to the
-    _NEG_INF sentinel. Shared by forward and backward so the two can never
-    disagree on what was masked."""
-    q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+def _causal_mask(s, q_pos0, k_pos0, q_axis=0):
+    """Mask future positions of a score block to the _NEG_INF sentinel.
+    q positions run along ``q_axis`` of ``s`` and k positions along the
+    other axis (``q_axis=1`` is the dK/dV kernel's transposed block).
+    Shared by forward and backward so the two can never disagree on what
+    was masked."""
+    q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _col(stat):
+    """[rows, 128] lane-repeated statistic -> its [rows, 1] column."""
+    return stat[:, :1]
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
@@ -71,15 +89,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
             preferred_element_type=jnp.float32)
         if causal:
             s = _causal_mask(s, (q_idx + q_offset_blocks) * block_q,
-                             kk * block_k, block_q, block_k)
-        m = m_acc[...]
+                             kk * block_k)
+        m = _col(m_acc[...])
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - m_new))
         p = jnp.exp(s - m_new)
         if causal:
             p = jnp.where(m_new == _NEG_INF, 0.0, p)
-        l_acc[...] = l_acc[...] * corr + p.sum(axis=1, keepdims=True)
-        m_acc[...] = m_new
+        l_new = _col(l_acc[...]) * corr + p.sum(axis=1, keepdims=True)
+        l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+        m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
         o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
             p, v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -96,28 +115,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
 
     @pl.when(kk == num_k_blocks - 1)
     def _finalize():
-        l = l_acc[...]
-        o_ref[0, ...] = (o_acc[...] /
-                         jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        l = jnp.maximum(l_acc[...], 1e-30)
+        o_ref[0, ...] = (o_acc[...] / _col(l)).astype(o_ref.dtype)
         # per-row logsumexp residual for the backward pass; fully-masked
         # rows stay at the _NEG_INF sentinel (m saturates f32 addition)
-        lse_ref[0, ...] = (m_acc[...] +
-                           jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        lse_ref[0, ...] = m_acc[...] + jnp.log(l)
 
 
-def _recompute_p(q_blk, k_blk, lse_col, *, scale, causal, q_pos0, k_pos0,
-                 block_q, block_k):
+def _recompute_p(q_blk, k_blk, lse, *, scale, causal, q_pos0, k_pos0,
+                 transposed=False):
     """Recompute the normalized probability block P = exp(S - lse) and S's
-    mask; shared by both backward kernels. All f32, MXU matmul."""
+    mask; shared by both backward kernels. All f32, MXU matmul.
+
+    ``transposed=False``: P is [block_q, block_k] and ``lse`` its
+    [block_q, 1] column. ``transposed=True``: P^T is [block_k, block_q],
+    computed directly as K Q^T, and ``lse`` its [1, block_q] row."""
+    lhs, rhs = (k_blk, q_blk * scale) if transposed else (q_blk * scale,
+                                                          k_blk)
     s = jax.lax.dot_general(
-        q_blk * scale, k_blk, (((1,), (1,)), ((), ())),
+        lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if causal:
-        s = _causal_mask(s, q_pos0, k_pos0, block_q, block_k)
+        s = _causal_mask(s, q_pos0, k_pos0, q_axis=1 if transposed else 0)
     # fully-masked rows have lse at the sentinel; exp(s - sentinel) would
     # be exp(0) = 1 for masked s, so zero those rows explicitly
-    p = jnp.exp(s - lse_col)
-    return jnp.where(lse_col <= _NEG_INF / 2, 0.0, p)
+    p = jnp.exp(s - lse)
+    return jnp.where(lse <= _NEG_INF / 2, 0.0, p)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -138,16 +161,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k_blk = k_ref[0].astype(jnp.float32)
         v_blk = v_ref[0].astype(jnp.float32)
         do_blk = do_ref[0].astype(jnp.float32)
-        lse_col = lse_ref[0][:, None]
-        delta_col = delta_ref[0][:, None]
         p = _recompute_p(
-            q_blk, k_blk, lse_col, scale=scale, causal=causal,
-            q_pos0=(q_idx + q_offset_blocks) * block_q, k_pos0=kk * block_k,
-            block_q=block_q, block_k=block_k)
+            q_blk, k_blk, _col(lse_ref[0]), scale=scale, causal=causal,
+            q_pos0=(q_idx + q_offset_blocks) * block_q, k_pos0=kk * block_k)
         dp = jax.lax.dot_general(  # dO V^T  [block_q, block_k]
             do_blk, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_col) * scale
+        ds = p * (dp - _col(delta_ref[0])) * scale
         dq_acc[...] += jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -171,7 +191,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     causal: bool, q_offset_blocks: int, num_q_blocks: int,
                     block_q: int, block_k: int):
     """dV = P^T dO and dK = (P * (dP - delta))^T Q, accumulated over q
-    blocks. Grid (bh, k-block, q-block), q innermost sequential."""
+    blocks. Grid (bh, k-block, q-block), q innermost sequential. Works on
+    the transposed blocks P^T, dP^T = V dO^T, dS^T throughout, with lse and
+    delta as [1, block_q] rows, so no operand is ever transposed."""
     iq = pl.program_id(2)
     k_idx = pl.program_id(1)
 
@@ -185,21 +207,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k_blk = k_ref[0].astype(jnp.float32)
         v_blk = v_ref[0].astype(jnp.float32)
         do_blk = do_ref[0].astype(jnp.float32)
-        lse_col = lse_ref[0][:, None]
-        delta_col = delta_ref[0][:, None]
-        p = _recompute_p(
-            q_blk, k_blk, lse_col, scale=scale, causal=causal,
+        p_t = _recompute_p(
+            q_blk, k_blk, lse_ref[0], scale=scale, causal=causal,
             q_pos0=(iq + q_offset_blocks) * block_q, k_pos0=k_idx * block_k,
-            block_q=block_q, block_k=block_k)
+            transposed=True)
         dv_acc[...] += jax.lax.dot_general(  # P^T dO  [block_k, d]
-            p, do_blk, (((0,), (0,)), ((), ())),
+            p_t, do_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
+        dp_t = jax.lax.dot_general(  # V dO^T  [block_k, block_q]
+            v_blk, do_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_col) * scale
+        ds_t = p_t * (dp_t - delta_ref[0]) * scale
         dk_acc[...] += jax.lax.dot_general(  # dS^T Q  [block_k, d]
-            ds, q_blk, (((0,), (0,)), ((), ())),
+            ds_t, q_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
@@ -229,42 +249,19 @@ def _from_bh(x, batch, heads):
     return x.reshape(batch, heads, seq, head_dim).transpose(0, 2, 1, 3)
 
 
-_warned_vma_kwarg_missing = False
-
-
 def _sds(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of ``like`` operands' vma type.
 
     Inside a vma-tracking ``shard_map`` (check_vma=True, the default),
     ``pallas_call`` outputs must declare how they vary over mesh axes —
     a kernel output varies exactly as much as its operands do. Outside
-    shard_map (or on JAX versions without vma) fall back to the plain
-    struct."""
+    shard_map the plain struct is returned."""
     from .spmd import operand_vma
 
     vma = operand_vma(*like)
     if vma is None:
         return jax.ShapeDtypeStruct(shape, dtype)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        # jax.typeof reports vma but ShapeDtypeStruct lacks the kwarg: a
-        # JAX-version mismatch. The dropped vma will surface later as an
-        # opaque check_vma error inside shard_map — name the cause here so
-        # that error is attributable. Once per process, not per out-shape:
-        # every fwd+bwd trace builds several structs.
-        global _warned_vma_kwarg_missing
-        if not _warned_vma_kwarg_missing:
-            _warned_vma_kwarg_missing = True
-            from ..core.logging import LOG
-
-            LOG.warning(
-                "this JAX version (%s) tracks vma types but "
-                "jax.ShapeDtypeStruct does not accept a vma= kwarg; "
-                "dropping the vma annotation on pallas_call out-shapes. "
-                "If a downstream shard_map(check_vma=True) error mentions "
-                "vma, this version mismatch is the cause.", jax.__version__)
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
@@ -288,22 +285,24 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
         out_specs=[
             pl.BlockSpec((1, block_q, head_dim),
                          lambda bh, i, kk: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, i, kk: (bh, i)),
+            pl.BlockSpec((1, block_q, _LANES), lambda bh, i, kk: (bh, i, 0)),
         ],
         out_shape=[
             _sds((batch * heads, seq_q, head_dim), q.dtype, q, k, v),
-            _sds((batch * heads, seq_q), jnp.float32, q, k, v),
+            _sds((batch * heads, seq_q, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb)
-    return _from_bh(o, batch, heads), lse
+    # the kernel repeats each row's value along the lane axis; the residual
+    # kept for the backward pass is the O(T) vector
+    return _from_bh(o, batch, heads), lse[..., 0]
 
 
 def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
@@ -322,7 +321,14 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                               lambda bh, i, kk: (bh, i, 0))
     qkv_spec_k = pl.BlockSpec((1, block_k, head_dim),
                               lambda bh, i, kk: (bh, kk, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh, i, kk: (bh, i))
+    # per-row statistics in the two layouts the kernels read (module
+    # docstring): lane-repeated columns for dQ, lane-dense rows for dK/dV
+    lse_cols, delta_cols = (
+        jnp.broadcast_to(x[..., None], (*x.shape, _LANES))
+        for x in (lse, delta))
+    lse_rows, delta_rows = lse[:, None, :], delta[:, None, :]
+    col_spec = pl.BlockSpec((1, block_q, _LANES),
+                            lambda bh, i, kk: (bh, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(
@@ -331,7 +337,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             block_q=block_q, block_k=block_k),
         grid=(batch * heads, num_q_blocks, num_k_blocks),
         in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
-                  row_spec, row_spec],
+                  col_spec, col_spec],
         out_specs=qkv_spec_q,
         out_shape=_sds((batch * heads, seq_q, head_dim), q.dtype,
                        q, k, v, do),
@@ -339,7 +345,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qb, kb, vb, dob, lse, delta)
+    )(qb, kb, vb, dob, lse_cols, delta_cols)
 
     # dK/dV grid: (bh, k-block, q-block) — the q dimension is innermost so
     # the (1, block_q, d) operands re-index by the LAST grid axis here
@@ -347,7 +353,8 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                              lambda bh, kk, i: (bh, i, 0))
     kv_k_spec = pl.BlockSpec((1, block_k, head_dim),
                              lambda bh, kk, i: (bh, kk, 0))
-    kv_row_spec = pl.BlockSpec((1, block_q), lambda bh, kk, i: (bh, i))
+    kv_row_spec = pl.BlockSpec((1, 1, block_q),
+                               lambda bh, kk, i: (bh, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal,
@@ -366,7 +373,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qb, kb, vb, dob, lse, delta)
+    )(qb, kb, vb, dob, lse_rows, delta_rows)
 
     return (_from_bh(dq, batch, heads), _from_bh(dk, batch, heads),
             _from_bh(dv, batch, heads))
@@ -414,7 +421,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.devices()[0].platform == "cpu"
     seq_q, seq_k = q.shape[1], k.shape[1]
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)

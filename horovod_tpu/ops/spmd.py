@@ -75,8 +75,6 @@ def _maybe_sentry(out, operand, axis_name):
 
 
 def _axis_size(axis_name: AxisName):
-    # lax.axis_size exists on every supported JAX: core.jax_compat
-    # installs it (from the axis-env frame) on releases that predate it
     size = 1
     for a in _axes(axis_name):
         size = size * lax.axis_size(a)

@@ -94,7 +94,22 @@ def build_native_core(out_dir: str) -> str:
     if result.returncode != 0:
         raise RuntimeError(
             f"native core build failed:\n$ {' '.join(cmd)}\n{result.stderr}")
+    # the loader rebuilds unless this digest matches the sources it finds
+    with open(lib + ".sources.sha256", "w", encoding="utf-8") as fh:
+        fh.write(_cc_binding()._sources_digest())
     return lib
+
+
+def _cc_binding():
+    """``horovod_tpu/cc/__init__.py`` loaded by path (stdlib-only at import;
+    the package itself pulls in jax, which a build host need not have)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "_htpu_cc_binding", os.path.join(_CC_DIR, "__init__.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 class BuildNative(Command):

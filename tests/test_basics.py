@@ -70,3 +70,33 @@ def test_init_subset_validation():
     hvd.init(comm=[0])
     assert hvd.rank() == 0 and hvd.size() == 1
     hvd.shutdown()
+
+
+def test_pin_cpu_platform_is_two_settings():
+    """conftest pinned this process: JAX_PLATFORMS=cpu plus the virtual
+    device count — both in the environment, so children inherit them."""
+    import os
+
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=8" in \
+        os.environ["XLA_FLAGS"]
+
+
+def test_launcher_world_does_not_invent_a_device_count(monkeypatch):
+    """A launcher-described rank whose JAX cannot start is an error, not
+    a world with one device per rank."""
+    import jax
+
+    from horovod_tpu.core import topology
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setenv("HOROVOD_RANK", "0")
+    monkeypatch.setenv("HOROVOD_SIZE", "2")
+    monkeypatch.setattr(jax, "local_device_count", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        topology.discover()
+    # the host plane never asks JAX at all
+    monkeypatch.setenv("HOROVOD_DATA_PLANE", "host")
+    assert topology.discover().local_device_count == 1

@@ -138,8 +138,8 @@ def test_int8_dp_step_wire_is_s8(hvd):
                                          axis_name=DATA_AXIS)
     step_p = make_dp_train_step(model, opt_p, mesh, axis_name=DATA_AXIS,
                                 donate=False)
-    pc, _, _ = step_c(params, opt_c.init(params), batch_stats, x, y)
-    pp, _, _ = step_p(params, opt_p.init(params), batch_stats, x, y)
+    pc, *_ = step_c(params, opt_c.init(params), batch_stats, x, y)
+    pp, *_ = step_p(params, opt_p.init(params), batch_stats, x, y)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-2, atol=5e-3), pc, pp)

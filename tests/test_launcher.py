@@ -155,6 +155,12 @@ def test_horovodrun_cli():
         capture_output=True, text=True, timeout=120,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert rc.returncode == 0, rc.stderr
+    # which plane carries the bytes is said once, at default verbosity
+    plane_lines = [line for line in rc.stderr.splitlines()
+                   if "eager data plane for 2 ranks" in line]
+    assert len(plane_lines) == 1, rc.stderr
+    assert "host" in plane_lines[0]
+    assert "HOROVOD_DATA_PLANE=host" in plane_lines[0]
 
 
 def test_parse_hosts():
@@ -349,8 +355,7 @@ def test_cli_example_composition():
     """The documented user flow, end to end: the CLI launcher driving a
     real example across 2 ranks (the exact command in
     examples/pytorch_mnist.py's header), steered onto CPU via
-    HOROVOD_PLATFORM — the knob exists because JAX_PLATFORMS alone cannot
-    keep workers off a TPU plugin that prepends itself to the list."""
+    HOROVOD_PLATFORM."""
     import subprocess
 
     env = dict(os.environ)

@@ -125,19 +125,6 @@ def test_hierarchical_knob_warns_when_all_leaves_presummed(hvd):
     the factored route silently never fires — the user must get a warning
     naming the check_vma=False remedy. Legacy tracing (check_vma=False)
     routes every leaf through the factored path and must stay silent."""
-    if not hasattr(jax, "typeof"):
-        # The warning's TRIGGER is vma tracking pre-summing replicated
-        # cotangents — a JAX without vma value types (jax.typeof; this
-        # image's 0.4.37, where the compat shim also forces
-        # check_rep=False) can never produce it, so asserting the warning
-        # here would test a code path the runtime cannot reach. The
-        # silent legacy half is covered by every hierarchical test in
-        # this file.
-        pytest.skip(
-            "vma tracking does not exist on this JAX (no jax.typeof): "
-            "pre-summed cotangents — the inert-route warning's trigger — "
-            "cannot occur; _vma_tracking_active correctly reports legacy "
-            "tracing and the factored route always fires")
     from jax.sharding import Mesh
 
     from horovod_tpu.core.logging import LOG

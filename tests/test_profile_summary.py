@@ -266,7 +266,7 @@ def test_main_final_line_json_contract(tmp_path, monkeypatch, capsys):
 
 
 def test_bench_table_renders_captures(tmp_path):
-    """tools/bench_table.py turns watcher captures into the docs table."""
+    """tools/bench_table.py turns result lines into the docs table."""
     (tmp_path / "resnet50.json").write_text(json.dumps({
         "metric": "resnet50_synthetic_train_images_per_sec_per_device",
         "value": 1700.0, "unit": "img/s", "vs_baseline": 16.4,

@@ -296,8 +296,8 @@ def test_hierarchical_step_matches_flat_numerically(hvd):
                                   donate=False, hierarchical=hier)
         outs[hier] = step(params, opt_state, batch_stats, x, y)
 
-    flat_p, _, flat_bn = outs[False]
-    hier_p, _, hier_bn = outs[True]
+    flat_p, _, flat_bn, _ = outs[False]
+    hier_p, _, hier_bn, _ = outs[True]
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
@@ -355,8 +355,8 @@ def test_compressed_dp_step_reduces_in_bf16(hvd):
     opt_p = hvd.DistributedOptimizer(optax.sgd(0.01), axis_name=DATA_AXIS)
     step_p = make_dp_train_step(model, opt_p, mesh, axis_name=DATA_AXIS,
                                 donate=False)
-    pc, _, _ = step_c(params, opt_c.init(params), batch_stats, x, y)
-    pp, _, _ = step_p(params, opt_p.init(params), batch_stats, x, y)
+    pc, *_ = step_c(params, opt_c.init(params), batch_stats, x, y)
+    pp, *_ = step_p(params, opt_p.init(params), batch_stats, x, y)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-2, atol=2e-3), pc, pp)

@@ -1,13 +1,13 @@
 #!/usr/bin/env python
-"""Render the measured-results markdown table from watcher captures.
+"""Render the measured-results markdown table from benchmark result lines.
 
-    python tools/bench_table.py bench_results_r4
+    python tools/bench_table.py <results_dir>
 
-Reads every ``*.json`` bench capture in the directory (one JSON line per
-file, as written by ``tools/chip_watch.sh``) and prints the
-docs/benchmarks.md measured table — config, img|tokens/s/device, ±1.96σ
-when present, achieved TFLOP/s, MFU, and vs-reference ratio — so landing
-a capture into the docs is one copy-paste, not hand-transcription.
+Reads every ``*.json`` file in the directory (the last JSON line of each
+is one benchmark's result line, e.g. ``python bench.py > dir/resnet50.json``)
+and prints the docs/benchmarks.md measured table — config,
+img|tokens/s/device, achieved TFLOP/s, MFU, and vs-reference ratio — so
+landing a result in the docs is one copy-paste, not hand-transcription.
 """
 
 from __future__ import annotations
@@ -108,7 +108,9 @@ def _render_hierarchy(rec: dict) -> None:
 
 
 def main() -> None:
-    out_dir = sys.argv[1] if len(sys.argv) > 1 else "bench_results_r5"
+    if len(sys.argv) != 2:
+        sys.exit(__doc__)
+    out_dir = sys.argv[1]
     rows = []
     serving_recs = []
     hier_recs = []
@@ -134,9 +136,8 @@ def main() -> None:
         print(f"(no parseable captures in {out_dir})", file=sys.stderr)
         sys.exit(1)
     if rows:
-        print("| Config | per-device rate | TFLOP/s | MFU | vs reference |"
-              " live |")
-        print("|---|---|---|---|---|---|")
+        print("| Config | per-device rate | TFLOP/s | MFU | vs reference |")
+        print("|---|---|---|---|---|")
     for name, rec in rows:
         unit = rec.get("unit", "")
         tf = rec.get("tflops_per_device")
@@ -145,8 +146,7 @@ def main() -> None:
         print(f"| {_label(rec)} | {rec['value']} {unit} | "
               f"{tf if tf is not None else '—'} | "
               f"{str(mfu) + '%' if mfu is not None else '—'} | "
-              f"{str(vs) + 'x' if vs is not None else '—'} | "
-              f"{'yes' if rec.get('live', True) else 'watcher'} |")
+              f"{str(vs) + 'x' if vs is not None else '—'} |")
     for rec in serving_recs:
         _render_serving(rec)
     for rec in hier_recs:

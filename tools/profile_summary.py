@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """Summarize a captured device profile into a bottleneck attribution.
 
-The round-3 verdict's open question is WHY ResNet-50 bs32 caps at ~11% MFU
-on a v5e chip — the BN/bandwidth-bound hypothesis needs the device profile
+Where ResNet-50 bs32's step time goes on a chip is an open question — the
+BN/bandwidth-bound hypothesis (docs/benchmarks.md) needs the device profile
 (``HOROVOD_BENCH_PROFILE=<dir>`` in bench.py) to confirm or refute it.
 This tool turns that captured XPlane into the answer without TensorBoard:
 
-    python tools/profile_summary.py bench_results_r4/resnet50_profile \
-        [--top 25] [--out bench_results_r4/resnet50_profile_summary.md]
+    python tools/profile_summary.py chiprun_out/resnet50_profile \
+        [--top 25] [--out chiprun_out/resnet50_profile_summary.md]
 
 It extracts xprof's ``hlo_stats`` table (self-time, bound_by, HBM
 bandwidth, FLOP rate per HLO op — populated for TPU traces) with

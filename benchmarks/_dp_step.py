@@ -6,6 +6,12 @@ DistributedOptimizer update, jitted as a shard_map over the data axis) so
 `chip_smoke.py` cannot drift apart — the reference keeps its protocol in
 one script per framework for the same reason
 (``examples/pytorch_synthetic_benchmark.py:37-110``).
+
+Both builders name the phases of the step with ``jax.named_scope`` —
+``hvd.loss`` (forward; its transpose is the backward pass),
+``hvd.apply_updates``, ``hvd.sync_stats`` — beside the ``hvd.exchange`` and
+``hvd.optimizer`` that ``DistributedOptimizer`` brings; the names reach the
+compiled HLO's ``op_name`` and are documented in docs/tracing.md.
 """
 
 from __future__ import annotations
@@ -110,11 +116,12 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
         explicit_grad_reduce = hierarchical
 
     def loss_fn(params, batch_stats, x, y):
-        logits, updated = model.apply(
-            {"params": params, "batch_stats": batch_stats}, x, train=True,
-            mutable=["batch_stats"])
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, y).mean()
+        with jax.named_scope("hvd.loss"):
+            logits, updated = model.apply(
+                {"params": params, "batch_stats": batch_stats}, x,
+                train=True, mutable=["batch_stats"])
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, y).mean()
         return loss, updated.get("batch_stats", {})
 
     def train_step(params, opt_state, batch_stats, x, y):
@@ -123,10 +130,14 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
         updates, opt_state = opt.update(grads, opt_state, params)
         # cross-replica BN statistics averaging (per-replica stats would be
         # rank-varying; the reference averages metrics the same way)
-        new_stats = jax.tree_util.tree_map(
-            lambda s: jax.lax.pmean(s, axis_name), new_stats)
-        return (optax.apply_updates(params, updates), opt_state, new_stats,
-                jax.lax.pmean(loss, axis_name))
+        with jax.named_scope("hvd.sync_stats"):
+            new_stats = jax.tree_util.tree_map(
+                lambda s: jax.lax.pmean(s, axis_name), new_stats)
+        with jax.named_scope("hvd.apply_updates"):
+            params = optax.apply_updates(params, updates)
+        with jax.named_scope("hvd.sync_stats"):
+            loss = jax.lax.pmean(loss, axis_name)
+        return params, opt_state, new_stats, loss
 
     if scan_batches > 1:
         single = train_step
@@ -165,12 +176,16 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
 
     def train_step(params, opt_state, tokens):
         def loss_fn(p):
-            return lm_loss(model.apply({"params": p}, tokens), tokens)
+            with jax.named_scope("hvd.loss"):
+                return lm_loss(model.apply({"params": p}, tokens), tokens)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         updates, opt_state = opt.update(grads, opt_state, params)
-        return (optax.apply_updates(params, updates), opt_state,
-                jax.lax.pmean(loss, axis_name))
+        with jax.named_scope("hvd.apply_updates"):
+            params = optax.apply_updates(params, updates)
+        with jax.named_scope("hvd.sync_stats"):
+            loss = jax.lax.pmean(loss, axis_name)
+        return params, opt_state, loss
 
     return jax.jit(
         shard_map(train_step, mesh=mesh,

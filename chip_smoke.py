@@ -50,7 +50,6 @@ GPT2_SMALL_FLASH = dict(num_layers=12, num_heads=12, d_model=768, d_ff=3072,
                         vocab_size=32768, seq_len=1024, batch_per_device=8,
                         steps=6)
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 
 
@@ -83,21 +82,21 @@ def require_tpu():
 @contextlib.contextmanager
 def count_compiles():
     """Counts the programs JAX hands to the compiler inside the block,
-    persistent-cache hits included (the event wraps the cache lookup)."""
-    from jax import monitoring
+    persistent-cache hits included: a reading of the program's own compile
+    ledger (``horovod_tpu/obs/compiles.py``; ``hvd.init()`` installs it,
+    and outside an initialized world this block does for its duration).
+    The count is in ``seen[0]`` once the block has ended."""
+    from horovod_tpu.obs import compiles
 
+    mine = compiles.ledger().install()
+    before = compiles.compiles_total()
     seen = [0]
-
-    def on_event(event, duration_secs, **kwargs):
-        del duration_secs, kwargs
-        if event == _COMPILE_EVENT:
-            seen[0] += 1
-
-    monitoring.register_event_duration_secs_listener(on_event)
     try:
         yield seen
     finally:
-        monitoring.unregister_event_duration_listener(on_event)
+        seen[0] = compiles.compiles_total() - before
+        if mine:
+            compiles.ledger().uninstall()
 
 
 def check_replicated(tree, mesh, what: str) -> None:

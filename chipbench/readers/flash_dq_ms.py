@@ -1,0 +1,9 @@
+"""``flash_dq_ms``: device time per step of the Mosaic custom calls the
+program names ``flash_bwd_dq`` (the dQ kernel of every layer), first
+device."""
+
+from chipbench import scopes
+
+
+def read(run):
+    return scopes.kernel_ms(run, "flash_bwd_dq")

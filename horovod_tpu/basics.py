@@ -152,6 +152,13 @@ def init(ranks=None, comm=None) -> None:
                   "This process's world rank").set(topo.rank)
         reg.gauge("horovod_elastic_world_epoch",
                   "Elastic world epoch (0 = first launch)").set(epoch)
+        # Compile ledger (obs/compiles.py): the one listener behind
+        # horovod_compiles_total and hvd.obs.compile_events(). It runs
+        # only when JAX compiles; shutdown removes it and keeps its list.
+        from .obs import compiles as _compiles
+
+        _compiles.ledger().install()
+        _global.engine_shutdown_hooks.append(_compiles.ledger().uninstall)
         if _global.config.metrics_port and topo.rank == 0 \
                 and topo.is_member:
             from .obs import exposition as _expo, world_snapshot_provider

@@ -57,15 +57,24 @@ def setup_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set the caller chose the place
-    and JAX reads it itself: nothing is configured here. Otherwise the
+    and JAX reads it itself: the place is left alone. Otherwise the
     cache is ``<checkout>/.jax_bench_cache`` — one fixed path, so that
     every entry point run from this checkout finds what an earlier one
-    compiled."""
+    compiled.
+
+    Either way the cache's key takes in the programs' metadata. JAX leaves
+    it out by default, and a fetched executable then carries the
+    ``op_name``s of whichever program filled the entry: the phase scopes
+    (docs/tracing.md, "Scopes in a compiled step") of a step that another
+    commit compiled, or none. The metadata holds source locations, so an
+    entry is shared only by runs that build the program through the same
+    call stack: the same entry point of the same checkout."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
-    import jax
-
     path = os.path.join(_REPO_ROOT, ".jax_bench_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

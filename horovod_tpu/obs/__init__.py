@@ -15,6 +15,9 @@ The subsystem docs live in docs/metrics.md; the pieces:
 * :mod:`.tracing` — the distributed-tracing half (docs/tracing.md):
   NTP-style clock alignment over the control wire and the coordinator's
   straggler attribution folded into :func:`straggler_report`;
+* :mod:`.compiles` — the compile ledger: one ``jax.monitoring`` listener
+  behind ``horovod_compiles_total`` / ``horovod_compile_seconds_total``
+  and :func:`compile_events` (which program compiled, when);
 * :func:`metrics_snapshot` — the Python API: this process's families, or
   the world-aggregated view rank 0's coordinator assembled from the
   per-rank pushes riding the HMAC control wire.
@@ -33,6 +36,8 @@ from .registry import (  # noqa: F401 - public surface
     registry,
 )
 from .bridge import TimelineBridge  # noqa: F401
+from . import compiles  # noqa: F401
+from .compiles import CompileEvent, compile_events  # noqa: F401
 from . import exposition  # noqa: F401
 from . import flightrec  # noqa: F401 - public surface (docs/blackbox.md)
 from . import tensorwatch  # noqa: F401 - public surface (docs/tensorwatch.md)

@@ -30,6 +30,10 @@ That kernel computes ``S^T = K Q^T`` directly, so every matmul in this
 file is a plain or transposed-RHS product — none contracts dim 0 of its
 left operand.
 
+The three ``pallas_call``s are named ``flash_fwd``, ``flash_bwd_dq`` and
+``flash_bwd_dkv``: the names a compiled program's custom calls and a
+profiler trace show them under (docs/tracing.md).
+
 ``interpret=True`` (automatic on the CPU backend only) runs the same
 kernels through the Pallas interpreter, which is how the CPU test suite
 validates them. On any other platform the kernels compile through Mosaic.
@@ -299,6 +303,7 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qb, kb, vb)
     # the kernel repeats each row's value along the lane axis; the residual
     # kept for the backward pass is the O(T) vector
@@ -345,6 +350,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qb, kb, vb, dob, lse_cols, delta_cols)
 
     # dK/dV grid: (bh, k-block, q-block) — the q dimension is innermost so
@@ -373,6 +379,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qb, kb, vb, dob, lse_rows, delta_rows)
 
     return (_from_bh(dq, batch, heads), _from_bh(dk, batch, heads),

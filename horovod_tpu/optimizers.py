@@ -240,14 +240,22 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         )
 
     def _reduce(grads):
-        return allreduce_gradients(grads, axis_name=axis_name,
-                                   average=average, compression=compression,
-                                   hierarchical=hierarchical)
+        # hvd.exchange / hvd.optimizer: the phase names a compiled step's
+        # device time is read by (docs/tracing.md "Scopes in a compiled
+        # step"); on the eager route a scope does nothing
+        with jax.named_scope("hvd.exchange"):
+            return allreduce_gradients(
+                grads, axis_name=axis_name, average=average,
+                compression=compression, hierarchical=hierarchical)
+
+    def _inner_update(reduced, inner, params):
+        with jax.named_scope("hvd.optimizer"):
+            return optimizer.update(reduced, inner, params)
 
     def update_fn(grads, state, params=None):
         if n_acc == 1:
             reduced = _reduce(grads)
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            updates, inner = _inner_update(reduced, state.inner, params)
             return updates, DistributedOptState(inner, None, state.counter)
 
         accum = jax.tree_util.tree_map(jnp.add, state.accum, grads)
@@ -256,7 +264,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
             # Eager path: concrete values, python control flow.
             if int(counter) >= n_acc:
                 reduced = _reduce(accum)
-                updates, inner = optimizer.update(reduced, state.inner, params)
+                updates, inner = _inner_update(reduced, state.inner, params)
                 zeros = jax.tree_util.tree_map(jnp.zeros_like, accum)
                 return updates, DistributedOptState(
                     inner, zeros, jnp.zeros((), jnp.int32))
@@ -267,7 +275,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         def sync_branch(operand):
             accum_, inner_, params_ = operand
             reduced = _reduce(accum_)
-            updates, new_inner = optimizer.update(reduced, inner_, params_)
+            updates, new_inner = _inner_update(reduced, inner_, params_)
             zeros = jax.tree_util.tree_map(jnp.zeros_like, accum_)
             return updates, new_inner, zeros, jnp.zeros((), jnp.int32)
 

@@ -60,6 +60,8 @@ def test_compile_cache_left_alone_when_placed_from_outside(monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     assert setup_compile_cache() == "/some/dir"
     assert jax.config.jax_compilation_cache_dir == before
+    # scopes are metadata: a fetched executable must be this program's
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):

@@ -7,13 +7,35 @@ kernel in this framework's domain is attention (the long-context extension,
 materializes the [T, T] score matrix in HBM and streams K/V through VMEM
 one block at a time.
 
-Design (per pallas_guide.md): 3-D grids (batch*heads, outer-blocks,
-inner-blocks) with the inner dimension sequential ("arbitrary" semantics);
-accumulators live in VMEM scratch and persist across the inner iterations.
-Per-program VMEM footprint is O(block_q * d + block_k * d) — independent of
-sequence length, so 16k+ contexts fit. Matmuls hit the MXU with f32
-accumulation; masking and rescaling ride the VPU. Causal blocks skip
-fully-masked work (`pl.when`), halving causal cost.
+Design (per pallas_guide.md): the grid is (batch*heads, tiles of the
+kernel's own operand, *major blocks* of the streamed operand), the last
+axis sequential ("arbitrary" semantics) with the accumulators in VMEM
+scratch across it. A major block is as much of the streamed operand (K and
+V for the forward and dQ; Q, dO and the row statistics for dK/dV) as fits a
+VMEM budget computed from ``(seq, head_dim, dtype)`` — the whole sequence
+up to 8192 bf16 rows — so the footprint stays independent of sequence
+length and 16k+ contexts fit. Inside, a ``lax.fori_loop`` walks compute
+tiles of the resident block (``pl.ds`` slices). Matmuls hit the MXU with
+f32 accumulation; masking and rescaling ride the VPU.
+
+The causal schedule: the loop's trip count comes from the program's own
+tile index and ``q_offset`` (``k_tile_bounds`` / ``q_tile_bounds``), so it
+ends at the diagonal tile (forward, dQ) or starts at it (dK/dV): no grid
+step, DMA or branch is spent on a tile wholly in the future, and the index
+map of the streamed operand stays on the last block a tile needs, so a
+skipped major block is not fetched. The loop is split: interior tiles hold
+no masked pair and run with no mask; only the tiles that straddle the
+diagonal run ``_causal_mask``. ``causal_schedule`` says what that comes
+to — tiles, diagonal tiles, executed over needed pairs — and the gauge
+``horovod_flash_executed_pair_ratio{kernel}`` holds it for the newest
+trace (docs/metrics.md). Tiles are chosen from the shapes by what the v5e
+measured (``_tiles``; PERF.md, PR 25): the per-step and per-row costs
+outweigh wasted pairs up to 512 rows (backward) and 1024 (forward).
+
+The forward's softmax is two loops a major block: scores into a VMEM
+buffer with their lane-wise maximum, one cross-lane reduction a row, then
+``exp``, lane-wise sums and P V — the statistics and the accumulators'
+rescaling happen once a major block, not once a tile (``_fwd_kernel``).
 
 Training is first-class: ``flash_attention`` carries a ``jax.custom_vjp``
 whose backward is the FlashAttention-2 recomputation scheme — the forward
@@ -24,7 +46,7 @@ materializing the [T, T] matrix.
 Per-row statistics (logsumexp, delta) cross the kernel boundary in
 layouts whose last two block dims tile on the TPU: a trailing 128-lane
 axis (``[B*H, T, 128]``, value repeated along lanes) where a kernel needs
-them as a column against ``[block_q, block_k]`` scores, and a lane-dense
+them as a column against ``[tile_q, tile_k]`` scores, and a lane-dense
 row (``[B*H, 1, T]``) where the dK/dV kernel works on transposed scores.
 That kernel computes ``S^T = K Q^T`` directly, so every matmul in this
 file is a plain or transposed-RHS product — none contracts dim 0 of its
@@ -50,8 +72,133 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.registry import registry as _metrics
+
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _LANES = 128  # TPU vreg lane count: the trailing axis of column statistics
+# VMEM a kernel's resident major blocks may take (both pipeline buffers of
+# both operands, and the forward's scores): half of the 16 MiB a v5e kernel
+# gets by default, the rest is for the tiles' f32 temporaries
+_RESIDENT_BYTES = 8 * 1024 * 1024
+
+_PAIR_RATIO = _metrics().gauge(
+    "horovod_flash_executed_pair_ratio",
+    "Score pairs the newest traced flash kernel executes over the pairs "
+    "its mask keeps (1.0: no work thrown away by the causal mask)",
+    labels=("kernel",))
+
+
+# -- the causal schedule ----------------------------------------------------
+#
+# Positions: q row r sits at q_offset + r, k column c at c; causal keeps
+# the pairs with q position >= k position. A tile is *interior* when it
+# holds no masked pair, *diagonal* when it holds both kinds, and is never
+# executed when every pair of it is masked. The two bound functions below
+# take a tile index that is a Python int (causal_schedule, on the host) or
+# a traced int32 (the kernels' program ids and the index maps), so the
+# counter and the loops cannot disagree.
+
+
+def _clip(x, lo, hi=None):
+    if isinstance(x, int):
+        x = max(x, lo)
+        return x if hi is None else min(x, hi)
+    return jnp.clip(x, lo, hi)
+
+
+def k_tile_bounds(q_tile, *, q_offset: int, tile_q: int, tile_k: int,
+                  num_k_tiles: int, causal: bool):
+    """``(interior, end)`` for one q tile: k tiles ``[0, interior)`` hold no
+    masked pair, ``[interior, end)`` straddle the diagonal, and those from
+    ``end`` on lie wholly in the future."""
+    if not causal:
+        return num_k_tiles, num_k_tiles
+    first_q = q_offset + q_tile * tile_q
+    interior = _clip((first_q + 1) // tile_k, 0, num_k_tiles)
+    end = _clip((first_q + tile_q - 1) // tile_k + 1, 0, num_k_tiles)
+    return interior, end
+
+
+def q_tile_bounds(k_tile, *, q_offset: int, tile_q: int, tile_k: int,
+                  num_q_tiles: int, causal: bool):
+    """``(start, interior)`` for one k tile: q tiles before ``start`` lie
+    wholly in the past (every pair masked), ``[start, interior)`` straddle
+    the diagonal, and those from ``interior`` on hold no masked pair."""
+    if not causal:
+        return 0, 0
+    first_k = k_tile * tile_k - q_offset  # in q-row coordinates
+    start = _clip(_clip(first_k, 0) // tile_q, 0, num_q_tiles)
+    interior = _clip((_clip(first_k + tile_k - 1, 0) + tile_q - 1) // tile_q,
+                     0, num_q_tiles)
+    return start, interior
+
+
+def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
+                    tile_k: int, causal: bool) -> dict:
+    """What each kernel executes at these shapes: ``tiles`` (compute tiles
+    run), ``diagonal`` (how many of them run the masked body) and
+    ``pair_ratio`` (executed score pairs over the pairs the mask keeps).
+    ``flash_fwd`` and ``flash_bwd_dq`` walk k tiles for each q tile,
+    ``flash_bwd_dkv`` walks q tiles for each k tile."""
+    num_q_tiles, num_k_tiles = seq_q // tile_q, seq_k // tile_k
+    schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+                    causal=causal)
+    by_q = [k_tile_bounds(i, num_k_tiles=num_k_tiles, **schedule)
+            for i in range(num_q_tiles)]
+    by_k = [q_tile_bounds(j, num_q_tiles=num_q_tiles, **schedule)
+            for j in range(num_k_tiles)]
+    needed = seq_q * seq_k if not causal else sum(
+        min(seq_k, q_offset + row + 1) for row in range(seq_q))
+
+    def walk(tiles, diagonal):
+        return {"tiles": tiles, "diagonal": diagonal,
+                "pair_ratio": tiles * tile_q * tile_k / needed}
+
+    over_k = walk(sum(end for _, end in by_q),
+                  sum(end - interior for interior, end in by_q))
+    over_q = walk(sum(num_q_tiles - start for start, _ in by_k),
+                  sum(interior - start for start, interior in by_k))
+    return {"flash_fwd": over_k, "flash_bwd_dq": over_k,
+            "flash_bwd_dkv": over_q}
+
+
+def _for_tiles(lo, hi, first, tiles_per_major: int, body):
+    """``body(j)`` for each tile of the global range ``[lo, hi)`` that lies
+    in the resident block of tiles ``[first, first + tiles_per_major)``,
+    ``j`` counting from the block's first tile. A block that is one tile
+    gets a branch in place of the loop and ``j`` as a Python int, so that
+    it slices its refs statically."""
+    lo = _clip(lo - first, 0, tiles_per_major)
+    hi = _clip(hi - first, 0, tiles_per_major)
+    static = isinstance(lo, int) and isinstance(hi, int)
+    if static and hi <= lo:
+        return
+    if tiles_per_major == 1:
+        if static:
+            body(0)
+        else:
+            pl.when(lo < hi)(lambda: body(0))
+        return
+
+    def step(j, carry):
+        body(j)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+
+def _tile_slice(j, tile: int):
+    """The rows (or lanes) of local tile ``j`` in a resident block."""
+    if isinstance(j, int):
+        return pl.ds(j * tile, tile)
+    return pl.ds(pl.multiple_of(j * tile, tile), tile)
+
+
+def _first_tile(major_idx, tiles_per_major: int, num_tiles: int):
+    """The global index of a major block's first tile; the Python 0 where
+    one block holds the whole sequence, which keeps a non-causal call's
+    loop bounds static."""
+    return 0 if tiles_per_major == num_tiles else major_idx * tiles_per_major
 
 
 def _causal_mask(s, q_pos0, k_pos0, q_axis=0):
@@ -70,13 +217,40 @@ def _col(stat):
     return stat[:, :1]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
-                scale: float, causal: bool, q_offset_blocks: int,
-                num_k_blocks: int, block_q: int, block_k: int):
+def _lane_fold(x, lanes: int, op):
+    """Fold the column blocks of ``x`` [rows, n * lanes] into one
+    [rows, lanes] block with ``op``: element-wise work on whole vregs, no
+    reduction across lanes."""
+    return functools.reduce(op, [x[:, c * lanes:(c + 1) * lanes]
+                                 for c in range(x.shape[1] // lanes)])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
+                s_buf, m_lane, l_lane, *, scale: float, causal: bool,
+                q_offset: int, tile_q: int, tile_k: int, major_k: int,
+                num_k_tiles: int):
+    """One q tile against the resident K/V major block ``kk``. Grid (bh,
+    q-tile, k-major), the last sequential; ``o_acc``, ``m_acc`` and
+    ``l_acc`` persist across it.
+
+    The softmax statistics are per row, and a reduction across lanes plus
+    the rescaling of the accumulators cost as much for one k tile as for a
+    whole major block. So they are done once a major block, in two loops
+    over its k tiles that both end at the diagonal: the first leaves the
+    scores in ``s_buf`` and their maximum lane by lane in ``m_lane``; then
+    one reduction gives the rows' new maximum; the second takes
+    ``exp(S - m)``, sums it lane by lane into ``l_lane`` and accumulates
+    P V. With the whole of K resident that is the plain softmax.
+
+    With ``q_offset >= 0`` every row keeps its pair with k position 0, which
+    the first tile of the first major block holds; no row is ever empty, so
+    ``m`` is finite wherever it is subtracted."""
     # program_id must be read at kernel top level: inside a pl.when body it
     # escapes the interpreter's scope (breaks interpret=True on CPU)
     kk = pl.program_id(2)
     q_idx = pl.program_id(1)
+    tiles_per_major = major_k // tile_k
+    lanes = m_lane.shape[1]
 
     @pl.when(kk == 0)
     def _init():
@@ -84,159 +258,198 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
         m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    def _update():
-        q_block = q_ref[0].astype(jnp.float32) * scale  # [block_q, d]
-        k_blk = k_ref[0].astype(jnp.float32)            # [block_k, d]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(  # [block_q, block_k] on the MXU
-            q_block, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    first = _first_tile(kk, tiles_per_major, num_k_tiles)
+    interior, end = k_tile_bounds(
+        q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+        num_k_tiles=num_k_tiles, causal=causal)
+
+    # nothing to do in a major block wholly in this q tile's future. (The
+    # branch also keeps the refs' slicing off the kernel's top level, where
+    # the interpreter refuses it under a vma-tracking shard_map.)
+    @pl.when(first < end)
+    def _run():
+        q_block = q_ref[0].astype(jnp.float32) * scale  # [tile_q, d]
+        q_pos0 = q_offset + q_idx * tile_q
+        m_lane[...] = jnp.full_like(m_lane, _NEG_INF)
+        l_lane[...] = jnp.zeros_like(l_lane)
+
+        def scores(j, masked):
+            cols = _tile_slice(j, tile_k)
+            k_blk = k_ref[0, cols, :].astype(jnp.float32)  # [tile_k, d]
+            s = jax.lax.dot_general(  # [tile_q, tile_k] on the MXU
+                q_block, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if masked:
+                s = _causal_mask(s, q_pos0, kk * major_k + j * tile_k)
+            s_buf[:, cols] = s
+            m_lane[...] = jnp.maximum(m_lane[...],
+                                      _lane_fold(s, lanes, jnp.maximum))
+
+        _for_tiles(0, interior, first, tiles_per_major,
+                   functools.partial(scores, masked=False))
         if causal:
-            s = _causal_mask(s, (q_idx + q_offset_blocks) * block_q,
-                             kk * block_k)
-        m = _col(m_acc[...])
-        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
-        corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - m_new))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(m_new == _NEG_INF, 0.0, p)
-        l_new = _col(l_acc[...]) * corr + p.sum(axis=1, keepdims=True)
+            _for_tiles(interior, end, first, tiles_per_major,
+                       functools.partial(scores, masked=True))
+
+        m_old = _col(m_acc[...])
+        m_new = jnp.maximum(m_old, m_lane[...].max(axis=1, keepdims=True))
+        corr = jnp.exp(m_old - m_new)  # 0 on the first block: m_old sentinel
+        o_acc[...] = o_acc[...] * corr
+        m_lanes = jnp.broadcast_to(m_new, m_lane.shape)
+
+        def weigh(j):
+            cols = _tile_slice(j, tile_k)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            p = jnp.exp(s_buf[:, cols]
+                        - jnp.tile(m_lanes, (1, tile_k // lanes)))
+            l_lane[...] += _lane_fold(p, lanes, jnp.add)
+            o_acc[...] += jax.lax.dot_general(
+                p, v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _for_tiles(0, end, first, tiles_per_major, weigh)
+
+        l_new = _col(l_acc[...]) * corr \
+            + l_lane[...].sum(axis=1, keepdims=True)
         l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
         m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
-        o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
 
-    if causal:
-        # skip k-blocks that lie entirely in this q-block's future
-        last_q_pos = (q_idx + q_offset_blocks + 1) * block_q - 1
-
-        @pl.when(last_q_pos >= kk * block_k)
-        def _run():
-            _update()
-    else:
-        _update()
-
-    @pl.when(kk == num_k_blocks - 1)
+    @pl.when(kk == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_acc[...], 1e-30)
+        l = l_acc[...]
         o_ref[0, ...] = (o_acc[...] / _col(l)).astype(o_ref.dtype)
-        # per-row logsumexp residual for the backward pass; fully-masked
-        # rows stay at the _NEG_INF sentinel (m saturates f32 addition)
+        # per-row logsumexp residual for the backward pass
         lse_ref[0, ...] = m_acc[...] + jnp.log(l)
 
 
-def _recompute_p(q_blk, k_blk, lse, *, scale, causal, q_pos0, k_pos0,
+def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
                  transposed=False):
-    """Recompute the normalized probability block P = exp(S - lse) and S's
-    mask; shared by both backward kernels. All f32, MXU matmul.
+    """Recompute the normalized probability block P = exp(S - lse), with
+    S's mask on a ``masked`` (diagonal) tile; shared by both backward
+    kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
 
-    ``transposed=False``: P is [block_q, block_k] and ``lse`` its
-    [block_q, 1] column. ``transposed=True``: P^T is [block_k, block_q],
-    computed directly as K Q^T, and ``lse`` its [1, block_q] row."""
-    lhs, rhs = (k_blk, q_blk * scale) if transposed else (q_blk * scale,
-                                                          k_blk)
+    ``transposed=False``: P is [tile_q, tile_k] and ``lse`` its
+    [tile_q, 1] column. ``transposed=True``: P^T is [tile_k, tile_q],
+    computed directly as K Q^T, and ``lse`` its [1, tile_q] row."""
+    lhs, rhs = (k_blk, q_blk) if transposed else (q_blk, k_blk)
     s = jax.lax.dot_general(
         lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    if causal:
+    if masked:
         s = _causal_mask(s, q_pos0, k_pos0, q_axis=1 if transposed else 0)
-    # fully-masked rows have lse at the sentinel; exp(s - sentinel) would
-    # be exp(0) = 1 for masked s, so zero those rows explicitly
-    p = jnp.exp(s - lse)
-    return jnp.where(lse <= _NEG_INF / 2, 0.0, p)
+    # no row is empty (``_fwd_kernel``), so lse is finite and a masked
+    # pair's exp(sentinel - lse) is the 0 it should be
+    return jnp.exp(s - lse)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale: float, causal: bool,
-                   q_offset_blocks: int, num_k_blocks: int, block_q: int,
-                   block_k: int):
-    """dQ = (P * (dO V^T - delta)) K * scale, accumulated over k blocks.
-    Grid (bh, q-block, k-block), k innermost sequential."""
+                   dq_acc, *, scale: float, causal: bool, q_offset: int,
+                   tile_q: int, tile_k: int, major_k: int, num_k_tiles: int):
+    """dQ = (P * (dO V^T - delta)) K * scale, accumulated over the k tiles
+    up to the diagonal. Grid (bh, q-tile, k-major) as the forward's."""
     kk = pl.program_id(2)
     q_idx = pl.program_id(1)
+    tiles_per_major = major_k // tile_k
 
     @pl.when(kk == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _update():
-        q_blk = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
+    first = _first_tile(kk, tiles_per_major, num_k_tiles)
+    interior, end = k_tile_bounds(
+        q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+        num_k_tiles=num_k_tiles, causal=causal)
+
+    @pl.when(first < end)  # as the forward's
+    def _run():
+        q_scaled = q_ref[0].astype(jnp.float32) * scale
         do_blk = do_ref[0].astype(jnp.float32)
-        p = _recompute_p(
-            q_blk, k_blk, _col(lse_ref[0]), scale=scale, causal=causal,
-            q_pos0=(q_idx + q_offset_blocks) * block_q, k_pos0=kk * block_k)
-        dp = jax.lax.dot_general(  # dO V^T  [block_q, block_k]
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _col(delta_ref[0])) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        lse, delta = _col(lse_ref[0]), _col(delta_ref[0])
+        q_pos0 = q_offset + q_idx * tile_q
 
-    if causal:
-        last_q_pos = (q_idx + q_offset_blocks + 1) * block_q - 1
+        def update(j, masked):
+            rows = _tile_slice(j, tile_k)
+            k_blk = k_ref[0, rows, :].astype(jnp.float32)
+            v_blk = v_ref[0, rows, :].astype(jnp.float32)
+            p = _recompute_p(q_scaled, k_blk, lse, masked=masked,
+                             q_pos0=q_pos0,
+                             k_pos0=kk * major_k + j * tile_k)
+            dp = jax.lax.dot_general(  # dO V^T  [tile_q, tile_k]
+                do_blk, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale
+            dq_acc[...] += jax.lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        @pl.when(last_q_pos >= kk * block_k)
-        def _run():
-            _update()
-    else:
-        _update()
+        _for_tiles(0, interior, first, tiles_per_major,
+                   functools.partial(update, masked=False))
+        if causal:
+            _for_tiles(interior, end, first, tiles_per_major,
+                       functools.partial(update, masked=True))
 
-    @pl.when(kk == num_k_blocks - 1)
+    @pl.when(kk == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0, ...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    causal: bool, q_offset_blocks: int, num_q_blocks: int,
-                    block_q: int, block_k: int):
-    """dV = P^T dO and dK = (P * (dP - delta))^T Q, accumulated over q
-    blocks. Grid (bh, k-block, q-block), q innermost sequential. Works on
-    the transposed blocks P^T, dP^T = V dO^T, dS^T throughout, with lse and
-    delta as [1, block_q] rows, so no operand is ever transposed."""
+                    causal: bool, q_offset: int, tile_q: int, tile_k: int,
+                    major_q: int, num_q_tiles: int):
+    """dV = P^T dO and dK = (P * (dP - delta))^T Q for one k tile,
+    accumulated over the q tiles of the resident major block ``iq`` from
+    the diagonal on. Grid (bh, k-tile, q-major), the last sequential.
+    Works on the transposed blocks P^T, dP^T = V dO^T, dS^T throughout,
+    with lse and delta as [1, tile_q] rows, so no operand is ever
+    transposed."""
     iq = pl.program_id(2)
     k_idx = pl.program_id(1)
+    tiles_per_major = major_q // tile_q
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _update():
-        q_blk = q_ref[0].astype(jnp.float32)
+    first = _first_tile(iq, tiles_per_major, num_q_tiles)
+    start, interior = q_tile_bounds(
+        k_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+        num_q_tiles=num_q_tiles, causal=causal)
+
+    # nothing to do in a major block wholly in this k tile's past
+    @pl.when(start < first + tiles_per_major)
+    def _run():
         k_blk = k_ref[0].astype(jnp.float32)
         v_blk = v_ref[0].astype(jnp.float32)
-        do_blk = do_ref[0].astype(jnp.float32)
-        p_t = _recompute_p(
-            q_blk, k_blk, lse_ref[0], scale=scale, causal=causal,
-            q_pos0=(iq + q_offset_blocks) * block_q, k_pos0=k_idx * block_k,
-            transposed=True)
-        dv_acc[...] += jax.lax.dot_general(  # P^T dO  [block_k, d]
-            p_t, do_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(  # V dO^T  [block_k, block_q]
-            v_blk, do_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[0]) * scale
-        dk_acc[...] += jax.lax.dot_general(  # dS^T Q  [block_k, d]
-            ds_t, q_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        k_pos0 = k_idx * tile_k
 
-    if causal:
-        # skip q-blocks that lie entirely before this k-block (P == 0 there)
-        last_q_pos = (iq + q_offset_blocks + 1) * block_q - 1
+        def update(i, masked):
+            rows = _tile_slice(i, tile_q)
+            q_blk = q_ref[0, rows, :].astype(jnp.float32)
+            do_blk = do_ref[0, rows, :].astype(jnp.float32)
+            p_t = _recompute_p(
+                q_blk * scale, k_blk, lse_ref[0, :, rows], masked=masked,
+                q_pos0=q_offset + iq * major_q + i * tile_q, k_pos0=k_pos0,
+                transposed=True)
+            dv_acc[...] += jax.lax.dot_general(  # P^T dO  [tile_k, d]
+                p_t, do_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(  # V dO^T  [tile_k, tile_q]
+                v_blk, do_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds_t = p_t * (dp_t - delta_ref[0, :, rows]) * scale
+            dk_acc[...] += jax.lax.dot_general(  # dS^T Q  [tile_k, d]
+                ds_t, q_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        @pl.when(last_q_pos >= k_idx * block_k)
-        def _run():
-            _update()
-    else:
-        _update()
+        if causal:
+            _for_tiles(start, interior, first, tiles_per_major,
+                       functools.partial(update, masked=True))
+        _for_tiles(interior, num_q_tiles, first, tiles_per_major,
+                   functools.partial(update, masked=False))
 
-    @pl.when(iq == num_q_blocks - 1)
+    @pl.when(iq == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0, ...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, ...] = dv_acc[...].astype(dv_ref.dtype)
@@ -268,40 +481,112 @@ def _sds(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
+def _tile(seq: int, bound: Optional[int], prefer: int, row_bytes: int) -> int:
+    """The compute tile along a sequence of ``seq``: the caller's ``bound``
+    where there is one (or the sequence, if shorter); else ``prefer``,
+    halved while it does not divide the sequence or its rows alone
+    overrun ``_RESIDENT_BYTES``, as long as it stays a multiple of the
+    lanes."""
+    if bound is not None:
+        return min(bound, seq)
+    tile = min(prefer, seq)
+    while tile % (2 * _LANES) == 0 and (
+            seq % tile or tile * row_bytes > _RESIDENT_BYTES):
+        tile //= 2
+    return tile
+
+
+def _tiles(seq_q: int, seq_k: int, head_dim: int, dtype,
+           block_q: Optional[int], block_k: Optional[int]):
+    """``((tile_q, tile_k) of the forward, (tile_q, tile_k) of the two
+    backward kernels)``. What the v5e measured (PERF.md, PR 25): a loop
+    step, a grid step and a row's statistics cost the same whatever the
+    tile, so bigger tiles win until the pairs a causal tile throws away
+    outweigh them. The forward's two matmuls a pair leave it cheapest at
+    1024 even where that is the whole causal sequence; the backward's
+    three and four are cheapest at 512."""
+    row_bytes = _operand_row_bytes(head_dim, dtype)
+    fwd_q = _tile(seq_q, block_q, 1024, row_bytes)
+    return ((fwd_q, _tile(seq_k, block_k, 1024, row_bytes + 4 * fwd_q)),
+            (_tile(seq_q, block_q, 512, row_bytes),
+             _tile(seq_k, block_k, 512, row_bytes)))
+
+
+def _major(seq: int, tile: int, row_bytes: int) -> int:
+    """Rows of the streamed operands that stay resident in VMEM: the most
+    tiles that divide ``seq`` and fit ``_RESIDENT_BYTES`` at ``row_bytes``
+    a row, at least one tile. The whole sequence where it fits."""
+    num_tiles = seq // tile
+    fit = max(1, min(num_tiles, _RESIDENT_BYTES // (row_bytes * tile)))
+    while num_tiles % fit:
+        fit -= 1
+    return fit * tile
+
+
+def _operand_row_bytes(head_dim: int, dtype) -> int:
+    """VMEM bytes a row of two resident operands takes: two pipeline
+    buffers each, ``head_dim`` padded to the lanes."""
+    return 2 * 2 * -(-head_dim // _LANES) * _LANES * jnp.dtype(dtype).itemsize
+
+
+def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, **schedule):
+    """BlockSpec of K or V on a (bh, q-tile, k-major) grid. A major block
+    wholly in a q tile's future is not fetched: the index stays on the last
+    one the tile needs."""
+    tiles_per_major = major_k // schedule["tile_k"]
+
+    def index(bh, i, kk):
+        _, end = k_tile_bounds(i, num_k_tiles=num_k_tiles, **schedule)
+        return (bh, jnp.minimum(kk, (end - 1) // tiles_per_major), 0)
+
+    return pl.BlockSpec((1, major_k, head_dim), index)
+
+
+def _compiler_params(interpret: bool):
+    return None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset):
+    tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
     seq_k = k.shape[1]
-    num_k_blocks = seq_k // block_k
+    num_k_tiles = seq_k // tile_k
+    # beside K and V, a row of the major block holds its f32 scores
+    major_k = _major(seq_k, tile_k,
+                     _operand_row_bytes(head_dim, k.dtype) + 4 * tile_q)
+    lanes = _LANES if tile_k % _LANES == 0 else tile_k
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
+    _PAIR_RATIO.labels(kernel="flash_fwd").set(causal_schedule(
+        seq_q, seq_k, q_offset, tile_q, tile_k, causal)
+        ["flash_fwd"]["pair_ratio"])
+    schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+                    causal=causal)
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        q_offset_blocks=q_offset // block_q, num_k_blocks=num_k_blocks,
-        block_q=block_q, block_k=block_k)
+    q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
+    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, **schedule)
     o, lse = pl.pallas_call(
-        kernel,
-        grid=(batch * heads, seq_q // block_q, num_k_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, head_dim), lambda bh, i, kk: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, head_dim), lambda bh, i, kk: (bh, kk, 0)),
-            pl.BlockSpec((1, block_k, head_dim), lambda bh, i, kk: (bh, kk, 0)),
-        ],
+        functools.partial(_fwd_kernel, scale=scale, major_k=major_k,
+                          num_k_tiles=num_k_tiles, **schedule),
+        grid=(batch * heads, seq_q // tile_q, seq_k // major_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, head_dim),
-                         lambda bh, i, kk: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, i, kk: (bh, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0)),
         ],
         out_shape=[
             _sds((batch * heads, seq_q, head_dim), q.dtype, q, k, v),
             _sds((batch * heads, seq_q, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((tile_q, head_dim), jnp.float32),
+            pltpu.VMEM((tile_q, _LANES), jnp.float32),
+            pltpu.VMEM((tile_q, _LANES), jnp.float32),
+            pltpu.VMEM((tile_q, major_k), jnp.float32),
+            pltpu.VMEM((tile_q, lanes), jnp.float32),
+            pltpu.VMEM((tile_q, lanes), jnp.float32),
         ],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="flash_fwd",
     )(qb, kb, vb)
@@ -310,63 +595,72 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
     return _from_bh(o, batch, heads), lse[..., 0]
 
 
-def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-              interpret, q_offset):
+def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
+              q_offset):
+    tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
     seq_k = k.shape[1]
-    num_q_blocks = seq_q // block_q
-    num_k_blocks = seq_k // block_k
+    num_q_tiles = seq_q // tile_q
+    num_k_tiles = seq_k // tile_k
+    major_k = _major(seq_k, tile_k, _operand_row_bytes(head_dim, k.dtype))
+    major_q = _major(seq_q, tile_q, _operand_row_bytes(head_dim, q.dtype))
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     ob, dob = _to_bh(o), _to_bh(do)
+    executed = causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k,
+                               causal)
+    for name in ("flash_bwd_dq", "flash_bwd_dkv"):
+        _PAIR_RATIO.labels(kernel=name).set(executed[name]["pair_ratio"])
+    schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+                    causal=causal)
     # delta_i = sum_d dO_id O_id = sum_j dP_ij P_ij  (softmax Jacobian term)
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)  # [B*H, Tq]
 
-    qkv_spec_q = pl.BlockSpec((1, block_q, head_dim),
-                              lambda bh, i, kk: (bh, i, 0))
-    qkv_spec_k = pl.BlockSpec((1, block_k, head_dim),
-                              lambda bh, i, kk: (bh, kk, 0))
     # per-row statistics in the two layouts the kernels read (module
     # docstring): lane-repeated columns for dQ, lane-dense rows for dK/dV
     lse_cols, delta_cols = (
         jnp.broadcast_to(x[..., None], (*x.shape, _LANES))
         for x in (lse, delta))
     lse_rows, delta_rows = lse[:, None, :], delta[:, None, :]
-    col_spec = pl.BlockSpec((1, block_q, _LANES),
-                            lambda bh, i, kk: (bh, i, 0))
 
+    q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
+    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, **schedule)
+    col_spec = pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0))
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            q_offset_blocks=q_offset // block_q, num_k_blocks=num_k_blocks,
-            block_q=block_q, block_k=block_k),
-        grid=(batch * heads, num_q_blocks, num_k_blocks),
-        in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k, qkv_spec_q,
-                  col_spec, col_spec],
-        out_specs=qkv_spec_q,
+        functools.partial(_bwd_dq_kernel, scale=scale, major_k=major_k,
+                          num_k_tiles=num_k_tiles, **schedule),
+        grid=(batch * heads, num_q_tiles, seq_k // major_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
+        out_specs=q_spec,
         out_shape=_sds((batch * heads, seq_q, head_dim), q.dtype,
                        q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((tile_q, head_dim), jnp.float32)],
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="flash_bwd_dq",
     )(qb, kb, vb, dob, lse_cols, delta_cols)
 
-    # dK/dV grid: (bh, k-block, q-block) — the q dimension is innermost so
-    # the (1, block_q, d) operands re-index by the LAST grid axis here
-    kv_q_spec = pl.BlockSpec((1, block_q, head_dim),
-                             lambda bh, kk, i: (bh, i, 0))
-    kv_k_spec = pl.BlockSpec((1, block_k, head_dim),
-                             lambda bh, kk, i: (bh, kk, 0))
-    kv_row_spec = pl.BlockSpec((1, 1, block_q),
-                               lambda bh, kk, i: (bh, 0, i))
+    # dK/dV grid: (bh, k-tile, q-major) — the streamed q-side operands
+    # re-index by the LAST grid axis here
+    num_q_majors = seq_q // major_q
+
+    def q_major(kk, iq):
+        # a major block wholly in this k tile's past is not fetched: the
+        # index waits on the first one the tile needs
+        start, _ = q_tile_bounds(kk, num_q_tiles=num_q_tiles, **schedule)
+        return jnp.maximum(iq, jnp.minimum(start // (major_q // tile_q),
+                                           num_q_majors - 1))
+
+    kv_q_spec = pl.BlockSpec((1, major_q, head_dim),
+                             lambda bh, kk, iq: (bh, q_major(kk, iq), 0))
+    kv_k_spec = pl.BlockSpec((1, tile_k, head_dim),
+                             lambda bh, kk, iq: (bh, kk, 0))
+    kv_row_spec = pl.BlockSpec((1, 1, major_q),
+                               lambda bh, kk, iq: (bh, 0, q_major(kk, iq)))
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            q_offset_blocks=q_offset // block_q, num_q_blocks=num_q_blocks,
-            block_q=block_q, block_k=block_k),
-        grid=(batch * heads, num_k_blocks, num_q_blocks),
+        functools.partial(_bwd_dkv_kernel, scale=scale, major_q=major_q,
+                          num_q_tiles=num_q_tiles, **schedule),
+        grid=(batch * heads, num_k_tiles, num_q_majors),
         in_specs=[kv_q_spec, kv_k_spec, kv_k_spec, kv_q_spec,
                   kv_row_spec, kv_row_spec],
         out_specs=[kv_k_spec, kv_k_spec],
@@ -374,10 +668,9 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             _sds((batch * heads, seq_k, head_dim), k.dtype, q, k, v, do),
             _sds((batch * heads, seq_k, head_dim), v.dtype, q, k, v, do),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, head_dim), jnp.float32),
-                        pltpu.VMEM((block_k, head_dim), jnp.float32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((tile_k, head_dim), jnp.float32),
+                        pltpu.VMEM((tile_k, head_dim), jnp.float32)],
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qb, kb, vb, dob, lse_rows, delta_rows)
@@ -387,23 +680,23 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, q_offset):
-    o, _ = _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
-                     q_offset)
+def _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
+           q_offset):
+    o, _ = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret, q_offset)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+def _flash_fwd(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
                q_offset):
-    o, lse = _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
+    o, lse = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret,
                        q_offset)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, q_offset, res,
-               do):
+def _flash_bwd(causal, scale, fwd_tiles, bwd_tiles, interpret, q_offset,
+               res, do):
     q, k, v, o, lse = res
-    return _bwd_impl(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+    return _bwd_impl(q, k, v, o, lse, do, causal, scale, bwd_tiles,
                      interpret, q_offset)
 
 
@@ -414,30 +707,33 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
     "causal", "scale", "block_q", "block_k", "interpret", "q_offset"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     q_offset: int = 0) -> jax.Array:
     """Fused attention, shapes [batch, seq, heads, head_dim]. Differentiable
     (custom VJP with FlashAttention-2 recomputation kernels).
 
-    ``q_offset`` shifts the global position of q (in elements) for causal
-    masking — how ring attention uses a kernel per KV shard. Sequence
-    lengths must be multiples of the block sizes (pad upstream; blocks
-    auto-shrink to the sequence length when shorter).
+    ``q_offset`` shifts the global position of q (in elements, any
+    non-negative count) for causal masking — how ring attention uses a
+    kernel per KV shard. ``block_q`` / ``block_k`` are upper bounds on the
+    compute tiles, which the shapes and ``causal`` choose (``_tiles``);
+    sequence lengths must be multiples of the tiles (pad upstream; a tile
+    is the whole sequence when that is shorter).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.devices()[0].platform == "cpu"
+    if q_offset < 0:
+        raise ValueError("q_offset must be non-negative")
     seq_q, seq_k = q.shape[1], k.shape[1]
-    block_q = min(block_q, seq_q)
-    block_k = min(block_k, seq_k)
-    if seq_q % block_q or seq_k % block_k:
-        raise ValueError(
-            f"sequence lengths ({seq_q}, {seq_k}) must be multiples of the "
-            f"block sizes ({block_q}, {block_k}); pad inputs first.")
-    if q_offset < 0 or q_offset % block_q:
-        raise ValueError(
-            "q_offset must be a non-negative multiple of block_q")
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
+    fwd_tiles, bwd_tiles = _tiles(seq_q, seq_k, q.shape[-1], k.dtype,
+                                  block_q, block_k)
+    for tile_q, tile_k in (fwd_tiles, bwd_tiles):
+        if seq_q % tile_q or seq_k % tile_k:
+            raise ValueError(
+                f"sequence lengths ({seq_q}, {seq_k}) must be multiples of "
+                f"the block sizes ({tile_q}, {tile_k}); pad inputs first.")
+    return _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
                   q_offset)

@@ -1,0 +1,95 @@
+"""The three flash kernels alone compile through Mosaic for the v5e at the
+benchmark cells' shape and at shapes that take the other branches of the
+schedule — more than one major block, a sequence the preferred tile does
+not divide, an offset diagonal, no mask — so that a VMEM overflow or a
+Mosaic refusal fails here and not on the chip. The chip is described, not
+attached: nothing runs, so this gives no time and no result
+(``on-chip-measurement`` guide, section 2.3).
+
+The topology is described inside a fixture, never at import: only one
+process at a time may hold the TPU compiler, and every pytest worker
+imports this file. The other file of such compiles is
+``tests/chipbench/test_chipbench_aot_v5e.py``; where the two land on
+different workers without ``ALLOW_MULTIPLE_LIBTPU_LOAD`` one of them skips.
+Each case takes a second or two.
+"""
+
+import functools
+
+import pytest
+
+TOPOLOGY = "v5e:2x2"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name=TOPOLOGY)
+    except Exception as e:  # noqa: BLE001 - whatever keeps the compiler away
+        pytest.skip(f"no {TOPOLOGY} topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but not read back without the chip; keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+_CASES = {
+    # id: (q shape, seq_k, dtype, causal, q_offset)
+    "gpt2m_cell": ((4, 1024, 16, 64), 1024, "bfloat16", True, 0),
+    "chip_smoke_f32": ((8, 1024, 12, 64), 1024, "float32", True, 0),
+    "past_the_resident_cap": ((1, 16384, 2, 128), 16384, "bfloat16", True, 0),
+    "past_the_cap_f32": ((1, 8192, 2, 128), 8192, "float32", True, 0),
+    "noncausal": ((8, 1024, 12, 64), 1024, "bfloat16", False, 0),
+    "noncausal_long_wide": ((1, 8192, 1, 256), 8192, "bfloat16", False, 0),
+    "preferred_tile_does_not_divide": ((1, 1536, 2, 64), 1536, "bfloat16",
+                                       True, 0),
+    "later_q_shard": ((2, 1024, 4, 64), 2048, "bfloat16", True, 1024),
+    "offset_inside_a_tile": ((2, 1024, 4, 64), 2048, "bfloat16", True, 1000),
+    "shorter_than_the_lanes": ((2, 64, 4, 64), 64, "bfloat16", True, 0),
+}
+
+
+@pytest.mark.parametrize("case", _CASES)
+def test_kernels_compile_for_v5e(one_chip, no_compile_cache, case):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    q_shape, seq_k, dtype, causal, q_offset = _CASES[case]
+    batch, seq_q, heads, head_dim = q_shape
+    q = jax.ShapeDtypeStruct(q_shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((batch, seq_k, heads, head_dim), dtype,
+                              sharding=one_chip)
+    attention = functools.partial(pa.flash_attention, causal=causal,
+                                  q_offset=q_offset, interpret=False)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"%{name}" in hlo, name
+    if case == "past_the_resident_cap":
+        fwd, bwd = pa._tiles(seq_q, seq_k, head_dim, dtype, None, None)
+        rows = pa._operand_row_bytes(head_dim, dtype)
+        assert pa._major(seq_k, fwd[1], rows + 4 * fwd[0]) < seq_k
+        assert pa._major(seq_k, bwd[1], rows) < seq_k

@@ -175,3 +175,212 @@ def test_flash_under_vma_shard_map_matches_dense():
     for gf, gd in zip(grads_f, grads_d):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
                                    rtol=2e-3, atol=2e-3)
+
+
+def _shifted_dense(q, k, v, causal, q_offset):
+    """Dense attention with q's rows at positions q_offset, q_offset + 1,
+    ...: q padded in front so that the plain causal mask lands there."""
+    padded = jnp.pad(q, ((0, 0), (q_offset, 0), (0, 0), (0, 0)))
+    return dense_attention(padded, k, v, causal=causal)[:, q_offset:]
+
+
+# (id, seq_q, seq_k, q_offset, block_q, block_k, causal, head_dim, dtype,
+#  resident bytes or None): the shapes that move the diagonal through the
+# kernels' loops. Tiles of 16 keep the interpreter quick.
+_DIAGONAL_CASES = [
+    ("one_tile", 16, 16, 0, 16, 16, True, 16, "float32", None),
+    ("three_tiles", 48, 48, 0, 16, 16, True, 16, "float32", None),
+    ("three_tiles_noncausal", 48, 48, 0, 16, 16, False, 16, "float32", None),
+    ("short_q_long_k", 32, 64, 0, 16, 16, True, 16, "float32", None),
+    ("long_q_short_k", 64, 32, 0, 16, 16, True, 16, "float32", None),
+    ("short_q_long_k_noncausal", 32, 64, 0, 16, 16, False, 16, "float32",
+     None),
+    ("offset_one_tile", 32, 48, 16, 16, 16, True, 16, "float32", None),
+    ("offset_three_tiles", 16, 64, 48, 16, 16, True, 16, "float32", None),
+    ("offset_inside_a_tile", 32, 64, 24, 16, 16, True, 16, "float32", None),
+    ("offset_past_every_k", 32, 32, 64, 16, 16, True, 16, "float32", None),
+    ("wide_q_tile", 64, 64, 0, 32, 16, True, 16, "float32", None),
+    ("wide_k_tile", 64, 64, 0, 16, 32, True, 16, "float32", None),
+    # 4 * 128 lanes * 4 bytes * 16 rows = one tile's worth of residents
+    ("two_tiles_a_major", 96, 96, 0, 16, 16, True, 16, "float32", 65536),
+    ("one_tile_a_major", 80, 80, 0, 16, 16, True, 16, "float32", 1),
+    ("one_tile_a_major_offset", 48, 80, 32, 16, 16, True, 16, "float32", 1),
+    ("two_tiles_a_major_noncausal", 96, 96, 0, 16, 16, False, 16, "float32",
+     65536),
+    ("head_dim_64", 48, 48, 0, 16, 16, True, 64, "float32", None),
+    ("head_dim_128", 48, 48, 0, 16, 16, True, 128, "float32", None),
+    ("bf16", 48, 48, 0, 16, 16, True, 64, "bfloat16", None),
+    ("bf16_head_dim_128_noncausal", 48, 48, 0, 16, 16, False, 128,
+     "bfloat16", None),
+    ("default_blocks", 256, 256, 0, 512, 512, True, 64, "bfloat16", None),
+]
+
+
+@pytest.mark.parametrize(
+    "seq_q,seq_k,q_offset,block_q,block_k,causal,head_dim,dtype,resident",
+    [case[1:] for case in _DIAGONAL_CASES],
+    ids=[case[0] for case in _DIAGONAL_CASES])
+def test_flash_follows_the_diagonal(monkeypatch, seq_q, seq_k, q_offset,
+                                    block_q, block_k, causal, head_dim,
+                                    dtype, resident):
+    """Forward and all three gradients against dense attention wherever
+    the loop bounds, the masked / unmasked split and the major-block grid
+    take another branch."""
+    import jax
+
+    from horovod_tpu.ops import pallas_attention
+
+    if resident is not None:
+        # read at trace time: these cases' shapes are no other test's, so
+        # no cached trace stands in for them
+        monkeypatch.setattr(pallas_attention, "_RESIDENT_BYTES", resident)
+        majors = seq_k // pallas_attention._major(
+            seq_k, block_k,
+            pallas_attention._operand_row_bytes(head_dim, dtype))
+        assert majors > 1, majors
+    rng = np.random.default_rng(seq_q + 7 * seq_k + 13 * q_offset)
+    q, cot = (jnp.asarray(rng.standard_normal((2, seq_q, 2, head_dim)),
+                          dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((2, seq_k, 2, head_dim)), dtype)
+            for _ in range(2))
+
+    def run(attention):
+        out, vjp = jax.vjp(attention, q, k, v)
+        return (out, *vjp(cot))
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        q_offset=q_offset))
+    want = run(lambda q, k, v: _shifted_dense(q, k, v, causal, q_offset))
+    tol = 5e-4 if dtype == "float32" else 3e-2
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=tol, atol=tol, err_msg=name)
+
+
+# -- causal_schedule alone ---------------------------------------------------
+
+_SCHEDULE_CASES = [
+    # seq_q, seq_k, q_offset, tile_q, tile_k, causal
+    (8, 8, 0, 8, 8, True), (24, 24, 0, 8, 8, True), (24, 24, 0, 4, 8, True),
+    (24, 24, 0, 8, 4, True), (16, 32, 0, 8, 8, True), (32, 16, 0, 8, 8, True),
+    (16, 32, 8, 8, 8, True), (8, 32, 24, 8, 8, True), (16, 32, 5, 8, 8, True),
+    (16, 16, 40, 8, 8, True), (24, 24, 0, 8, 8, False),
+    (16, 32, 8, 8, 4, False),
+]
+
+
+@pytest.mark.parametrize("seq_q,seq_k,q_offset,tile_q,tile_k,causal",
+                         _SCHEDULE_CASES)
+def test_causal_schedule_against_brute_force(seq_q, seq_k, q_offset, tile_q,
+                                             tile_k, causal):
+    """Pair by pair: the executed tiles cover every kept pair, no tile that
+    lies wholly in the future is executed, a tile flagged interior holds no
+    masked pair — for the k walk of forward and dQ and for the q walk of
+    dK/dV, which must come to the same tiles."""
+    from horovod_tpu.ops.pallas_attention import (
+        causal_schedule, k_tile_bounds, q_tile_bounds)
+
+    kept = np.ones((seq_q, seq_k), bool) if not causal else (
+        q_offset + np.arange(seq_q)[:, None] >= np.arange(seq_k)[None, :])
+    nq, nk = seq_q // tile_q, seq_k // tile_k
+    shared = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+                  causal=causal)
+    by_k_walk, by_q_walk = {}, {}  # (q tile, k tile) -> masked body?
+    for i in range(nq):
+        interior, end = k_tile_bounds(i, num_k_tiles=nk, **shared)
+        assert 0 <= interior <= end <= nk
+        by_k_walk.update({(i, j): j >= interior for j in range(end)})
+    for j in range(nk):
+        start, interior = q_tile_bounds(j, num_q_tiles=nq, **shared)
+        assert 0 <= start <= interior <= nq
+        by_q_walk.update({(i, j): i < interior for i in range(start, nq)})
+    assert by_k_walk == by_q_walk
+    for i in range(nq):
+        for j in range(nk):
+            tile = kept[i * tile_q:(i + 1) * tile_q,
+                        j * tile_k:(j + 1) * tile_k]
+            if (i, j) not in by_k_walk:
+                assert not tile.any(), (i, j)   # every kept pair is covered
+            else:
+                assert tile.any(), (i, j)       # nothing wholly in the future
+                if not by_k_walk[i, j]:
+                    assert tile.all(), (i, j)   # interior: nothing masked
+                else:
+                    assert not tile.all(), (i, j)
+    schedule = causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k, causal)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert schedule[name]["tiles"] == len(by_k_walk)
+        assert schedule[name]["diagonal"] == sum(by_k_walk.values())
+        assert schedule[name]["pair_ratio"] == pytest.approx(
+            len(by_k_walk) * tile_q * tile_k / kept.sum())
+
+
+def test_schedule_at_the_cells_shape_and_its_gauge():
+    """GPT-2-medium's call — T = 1024, head_dim 64, no bounds passed: the
+    parent's 512-row grid executed 1.50 x the needed pairs; the forward now
+    takes the sequence as one tile (2.0 x: a step costs more than its
+    wasted pairs, PERF.md PR 25), the backward kernels stay at 512, and the
+    gauge holds what was traced."""
+    import jax
+
+    from horovod_tpu.obs import registry
+    from horovod_tpu.ops import pallas_attention as pa
+
+    parent = pa.causal_schedule(1024, 1024, 0, 512, 512, True)
+    assert parent["flash_fwd"] == {
+        "tiles": 3, "diagonal": 2,
+        "pair_ratio": pytest.approx(1.4985, rel=1e-4)}
+    fwd, bwd = pa._tiles(1024, 1024, 64, "bfloat16", None, None)
+    assert (fwd, bwd) == ((1024, 1024), (512, 512))
+    want = {"flash_fwd": pa.causal_schedule(1024, 1024, 0, *fwd, True),
+            "flash_bwd_dq": parent, "flash_bwd_dkv": parent}
+    assert want["flash_fwd"]["flash_fwd"]["pair_ratio"] == pytest.approx(
+        1.998, rel=1e-3)
+
+    x = jax.ShapeDtypeStruct((4, 1024, 16, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: pa.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        x, x, x)
+    samples = registry().snapshot()[
+        "horovod_flash_executed_pair_ratio"]["samples"]
+    read = {s["labels"]["kernel"]: s["value"] for s in samples}
+    assert read == {name: pytest.approx(entry[name]["pair_ratio"])
+                    for name, entry in want.items()}
+
+
+@pytest.mark.parametrize("args,want", [
+    # seq_q, seq_k, head_dim, dtype, block_q, block_k
+    ((1024, 1024, 64, "bfloat16", None, None), ((1024, 1024), (512, 512))),
+    ((8192, 8192, 128, "bfloat16", None, None), ((1024, 1024), (512, 512))),
+    ((128, 128, 64, "float32", None, None), ((128, 128), (128, 128))),
+    ((64, 2048, 64, "float32", None, None), ((64, 1024), (64, 512))),
+    # a sequence the preferred tile does not divide: halved until it does
+    ((1536, 1536, 64, "bfloat16", None, None), ((512, 512), (512, 512))),
+    ((1280, 768, 64, "bfloat16", None, None), ((256, 768), (256, 256))),
+    # the caller's bounds hold, right or wrong for the shape
+    ((1024, 1024, 64, "bfloat16", 128, 256), ((128, 256), (128, 256))),
+    ((64, 64, 16, "float32", 16, 512), ((16, 64), (16, 64))),
+    # rows so wide that a preferred tile of them overruns the budget
+    ((2048, 2048, 1024, "float32", None, None), ((512, 256), (512, 512))),
+])
+def test_tiles_follow_the_shape(args, want):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    assert pa._tiles(*args) == want
+
+
+def test_major_blocks_fit_the_budget():
+    from horovod_tpu.ops import pallas_attention as pa
+
+    bf16_rows = pa._operand_row_bytes(64, "bfloat16")
+    assert bf16_rows == pa._operand_row_bytes(128, "bfloat16") == 1024
+    assert pa._operand_row_bytes(64, "float32") == 2048
+    budget = pa._RESIDENT_BYTES
+    assert pa._major(1024, 512, bf16_rows) == 1024  # a head's whole K, V
+    assert pa._major(4 * budget // bf16_rows, 512, bf16_rows) \
+        == budget // bf16_rows
+    assert pa._major(3 * 512, 512, budget // 1024) == 512  # 3 tiles: 1 or 3
+    assert pa._major(2048, 512, budget) == 512  # never less than a tile

@@ -8,10 +8,12 @@ benchmark protocol (``examples/pytorch_synthetic_benchmark.py``).
 """
 
 from .inception import InceptionV3
+from .laguna import ExpertLayer, LagunaLM
 from .mnist import MnistCNN
 from .resnet import ResNet, ResNet50, ResNet101
 from .transformer import TransformerLM, lm_loss
 from .vgg import VGG16, VGG19
 
 __all__ = ["MnistCNN", "ResNet", "ResNet50", "ResNet101",
-           "TransformerLM", "lm_loss", "VGG16", "VGG19", "InceptionV3"]
+           "TransformerLM", "lm_loss", "VGG16", "VGG19", "InceptionV3",
+           "LagunaLM", "ExpertLayer"]

@@ -1,0 +1,487 @@
+"""Laguna-style decoder: RMSNorm, rotary positions, grouped key/value
+heads, window and full attention mixed layer by layer with a head count of
+their own, a head-wise output gate, gated SiLU MLPs and a routed expert
+layer that is told which experts it holds (docs/laguna.md).
+
+The second decoder beside ``transformer.TransformerLM``: the same call
+(``model(tokens) -> float32 logits``), so ``make_lm_train_step`` and
+``lm_loss`` take it unchanged, and the same kernel
+(``ops.pallas_attention.flash_attention``, here with grouped heads and a
+window). ``LagunaLM.from_config`` reads the keys of the published
+``config.json`` (poolside/Laguna-XS.2) plus ``experts_held``.
+
+The expert layer (``ExpertLayer``) stands for one chip of an expert-parallel
+deployment: it routes every token over all ``num_experts``, keeps
+``experts_per_token`` of them, and computes the part of the result that
+the experts it holds give, plus the shared expert. What the absent experts
+would add is left out, and nothing stands in for their chips or their
+traffic. No token is dropped under any imbalance: the rows routed to held
+experts are sorted by expert and multiplied as grouped matrix products
+(``ops.grouped_matmul``) over a buffer sized for a few times their expected
+number (``SLICE_OF_EVEN``), and further passes, a loop whose trip count is
+the routing's, take whatever goes beyond that, up to every row.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+ATTENTION_BACKENDS = ("flash", "dense")
+# rows the expert layer's first, unconditional pass has room for, over the
+# rows an even router would send to the held experts. The gather, the masks
+# and the scatter-add of a pass cost its slots, not the rows in them (the
+# kernels alone follow the rows), and an overflow costs a whole further
+# pass: the room buys a level step time with slots that stay empty, 37 ms
+# and 0.45 GB a step at the Laguna-XS.2 cell's size for 4 against 2 (my chip
+# run, PR 26). What filled 2 there was no trained model's routing but a
+# router frozen as seeded while the other weights trained on a cycled pool
+# (PERF.md section 6 and Open questions: make a pass follow its rows)
+SLICE_OF_EVEN = 4
+_INIT = nn.initializers.normal(0.02)
+
+
+# -- rotary positions ---------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """Rotary positions of one layer type: ``dim`` leading dims of each head
+    are rotated; ``factor`` (YaRN's, ``None`` for plain rotary) blends
+    interpolated and extrapolated frequencies as the ``transformers``
+    library does."""
+
+    theta: float
+    dim: int
+    factor: Optional[float] = None
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self):
+        """``[dim // 2]`` float32 inverse frequencies."""
+        i = jnp.arange(0, self.dim, 2, dtype=jnp.float32)
+        extrapolated = 1.0 / self.theta ** (i / self.dim)
+        if self.factor is None:
+            return extrapolated
+
+        def correction_dim(rotations):
+            return self.dim * math.log(self.original_max_position / (
+                rotations * 2 * math.pi)) / (2 * math.log(self.theta))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), self.dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = jnp.clip((jnp.arange(self.dim // 2, dtype=jnp.float32) - low)
+                        / (high - low), 0.0, 1.0)
+        return extrapolated / self.factor * ramp + extrapolated * (1.0 - ramp)
+
+    def __call__(self, x, positions):
+        """Rotate ``x`` [B, T, H, D] at ``positions`` [B, T]: the first
+        ``dim`` dims of each head in halves (``rotate_half``), float32."""
+        angles = positions[..., None].astype(jnp.float32) * self.inv_freq()
+        cos, sin = (jnp.concatenate([f(angles)] * 2, axis=-1)[:, :, None, :]
+                    * self.attention_factor for f in (jnp.cos, jnp.sin))
+        turned, kept = x[..., :self.dim].astype(jnp.float32), x[..., self.dim:]
+        first, second = jnp.split(turned, 2, axis=-1)
+        turned = turned * cos + jnp.concatenate([-second, first], -1) * sin
+        return jnp.concatenate([turned.astype(x.dtype), kept], axis=-1)
+
+
+def dense_attention(q, k, v, window: Optional[int] = None):
+    """Causal attention written out, grouped heads and a window as
+    ``flash_attention`` takes them; float32 softmax."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
+        / math.sqrt(q.shape[-1])
+    distance = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])
+    keep = distance >= 0
+    if window is not None:
+        keep = keep & (distance < window)
+    weights = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v)
+
+
+# -- the block's parts --------------------------------------------------------
+
+
+class GatedMLP(nn.Module):
+    """``(silu(x W1) * (x W3)) W2``, no biases."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, kernel_init=_INIT, name=name)
+        h = nn.silu(dense(self.width, "w1")(x)) * dense(self.width, "w3")(x)
+        return dense(x.shape[-1], "w2")(h)
+
+
+class GroupedAttention(nn.Module):
+    """Causal self-attention with ``num_heads`` query heads on
+    ``num_kv_heads`` key/value heads, rotary positions, an optional window
+    and a head-wise sigmoid gate on its output."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rotary: Rotary
+    window: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+    attention: str = "flash"
+
+    @nn.compact
+    def __call__(self, x, positions):
+        if self.attention not in ATTENTION_BACKENDS:
+            raise ValueError(f"attention must be one of {ATTENTION_BACKENDS},"
+                             f" got {self.attention!r}")
+
+        def heads(n, name):
+            return nn.DenseGeneral((n, self.head_dim), use_bias=False,
+                                   dtype=self.dtype, kernel_init=_INIT,
+                                   name=name)(x)
+
+        q = self.rotary(heads(self.num_heads, "query"), positions)
+        k = self.rotary(heads(self.num_kv_heads, "key"), positions)
+        v = heads(self.num_kv_heads, "value")
+        if self.attention == "flash":
+            from ..ops.pallas_attention import flash_attention
+
+            out = flash_attention(q, k, v, causal=True, window=self.window)
+        else:
+            out = dense_attention(q, k, v, self.window)
+        gate = nn.Dense(self.num_heads, use_bias=False, dtype=self.dtype,
+                        kernel_init=_INIT, name="gate")(x)
+        out = out.astype(self.dtype) * nn.sigmoid(gate)[..., None]
+        return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
+                               dtype=self.dtype, kernel_init=_INIT,
+                               name="out")(out)
+
+
+# -- the expert layer ---------------------------------------------------------
+
+
+def route(scores, experts_per_token: int, scaling: float):
+    """``(ids, weights)`` [N, k]: the ``experts_per_token`` largest of the
+    sigmoid ``scores`` [N, E], their weights normalised to sum 1 over the
+    selected and scaled."""
+    top, ids = jax.lax.top_k(scores, experts_per_token)
+    return ids, scaling * top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def _expert_pass(lo, x, weights, w1, w3, w2, slots, tile_ends, size: int,
+                 tile: int):
+    """The held experts' weighted outputs for the ``size`` slots from ``lo``
+    on, added up by token: float32 [N, d]. ``slots`` holds, expert after
+    expert and each expert's rows padded to whole tiles, the index of an
+    assignment (token ``index // k``) or -1; expert ``e``'s tiles (of
+    ``tile`` rows) end at tile ``tile_ends[e]``. ``weights`` [N, k]."""
+    from ..ops.grouped_matmul import grouped_matmul
+
+    k = weights.shape[1]
+    rows = jax.lax.dynamic_slice_in_dim(slots, lo, size)
+    valid = (rows >= 0)[:, None]
+    token = jnp.maximum(rows, 0) // k
+    tiles = lo // tile + jnp.arange(size // tile)
+    group = jnp.minimum(jnp.searchsorted(tile_ends, tiles, side="right"),
+                        w1.shape[0] - 1)
+    active = jnp.clip(tile_ends[-1] - lo // tile, 0, tiles.size)
+
+    def grouped(a, w):
+        # the tiles past the last active one belong to no product: whatever
+        # the kernel leaves there is replaced, forward and backward
+        return jnp.where(valid, grouped_matmul(a, w, group, active, tile), 0)
+
+    a = jnp.where(valid, x[token], 0)
+    y = grouped(nn.silu(grouped(a, w1)) * grouped(a, w3), w2)
+    y = y.astype(jnp.float32) * jnp.where(
+        valid, weights.reshape(-1)[jnp.maximum(rows, 0)][:, None], 0.0)
+    return jnp.zeros((x.shape[0], x.shape[1]), jnp.float32).at[token].add(y)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _with_further_passes(first, x, weights, w1, w3, w2, slots, tile_ends,
+                         size: int, tile: int):
+    """``first`` (the pass over the leading ``size`` slots) plus the passes
+    over as many further slices as the slots in use reach: a loop whose
+    trip count is the routing's, none at all under an even router. Its
+    backward pass runs the same loop, a pass recomputed and transposed at
+    a time, so that an overflow costs time and no memory."""
+    return jax.lax.fori_loop(
+        1, -(-tile_ends[-1] * tile // size),
+        lambda p, out: out + _expert_pass(
+            p * size, x, weights, w1, w3, w2, slots, tile_ends, size, tile),
+        first)
+
+
+def _further_fwd(first, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
+    out = _with_further_passes(first, x, weights, w1, w3, w2, slots,
+                               tile_ends, size, tile)
+    return out, (x, weights, w1, w3, w2, slots, tile_ends)
+
+
+def _further_bwd(size, tile, res, g):
+    *operands, slots, tile_ends = res
+
+    def one(p, grads):
+        _, transpose = jax.vjp(lambda *a: _expert_pass(
+            p * size, *a, slots, tile_ends, size, tile), *operands)
+        return jax.tree_util.tree_map(jnp.add, grads, transpose(g))
+
+    # zeros that vary over mesh axes as their operands do (shard_map)
+    zeros = tuple(jax.lax.select(jnp.zeros(a.shape, bool), a,
+                                 jnp.zeros_like(a)) for a in operands)
+    grads = jax.lax.fori_loop(1, -(-tile_ends[-1] * tile // size), one, zeros)
+    return (g, *grads, None, None)
+
+
+_with_further_passes.defvjp(_further_fwd, _further_bwd)
+
+
+def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
+                    num_experts: int):
+    """``sum over the held e among a token's experts of weight_e *
+    expert_e(x)`` for tokens ``x`` [N, d], routed to ``ids`` [N, k] with
+    ``weights`` [N, k]; the experts held are ``first .. first + len(w1)``,
+    each ``(silu(x w1) * (x w3)) w2``. Float32 [N, d].
+
+    The assignments to held experts are sorted by expert and laid out in
+    slots, each expert's rows padded to whole tiles of the grouped-product
+    kernel (``ops.grouped_matmul``). A pass multiplies the rows of a slice
+    of the slots. A slice holds ``SLICE_OF_EVEN`` times the rows an even
+    router would send here, and the first pass is unconditional; further
+    ones run only as far as the slots in use reach, so the kernels' work
+    follows the rows routed here and no row is ever dropped."""
+    from ..ops.grouped_matmul import ROW_TILE
+    from ..ops.spmd import vary_like
+
+    n, k = ids.shape
+    held = w1.shape[0]
+    capacity = n * k
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)  # held rows first, by expert
+    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    ends = jnp.cumsum(counts)
+    # a slice: SLICE_OF_EVEN times the rows an even router sends here, in
+    # whole tiles (tiles of 8 rows where that is less than one kernel tile)
+    even = -(-SLICE_OF_EVEN * capacity * held // num_experts)
+    tile = ROW_TILE if even >= ROW_TILE else 8
+    size = min(-(-capacity // tile), -(-even // tile)) * tile
+    tiles_of = -(-counts // tile)
+    tile_ends = jnp.cumsum(tiles_of)
+    # the slot of the p-th sorted assignment: its expert's first slot plus
+    # its rank among the expert's rows
+    expert = jnp.minimum(key[order], held - 1)
+    slot = (tile_ends - tiles_of)[expert] * tile \
+        + jnp.arange(capacity) - (ends - counts)[expert]
+    room = -(-(capacity + held * tile) // size) * size
+    slots = jnp.full((room,), -1, jnp.int32).at[
+        jnp.where(jnp.arange(capacity) < ends[-1], slot, room)].set(
+            order.astype(jnp.int32), mode="drop")
+    operands = (x, weights, *(w.astype(x.dtype) for w in (w1, w3, w2)),
+                slots, tile_ends)
+    out = _expert_pass(0, *operands, size, tile)
+    if size < room:
+        # the loop's trip count is this device's own: its operands are typed
+        # as varying like the tokens, so that the sum of the replicated
+        # weights' gradient over the mesh axis happens once, outside it
+        out = _with_further_passes(out, *vary_like(x, *operands), size, tile)
+    return out
+
+
+class ExpertLayer(nn.Module):
+    """Routed experts, of which this chip holds ``experts_held = (first,
+    count)``, plus one shared expert. Routes over all ``num_experts`` in
+    float32, keeps ``experts_per_token``, adds ``shared(x)`` and the held
+    experts' weighted outputs; what absent experts would add is left out.
+
+    Sows into the collection ``moe_stats`` (when the caller makes it
+    mutable): ``assignments`` [num_experts], how many of the ``N * k``
+    assignments each expert got, and ``absent``, how many went to experts
+    not held (``obs.moe.publish`` turns them into gauges)."""
+
+    num_experts: int
+    experts_per_token: int
+    experts_held: Tuple[int, int]
+    width: int
+    shared_width: int
+    scaling: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        first, held = self.experts_held
+        if not 0 <= first <= first + held <= self.num_experts or held < 1:
+            raise ValueError(f"experts_held {self.experts_held} is no part "
+                             f"of {self.num_experts} experts")
+        d = x.shape[-1]
+        tokens = x.reshape(-1, d)
+        with jax.named_scope("hvd.moe"):
+            with jax.named_scope("hvd.moe.route"):
+                # float32 in earnest: without ``highest`` the TPU multiplies
+                # float32 operands in one bfloat16 pass
+                scores = nn.sigmoid(nn.Dense(
+                    self.num_experts, use_bias=False, dtype=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST, kernel_init=_INIT,
+                    name="router")(tokens.astype(jnp.float32)))
+                ids, weights = route(scores, self.experts_per_token,
+                                     self.scaling)
+            counts = jnp.zeros((self.num_experts,), jnp.int32).at[
+                ids.reshape(-1)].add(1)
+            self.sow("moe_stats", "assignments", counts)
+            self.sow("moe_stats", "absent",
+                     ids.size - jnp.sum(counts[first:first + held]))
+            with jax.named_scope("hvd.moe.experts"):
+                w1, w3 = (self.param(name, _INIT, (held, d, self.width))
+                          for name in ("experts_w1", "experts_w3"))
+                w2 = self.param("experts_w2", _INIT, (held, self.width, d))
+                routed = held_expert_sum(tokens, ids, weights, w1, w3, w2,
+                                         first, self.num_experts)
+                shared = GatedMLP(self.shared_width, self.dtype,
+                                  name="shared")(tokens)
+            with jax.named_scope("hvd.moe.combine"):
+                out = shared + routed.astype(self.dtype)
+        return out.reshape(x.shape)
+
+
+class LagunaBlock(nn.Module):
+    """Pre-RMSNorm residual block: grouped attention, then a dense gated
+    MLP (``dense_width``) or, where that is ``None``, the expert layer."""
+
+    attn: dict          # GroupedAttention's fields
+    dense_width: Optional[int]
+    experts: dict       # ExpertLayer's fields
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, positions):
+        norm = lambda name: nn.RMSNorm(  # noqa: E731
+            epsilon=self.eps, dtype=self.dtype, name=name)
+        x = x + GroupedAttention(dtype=self.dtype, name="attn", **self.attn)(
+            norm("ln_attn")(x), positions)
+        h = norm("ln_mlp")(x)
+        if self.dense_width is not None:
+            return x + GatedMLP(self.dense_width, self.dtype, name="mlp")(h)
+        return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
+
+
+class LagunaLM(nn.Module):
+    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
+    Layer ``i`` is ``layer_types[i]`` (``"full_attention"`` or
+    ``"sliding_attention"``) with ``heads_per_layer[i]`` query heads, and
+    its MLP ``mlp_layer_types[i]`` (``"dense"`` or ``"sparse"``)."""
+
+    vocab_size: int
+    d_model: int
+    head_dim: int
+    num_kv_heads: int
+    layer_types: Tuple[str, ...]
+    heads_per_layer: Tuple[int, ...]
+    mlp_layer_types: Tuple[str, ...]
+    dense_width: int
+    expert_width: int
+    shared_width: int
+    num_experts: int
+    experts_per_token: int
+    experts_held: Tuple[int, int]
+    routed_scaling: float
+    window: int
+    rotary_full: Rotary
+    rotary_sliding: Rotary
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    attention: str = "flash"
+    # jax.checkpoint each block: only the block-boundary activations are
+    # stored, a block's interior is recomputed in backward
+    remat: bool = False
+
+    @classmethod
+    def from_config(cls, config: dict, **overrides) -> "LagunaLM":
+        """The model of a published ``config.json``'s keys, cut to
+        ``num_hidden_layers`` leading layers, with ``experts_held``
+        ``{"first": .., "count": ..}`` (all of them when absent)."""
+        depth = config["num_hidden_layers"]
+        ropes = config["rope_parameters"]
+
+        def rotary(p):
+            dim = int(config["head_dim"] * p.get("partial_rotary_factor", 1))
+            if p.get("rope_type", "default") == "default":
+                return Rotary(theta=p["rope_theta"], dim=dim)
+            return Rotary(
+                theta=p["rope_theta"], dim=dim, factor=p["factor"],
+                original_max_position=p["original_max_position_embeddings"],
+                beta_fast=p["beta_fast"], beta_slow=p["beta_slow"],
+                attention_factor=p["attention_factor"])
+
+        held = config.get("experts_held",
+                          {"first": 0, "count": config["num_experts"]})
+        fields = dict(
+            vocab_size=config["vocab_size"], d_model=config["hidden_size"],
+            head_dim=config["head_dim"],
+            num_kv_heads=config["num_key_value_heads"],
+            layer_types=tuple(config["layer_types"][:depth]),
+            heads_per_layer=tuple(
+                config["num_attention_heads_per_layer"][:depth]),
+            mlp_layer_types=tuple(config["mlp_layer_types"][:depth]),
+            dense_width=config["intermediate_size"],
+            expert_width=config["moe_intermediate_size"],
+            shared_width=config["shared_expert_intermediate_size"],
+            num_experts=config["num_experts"],
+            experts_per_token=config["num_experts_per_tok"],
+            experts_held=(held["first"], held["count"]),
+            routed_scaling=config["moe_routed_scaling_factor"],
+            window=config["sliding_window"],
+            rotary_full=rotary(ropes["full_attention"]),
+            rotary_sliding=rotary(ropes["sliding_attention"]),
+            eps=config["rms_norm_eps"])
+        fields.update(overrides)
+        return cls(**fields)
+
+    @nn.compact
+    def __call__(self, tokens, positions=None):
+        if not len(self.layer_types) == len(self.heads_per_layer) \
+                == len(self.mlp_layer_types):
+            raise ValueError("layer_types, heads_per_layer and "
+                             "mlp_layer_types must be equally long")
+        if positions is None:
+            positions = jnp.broadcast_to(
+                jnp.arange(tokens.shape[1]), tokens.shape)
+        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                     embedding_init=_INIT, name="tok_embed")(tokens)
+        block_cls = nn.remat(LagunaBlock) if self.remat else LagunaBlock
+        for i, (kind, heads, mlp) in enumerate(zip(
+                self.layer_types, self.heads_per_layer,
+                self.mlp_layer_types)):
+            sliding = kind == "sliding_attention"
+            attn = dict(
+                num_heads=heads, num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim, attention=self.attention,
+                rotary=self.rotary_sliding if sliding else self.rotary_full,
+                window=self.window if sliding else None)
+            experts = dict(
+                num_experts=self.num_experts,
+                experts_per_token=self.experts_per_token,
+                experts_held=self.experts_held, width=self.expert_width,
+                shared_width=self.shared_width, scaling=self.routed_scaling)
+            x = block_cls(
+                attn=attn, experts=experts, eps=self.eps, dtype=self.dtype,
+                dense_width=self.dense_width if mlp == "dense" else None,
+                name=f"block_{i}")(x, positions)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                       name="ln_final")(x)
+        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                          kernel_init=_INIT, name="lm_head")(x)
+        return logits.astype(jnp.float32)

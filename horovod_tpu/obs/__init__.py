@@ -18,6 +18,8 @@ The subsystem docs live in docs/metrics.md; the pieces:
 * :mod:`.compiles` — the compile ledger: one ``jax.monitoring`` listener
   behind ``horovod_compiles_total`` / ``horovod_compile_seconds_total``
   and :func:`compile_events` (which program compiled, when);
+* :mod:`.moe` — the expert layer's routing gauges, from the flax
+  collection it sows (docs/laguna.md);
 * :func:`metrics_snapshot` — the Python API: this process's families, or
   the world-aggregated view rank 0's coordinator assembled from the
   per-rank pushes riding the HMAC control wire.
@@ -40,6 +42,7 @@ from . import compiles  # noqa: F401
 from .compiles import CompileEvent, compile_events  # noqa: F401
 from . import exposition  # noqa: F401
 from . import flightrec  # noqa: F401 - public surface (docs/blackbox.md)
+from . import moe  # noqa: F401 - public surface (docs/laguna.md)
 from . import tensorwatch  # noqa: F401 - public surface (docs/tensorwatch.md)
 from .tensorwatch import tensor_report  # noqa: F401
 from .tracing import (  # noqa: F401 - public surface (docs/tracing.md)

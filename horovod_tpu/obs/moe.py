@@ -1,0 +1,50 @@
+"""Gauges of the expert layer's routing (docs/laguna.md).
+
+``models.laguna.ExpertLayer`` sows, into the flax collection ``moe_stats``,
+how many assignments each expert got (``assignments``) and how many went to
+experts this chip does not hold (``absent``). A training step does not
+carry the collection; a caller who wants the numbers applies the model with
+``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
+"""
+
+from __future__ import annotations
+
+from .registry import registry as _metrics
+
+_LOAD = _metrics().gauge(
+    "horovod_moe_expert_load_max_over_mean",
+    "Assignments of the busiest expert over the mean over all experts, by "
+    "expert layer (1.0: the router spreads tokens evenly)",
+    labels=("layer",))
+_HELD = _metrics().gauge(
+    "horovod_moe_held_assignment_share",
+    "Share of a layer's token-to-expert assignments that went to experts "
+    "this chip holds (held / num_experts under an even router)",
+    labels=("layer",))
+
+
+def publish(moe_stats) -> dict:
+    """Set the gauges from a ``moe_stats`` collection and return what was
+    set, ``{layer: {"load_max_over_mean": .., "held_share": ..}}``; a
+    layer is the path of its module, ``block_3/moe``."""
+    import numpy as np
+    from flax.traverse_util import flatten_dict
+
+    sown = {}
+    for (*module, name), values in flatten_dict(dict(moe_stats)).items():
+        # ``sow`` keeps a tuple of what was sown: the newest is the last
+        sown.setdefault("/".join(module), {})[name] = np.asarray(values[-1])
+    out = {}
+    for layer, stats in sorted(sown.items()):
+        if not {"assignments", "absent"} <= set(stats):
+            continue
+        counts = stats["assignments"].astype(np.float64)
+        total = counts.sum()
+        if not total:
+            continue
+        out[layer] = {
+            "load_max_over_mean": float(counts.max() / counts.mean()),
+            "held_share": float(1.0 - stats["absent"] / total)}
+        _LOAD.labels(layer=layer).set(out[layer]["load_max_over_mean"])
+        _HELD.labels(layer=layer).set(out[layer]["held_share"])
+    return out

@@ -1,0 +1,231 @@
+"""Pallas grouped matrix products for the experts a chip holds.
+
+``grouped_matmul(rows, weights, tile_group, active_tiles)`` multiplies each
+tile of ``row_tile`` consecutive ``rows`` [m, k] by the matrix of
+``weights`` [groups, k, n] that ``tile_group`` names for it. The caller
+lays the rows out so that a tile belongs to one group (a group's rows
+padded to whole tiles, ``models.laguna.held_expert_sum``); only the first
+``active_tiles`` tiles are computed, so the work follows the rows that were
+routed here, not the buffer that could hold all of them. Rows of later
+tiles come back unspecified (the caller masks them).
+
+Design: the grid walks the row tiles; a group's whole matrix is one block,
+so that consecutive tiles of a group fetch it once (the index map repeats
+the block index, and Pallas skips the copy); ``tile_group`` and
+``active_tiles`` are scalar-prefetched, and past the last active tile every
+index map stays on that tile, so an idle grid step moves no data. Three
+calls, named as a trace shows them: ``expert_matmul_fwd`` (rows x W),
+``expert_matmul_bwd_dx`` (the rows' gradient, dY x W^T, the same kernel
+with the matrix's other axis contracted) and ``expert_matmul_bwd_dw`` (the
+weights' gradient, rows^T x dY summed over a group's tiles in a float32
+accumulator). Float32 accumulation throughout.
+
+``jax.lax.ragged_dot`` computes the same products, and the TPU compiler
+turns it into kernels of its own — but under the name ``ragged-dot-none``
+and with the program's scopes dropped from their metadata (a step of the
+``laguna_xs2_8k_1chip`` cell read ``unscoped_pct`` 6.4 with it; my chip
+run, PR 26), so a trace could not say whose time they are; JAX's own Pallas
+grouped matmul (``pallas.ops.tpu.megablox``) declares no ``vma`` on its
+outputs and cannot be traced inside the step's ``shard_map``.
+
+``interpret=True`` (automatic on the CPU backend only) runs the kernels
+through the Pallas interpreter, as ``pallas_attention`` does. Inside a
+vma-tracking ``shard_map`` the interpreter cannot run them; there a
+``jax.numpy`` stand-in is lowered for the CPU and the kernels for any other
+platform, whichever backend is attached.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_attention import _sds
+from .spmd import operand_vma, vary_like
+
+ROW_TILE = 256
+_MIB = 1024 * 1024
+# the largest matrix of one group that is kept whole in VMEM; the kernels
+# ask for room for its two pipeline buffers, a float32 accumulator of its
+# size and the row tiles (``_vmem_limit``), within a v5e core's 128 MiB
+_MATRIX_BYTES = 16 * _MIB
+
+
+def _last(i, active_ref):
+    """Tile ``i``, or the last active one past it (0 when none is)."""
+    return jnp.maximum(jnp.minimum(i, active_ref[0] - 1), 0)
+
+
+def _rows_kernel(group_ref, active_ref, a_ref, w_ref, o_ref, *,
+                 transpose: bool):
+    """One row tile times its group's matrix (``transpose``: times the
+    matrix transposed, for the rows' gradient)."""
+    del group_ref
+
+    @pl.when(pl.program_id(0) < active_ref[0])
+    def _run():
+        o_ref[...] = jax.lax.dot_general(
+            a_ref[...], w_ref[0],
+            (((1,), (1 if transpose else 0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _weights_kernel(group_ref, active_ref, a_ref, g_ref, o_ref, acc):
+    """``rows^T x dY`` of one tile, added to its group's accumulator: the
+    grid is sequential, a group's tiles are consecutive, and its block is
+    written back when the walk leaves it."""
+    i = pl.program_id(0)
+    first = (i == 0) | (group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])
+
+    @pl.when(i < active_ref[0])
+    def _run():
+        @pl.when(first)
+        def _init():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += jax.lax.dot_general(
+            a_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0] = acc[...].astype(o_ref.dtype)
+
+
+def _check(rows, weights, contracted: int, tile: int):
+    """The number of ``tile``-row tiles of ``rows`` [m, width], checked
+    against axis ``contracted`` of ``weights`` [groups, k, n]."""
+    m, width = rows.shape
+    if weights.shape[contracted] != width or m % tile:
+        raise ValueError(f"rows {rows.shape} do not fit weights "
+                         f"{weights.shape} in tiles of {tile} rows")
+    if weights[0].size * weights.dtype.itemsize > _MATRIX_BYTES:
+        raise ValueError(
+            f"a group's matrix {weights.shape[1:]} {weights.dtype} does not "
+            f"stay resident: more than {_MATRIX_BYTES} bytes")
+    return m // tile
+
+
+def _compiler_params(interpret: bool, weights):
+    # sequential: the idle steps past the last active tile revisit its
+    # blocks, and the weights' gradient accumulates over a group's tiles.
+    # VMEM: the matrix twice (pipeline buffers), once more in float32 for
+    # the weights' gradient, and 16 MiB for the row tiles and temporaries
+    matrix = weights[0].size * max(weights.dtype.itemsize, 4)
+    return None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=16 * _MIB + 4 * matrix)
+
+
+def _rows_call(rows, weights, tile_group, active, transpose, tile, interpret):
+    tiles = _check(rows, weights, 2 if transpose else 1, tile)
+    out_width = weights.shape[1 if transpose else 2]
+    row_block = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda i, group, active: (_last(i, active), 0))
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, transpose=transpose),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles,),
+            in_specs=[row_block(rows.shape[1]),
+                      pl.BlockSpec((1, *weights.shape[1:]),
+                                   lambda i, group, active: (
+                                       group[_last(i, active)], 0, 0))],
+            out_specs=row_block(out_width)),
+        out_shape=_sds((rows.shape[0], out_width), rows.dtype, rows, weights),
+        compiler_params=_compiler_params(interpret, weights),
+        interpret=interpret,
+        name="expert_matmul_bwd_dx" if transpose else "expert_matmul_fwd",
+    )(tile_group, active, rows, weights)
+
+
+def _weights_call(rows, grads, like, tile_group, active, tile, interpret):
+    tiles = _check(rows, like, 1, tile)
+    row_block = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda i, group, active: (_last(i, active), 0))
+    out = pl.pallas_call(
+        _weights_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles,),
+            in_specs=[row_block(rows.shape[1]), row_block(grads.shape[1])],
+            out_specs=pl.BlockSpec(
+                (1, *like.shape[1:]),
+                lambda i, group, active: (group[_last(i, active)], 0, 0)),
+            scratch_shapes=[pltpu.VMEM(like.shape[1:], jnp.float32)]),
+        out_shape=_sds(like.shape, like.dtype, rows, grads),
+        compiler_params=_compiler_params(interpret, like),
+        interpret=interpret,
+        name="expert_matmul_bwd_dw",
+    )(tile_group, active, rows, grads)
+    # a group that no active tile belongs to was never written
+    visited = jnp.zeros((like.shape[0],), bool).at[tile_group].max(
+        jnp.arange(tiles) < active[0])
+    return jnp.where(visited[:, None, None], out, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _grouped(rows, weights, tile_group, active, tile, interpret):
+    return _rows_call(rows, weights, tile_group, active, False, tile,
+                      interpret)
+
+
+def _grouped_fwd(rows, weights, tile_group, active, tile, interpret):
+    return (_rows_call(rows, weights, tile_group, active, False, tile,
+                       interpret), (rows, weights, tile_group, active))
+
+
+def _grouped_bwd(tile, interpret, res, g):
+    rows, weights, tile_group, active = res
+    return (_rows_call(g, weights, tile_group, active, True, tile, interpret),
+            _weights_call(rows, g, weights, tile_group, active, tile,
+                          interpret),
+            None, None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# jitted, as ``flash_attention`` is: behind the boundary the calls keep
+# their names whatever transformation traces them (without it a trace shows
+# ``transpose_jvp_expert_matmul_bwd_dx__``)
+@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
+def grouped_matmul(rows: jax.Array, weights: jax.Array,
+                   tile_group: jax.Array, active_tiles: jax.Array,
+                   row_tile: int = ROW_TILE,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """``[m, n]`` in ``rows``' dtype: tile ``t`` of ``rows`` [m, k]
+    (``row_tile`` rows) times ``weights[tile_group[t]]`` [k, n] for
+    ``t < active_tiles``; later rows unspecified. Differentiable in
+    ``rows`` (whose gradient is unspecified in the same rows) and
+    ``weights``; what the caller puts into inactive tiles is never read."""
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    operands = (weights, tile_group.astype(jnp.int32),
+                jnp.reshape(active_tiles, (1,)).astype(jnp.int32))
+    # inside a vma-tracking shard_map the rows vary over the data axis and
+    # replicated weights do not: the kernels' operands are typed alike
+    operands = vary_like(rows, *operands)
+    if interpret and operand_vma(rows):
+        # the stand-in is for a program lowered for the CPU alone: one
+        # lowered for a TPU from a CPU box (``chipbench.aot``) gets the
+        # kernels the chip will run
+        return jax.lax.platform_dependent(
+            rows, *operands,
+            cpu=functools.partial(_tile_by_tile, tile=row_tile),
+            default=lambda *a: _grouped(*a, row_tile, False))
+    return _grouped(rows, *operands, row_tile, interpret)
+
+
+def _tile_by_tile(rows, weights, tile_group, active, tile: int):
+    """The same product in ``jax.numpy``, for the one place the kernels
+    cannot be interpreted: the Pallas interpreter refuses to index a
+    scalar-prefetched operand that varies over a mesh axis (a
+    vma-tracking ``shard_map`` lowered for the CPU)."""
+    tiles = rows.shape[0] // tile
+    out = jnp.einsum("tmk,tkn->tmn", rows.reshape(tiles, tile, -1),
+                     weights[tile_group],
+                     preferred_element_type=jnp.float32)
+    live = (jnp.arange(tiles) < active[0])[:, None, None]
+    return jnp.where(live, out, 0).astype(rows.dtype).reshape(
+        rows.shape[0], -1)

@@ -32,6 +32,17 @@ trace (docs/metrics.md). Tiles are chosen from the shapes by what the v5e
 measured (``_tiles``; PERF.md, PR 25): the per-step and per-row costs
 outweigh wasted pairs up to 512 rows (backward) and 1024 (forward).
 
+Grouped heads and a window. Query head ``h`` of ``Hq`` reads K/V head
+``h // (Hq // Hkv)``: the K/V index map divides the grid's head index by
+the group's size, so K and V are never repeated in HBM, and the dK/dV grid
+runs over K/V heads with the group's query heads on its sequential axis, so
+that dK and dV are summed over the group in the accumulators. Under a
+``window`` the loops also *start* at the window's edge (``k_window_bounds``
+/ ``q_window_bounds``), the sequential grid axis counts only the major
+blocks a tile's window can reach, starting from its first
+(``_grid_majors``, ``_major_index``), and the window's mask runs on the
+tiles that straddle its edge; no tile is preferred larger than the window.
+
 The forward's softmax is two loops a major block: scores into a VMEM
 buffer with their lane-wise maximum, one cross-lane reduction a row, then
 ``exp``, lane-wise sums and P V — the statistics and the accumulators'
@@ -53,8 +64,10 @@ file is a plain or transposed-RHS product — none contracts dim 0 of its
 left operand.
 
 The three ``pallas_call``s are named ``flash_fwd``, ``flash_bwd_dq`` and
-``flash_bwd_dkv``: the names a compiled program's custom calls and a
-profiler trace show them under (docs/tracing.md).
+``flash_bwd_dkv`` (``flash_win_fwd``, ``flash_win_bwd_dq``,
+``flash_win_bwd_dkv`` for a call with a window): the names a compiled
+program's custom calls and a profiler trace show them under
+(docs/tracing.md).
 
 ``interpret=True`` (automatic on the CPU backend only) runs the same
 kernels through the Pallas interpreter, which is how the CPU test suite
@@ -133,31 +146,91 @@ def q_tile_bounds(k_tile, *, q_offset: int, tile_q: int, tile_k: int,
     return start, interior
 
 
+def k_window_bounds(q_tile, *, q_offset: int, tile_q: int, tile_k: int,
+                    num_k_tiles: int, window: Optional[int]):
+    """``(lo, clear)`` for one q tile under a window (q position ``t`` sees
+    the k positions ``s`` with ``t - window < s``): k tiles before ``lo``
+    lie wholly behind the window, ``[lo, clear)`` straddle its edge, and
+    those from ``clear`` on lose no pair to it."""
+    if window is None:
+        return 0, 0
+    first_q = q_offset + q_tile * tile_q
+    lo = _clip(_clip(first_q - window + 1, 0) // tile_k, 0, num_k_tiles)
+    clear = _clip(_clip(first_q + tile_q - 1 - window + tile_k, 0) // tile_k,
+                  0, num_k_tiles)
+    return lo, clear
+
+
+def q_window_bounds(k_tile, *, q_offset: int, tile_q: int, tile_k: int,
+                    num_q_tiles: int, window: Optional[int]):
+    """``(edge, stop)`` for one k tile under a window: q tiles before
+    ``edge`` lose no pair of this k tile to the window, ``[edge, stop)``
+    straddle its edge, and those from ``stop`` on lie wholly beyond it."""
+    if window is None:
+        return num_q_tiles, num_q_tiles
+    first_k = k_tile * tile_k - q_offset  # in q-row coordinates
+    edge = _clip(_clip(first_k + window, 0) // tile_q, 0, num_q_tiles)
+    stop = _clip((_clip(first_k + tile_k - 1 + window, 0) + tile_q - 1)
+                 // tile_q, 0, num_q_tiles)
+    return edge, stop
+
+
+def _k_walk(q_tile, *, window: Optional[int], causal: bool, **tiles):
+    """``(lo, a, b, end)``: one q tile executes the k tiles ``[lo, end)``;
+    ``[lo, a)`` and ``[b, end)`` run the masked body (the window's edge and
+    the diagonal), ``[a, b)`` runs with no mask."""
+    interior, end = k_tile_bounds(q_tile, causal=causal, **tiles)
+    if window is None:
+        return 0, 0, interior, end
+    lo, clear = k_window_bounds(q_tile, window=window, **tiles)
+    a = _clip(clear, lo, end)
+    return lo, a, _clip(interior, a, end), end
+
+
+def _q_walk(k_tile, *, window: Optional[int], causal: bool, **tiles):
+    """``(start, a, b, stop)``: one k tile executes the q tiles
+    ``[start, stop)``; ``[start, a)`` and ``[b, stop)`` run the masked body
+    (the diagonal and the window's edge), ``[a, b)`` runs with no mask."""
+    start, interior = q_tile_bounds(k_tile, causal=causal, **tiles)
+    num_q_tiles = tiles["num_q_tiles"]
+    if window is None:
+        return start, interior, num_q_tiles, num_q_tiles
+    edge, stop = q_window_bounds(k_tile, window=window, **tiles)
+    a = _clip(interior, start, stop)
+    return start, a, _clip(edge, a, stop), stop
+
+
 def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
-                    tile_k: int, causal: bool) -> dict:
+                    tile_k: int, causal: bool,
+                    window: Optional[int] = None) -> dict:
     """What each kernel executes at these shapes: ``tiles`` (compute tiles
     run), ``diagonal`` (how many of them run the masked body) and
     ``pair_ratio`` (executed score pairs over the pairs the mask keeps).
     ``flash_fwd`` and ``flash_bwd_dq`` walk k tiles for each q tile,
-    ``flash_bwd_dkv`` walks q tiles for each k tile."""
+    ``flash_bwd_dkv`` walks q tiles for each k tile; a call with a
+    ``window`` runs the same three under their ``flash_win_*`` names."""
     num_q_tiles, num_k_tiles = seq_q // tile_q, seq_k // tile_k
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-                    causal=causal)
-    by_q = [k_tile_bounds(i, num_k_tiles=num_k_tiles, **schedule)
+                    causal=causal, window=window)
+    by_q = [_k_walk(i, num_k_tiles=num_k_tiles, **schedule)
             for i in range(num_q_tiles)]
-    by_k = [q_tile_bounds(j, num_q_tiles=num_q_tiles, **schedule)
+    by_k = [_q_walk(j, num_q_tiles=num_q_tiles, **schedule)
             for j in range(num_k_tiles)]
-    needed = seq_q * seq_k if not causal else sum(
-        min(seq_k, q_offset + row + 1) for row in range(seq_q))
 
-    def walk(tiles, diagonal):
-        return {"tiles": tiles, "diagonal": diagonal,
+    def visible(row):
+        last = min(seq_k, q_offset + row + 1)
+        first = 0 if window is None else max(0, q_offset + row + 1 - window)
+        return max(0, last - first)
+
+    needed = seq_q * seq_k if not causal else sum(map(visible, range(seq_q)))
+
+    def walk(walks):
+        tiles = sum(max(0, end - lo) for lo, _, _, end in walks)
+        clear = sum(max(0, b - a) for _, a, b, _ in walks)
+        return {"tiles": tiles, "diagonal": tiles - clear,
                 "pair_ratio": tiles * tile_q * tile_k / needed}
 
-    over_k = walk(sum(end for _, end in by_q),
-                  sum(end - interior for interior, end in by_q))
-    over_q = walk(sum(num_q_tiles - start for start, _ in by_k),
-                  sum(interior - start for start, interior in by_k))
+    over_k, over_q = walk(by_q), walk(by_k)
     return {"flash_fwd": over_k, "flash_bwd_dq": over_k,
             "flash_bwd_dkv": over_q}
 
@@ -201,15 +274,41 @@ def _first_tile(major_idx, tiles_per_major: int, num_tiles: int):
     return 0 if tiles_per_major == num_tiles else major_idx * tiles_per_major
 
 
-def _causal_mask(s, q_pos0, k_pos0, q_axis=0):
-    """Mask future positions of a score block to the _NEG_INF sentinel.
-    q positions run along ``q_axis`` of ``s`` and k positions along the
-    other axis (``q_axis=1`` is the dK/dV kernel's transposed block).
-    Shared by forward and backward so the two can never disagree on what
-    was masked."""
+def _grid_majors(num_majors: int, major: int, tile: int,
+                 window: Optional[int]) -> int:
+    """Steps of the sequential grid axis. Without a window it counts the
+    streamed operand's major blocks from the first; with one it counts
+    from the first block a tile's window reaches (``_major_index``), and a
+    tile of ``tile`` rows sees ``tile + window - 1`` positions, which span
+    no more blocks than this."""
+    if window is None:
+        return num_majors
+    return min(num_majors, (tile + window - 1 + major - 2) // major + 1)
+
+
+def _major_index(step, first_tile, tiles_per_major: int, grid_majors: int,
+                 num_majors: int):
+    """The major block that grid step ``step`` works on: ``step`` itself
+    where the grid walks every block, else counted from the block that
+    holds ``first_tile``, the first tile the window reaches."""
+    if grid_majors == num_majors:
+        return step
+    return first_tile // tiles_per_major + step
+
+
+def _causal_mask(s, q_pos0, k_pos0, q_axis=0, window=None):
+    """Mask future positions of a score block to the _NEG_INF sentinel, and
+    with a ``window`` the positions it has left behind (``q - k >=
+    window``). q positions run along ``q_axis`` of ``s`` and k positions
+    along the other axis (``q_axis=1`` is the dK/dV kernel's transposed
+    block). Shared by forward and backward so the two can never disagree
+    on what was masked."""
     q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
 def _col(stat):
@@ -228,7 +327,7 @@ def _lane_fold(x, lanes: int, op):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
                 s_buf, m_lane, l_lane, *, scale: float, causal: bool,
                 q_offset: int, tile_q: int, tile_k: int, major_k: int,
-                num_k_tiles: int):
+                num_k_tiles: int, window: Optional[int], grid_majors: int):
     """One q tile against the resident K/V major block ``kk``. Grid (bh,
     q-tile, k-major), the last sequential; ``o_acc``, ``m_acc`` and
     ``l_acc`` persist across it.
@@ -242,26 +341,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
     ``exp(S - m)``, sums it lane by lane into ``l_lane`` and accumulates
     P V. With the whole of K resident that is the plain softmax.
 
-    With ``q_offset >= 0`` every row keeps its pair with k position 0, which
-    the first tile of the first major block holds; no row is ever empty, so
-    ``m`` is finite wherever it is subtracted."""
+    No row is ever empty: with ``q_offset >= 0`` every row keeps its pair
+    with k position 0, and under a window its pair with its own position
+    (``flash_attention`` requires that K holds it). A window's row may
+    still find every pair of an *early* major block masked; its statistics
+    stay at the sentinel there, what it accumulates is finite, and the
+    first block with a visible pair rescales it by ``exp(sentinel - m)``,
+    which is 0. So ``m`` is finite wherever the result depends on it."""
     # program_id must be read at kernel top level: inside a pl.when body it
     # escapes the interpreter's scope (breaks interpret=True on CPU)
-    kk = pl.program_id(2)
+    step = pl.program_id(2)
     q_idx = pl.program_id(1)
     tiles_per_major = major_k // tile_k
     lanes = m_lane.shape[1]
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         o_acc[...] = jnp.zeros_like(o_acc)
         m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    first = _first_tile(kk, tiles_per_major, num_k_tiles)
-    interior, end = k_tile_bounds(
+    lo, a, b, end = _k_walk(
         q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-        num_k_tiles=num_k_tiles, causal=causal)
+        num_k_tiles=num_k_tiles, causal=causal, window=window)
+    kk = _major_index(step, lo, tiles_per_major, grid_majors,
+                      num_k_tiles // tiles_per_major)
+    first = _first_tile(kk, tiles_per_major, num_k_tiles)
 
     # nothing to do in a major block wholly in this q tile's future. (The
     # branch also keeps the refs' slicing off the kernel's top level, where
@@ -280,15 +385,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
                 q_block, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if masked:
-                s = _causal_mask(s, q_pos0, kk * major_k + j * tile_k)
+                s = _causal_mask(s, q_pos0, kk * major_k + j * tile_k,
+                                 window=window)
             s_buf[:, cols] = s
             m_lane[...] = jnp.maximum(m_lane[...],
                                       _lane_fold(s, lanes, jnp.maximum))
 
-        _for_tiles(0, interior, first, tiles_per_major,
+        if window is not None:
+            _for_tiles(lo, a, first, tiles_per_major,
+                       functools.partial(scores, masked=True))
+        _for_tiles(a, b, first, tiles_per_major,
                    functools.partial(scores, masked=False))
         if causal:
-            _for_tiles(interior, end, first, tiles_per_major,
+            _for_tiles(b, end, first, tiles_per_major,
                        functools.partial(scores, masked=True))
 
         m_old = _col(m_acc[...])
@@ -307,14 +416,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
                 p, v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        _for_tiles(0, end, first, tiles_per_major, weigh)
+        _for_tiles(lo, end, first, tiles_per_major, weigh)
 
         l_new = _col(l_acc[...]) * corr \
             + l_lane[...].sum(axis=1, keepdims=True)
         l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
         m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
 
-    @pl.when(kk == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l = l_acc[...]
         o_ref[0, ...] = (o_acc[...] / _col(l)).astype(o_ref.dtype)
@@ -323,7 +432,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
 
 
 def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
-                 transposed=False):
+                 transposed=False, window=None):
     """Recompute the normalized probability block P = exp(S - lse), with
     S's mask on a ``masked`` (diagonal) tile; shared by both backward
     kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
@@ -336,7 +445,8 @@ def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
         lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if masked:
-        s = _causal_mask(s, q_pos0, k_pos0, q_axis=1 if transposed else 0)
+        s = _causal_mask(s, q_pos0, k_pos0, q_axis=1 if transposed else 0,
+                         window=window)
     # no row is empty (``_fwd_kernel``), so lse is finite and a masked
     # pair's exp(sentinel - lse) is the 0 it should be
     return jnp.exp(s - lse)
@@ -344,21 +454,25 @@ def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale: float, causal: bool, q_offset: int,
-                   tile_q: int, tile_k: int, major_k: int, num_k_tiles: int):
+                   tile_q: int, tile_k: int, major_k: int, num_k_tiles: int,
+                   window: Optional[int], grid_majors: int):
     """dQ = (P * (dO V^T - delta)) K * scale, accumulated over the k tiles
-    up to the diagonal. Grid (bh, q-tile, k-major) as the forward's."""
-    kk = pl.program_id(2)
+    from the window's edge up to the diagonal. Grid (bh, q-tile, k-major)
+    as the forward's."""
+    step = pl.program_id(2)
     q_idx = pl.program_id(1)
     tiles_per_major = major_k // tile_k
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    first = _first_tile(kk, tiles_per_major, num_k_tiles)
-    interior, end = k_tile_bounds(
+    lo, a, b, end = _k_walk(
         q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-        num_k_tiles=num_k_tiles, causal=causal)
+        num_k_tiles=num_k_tiles, causal=causal, window=window)
+    kk = _major_index(step, lo, tiles_per_major, grid_majors,
+                      num_k_tiles // tiles_per_major)
+    first = _first_tile(kk, tiles_per_major, num_k_tiles)
 
     @pl.when(first < end)  # as the forward's
     def _run():
@@ -373,7 +487,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             v_blk = v_ref[0, rows, :].astype(jnp.float32)
             p = _recompute_p(q_scaled, k_blk, lse, masked=masked,
                              q_pos0=q_pos0,
-                             k_pos0=kk * major_k + j * tile_k)
+                             k_pos0=kk * major_k + j * tile_k,
+                             window=window)
             dp = jax.lax.dot_general(  # dO V^T  [tile_q, tile_k]
                 do_blk, v_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -382,13 +497,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 ds, k_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        _for_tiles(0, interior, first, tiles_per_major,
+        if window is not None:
+            _for_tiles(lo, a, first, tiles_per_major,
+                       functools.partial(update, masked=True))
+        _for_tiles(a, b, first, tiles_per_major,
                    functools.partial(update, masked=False))
         if causal:
-            _for_tiles(interior, end, first, tiles_per_major,
+            _for_tiles(b, end, first, tiles_per_major,
                        functools.partial(update, masked=True))
 
-    @pl.when(kk == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0, ...] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -396,29 +514,39 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                     causal: bool, q_offset: int, tile_q: int, tile_k: int,
-                    major_q: int, num_q_tiles: int):
-    """dV = P^T dO and dK = (P * (dP - delta))^T Q for one k tile,
-    accumulated over the q tiles of the resident major block ``iq`` from
-    the diagonal on. Grid (bh, k-tile, q-major), the last sequential.
-    Works on the transposed blocks P^T, dP^T = V dO^T, dS^T throughout,
-    with lse and delta as [1, tile_q] rows, so no operand is ever
-    transposed."""
-    iq = pl.program_id(2)
+                    major_q: int, num_q_tiles: int, window: Optional[int],
+                    group: int, grid_majors: int):
+    """dV = P^T dO and dK = (P * (dP - delta))^T Q for one k tile of one
+    K/V head, accumulated over the q tiles of the resident major block from
+    the diagonal to the window's far edge, and over the query heads of the
+    K/V head's group. Grid (kv head, k-tile, group x q-major), the last
+    sequential: a step is one query head's major block
+    (``_dkv_step``). Works on the transposed blocks P^T, dP^T = V dO^T,
+    dS^T throughout, with lse and delta as [1, tile_q] rows, so no operand
+    is ever transposed."""
+    step = pl.program_id(2)
     k_idx = pl.program_id(1)
     tiles_per_major = major_q // tile_q
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    first = _first_tile(iq, tiles_per_major, num_q_tiles)
-    start, interior = q_tile_bounds(
+    start, a, b, stop = _q_walk(
         k_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-        num_q_tiles=num_q_tiles, causal=causal)
+        num_q_tiles=num_q_tiles, causal=causal, window=window)
+    _, major_step = _dkv_step(step, group, grid_majors)
+    iq = _major_index(major_step, start, tiles_per_major, grid_majors,
+                      num_q_tiles // tiles_per_major)
+    first = _first_tile(iq, tiles_per_major, num_q_tiles)
+    # nothing to do in a major block wholly in this k tile's past, nor in
+    # one wholly beyond its window
+    needed = start < first + tiles_per_major
+    if window is not None:
+        needed = needed & (first < stop)
 
-    # nothing to do in a major block wholly in this k tile's past
-    @pl.when(start < first + tiles_per_major)
+    @pl.when(needed)
     def _run():
         k_blk = k_ref[0].astype(jnp.float32)
         v_blk = v_ref[0].astype(jnp.float32)
@@ -431,7 +559,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p_t = _recompute_p(
                 q_blk * scale, k_blk, lse_ref[0, :, rows], masked=masked,
                 q_pos0=q_offset + iq * major_q + i * tile_q, k_pos0=k_pos0,
-                transposed=True)
+                transposed=True, window=window)
             dv_acc[...] += jax.lax.dot_general(  # P^T dO  [tile_k, d]
                 p_t, do_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -444,15 +572,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
 
         if causal:
-            _for_tiles(start, interior, first, tiles_per_major,
+            _for_tiles(start, a, first, tiles_per_major,
                        functools.partial(update, masked=True))
-        _for_tiles(interior, num_q_tiles, first, tiles_per_major,
+        _for_tiles(a, b, first, tiles_per_major,
                    functools.partial(update, masked=False))
+        if window is not None:
+            _for_tiles(b, stop, first, tiles_per_major,
+                       functools.partial(update, masked=True))
 
-    @pl.when(iq == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0, ...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, ...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _dkv_step(step, group: int, grid_majors: int):
+    """``(member, major step)`` of the dK/dV grid's sequential axis: the
+    ``group`` query heads of a K/V head one after another, each through
+    its major blocks."""
+    if group == 1:
+        return 0, step
+    if grid_majors == 1:
+        return step, 0
+    return step // grid_majors, step % grid_majors
 
 
 def _to_bh(x):
@@ -497,19 +639,25 @@ def _tile(seq: int, bound: Optional[int], prefer: int, row_bytes: int) -> int:
 
 
 def _tiles(seq_q: int, seq_k: int, head_dim: int, dtype,
-           block_q: Optional[int], block_k: Optional[int]):
+           block_q: Optional[int], block_k: Optional[int],
+           window: Optional[int] = None):
     """``((tile_q, tile_k) of the forward, (tile_q, tile_k) of the two
     backward kernels)``. What the v5e measured (PERF.md, PR 25): a loop
     step, a grid step and a row's statistics cost the same whatever the
     tile, so bigger tiles win until the pairs a causal tile throws away
     outweigh them. The forward's two matmuls a pair leave it cheapest at
     1024 even where that is the whole causal sequence; the backward's
-    three and four are cheapest at 512."""
+    three and four are cheapest at 512. Under a window no tile is
+    preferred larger than the window: every tile a row's window touches
+    would be an edge tile, most of its pairs masked."""
     row_bytes = _operand_row_bytes(head_dim, dtype)
-    fwd_q = _tile(seq_q, block_q, 1024, row_bytes)
-    return ((fwd_q, _tile(seq_k, block_k, 1024, row_bytes + 4 * fwd_q)),
-            (_tile(seq_q, block_q, 512, row_bytes),
-             _tile(seq_k, block_k, 512, row_bytes)))
+    fwd, bwd = 1024, 512
+    if window is not None:
+        fwd, bwd = (min(t, max(_LANES, window)) for t in (fwd, bwd))
+    fwd_q = _tile(seq_q, block_q, fwd, row_bytes)
+    return ((fwd_q, _tile(seq_k, block_k, fwd, row_bytes + 4 * fwd_q)),
+            (_tile(seq_q, block_q, bwd, row_bytes),
+             _tile(seq_k, block_k, bwd, row_bytes)))
 
 
 def _major(seq: int, tile: int, row_bytes: int) -> int:
@@ -529,15 +677,20 @@ def _operand_row_bytes(head_dim: int, dtype) -> int:
     return 2 * 2 * -(-head_dim // _LANES) * _LANES * jnp.dtype(dtype).itemsize
 
 
-def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, **schedule):
-    """BlockSpec of K or V on a (bh, q-tile, k-major) grid. A major block
-    wholly in a q tile's future is not fetched: the index stays on the last
-    one the tile needs."""
+def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, group: int,
+                   grid_majors: int, **schedule):
+    """BlockSpec of K or V on a (q head, q-tile, k-major) grid: the block
+    of the K/V head that the q head's group shares. A major block wholly in
+    a q tile's future is not fetched: the index stays on the last one the
+    tile needs."""
     tiles_per_major = major_k // schedule["tile_k"]
 
-    def index(bh, i, kk):
-        _, end = k_tile_bounds(i, num_k_tiles=num_k_tiles, **schedule)
-        return (bh, jnp.minimum(kk, (end - 1) // tiles_per_major), 0)
+    def index(bh, i, step):
+        lo, _, _, end = _k_walk(i, num_k_tiles=num_k_tiles, **schedule)
+        kk = _major_index(step, lo, tiles_per_major, grid_majors,
+                          num_k_tiles // tiles_per_major)
+        return (bh if group == 1 else bh // group,
+                jnp.minimum(kk, (end - 1) // tiles_per_major), 0)
 
     return pl.BlockSpec((1, major_k, head_dim), index)
 
@@ -547,28 +700,42 @@ def _compiler_params(interpret: bool):
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset):
+def _kernel_names(window: Optional[int]) -> dict:
+    """The ``pallas_call`` names by ``causal_schedule``'s keys: a call with
+    a window is named apart, so that a trace tells the two apart."""
+    if window is None:
+        return {name: name for name in
+                ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    return {"flash_fwd": "flash_win_fwd", "flash_bwd_dq": "flash_win_bwd_dq",
+            "flash_bwd_dkv": "flash_win_bwd_dkv"}
+
+
+def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
-    seq_k = k.shape[1]
+    seq_k, kv_heads = k.shape[1], k.shape[2]
     num_k_tiles = seq_k // tile_k
     # beside K and V, a row of the major block holds its f32 scores
     major_k = _major(seq_k, tile_k,
                      _operand_row_bytes(head_dim, k.dtype) + 4 * tile_q)
+    grid_majors = _grid_majors(seq_k // major_k, major_k, tile_q, window)
     lanes = _LANES if tile_k % _LANES == 0 else tile_k
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
-    _PAIR_RATIO.labels(kernel="flash_fwd").set(causal_schedule(
-        seq_q, seq_k, q_offset, tile_q, tile_k, causal)
+    name = _kernel_names(window)["flash_fwd"]
+    _PAIR_RATIO.labels(kernel=name).set(causal_schedule(
+        seq_q, seq_k, q_offset, tile_q, tile_k, causal, window)
         ["flash_fwd"]["pair_ratio"])
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-                    causal=causal)
+                    causal=causal, window=window)
 
     q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
-    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, **schedule)
+    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles,
+                             heads // kv_heads, grid_majors, **schedule)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, major_k=major_k,
-                          num_k_tiles=num_k_tiles, **schedule),
-        grid=(batch * heads, seq_q // tile_q, seq_k // major_k),
+                          num_k_tiles=num_k_tiles, grid_majors=grid_majors,
+                          **schedule),
+        grid=(batch * heads, seq_q // tile_q, grid_majors),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             q_spec,
@@ -588,7 +755,7 @@ def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset):
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_fwd",
+        name=name,
     )(qb, kb, vb)
     # the kernel repeats each row's value along the lane axis; the residual
     # kept for the backward pass is the O(T) vector
@@ -596,22 +763,25 @@ def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset):
 
 
 def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
-              q_offset):
+              q_offset, window):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
-    seq_k = k.shape[1]
+    seq_k, kv_heads = k.shape[1], k.shape[2]
+    group = heads // kv_heads
     num_q_tiles = seq_q // tile_q
     num_k_tiles = seq_k // tile_k
     major_k = _major(seq_k, tile_k, _operand_row_bytes(head_dim, k.dtype))
     major_q = _major(seq_q, tile_q, _operand_row_bytes(head_dim, q.dtype))
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     ob, dob = _to_bh(o), _to_bh(do)
+    names = _kernel_names(window)
     executed = causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k,
-                               causal)
-    for name in ("flash_bwd_dq", "flash_bwd_dkv"):
-        _PAIR_RATIO.labels(kernel=name).set(executed[name]["pair_ratio"])
+                               causal, window)
+    for key in ("flash_bwd_dq", "flash_bwd_dkv"):
+        _PAIR_RATIO.labels(kernel=names[key]).set(
+            executed[key]["pair_ratio"])
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
-                    causal=causal)
+                    causal=causal, window=window)
     # delta_i = sum_d dO_id O_id = sum_j dP_ij P_ij  (softmax Jacobian term)
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)  # [B*H, Tq]
@@ -623,13 +793,16 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
         for x in (lse, delta))
     lse_rows, delta_rows = lse[:, None, :], delta[:, None, :]
 
+    k_majors = _grid_majors(seq_k // major_k, major_k, tile_q, window)
     q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
-    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, **schedule)
+    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, group, k_majors,
+                             **schedule)
     col_spec = pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, major_k=major_k,
-                          num_k_tiles=num_k_tiles, **schedule),
-        grid=(batch * heads, num_q_tiles, seq_k // major_k),
+                          num_k_tiles=num_k_tiles, grid_majors=k_majors,
+                          **schedule),
+        grid=(batch * heads, num_q_tiles, k_majors),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
         out_specs=q_spec,
         out_shape=_sds((batch * heads, seq_q, head_dim), q.dtype,
@@ -637,87 +810,117 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
         scratch_shapes=[pltpu.VMEM((tile_q, head_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=names["flash_bwd_dq"],
     )(qb, kb, vb, dob, lse_cols, delta_cols)
 
-    # dK/dV grid: (bh, k-tile, q-major) — the streamed q-side operands
-    # re-index by the LAST grid axis here
+    # dK/dV grid: (kv head, k-tile, group x q-major) — the streamed q-side
+    # operands re-index by the LAST grid axis here: the query heads of the
+    # K/V head's group, each through its major blocks
     num_q_majors = seq_q // major_q
+    q_majors = _grid_majors(num_q_majors, major_q, tile_k, window)
 
-    def q_major(kk, iq):
-        # a major block wholly in this k tile's past is not fetched: the
-        # index waits on the first one the tile needs
-        start, _ = q_tile_bounds(kk, num_q_tiles=num_q_tiles, **schedule)
-        return jnp.maximum(iq, jnp.minimum(start // (major_q // tile_q),
-                                           num_q_majors - 1))
+    def q_head(bh, step):
+        member, _ = _dkv_step(step, group, q_majors)
+        return bh if group == 1 else bh * group + member
 
-    kv_q_spec = pl.BlockSpec((1, major_q, head_dim),
-                             lambda bh, kk, iq: (bh, q_major(kk, iq), 0))
+    def q_major(kk, step):
+        _, major_step = _dkv_step(step, group, q_majors)
+        start, _, _, stop = _q_walk(kk, num_q_tiles=num_q_tiles, **schedule)
+        tiles_per_major = major_q // tile_q
+        if window is None:
+            # a major block wholly in this k tile's past is not fetched:
+            # the index waits on the first one the tile needs
+            return jnp.maximum(major_step, jnp.minimum(
+                start // tiles_per_major, num_q_majors - 1))
+        # nor is one wholly beyond its window: the index stays on the last
+        iq = _major_index(major_step, start, tiles_per_major, q_majors,
+                          num_q_majors)
+        first = jnp.minimum(start // tiles_per_major, num_q_majors - 1)
+        last = jnp.maximum((stop - 1) // tiles_per_major, first)
+        return jnp.clip(iq, first, last)
+
+    kv_q_spec = pl.BlockSpec(
+        (1, major_q, head_dim),
+        lambda bh, kk, step: (q_head(bh, step), q_major(kk, step), 0))
     kv_k_spec = pl.BlockSpec((1, tile_k, head_dim),
-                             lambda bh, kk, iq: (bh, kk, 0))
-    kv_row_spec = pl.BlockSpec((1, 1, major_q),
-                               lambda bh, kk, iq: (bh, 0, q_major(kk, iq)))
+                             lambda bh, kk, step: (bh, kk, 0))
+    kv_row_spec = pl.BlockSpec(
+        (1, 1, major_q),
+        lambda bh, kk, step: (q_head(bh, step), 0, q_major(kk, step)))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, major_q=major_q,
-                          num_q_tiles=num_q_tiles, **schedule),
-        grid=(batch * heads, num_k_tiles, num_q_majors),
+                          num_q_tiles=num_q_tiles, group=group,
+                          grid_majors=q_majors, **schedule),
+        grid=(batch * kv_heads, num_k_tiles, group * q_majors),
         in_specs=[kv_q_spec, kv_k_spec, kv_k_spec, kv_q_spec,
                   kv_row_spec, kv_row_spec],
         out_specs=[kv_k_spec, kv_k_spec],
         out_shape=[
-            _sds((batch * heads, seq_k, head_dim), k.dtype, q, k, v, do),
-            _sds((batch * heads, seq_k, head_dim), v.dtype, q, k, v, do),
+            _sds((batch * kv_heads, seq_k, head_dim), k.dtype, q, k, v, do),
+            _sds((batch * kv_heads, seq_k, head_dim), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[pltpu.VMEM((tile_k, head_dim), jnp.float32),
                         pltpu.VMEM((tile_k, head_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=names["flash_bwd_dkv"],
     )(qb, kb, vb, dob, lse_rows, delta_rows)
 
-    return (_from_bh(dq, batch, heads), _from_bh(dk, batch, heads),
-            _from_bh(dv, batch, heads))
+    return (_from_bh(dq, batch, heads), _from_bh(dk, batch, kv_heads),
+            _from_bh(dv, batch, kv_heads))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-           q_offset):
-    o, _ = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret, q_offset)
+           q_offset, window):
+    o, _ = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret, q_offset,
+                     window)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-               q_offset):
+               q_offset, window):
     o, lse = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret,
-                       q_offset)
+                       q_offset, window)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, scale, fwd_tiles, bwd_tiles, interpret, q_offset,
-               res, do):
+               window, res, do):
     q, k, v, o, lse = res
     return _bwd_impl(q, k, v, o, lse, do, causal, scale, bwd_tiles,
-                     interpret, q_offset)
+                     interpret, q_offset, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret", "q_offset"))
+    "causal", "scale", "block_q", "block_k", "interpret", "q_offset",
+    "window"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    q_offset: int = 0) -> jax.Array:
-    """Fused attention, shapes [batch, seq, heads, head_dim]. Differentiable
-    (custom VJP with FlashAttention-2 recomputation kernels).
+                    q_offset: int = 0,
+                    window: Optional[int] = None) -> jax.Array:
+    """Fused attention, q ``[batch, seq, heads, head_dim]`` and k, v
+    ``[batch, seq_k, kv_heads, head_dim]``. Differentiable (custom VJP with
+    FlashAttention-2 recomputation kernels).
+
+    Grouped heads: ``heads`` is a multiple of ``kv_heads``, and query head
+    ``h`` reads K/V head ``h // (heads // kv_heads)``; K and V are not
+    repeated in HBM, and dK, dV come back summed over each group.
 
     ``q_offset`` shifts the global position of q (in elements, any
     non-negative count) for causal masking — how ring attention uses a
-    kernel per KV shard. ``block_q`` / ``block_k`` are upper bounds on the
-    compute tiles, which the shapes and ``causal`` choose (``_tiles``);
+    kernel per KV shard. ``window`` (causal only) keeps, for the query at
+    position ``t``, the keys at ``t - window < s <= t``; K must hold every
+    query's own position (``q_offset + seq <= seq_k``), so that no row is
+    empty. ``block_q`` / ``block_k`` are upper bounds on the compute
+    tiles, which the shapes, ``causal`` and ``window`` choose (``_tiles``);
     sequence lengths must be multiples of the tiles (pad upstream; a tile
     is the whole sequence when that is shorter).
     """
@@ -728,12 +931,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if q_offset < 0:
         raise ValueError("q_offset must be non-negative")
     seq_q, seq_k = q.shape[1], k.shape[1]
+    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"k {k.shape} and v {v.shape} must agree, and their heads must "
+            f"divide q's {q.shape[2]}")
+    if window is not None and (not causal or window < 1
+                               or q_offset + seq_q > seq_k):
+        raise ValueError(
+            "window needs causal=True, window >= 1 and every query's own "
+            f"position among the keys (q_offset {q_offset} + {seq_q} "
+            f"queries > {seq_k} keys)")
     fwd_tiles, bwd_tiles = _tiles(seq_q, seq_k, q.shape[-1], k.dtype,
-                                  block_q, block_k)
+                                  block_q, block_k, window)
     for tile_q, tile_k in (fwd_tiles, bwd_tiles):
         if seq_q % tile_q or seq_k % tile_k:
             raise ValueError(
                 f"sequence lengths ({seq_q}, {seq_k}) must be multiples of "
                 f"the block sizes ({tile_q}, {tile_k}); pad inputs first.")
     return _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-                  q_offset)
+                  q_offset, window)

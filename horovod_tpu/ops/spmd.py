@@ -126,6 +126,21 @@ def operand_vma(*xs):
         return None
 
 
+def vary_like(like, *xs):
+    """``xs`` typed as varying over every manual mesh axis that ``like``
+    varies over (``xs`` unchanged outside a vma-tracking ``shard_map``).
+    For operands of a ``custom_vjp`` or a ``pallas_call`` that mixes
+    sharded activations with replicated weights: all are typed alike
+    inside, and the transpose of this cast — a ``psum`` — sums a
+    replicated operand's gradient over the axis once, outside."""
+    axes = operand_vma(like)
+    if not axes:
+        return xs
+    return tuple(
+        lax.pcast(x, tuple(axes - operand_vma(x)), to="varying")
+        if axes - operand_vma(x) else x for x in xs)
+
+
 def allreduce(x: jax.Array, axis_name: AxisName, average: bool = True) -> jax.Array:
     """Sum (or average) across the named mesh axis.
 

@@ -108,6 +108,8 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
+    from horovod_tpu.ops.spmd import overlap_compiler_options
+
     if hierarchical is None:
         from horovod_tpu.optimizers import _use_hierarchical
 
@@ -157,7 +159,8 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
                   in_specs=(P(), P(), P(), P(axis_name), P(axis_name)),
                   out_specs=(P(), P(), P(), P()),
                   check_vma=not (hierarchical or explicit_grad_reduce)),
-        donate_argnums=(0, 1, 2) if donate else ())
+        donate_argnums=(0, 1, 2) if donate else (),
+        compiler_options=overlap_compiler_options(mesh, axis_name))
 
 
 def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
@@ -173,6 +176,7 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     from jax.sharding import PartitionSpec as P
 
     from horovod_tpu.models import lm_loss
+    from horovod_tpu.ops.spmd import overlap_compiler_options
 
     def train_step(params, opt_state, tokens):
         def loss_fn(p):
@@ -191,4 +195,5 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
         shard_map(train_step, mesh=mesh,
                   in_specs=(P(), P(), P(axis_name)),
                   out_specs=(P(), P(), P())),
-        donate_argnums=(0, 1))
+        donate_argnums=(0, 1),
+        compiler_options=overlap_compiler_options(mesh, axis_name))

@@ -218,13 +218,15 @@ def train_resnet(model, mesh, *, batch_per_device: int, image_side: int,
     compiled = step.lower(params, opt_state, batch_stats, images,
                           labels).compile()
     compile_seconds = time.perf_counter() - t0
-    n_allreduce = check_allreduce_spans_mesh(compiled.as_text(), mesh,
-                                             "ResNet step")
+    hlo = compiled.as_text()
+    n_allreduce = check_allreduce_spans_mesh(hlo, mesh, "ResNet step")
+    _, n_async = hvd.obs.record_exchange_collectives("resnet_step", hlo)
 
     (params, _, _), facts = run_steps(
         compiled, (params, opt_state, batch_stats), (images, labels), steps)
     check_replicated(params, mesh, "params after training")
     return {"global_batch": global_batch, "all_reduces": n_allreduce,
+            "async_collectives": n_async,
             "setup_seconds": round(setup_seconds, 2),
             "compile_seconds": round(compile_seconds, 2), **facts}
 
@@ -270,12 +272,13 @@ def train_lm(mesh, *, num_layers: int, num_heads: int, d_model: int,
               "the compiled LM step has no Mosaic custom call: the flash "
               "kernel did not compile for the chip")
     n_allreduce = check_allreduce_spans_mesh(hlo, mesh, "LM step")
+    _, n_async = hvd.obs.record_exchange_collectives("lm_step", hlo)
 
     (params, _), facts = run_steps(compiled, (params, opt_state), (tokens,),
                                    steps)
     check_replicated(params, mesh, "params after training")
     return {"global_batch": global_batch, "mosaic_custom_calls": mosaic_calls,
-            "all_reduces": n_allreduce,
+            "all_reduces": n_allreduce, "async_collectives": n_async,
             "setup_seconds": round(setup_seconds, 2),
             "compile_seconds": round(compile_seconds, 2), **facts}
 

@@ -17,7 +17,9 @@ The subsystem docs live in docs/metrics.md; the pieces:
   straggler attribution folded into :func:`straggler_report`;
 * :mod:`.compiles` — the compile ledger: one ``jax.monitoring`` listener
   behind ``horovod_compiles_total`` / ``horovod_compile_seconds_total``
-  and :func:`compile_events` (which program compiled, when);
+  and :func:`compile_events` (which program compiled, when), and
+  :func:`record_exchange_collectives` (whether a compiled step's
+  collectives can run beside compute);
 * :mod:`.moe` — the expert layer's routing gauges, from the flax
   collection it sows (docs/laguna.md);
 * :func:`metrics_snapshot` — the Python API: this process's families, or
@@ -39,7 +41,8 @@ from .registry import (  # noqa: F401 - public surface
 )
 from .bridge import TimelineBridge  # noqa: F401
 from . import compiles  # noqa: F401
-from .compiles import CompileEvent, compile_events  # noqa: F401
+from .compiles import (CompileEvent, compile_events,  # noqa: F401
+                       record_exchange_collectives)
 from . import exposition  # noqa: F401
 from . import flightrec  # noqa: F401 - public surface (docs/blackbox.md)
 from . import moe  # noqa: F401 - public surface (docs/laguna.md)

@@ -22,11 +22,17 @@ keeps
 The listener runs only when JAX compiles; a steady training loop never
 reaches it. The list outlives ``shutdown()``, so that it can be read
 after the job.
+
+:func:`record_exchange_collectives` reads a compiled step's text for the
+other thing compilation decides: whether the gradient exchange can run
+beside compute (``horovod_exchange_collectives{program}``,
+``horovod_exchange_async_collectives{program}``).
 """
 
 from __future__ import annotations
 
 import collections
+import re
 import threading
 import time
 from typing import List, NamedTuple
@@ -50,6 +56,21 @@ _COMPILE_SECONDS = _metrics().counter(
     "horovod_compile_seconds_total",
     "Seconds JAX spent per compile stage (cache_retrieval is also inside "
     "backend_compile)", labels=("stage",))
+_EXCHANGE_COLLECTIVES = _metrics().gauge(
+    "horovod_exchange_collectives",
+    "Collectives (all-reduce, reduce-scatter, all-gather) in a compiled "
+    "step's scheduled text", labels=("program",))
+_EXCHANGE_ASYNC_COLLECTIVES = _metrics().gauge(
+    "horovod_exchange_async_collectives",
+    "Those of horovod_exchange_collectives that compiled to a form compute "
+    "runs beside: a -start/-done pair or an async collective fusion",
+    labels=("program",))
+
+# the opcode of a collective instruction in scheduled HLO text; a ``-done``
+# closes a ``-start`` and is not counted again
+_COLLECTIVE_OPCODE = re.compile(
+    r" (?:all-reduce|reduce-scatter|all-gather)(-start)?\(")
+_CHAIN_ID = re.compile(r'chain_id="(\d+)"')
 
 
 class CompileEvent(NamedTuple):
@@ -144,6 +165,37 @@ def ledger() -> CompileLedger:
 def compiles_total() -> int:
     """``horovod_compiles_total`` as it stands."""
     return int(_COMPILES.value)
+
+
+def record_exchange_collectives(program: str, hlo_text: str) -> tuple:
+    """``(collectives, asynchronous ones)`` of a compiled step's text
+    (``compiled.as_text()``), set on the two gauges under ``program``.
+
+    A synchronous collective holds the core while it runs. Asynchronous,
+    and counted as such, is one that compiled to a form in which compute
+    runs beside it: a ``-start``/``-done`` pair or, from the TPU compiler,
+    an *async collective fusion* — the collective cut into steps, each
+    fused with a neighbouring operation, every step's copy of it carrying
+    the same ``chain_id`` (counted once). An ``async_collective_name``
+    alone is not counted: the v5e runs such an all-reduce as one
+    operation with nothing beside it (PERF.md §6, PR 27)."""
+    total = asynchronous = 0
+    chains = set()
+    for line in hlo_text.splitlines():
+        m = _COLLECTIVE_OPCODE.search(line)
+        if m is None:
+            continue
+        chain = _CHAIN_ID.search(line)
+        if chain is not None:
+            chains.add(chain.group(1))
+            continue
+        total += 1
+        asynchronous += bool(m.group(1))
+    total += len(chains)
+    asynchronous += len(chains)
+    _EXCHANGE_COLLECTIVES.labels(program=program).set(total)
+    _EXCHANGE_ASYNC_COLLECTIVES.labels(program=program).set(asynchronous)
+    return total, asynchronous
 
 
 def compile_events() -> List[CompileEvent]:

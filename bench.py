@@ -101,47 +101,6 @@ def _git_head() -> Optional[str]:
     return git_head_sha(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _scan_cost_counts_body_once(log) -> bool:
-    """Verify, on this backend, that ``cost_analysis()`` counts a
-    ``lax.scan`` body once rather than times the trip count.
-
-    The scan-mode MFU fields rest on that assumption; if a JAX/XLA
-    version multiplied body flops by the trip count, mfu_pct/tflops
-    would silently inflate by ``scan_batches``. Two toy compiles
-    (64x64 matmul scanned 1x vs 4x) settle it at runtime; on any
-    failure to measure, answer False so MFU is omitted rather than
-    risk emitting inflated numbers.
-    """
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        def flops_at(length):
-            def f(x):
-                y, _ = jax.lax.scan(lambda c, _: (c @ c, None), x,
-                                    None, length=length)
-                return y
-            comp = jax.jit(f).lower(
-                jnp.ones((64, 64), jnp.float32)).compile()
-            return float(comp.cost_analysis().get("flops", 0.0))
-
-        f1, f4 = flops_at(1), flops_at(4)
-        if not f1 or not f4:
-            log("scan cost-model check inconclusive (no flops reported); "
-                "omitting MFU fields for the scan-mode row")
-            return False
-        once = f4 < 2.0 * f1
-        if not once:
-            log(f"cost_analysis multiplies scan body by trip count on this "
-                f"backend (flops x{f4 / f1:.1f} at length 4); omitting MFU "
-                f"fields for the scan-mode row")
-        return once
-    except Exception as exc:  # noqa: BLE001 - check is best-effort
-        log(f"scan cost-model check failed ({exc!r}); omitting MFU fields "
-            f"for the scan-mode row")
-        return False
-
-
 def _step_flops_of(compiled, log) -> Optional[float]:
     """XLA's own FLOP count for one compiled step (per-device SPMD
     program) — what MFU should be computed from; an analytic 2*MACs
@@ -466,31 +425,7 @@ def main() -> None:
     opt_state = opt.init(params)
     params = hvd.broadcast_parameters(params, root_rank=0)
 
-    # HOROVOD_BENCH_SCAN_BATCHES (opt-in): execute batches in lax.scan-ned
-    # device calls — =1 means one call per whole iteration
-    # (--num-batches-per-iter batches), =N>1 means N-batch calls (N must
-    # divide --num-batches-per-iter). Diagnostic, not the reference
-    # protocol — comparing against the default isolates
-    # Python-dispatch/pipeline-drain overhead from true device time. The
-    # result line is marked (scan_batches, vs_baseline null).
-    scan_env = int(os.environ.get("HOROVOD_BENCH_SCAN_BATCHES", "0"))
-    scan_mode = scan_env > 0
-    scan_batches = ((args.num_batches_per_iter if scan_env == 1
-                     else scan_env) if scan_mode else 1)
-    if scan_mode:
-        if args.num_batches_per_iter % scan_batches:
-            log(f"HOROVOD_BENCH_SCAN_BATCHES={scan_batches} must divide "
-                f"--num-batches-per-iter {args.num_batches_per_iter}")
-            sys.exit(2)
-        log(f"scan mode: {scan_batches} batches per dispatched call "
-            f"(NOT the reference protocol)")
-    step = make_dp_train_step(model, opt, mesh, axis_name="data",
-                              scan_batches=scan_batches,
-                              # compressed allreduce must CARRY the bytes:
-                              # see _dp_step's explicit_grad_reduce note
-                              explicit_grad_reduce=(args.fp16_allreduce
-                                                    or args.int8_allreduce)
-                              or None)
+    step = make_dp_train_step(model, opt, mesh, axis_name="data")
 
     # AOT-compile once; _step_flops_of reads the executable's own cost
     # analysis for the MFU denominator's numerator.
@@ -507,12 +442,8 @@ def main() -> None:
         params, opt_state, batch_stats, loss = compiled(
             params, opt_state, batch_stats, images, labels)
 
-    # in scan mode each dispatched call IS scan_batches batches; ceil so
-    # at least the requested warmup runs, and 0 still means none
-    warmup_calls = -(-args.num_warmup_batches // scan_batches)
-    calls_per_iter = args.num_batches_per_iter // scan_batches
-    log(f"Running {warmup_calls * scan_batches} warmup batches...")
-    for _ in range(warmup_calls):
+    log(f"Running {args.num_warmup_batches} warmup batches...")
+    for _ in range(args.num_warmup_batches):
         run_batch()
     jax.block_until_ready(params)
 
@@ -529,8 +460,6 @@ def main() -> None:
         **_device_stamp(device, n_dev),
         "git_sha": _git_head(),
     }
-    if scan_mode:
-        provenance["scan_batches"] = scan_batches  # marked: not protocol
     if args.fp16_allreduce:
         provenance["fp16_allreduce"] = True
     if args.int8_allreduce:
@@ -550,7 +479,7 @@ def main() -> None:
 
     for i in range(args.num_iters):
         t0 = time.perf_counter()
-        for _ in range(calls_per_iter):
+        for _ in range(args.num_batches_per_iter):
             run_batch()
         jax.block_until_ready(params)
         dt = time.perf_counter() - t0
@@ -566,11 +495,9 @@ def main() -> None:
         f"(loss {float(loss):.3f})")
 
     # the P100 anchor is a ResNet-101 figure; a cross-model ratio would be
-    # meaningless for vgg16/inception3, so emit null there — and for the
-    # non-protocol scan diagnostic, whatever the model
+    # meaningless for vgg16/inception3, so emit null there
     vs_baseline = (round(per_device / REFERENCE_PER_DEVICE_IMG_S, 3)
-                   if args.model.startswith("resnet") and not scan_mode
-                   else None)
+                   if args.model.startswith("resnet") else None)
     result = dict(provenance)
     result.update({
         "value": round(per_device, 2),
@@ -699,15 +626,9 @@ def main() -> None:
             _hier_total("horovod_hier_merged_cycles_total"))
         result["hier_raw_cycles"] = int(
             _hier_total("horovod_hier_raw_cycles_total"))
-    # cost_analysis() reports the per-device SPMD program's flops — and for
-    # a lax.scan program it must count the loop BODY once, not times the
-    # trip count, or mfu/tflops inflate by scan_batches. One body == one
-    # batch in either mode, so the rate to multiply by is batches/s — but
-    # in scan mode only after verifying the count-once behavior on this
-    # backend (two toy compiles; omit MFU fields if it doesn't hold).
-    if not scan_mode or _scan_cost_counts_body_once(log):
-        _add_mfu_fields(result, step_flops, mean / global_batch,
-                        device, log)
+    # cost_analysis() reports the per-device SPMD program's flops for one
+    # batch, so the rate to multiply by is batches/s
+    _add_mfu_fields(result, step_flops, mean / global_batch, device, log)
     print(json.dumps(result))
     hvd.shutdown()
 

@@ -1,13 +1,13 @@
-"""Shared data-parallel train-step construction for the benchmark scripts.
+"""The benchmark's two step bodies and their synthetic jobs.
 
-One definition of each measured program (model apply + loss + grad +
-DistributedOptimizer update, jitted as a shard_map over the data axis) so
-`bench.py`, `benchmarks/scaling_bench.py`, `benchmarks/lm_bench.py` and
-`chip_smoke.py` cannot drift apart — the reference keeps its protocol in
-one script per framework for the same reason
-(``examples/pytorch_synthetic_benchmark.py:37-110``).
+What every cell of ``chipbench`` (and ``bench.py``, ``chip_smoke.py``, the
+``benchmarks/*_bench.py`` scripts) measures: an image step (model apply +
+loss + grad + ``DistributedOptimizer`` update + BatchNorm statistics) and
+a language-model step, each written once as the per-shard function and
+compiled by ``hvd.parallel.data_parallel_step``, which owns how a
+data-parallel step is traced and compiled.
 
-Both builders name the phases of the step with ``jax.named_scope`` —
+Both bodies name the phases of the step with ``jax.named_scope`` —
 ``hvd.loss`` (forward; its transpose is the backward pass),
 ``hvd.apply_updates``, ``hvd.sync_stats`` — beside the ``hvd.exchange`` and
 ``hvd.optimizer`` that ``DistributedOptimizer`` brings; the names reach the
@@ -70,52 +70,20 @@ def synthesize_lm_job(model, mesh, global_batch: int, seq_len: int,
 
 
 def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
-                       donate: bool = True, hierarchical=None,
-                       scan_batches: int = 1, explicit_grad_reduce=None):
+                       donate: bool = True):
     """Build the jitted DP train step over ``mesh``'s ``axis_name``.
 
     Returns ``step(params, opt_state, batch_stats, x, y) -> (params,
     opt_state, batch_stats, loss)`` with x/y sharded on the data axis and
     everything else replicated; ``loss`` is the cross-replica mean of the
-    loss the update was computed from (the last batch's, when scanning).
-    Models without BatchNorm pass ``batch_stats={}`` through unchanged.
-
-    ``scan_batches > 1`` wraps the step body in ``lax.scan`` so ONE
-    dispatched call executes N batches back to back on device (same
-    static batch — the synthetic-benchmark situation). Diagnostic, not
-    protocol: comparing it against N separate dispatches isolates
-    Python-dispatch / pipeline-drain overhead from true device time
-    (docs/benchmarks.md "Why bs32 caps", item 2).
-
-    ``hierarchical`` (default: follow ``HOROVOD_HIERARCHICAL_ALLREDUCE``
-    via the optimizer's own resolution) selects the two-level factored
-    gradient reduction over a (dcn, ici) ``axis_name`` pair. That mode
-    traces with ``check_vma=False``: under vma tracking shard_map pre-sums
-    replicated-param cotangents with a flat whole-mesh psum before the
-    optimizer's transform runs, which would silently bypass the factored
-    reduce_scatter/psum/all_gather route (``operations.cc:1284-1436``'s
-    TPU analog in ``parallel/hierarchical.py``).
-
-    ``explicit_grad_reduce`` (default: equals ``hierarchical``) forces the
-    same ``check_vma=False`` tracing WITHOUT the factored route — needed
-    whenever the optimizer's own reduction must carry the bytes, e.g.
-    gradient compression: under vma tracking the auto-inserted psum runs
-    in f32 BEFORE the compress hook, so the cast would be numerics-only
-    and never shrink the collective's wire traffic.
-    """
+    loss the update was computed from. Models without BatchNorm pass
+    ``batch_stats={}`` through unchanged. ``opt`` is the
+    ``hvd.DistributedOptimizer`` over ``axis_name``."""
     import jax
     import optax
-    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.ops.spmd import overlap_compiler_options
-
-    if hierarchical is None:
-        from horovod_tpu.optimizers import _use_hierarchical
-
-        hierarchical = _use_hierarchical(axis_name, None)
-    if explicit_grad_reduce is None:
-        explicit_grad_reduce = hierarchical
+    from horovod_tpu.parallel import data_parallel_step
 
     def loss_fn(params, batch_stats, x, y):
         with jax.named_scope("hvd.loss"):
@@ -141,26 +109,11 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
             loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, new_stats, loss
 
-    if scan_batches > 1:
-        single = train_step
-
-        def train_step(params, opt_state, batch_stats, x, y):  # noqa: F811
-            def body(carry, _):
-                *carry, loss = single(*carry, x, y)
-                return tuple(carry), loss
-
-            carry, losses = jax.lax.scan(
-                body, (params, opt_state, batch_stats), None,
-                length=scan_batches)
-            return (*carry, losses[-1])
-
-    return jax.jit(
-        shard_map(train_step, mesh=mesh,
-                  in_specs=(P(), P(), P(), P(axis_name), P(axis_name)),
-                  out_specs=(P(), P(), P(), P()),
-                  check_vma=not (hierarchical or explicit_grad_reduce)),
-        donate_argnums=(0, 1, 2) if donate else (),
-        compiler_options=overlap_compiler_options(mesh, axis_name))
+    return data_parallel_step(
+        train_step, opt, mesh,
+        in_specs=(P(), P(), P(), P(axis_name), P(axis_name)),
+        out_specs=(P(), P(), P(), P()),
+        donate_argnums=(0, 1, 2) if donate else ())
 
 
 def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
@@ -172,11 +125,10 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     cross-replica mean next-token cross entropy."""
     import jax
     import optax
-    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from horovod_tpu.models import lm_loss
-    from horovod_tpu.ops.spmd import overlap_compiler_options
+    from horovod_tpu.parallel import data_parallel_step
 
     def train_step(params, opt_state, tokens):
         def loss_fn(p):
@@ -191,9 +143,7 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
             loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, loss
 
-    return jax.jit(
-        shard_map(train_step, mesh=mesh,
-                  in_specs=(P(), P(), P(axis_name)),
-                  out_specs=(P(), P(), P())),
-        donate_argnums=(0, 1),
-        compiler_options=overlap_compiler_options(mesh, axis_name))
+    return data_parallel_step(
+        train_step, opt, mesh,
+        in_specs=(P(), P(), P(axis_name)), out_specs=(P(), P(), P()),
+        donate_argnums=(0, 1))

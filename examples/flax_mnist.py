@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
@@ -71,10 +70,10 @@ def main() -> None:
         loss = jax.lax.pmean(loss, hvd.parallel.DATA_AXIS)
         return state.apply_gradients(grads=grads), loss
 
-    step = jax.jit(shard_map(
-        train_step, mesh=mesh,
+    step = hvd.parallel.data_parallel_step(
+        train_step, state.tx, mesh,
         in_specs=(P(), P(hvd.parallel.DATA_AXIS), P(hvd.parallel.DATA_AXIS)),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()))
 
     x_all, y_all = synthetic_mnist(global_batch * 10, seed=1000 + hvd.rank())
     for epoch in range(args.epochs):

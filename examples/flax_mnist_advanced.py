@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
@@ -77,10 +76,10 @@ def main() -> None:
         return (optax.apply_updates(params, updates), opt_state,
                 jax.lax.pmean(loss, "data"), jax.lax.pmean(acc, "data"))
 
-    step = jax.jit(shard_map(
-        train_step, mesh=mesh,
+    step = hvd.parallel.data_parallel_step(
+        train_step, opt, mesh,
         in_specs=(P(), P(), P("data"), P("data")),
-        out_specs=(P(), P(), P(), P())))
+        out_specs=(P(), P(), P(), P()))
 
     x_all, y_all = synthetic_mnist(global_batch * 12, seed=1000 + hvd.rank())
     steps_per_epoch = x_all.shape[0] // global_batch

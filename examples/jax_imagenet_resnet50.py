@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
@@ -85,10 +84,10 @@ def main() -> None:
         return (optax.apply_updates(p, updates), s, stats,
                 jax.lax.pmean(loss, "data"))
 
-    step = jax.jit(shard_map(
-        train_step, mesh=mesh,
+    step = hvd.parallel.data_parallel_step(
+        train_step, opt, mesh,
         in_specs=(P(), P(), P(), P("data"), P("data")),
-        out_specs=(P(), P(), P(), P())))
+        out_specs=(P(), P(), P(), P()))
 
     global_batch = args.batch_size * n_dev
     rng = np.random.default_rng(hvd.rank())

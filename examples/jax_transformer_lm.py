@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
@@ -98,10 +97,10 @@ def main() -> None:
         return new_vars, opt_state, jax.lax.pmean(loss, axis)
 
     data_spec = P(None, axis) if seq_parallel else P(axis)
-    step = jax.jit(shard_map(
-        train_step, mesh=mesh,
+    step = hvd.parallel.data_parallel_step(
+        train_step, opt, mesh,
         in_specs=(P(), P(), data_spec, data_spec),
-        out_specs=(P(), P(), P())))
+        out_specs=(P(), P(), P()))
 
     for i in range(args.steps):
         variables, opt_state, loss = step(variables, opt_state, tokens,

@@ -50,7 +50,7 @@ def _use_hierarchical(axis_name, hierarchical) -> bool:
         return False
     # HOROVOD_HIERARCHICAL_ALLREDUCE knob, as in the reference
     # (operations.cc:1880-1890). Resolution must not depend on init order:
-    # make_dp_train_step consults this at BUILD time to pick check_vma, and
+    # exchange_route consults this at BUILD time to pick check_vma, and
     # a step built before hvd.init() would otherwise silently lose the
     # factored route (vma tracking pre-psums the cotangents). Initialized
     # worlds use the pinned config; otherwise read the env directly.
@@ -180,9 +180,10 @@ def allreduce_gradients(grads: Any, axis_name=None, average: bool = True,
                     "gradient leaf arrived pre-summed (vma tracking inserts "
                     "a flat whole-mesh psum in the shard_map transpose), so "
                     "the factored hierarchical route is inert for this "
-                    "step. Build the step with shard_map(..., "
-                    "check_vma=False) so cotangents reach the optimizer "
-                    "unsummed (see benchmarks/_dp_step.py).", source)
+                    "step. Build the step with "
+                    "hvd.parallel.data_parallel_step (by hand: shard_map("
+                    "..., check_vma=False)) so cotangents reach the "
+                    "optimizer unsummed.", source)
             return jax.tree_util.tree_unflatten(treedef, reduced)
         reduced = [
             ops.allreduce(g, average=average, compression=compression,
@@ -304,6 +305,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     update_fn._horovod_apply_meta = {
         "axis_name": axis_name, "average": average,
         "compression": compression, "n_acc": n_acc,
+        "hierarchical": hierarchical,
     }
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -312,6 +314,31 @@ def is_distributed(tx: optax.GradientTransformation) -> bool:
     """True if ``tx`` was produced by :func:`DistributedOptimizer` (used by
     the front-ends to refuse double wrapping)."""
     return bool(getattr(tx.update, "_horovod_distributed", False))
+
+
+def exchange_route(tx: optax.GradientTransformation) -> tuple:
+    """``(axis_name, carries_bytes)`` of a compiled step that calls ``tx``,
+    a :func:`DistributedOptimizer` over a mesh axis. ``carries_bytes``:
+    the exchange itself has to move the gradients, because its codec is
+    not ``Compression.none`` or its (dcn, ici) axis takes the factored
+    route (what ``parallel.data_parallel_step`` does about it is said
+    there). Both knobs resolve as they do when the step is traced, and a
+    resolution made before ``hvd.init()`` is kept for
+    :func:`check_build_time_resolutions`."""
+    if not is_distributed(tx):
+        raise ValueError(
+            "a compiled data-parallel step needs a DistributedOptimizer-"
+            "wrapped transform: a plain optax transform exchanges nothing")
+    meta = tx.update._horovod_apply_meta
+    axis_name = meta["axis_name"]
+    if axis_name is None:
+        raise ValueError(
+            "a compiled data-parallel step needs DistributedOptimizer(..., "
+            "axis_name=<the mesh's data axis>): without one the optimizer "
+            "reduces through the eager engine, which a traced step cannot")
+    codec = _resolve_compression(meta["compression"], record=True)
+    return axis_name, (codec is not Compression.none or _use_hierarchical(
+        axis_name, meta["hierarchical"]))
 
 
 def _fused_apply_armed() -> bool:

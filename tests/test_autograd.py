@@ -67,19 +67,24 @@ def test_allreduce_grad(hvd):
 def test_allreduce_mean_grad(hvd):
     """Average variant: backward divides by the world size
     (``torch/mpi_ops.py:110-121`` divides the cotangent for average=True).
-    Local contribution L_i = sum(y)/N, so L = sum(y) and dL/dx = 1/N."""
-    x = jnp.ones((N, 2), jnp.float32)
+    Local contributions as in ``test_allreduce_grad``, L_i = w_i . y with
+    y the mean, so dL/dx_j = sum_i w_i / N. The weight is what makes L_i a
+    per-shard value: a loss of ``y`` alone is replicated, which vma
+    tracking differentiates as ONE loss, not as the sum of N copies."""
+    x = jnp.arange(N * 2, dtype=jnp.float32).reshape(N, 2)
+    w = jnp.arange(1.0, N + 1)[:, None] * jnp.ones((N, 2))  # shard i -> i+1
 
-    def per_shard(x):
+    def per_shard(x, w):
         def loss(x):
             y = spmd.allreduce(x, DATA_AXIS, average=True)
-            return y.sum() / N
+            return jnp.vdot(w[0], y)
 
         return jax.grad(loss)(x)
 
-    g = _run(per_shard, x, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS))
-    np.testing.assert_allclose(np.asarray(g), np.full((N, 2), 1.0 / N),
-                               rtol=1e-6)
+    g = _run(per_shard, x, w,
+             in_specs=(P(DATA_AXIS), P(DATA_AXIS)), out_specs=P(DATA_AXIS))
+    expected = np.full((N, 2), sum(range(1, N + 1)) / N, np.float32)
+    np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-6)
 
 
 def test_allgather_grad(hvd):

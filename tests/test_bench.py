@@ -113,42 +113,3 @@ def test_lm_bench_end_to_end_cpu():
         assert line["tflops_per_device"] > 0
         assert (line["platform"], line["device_kind"]) == ("cpu", "cpu")
         assert line["n_devices"] >= 1
-
-
-def test_scan_wrapper_matches_separate_steps():
-    """HOROVOD_BENCH_SCAN_BATCHES runs one lax.scan-ned device call per N
-    batches: N scanned batches == N separate steps, parameters and the
-    reported loss alike (tiny model in-process; a full bench.py scan run
-    costs minutes of ResNet-50 compile and belongs on the chip)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    from jax.sharding import Mesh
-
-    import horovod_tpu as hvd
-    from benchmarks._dp_step import make_dp_train_step
-    from horovod_tpu.models import ResNet
-    from horovod_tpu.models.resnet import ResNetBlock
-
-    mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
-    model = ResNet(stage_sizes=[1], num_filters=8, num_classes=10,
-                   block_cls=ResNetBlock, dtype=jnp.float32)
-    x = jnp.ones((8, 16, 16, 3), jnp.float32)
-    y = jnp.zeros((8,), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), x)
-    params, batch_stats = variables["params"], variables["batch_stats"]
-    opt = hvd.DistributedOptimizer(optax.sgd(0.01), axis_name="data")
-    opt_state = opt.init(params)
-
-    single = make_dp_train_step(model, opt, mesh, donate=False)
-    scanned = make_dp_train_step(model, opt, mesh, donate=False,
-                                 scan_batches=3)
-    p1, s1, b1 = params, opt_state, batch_stats
-    for _ in range(3):
-        p1, s1, b1, loss1 = single(p1, s1, b1, x, y)
-    p3, s3, b3, loss3 = scanned(params, opt_state, batch_stats, x, y)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), p1, p3)
-    np.testing.assert_allclose(float(loss1), float(loss3), rtol=1e-5)

@@ -21,21 +21,6 @@ def _load_bench():
     return bench
 
 
-def test_scan_cost_model_check_cpu():
-    """Scan-mode MFU rests on cost_analysis() counting a lax.scan body
-    once, not times the trip count; bench.py verifies that at runtime
-    before attaching MFU fields (round-4 advisor). The check must answer
-    True on this backend — a full scan-mode bench run on CPU (~9 min of
-    ResNet-50 AOT compile) confirmed the end-to-end row carries
-    scan_batches + tflops_per_device; this pins the gate cheaply, so a
-    JAX upgrade that breaks the assumption surfaces in the quick tier."""
-    bench = _load_bench()
-    messages = []
-    assert bench._scan_cost_counts_body_once(messages.append) is True, \
-        messages
-    assert not messages  # no "omitting MFU" path taken
-
-
 def test_git_head_matches_shared_helper(tmp_path):
     """bench.py's _git_head must stay a thin delegate of the shared
     provenance helper (one sha-stamping implementation for every entry

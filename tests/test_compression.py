@@ -120,8 +120,9 @@ def test_int8_dp_step_wire_is_s8(hvd):
     opt_c = hvd_mod.DistributedOptimizer(optax.sgd(0.01),
                                          axis_name=DATA_AXIS,
                                          compression=Compression.int8)
+    # the codec on the optimizer is enough: the builder takes no flag
     step_c = make_dp_train_step(model, opt_c, mesh, axis_name=DATA_AXIS,
-                                donate=False, explicit_grad_reduce=True)
+                                donate=False)
     hlo = step_c.lower(params, opt_c.init(params), batch_stats, x,
                        y).compile().as_text()
     s8_collectives = re.findall(

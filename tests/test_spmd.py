@@ -246,7 +246,7 @@ step_flat = make_dp_train_step(
     model, hvd.DistributedOptimizer(optax.sgd(0.01),
                                     axis_name=("dcn", "ici"),
                                     hierarchical=False),
-    mesh, axis_name=("dcn", "ici"), hierarchical=False)
+    mesh, axis_name=("dcn", "ici"))
 hlo_flat = step_flat.lower(params, opt_state, batch_stats, x,
                            y).compile().as_text()
 assert "reduce-scatter" not in hlo_flat, "flat path grew a reduce-scatter?"
@@ -266,7 +266,9 @@ print("HIER-OK")
 def test_hierarchical_step_matches_flat_numerically(hvd):
     """The factored reduce_scatter/psum/all_gather route must be a pure
     implementation detail: one hierarchical train step from a shared init
-    produces the same parameters as the flat whole-mesh psum step."""
+    produces the same parameters as the flat whole-mesh psum step. The
+    optimizer's ``hierarchical`` alone picks the route and the tracing
+    mode the route needs: the builder takes no flag for it."""
     import optax
     from jax.sharding import Mesh
 
@@ -292,8 +294,7 @@ def test_hierarchical_step_matches_flat_numerically(hvd):
                                        hierarchical=hier)
         opt_state = opt.init(params)
         step = make_dp_train_step(model, opt, mesh,
-                                  axis_name=("dcn", "ici"),
-                                  donate=False, hierarchical=hier)
+                                  axis_name=("dcn", "ici"), donate=False)
         outs[hier] = step(params, opt_state, batch_stats, x, y)
 
     flat_p, _, flat_bn, _ = outs[False]
@@ -309,11 +310,11 @@ def test_hierarchical_step_matches_flat_numerically(hvd):
 
 
 def test_compressed_dp_step_reduces_in_bf16(hvd):
-    """--fp16-allreduce must COMPRESS THE WIRE: with explicit_grad_reduce
-    the compiled gradient all-reduce carries bf16 operands (under vma
-    tracking the auto-psum would run f32 before the compress hook, making
-    the flag numerics-only). Parameters stay close to the uncompressed
-    step."""
+    """--fp16-allreduce must COMPRESS THE WIRE: a compressing optimizer
+    is all the builder needs to make the compiled gradient all-reduce
+    carry bf16 operands (traced under vma tracking the auto-psum would run
+    f32 before the compress hook, making the codec numerics-only).
+    Parameters stay close to the uncompressed step."""
     import optax
 
     from benchmarks._dp_step import make_dp_train_step
@@ -342,7 +343,7 @@ def test_compressed_dp_step_reduces_in_bf16(hvd):
     opt_c = hvd.DistributedOptimizer(optax.sgd(0.01), axis_name=DATA_AXIS,
                                      compression=hvd.Compression.bf16)
     step_c = make_dp_train_step(model, opt_c, mesh, axis_name=DATA_AXIS,
-                                donate=False, explicit_grad_reduce=True)
+                                donate=False)
     total, bf16_n = bf16_all_reduces(step_c, opt_c.init(params))
     # a format change that breaks the scan must fail loudly, not pass 0>=0
     assert total > 0, "no stablehlo.all_reduce found in the lowered text"

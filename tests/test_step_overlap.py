@@ -1,7 +1,8 @@
 """The data-parallel step asks the compiler to run its gradient exchange
 beside other work — when, and only when, the mesh's data axis holds
 more than one TPU (``ops.spmd.overlap_compiler_options``, handed to
-``jax.jit`` by both ``benchmarks/_dp_step.py`` builders).
+``jax.jit`` by ``hvd.parallel.data_parallel_step``, which both
+``benchmarks/_dp_step.py`` builders call).
 
 On the CPU's virtual devices the options must never reach the compiler;
 for a described ``v5e:2x2`` (nothing attached, nothing run: counts, never
@@ -13,7 +14,6 @@ The topology is described inside a fixture, never at import
 """
 
 import functools
-import re
 import warnings
 
 import jax
@@ -29,6 +29,7 @@ from benchmarks._dp_step import (make_dp_train_step, make_lm_train_step,
 from horovod_tpu.obs import compiles
 from horovod_tpu.obs.registry import registry
 from horovod_tpu.ops import spmd
+from tools.step_hlo import without_source_locations as bare
 
 REFUSED = "xla_tpu_no_such_option_in_any_libtpu"
 
@@ -181,16 +182,6 @@ def _small_lm_step(devices, n):
         placed(tokens, P("data"))).compile().as_text()
 
 
-def _without_source_locations(text):
-    """Compiled text less what records *where* it was traced from: the
-    header's file, function, location and stack-frame tables and each
-    instruction's ``stack_frame_id`` (JAX's trace caches make those differ
-    between two traces of one program in one process)."""
-    tables = re.compile(r"^(FileNames|FunctionNames|FileLocations|"
-                        r"StackFrames)\n(.+\n)*", re.M)
-    return re.sub(r" stack_frame_id=\d+", "", tables.sub("", text))
-
-
 def _gpt2m_4chip_step(devices, n):
     from chipbench import aot
     from chipbench import cell as cells
@@ -241,8 +232,7 @@ def test_one_device_step_compiles_as_without_the_helper(
         topo, no_compile_cache, fresh_probe, monkeypatch):
     with_helper = _small_lm_step(topo.devices, 1)
     monkeypatch.setattr(spmd, "overlap_compiler_options", lambda *a: {})
-    assert _without_source_locations(_small_lm_step(topo.devices, 1)) \
-        == _without_source_locations(with_helper)
+    assert bare(_small_lm_step(topo.devices, 1)) == bare(with_helper)
     assert "async_collective_name" not in with_helper
 
 

@@ -14,13 +14,14 @@ import pytest
 
 from benchmarks._dp_step import (make_dp_train_step, make_lm_train_step,
                                  synthesize_image_job, synthesize_lm_job)
+from horovod_tpu.ops.compression import Compression
 
 SCOPES = ("hvd.loss", "transpose(jvp(hvd.loss))", "hvd.exchange",
           "hvd.optimizer", "hvd.apply_updates", "hvd.sync_stats")
 BACKWARD = "transpose(jvp(hvd.loss))"
 
 
-def _lower_image_step(hvd, explicit_grad_reduce):
+def _lower_image_step(hvd, compression):
     from horovod_tpu.models import ResNet
     from horovod_tpu.models.resnet import ResNetBlock
 
@@ -29,16 +30,15 @@ def _lower_image_step(hvd, explicit_grad_reduce):
                    block_cls=ResNetBlock, dtype=jnp.float32)
     x, y, variables = synthesize_image_job(model, mesh, 16, 16, 10)
     opt = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
-                                   axis_name="data")
+                                   axis_name="data",
+                                   compression=compression)
     opt_state = jax.jit(opt.init)(variables["params"])
-    step = make_dp_train_step(model, opt, mesh, donate=False,
-                              explicit_grad_reduce=explicit_grad_reduce)
+    step = make_dp_train_step(model, opt, mesh, donate=False)
     return step.lower(variables["params"], opt_state,
                       variables["batch_stats"], x, y)
 
 
-def _lower_lm_step(hvd, explicit_grad_reduce):
-    del explicit_grad_reduce  # the LM builder has the one tracing mode
+def _lower_lm_step(hvd, compression):
     from horovod_tpu.models import TransformerLM
 
     mesh = hvd.parallel.data_parallel_mesh()
@@ -47,7 +47,8 @@ def _lower_lm_step(hvd, explicit_grad_reduce):
                           attention="flash")
     tokens, variables = synthesize_lm_job(model, mesh, 8, 128)
     opt = hvd.DistributedOptimizer(optax.adamw(3e-4, weight_decay=0.01),
-                                   axis_name="data")
+                                   axis_name="data",
+                                   compression=compression)
     opt_state = jax.jit(opt.init)(variables["params"])
     return make_lm_train_step(model, opt, mesh).lower(
         variables["params"], opt_state, tokens)
@@ -63,14 +64,15 @@ def _all_reduce_scopes(text):
     return out
 
 
-@pytest.mark.parametrize("lower,explicit_grad_reduce,gradients_under", [
+@pytest.mark.parametrize("lower,compression,gradients_under", [
     (_lower_image_step, None, BACKWARD),
-    (_lower_image_step, True, "hvd.exchange"),
+    # a codec makes the exchange carry the bytes (optimizers.exchange_route)
+    (_lower_image_step, Compression.bf16, "hvd.exchange"),
     (_lower_lm_step, None, BACKWARD),
 ], ids=["image", "image-explicit-reduce", "lm"])
-def test_compiled_step_holds_every_scope(hvd, lower, explicit_grad_reduce,
+def test_compiled_step_holds_every_scope(hvd, lower, compression,
                                          gradients_under):
-    lowered = lower(hvd, explicit_grad_reduce)
+    lowered = lower(hvd, compression)
     text = lowered.compile().as_text()
     for scope in SCOPES:
         assert re.search(r'op_name="[^"]*' + re.escape(scope), text), scope

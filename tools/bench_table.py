@@ -39,14 +39,9 @@ def _label(rec: dict) -> str:
     tmpl = _LABELS.get(rec.get("metric", ""), _LABELS.get(model,
                                                           model or "?"))
     try:
-        label = tmpl.format(**rec)
+        return tmpl.format(**rec)
     except KeyError:
-        label = tmpl
-    if rec.get("scan_batches"):
-        # non-protocol dispatch-overhead diagnostic; must never read as a
-        # second protocol row
-        label += f" — scan diagnostic ({rec['scan_batches']}/call)"
-    return label
+        return tmpl
 
 
 def _render_serving(rec: dict) -> None:

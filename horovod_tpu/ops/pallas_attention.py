@@ -25,12 +25,23 @@ step, DMA or branch is spent on a tile wholly in the future, and the index
 map of the streamed operand stays on the last block a tile needs, so a
 skipped major block is not fetched. The loop is split: interior tiles hold
 no masked pair and run with no mask; only the tiles that straddle the
-diagonal run ``_causal_mask``. ``causal_schedule`` says what that comes
-to — tiles, diagonal tiles, executed over needed pairs — and the gauge
-``horovod_flash_executed_pair_ratio{kernel}`` holds it for the newest
-trace (docs/metrics.md). Tiles are chosen from the shapes by what the v5e
-measured (``_tiles``; PERF.md, PR 25): the per-step and per-row costs
+diagonal run ``_causal_mask``. Tiles are chosen from the shapes by what the
+v5e measured (``_tiles``; PERF.md, PR 25): the per-step and per-row costs
 outweigh wasted pairs up to 512 rows (backward) and 1024 (forward).
+
+Static strips: a masked tile that was multiplied whole threw half of its
+products away. Where the boundary's place inside the tile is known at
+trace time — square tiles, ``q_offset`` a multiple of them, and for the
+window's edge a window that is a multiple of them too (``_strips``) — the
+tile's products run as strips of 128 rows of the kernel's own operand, each
+against only the columns it can see (``_pieces``), with ``_causal_mask`` on
+the one 128 x 128 block a strip has on the boundary: no tile, grid step,
+loop step or statistics pass more, the same products on every visible pair.
+Anywhere else the masked tile runs whole. ``causal_schedule`` says what
+either comes to — tiles, masked tiles, how many of those ran as strips,
+executed over needed pairs — and the gauge
+``horovod_flash_executed_pair_ratio{kernel}`` holds it for the newest
+trace (docs/metrics.md).
 
 Grouped heads and a window. Query head ``h`` of ``Hq`` reads K/V head
 ``h // (Hq // Hkv)``: the K/V index map divides the grid's head index by
@@ -42,6 +53,9 @@ that dK and dV are summed over the group in the accumulators. Under a
 blocks a tile's window can reach, starting from its first
 (``_grid_majors``, ``_major_index``), and the window's mask runs on the
 tiles that straddle its edge; no tile is preferred larger than the window.
+The dK/dV grid moves to another query head every step, so its q-side block
+is fetched every step: under a window it is no longer than a k tile's
+window reaches (``_major``'s ``reach``).
 
 The forward's softmax is two loops a major block: scores into a VMEM
 buffer with their lane-wise maximum, one cross-lane reduction a row, then
@@ -200,22 +214,95 @@ def _q_walk(k_tile, *, window: Optional[int], causal: bool, **tiles):
     return start, a, _clip(edge, a, stop), stop
 
 
+# -- static strips ----------------------------------------------------------
+#
+# A masked tile multiplied whole throws half of its products away. Where the
+# boundary's place inside the tile is known at trace time, the tile runs as
+# strips of its own operand's rows instead, each against only the columns it
+# can see: no tile, grid step, loop step or statistics pass more, only
+# smaller products. That is so when the tiles are square and ``q_offset`` is
+# a multiple of them — the one diagonal tile of a walk then has the diagonal
+# for its own — and, for the window's edge, when the window is a multiple of
+# the tile too: the one edge tile of a walk then keeps what lies strictly
+# above its diagonal. Anywhere else the masked body runs whole.
+
+# Rows of a strip: the narrowest the lanes allow. What the v5e measured
+# (PERF.md, PR 29): at 128 rows each of the three kernels is faster than
+# at 256 or at half the tile, and the forward's 1024-row tile is slower in
+# strips of 256 or 512 than whole.
+_STRIP = _LANES
+
+
+def _strips(*, q_offset: int, tile_q: int, tile_k: int, causal: bool,
+            window: Optional[int]):
+    """``(diagonal, edge)``: the rows of a strip on a kernel's diagonal
+    tiles and on its window-edge tiles, ``None`` where those run whole. A
+    rule on the call's own shapes, as ``_tiles`` is."""
+    if (not causal or tile_q != tile_k or q_offset % tile_q
+            or not 0 < _STRIP < tile_q or tile_q % _STRIP):
+        return None, None
+    if window is None:
+        return _STRIP, None
+    if window < tile_q:  # the diagonal tile holds the window's edge too
+        return None, None
+    # a window that is no multiple of the tile has its edge on two tiles
+    return _STRIP, None if window % tile_q else _STRIP
+
+
+def _parts(rows: int, cols: int, masked: bool, strip: Optional[int] = None,
+           lower: bool = True):
+    """The pieces a ``rows x cols`` tile's products run in, each ``(row
+    slice, first column, columns, mask)`` with ``mask`` the ``(first,
+    count)`` of the piece's columns that ``_causal_mask`` runs on, or
+    ``None``. Without a ``strip`` the tile is one piece, masked whole or
+    not at all. With one, the strip of rows from ``r`` takes the columns
+    ``[0, r + strip)`` of a tile that keeps its ``lower`` triangle and
+    ``[r, cols)`` of one that keeps its upper: every block of ``strip x
+    strip`` that holds a visible pair, of which the one on the tile's
+    diagonal holds masked pairs too."""
+    if strip is None:
+        return [(slice(0, rows), 0, cols, (0, cols) if masked else None)]
+    return [(slice(r, r + strip), 0 if lower else r,
+             r + strip if lower else cols - r,
+             (r if lower else 0, strip))
+            for r in range(0, rows, strip)]
+
+
+def _pieces(transposed: bool = False, *, tile_q: int, tile_k: int,
+            **schedule):
+    """``(clear, diagonal, edge)``: the pieces (``_parts``) in which a
+    kernel runs a tile with no masked pair, its diagonal tile and its
+    window-edge tile. Rows are q rows and the diagonal tile keeps its
+    lower triangle, the edge tile its upper; ``transposed`` (the dK/dV
+    kernel's blocks) rows are k rows and it is the other way round."""
+    diagonal, edge = _strips(tile_q=tile_q, tile_k=tile_k, **schedule)
+    rows, cols = (tile_k, tile_q) if transposed else (tile_q, tile_k)
+    return (_parts(rows, cols, False),
+            _parts(rows, cols, True, diagonal, lower=not transposed),
+            _parts(rows, cols, True, edge, lower=transposed))
+
+
 def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
                     tile_k: int, causal: bool,
                     window: Optional[int] = None) -> dict:
     """What each kernel executes at these shapes: ``tiles`` (compute tiles
-    run), ``diagonal`` (how many of them run the masked body) and
-    ``pair_ratio`` (executed score pairs over the pairs the mask keeps).
-    ``flash_fwd`` and ``flash_bwd_dq`` walk k tiles for each q tile,
-    ``flash_bwd_dkv`` walks q tiles for each k tile; a call with a
-    ``window`` runs the same three under their ``flash_win_*`` names."""
+    run), ``diagonal`` (how many of them hold masked pairs), ``trimmed``
+    (how many of those run as strips, ``_strips``; 0 where the masked body
+    runs whole) and ``pair_ratio`` (executed score pairs, counted strip by
+    strip, over the pairs the mask keeps). ``flash_fwd`` and
+    ``flash_bwd_dq`` walk k tiles for each q tile, ``flash_bwd_dkv`` walks
+    q tiles for each k tile; a call with a ``window`` runs the same three
+    under their ``flash_win_*`` names."""
     num_q_tiles, num_k_tiles = seq_q // tile_q, seq_k // tile_k
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
                     causal=causal, window=window)
-    by_q = [_k_walk(i, num_k_tiles=num_k_tiles, **schedule)
-            for i in range(num_q_tiles)]
-    by_k = [_q_walk(j, num_q_tiles=num_q_tiles, **schedule)
-            for j in range(num_k_tiles)]
+    # (edge tiles, clear tiles, diagonal tiles) of each walk
+    by_q = [(a - lo, b - a, end - b) for lo, a, b, end in (
+        _k_walk(i, num_k_tiles=num_k_tiles, **schedule)
+        for i in range(num_q_tiles))]
+    by_k = [(stop - b, b - a, a - start) for start, a, b, stop in (
+        _q_walk(j, num_q_tiles=num_q_tiles, **schedule)
+        for j in range(num_k_tiles))]
 
     def visible(row):
         last = min(seq_k, q_offset + row + 1)
@@ -225,10 +312,16 @@ def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
     needed = seq_q * seq_k if not causal else sum(map(visible, range(seq_q)))
 
     def walk(walks):
-        tiles = sum(max(0, end - lo) for lo, _, _, end in walks)
-        clear = sum(max(0, b - a) for _, a, b, _ in walks)
-        return {"tiles": tiles, "diagonal": tiles - clear,
-                "pair_ratio": tiles * tile_q * tile_k / needed}
+        edge, clear, diagonal = (sum(max(0, n) for n in kind)
+                                 for kind in zip(*walks))
+        pairs, trimmed = clear * tile_q * tile_k, 0
+        for count, parts in zip((diagonal, edge), _pieces(**schedule)[1:]):
+            pairs += count * sum((rows.stop - rows.start) * cols
+                                 for rows, _, cols, _ in parts)
+            trimmed += count if len(parts) > 1 else 0
+        return {"tiles": edge + clear + diagonal,
+                "diagonal": edge + diagonal, "trimmed": trimmed,
+                "pair_ratio": pairs / needed}
 
     over_k, over_q = walk(by_q), walk(by_k)
     return {"flash_fwd": over_k, "flash_bwd_dq": over_k,
@@ -260,11 +353,14 @@ def _for_tiles(lo, hi, first, tiles_per_major: int, body):
     jax.lax.fori_loop(lo, hi, step, 0)
 
 
-def _tile_slice(j, tile: int):
-    """The rows (or lanes) of local tile ``j`` in a resident block."""
+def _tile_slice(j, tile: int, first: int = 0, size: Optional[int] = None):
+    """The rows (or lanes) of local tile ``j`` in a resident block: all of
+    them, or the ``size`` from the tile's ``first``."""
+    size = tile if size is None else size
     if isinstance(j, int):
-        return pl.ds(j * tile, tile)
-    return pl.ds(pl.multiple_of(j * tile, tile), tile)
+        return pl.ds(j * tile + first, size)
+    return pl.ds(pl.multiple_of(j * tile + first, math.gcd(tile, first)),
+                 size)
 
 
 def _first_tile(major_idx, tiles_per_major: int, num_tiles: int):
@@ -311,6 +407,25 @@ def _causal_mask(s, q_pos0, k_pos0, q_axis=0, window=None):
     return jnp.where(keep, s, _NEG_INF)
 
 
+def _mask_columns(s, mask, q_pos0, k_pos0, q_axis=0, window=None):
+    """``_causal_mask`` on the ``mask = (first, count)`` columns of the
+    score block ``s`` alone (``_parts``); the positions are those of the
+    block's first row and column."""
+    if mask is None:
+        return s
+    at, count = mask
+    if count == s.shape[1]:
+        return _causal_mask(s, q_pos0, k_pos0, q_axis, window)
+    if q_axis:
+        q_pos0 += at
+    else:
+        k_pos0 += at
+    block = _causal_mask(s[:, at:at + count], q_pos0, k_pos0, q_axis, window)
+    return jnp.concatenate(
+        [x for x in (s[:, :at], block, s[:, at + count:]) if x.shape[1]],
+        axis=1)
+
+
 def _col(stat):
     """[rows, 128] lane-repeated statistic -> its [rows, 1] column."""
     return stat[:, :1]
@@ -327,7 +442,8 @@ def _lane_fold(x, lanes: int, op):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
                 s_buf, m_lane, l_lane, *, scale: float, causal: bool,
                 q_offset: int, tile_q: int, tile_k: int, major_k: int,
-                num_k_tiles: int, window: Optional[int], grid_majors: int):
+                num_k_tiles: int, window: Optional[int], grid_majors: int,
+                pieces):
     """One q tile against the resident K/V major block ``kk``. Grid (bh,
     q-tile, k-major), the last sequential; ``o_acc``, ``m_acc`` and
     ``l_acc`` persist across it.
@@ -340,6 +456,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
     one reduction gives the rows' new maximum; the second takes
     ``exp(S - m)``, sums it lane by lane into ``l_lane`` and accumulates
     P V. With the whole of K resident that is the plain softmax.
+
+    A tile runs in its ``pieces`` (``_pieces``: the strips of a diagonal
+    or a window-edge tile where the shapes allow them): both loops take a
+    piece's rows against its columns only, so a block the mask would empty
+    is neither multiplied, written, read nor exponentiated.
 
     No row is ever empty: with ``q_offset >= 0`` every row keeps its pair
     with k position 0, and under a window its pair with its own position
@@ -377,28 +498,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
         q_pos0 = q_offset + q_idx * tile_q
         m_lane[...] = jnp.full_like(m_lane, _NEG_INF)
         l_lane[...] = jnp.zeros_like(l_lane)
+        clear, diagonal, edge = pieces
 
-        def scores(j, masked):
-            cols = _tile_slice(j, tile_k)
-            k_blk = k_ref[0, cols, :].astype(jnp.float32)  # [tile_k, d]
-            s = jax.lax.dot_general(  # [tile_q, tile_k] on the MXU
-                q_block, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if masked:
-                s = _causal_mask(s, q_pos0, kk * major_k + j * tile_k,
-                                 window=window)
-            s_buf[:, cols] = s
-            m_lane[...] = jnp.maximum(m_lane[...],
-                                      _lane_fold(s, lanes, jnp.maximum))
+        def scores(j, parts):
+            for rows, first_col, count, mask in parts:
+                cols = _tile_slice(j, tile_k, first_col, count)
+                k_blk = k_ref[0, cols, :].astype(jnp.float32)  # [count, d]
+                s = jax.lax.dot_general(  # [rows, count] on the MXU
+                    q_block[rows], k_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                s = _mask_columns(
+                    s, mask, q_pos0 + rows.start,
+                    kk * major_k + j * tile_k + first_col, window=window)
+                s_buf[rows, cols] = s
+                m_lane[rows, :] = jnp.maximum(
+                    m_lane[rows, :], _lane_fold(s, lanes, jnp.maximum))
 
         if window is not None:
             _for_tiles(lo, a, first, tiles_per_major,
-                       functools.partial(scores, masked=True))
+                       functools.partial(scores, parts=edge))
         _for_tiles(a, b, first, tiles_per_major,
-                   functools.partial(scores, masked=False))
+                   functools.partial(scores, parts=clear))
         if causal:
             _for_tiles(b, end, first, tiles_per_major,
-                       functools.partial(scores, masked=True))
+                       functools.partial(scores, parts=diagonal))
 
         m_old = _col(m_acc[...])
         m_new = jnp.maximum(m_old, m_lane[...].max(axis=1, keepdims=True))
@@ -406,17 +529,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
         o_acc[...] = o_acc[...] * corr
         m_lanes = jnp.broadcast_to(m_new, m_lane.shape)
 
-        def weigh(j):
-            cols = _tile_slice(j, tile_k)
-            v_blk = v_ref[0, cols, :].astype(jnp.float32)
-            p = jnp.exp(s_buf[:, cols]
-                        - jnp.tile(m_lanes, (1, tile_k // lanes)))
-            l_lane[...] += _lane_fold(p, lanes, jnp.add)
-            o_acc[...] += jax.lax.dot_general(
-                p, v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        def weigh(j, parts):
+            for rows, first_col, count, _ in parts:
+                cols = _tile_slice(j, tile_k, first_col, count)
+                v_blk = v_ref[0, cols, :].astype(jnp.float32)
+                p = jnp.exp(s_buf[rows, cols]
+                            - jnp.tile(m_lanes[rows], (1, count // lanes)))
+                l_lane[rows, :] += _lane_fold(p, lanes, jnp.add)
+                o_acc[rows, :] += jax.lax.dot_general(
+                    p, v_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-        _for_tiles(lo, end, first, tiles_per_major, weigh)
+        # a masked tile that runs whole is weighed like a clear one
+        if len(edge) > 1:
+            _for_tiles(lo, a, first, tiles_per_major,
+                       functools.partial(weigh, parts=edge))
+        _for_tiles(a if len(edge) > 1 else lo,
+                   b if len(diagonal) > 1 else end, first, tiles_per_major,
+                   functools.partial(weigh, parts=clear))
+        if len(diagonal) > 1:
+            _for_tiles(b, end, first, tiles_per_major,
+                       functools.partial(weigh, parts=diagonal))
 
         l_new = _col(l_acc[...]) * corr \
             + l_lane[...].sum(axis=1, keepdims=True)
@@ -431,11 +564,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
         lse_ref[0, ...] = m_acc[...] + jnp.log(l)
 
 
-def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
+def _recompute_p(q_blk, k_blk, lse, *, mask, q_pos0, k_pos0,
                  transposed=False, window=None):
     """Recompute the normalized probability block P = exp(S - lse), with
-    S's mask on a ``masked`` (diagonal) tile; shared by both backward
-    kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
+    S's mask on the piece's ``mask`` columns (``_parts``); shared by both
+    backward kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
 
     ``transposed=False``: P is [tile_q, tile_k] and ``lse`` its
     [tile_q, 1] column. ``transposed=True``: P^T is [tile_k, tile_q],
@@ -444,9 +577,8 @@ def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
     s = jax.lax.dot_general(
         lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    if masked:
-        s = _causal_mask(s, q_pos0, k_pos0, q_axis=1 if transposed else 0,
-                         window=window)
+    s = _mask_columns(s, mask, q_pos0, k_pos0,
+                      q_axis=1 if transposed else 0, window=window)
     # no row is empty (``_fwd_kernel``), so lse is finite and a masked
     # pair's exp(sentinel - lse) is the 0 it should be
     return jnp.exp(s - lse)
@@ -455,10 +587,12 @@ def _recompute_p(q_blk, k_blk, lse, *, masked, q_pos0, k_pos0,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale: float, causal: bool, q_offset: int,
                    tile_q: int, tile_k: int, major_k: int, num_k_tiles: int,
-                   window: Optional[int], grid_majors: int):
+                   window: Optional[int], grid_majors: int, pieces):
     """dQ = (P * (dO V^T - delta)) K * scale, accumulated over the k tiles
     from the window's edge up to the diagonal. Grid (bh, q-tile, k-major)
-    as the forward's."""
+    as the forward's, and a tile in the forward's ``pieces``: a strip of q
+    rows forms P, dP and dS on its columns and adds to its rows of
+    ``dq_acc``."""
     step = pl.program_id(2)
     q_idx = pl.program_id(1)
     tiles_per_major = major_k // tile_k
@@ -481,30 +615,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         lse, delta = _col(lse_ref[0]), _col(delta_ref[0])
         q_pos0 = q_offset + q_idx * tile_q
 
-        def update(j, masked):
-            rows = _tile_slice(j, tile_k)
-            k_blk = k_ref[0, rows, :].astype(jnp.float32)
-            v_blk = v_ref[0, rows, :].astype(jnp.float32)
-            p = _recompute_p(q_scaled, k_blk, lse, masked=masked,
-                             q_pos0=q_pos0,
-                             k_pos0=kk * major_k + j * tile_k,
-                             window=window)
-            dp = jax.lax.dot_general(  # dO V^T  [tile_q, tile_k]
-                do_blk, v_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dq_acc[...] += jax.lax.dot_general(
-                ds, k_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        def update(j, parts):
+            for rows, first_col, count, mask in parts:
+                cols = _tile_slice(j, tile_k, first_col, count)
+                k_blk = k_ref[0, cols, :].astype(jnp.float32)
+                v_blk = v_ref[0, cols, :].astype(jnp.float32)
+                p = _recompute_p(
+                    q_scaled[rows], k_blk, lse[rows], mask=mask,
+                    q_pos0=q_pos0 + rows.start,
+                    k_pos0=kk * major_k + j * tile_k + first_col,
+                    window=window)
+                dp = jax.lax.dot_general(  # dO V^T  [rows, count]
+                    do_blk[rows], v_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta[rows]) * scale
+                dq_acc[rows, :] += jax.lax.dot_general(
+                    ds, k_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
+        clear, diagonal, edge = pieces
         if window is not None:
             _for_tiles(lo, a, first, tiles_per_major,
-                       functools.partial(update, masked=True))
+                       functools.partial(update, parts=edge))
         _for_tiles(a, b, first, tiles_per_major,
-                   functools.partial(update, masked=False))
+                   functools.partial(update, parts=clear))
         if causal:
             _for_tiles(b, end, first, tiles_per_major,
-                       functools.partial(update, masked=True))
+                       functools.partial(update, parts=diagonal))
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
@@ -515,7 +652,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                     causal: bool, q_offset: int, tile_q: int, tile_k: int,
                     major_q: int, num_q_tiles: int, window: Optional[int],
-                    group: int, grid_majors: int):
+                    group: int, grid_majors: int, pieces):
     """dV = P^T dO and dK = (P * (dP - delta))^T Q for one k tile of one
     K/V head, accumulated over the q tiles of the resident major block from
     the diagonal to the window's far edge, and over the query heads of the
@@ -523,7 +660,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     sequential: a step is one query head's major block
     (``_dkv_step``). Works on the transposed blocks P^T, dP^T = V dO^T,
     dS^T throughout, with lse and delta as [1, tile_q] rows, so no operand
-    is ever transposed."""
+    is ever transposed. A tile runs in its ``pieces`` the other way round
+    (``_pieces(transposed=True)``): a strip of *k* rows against the q
+    columns it can see — from its own on in the diagonal tile, up to its
+    own in the window's edge tile — adding to its rows of ``dk_acc`` and
+    ``dv_acc``."""
     step = pl.program_id(2)
     k_idx = pl.program_id(1)
     tiles_per_major = major_q // tile_q
@@ -552,33 +693,37 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v_blk = v_ref[0].astype(jnp.float32)
         k_pos0 = k_idx * tile_k
 
-        def update(i, masked):
-            rows = _tile_slice(i, tile_q)
-            q_blk = q_ref[0, rows, :].astype(jnp.float32)
-            do_blk = do_ref[0, rows, :].astype(jnp.float32)
-            p_t = _recompute_p(
-                q_blk * scale, k_blk, lse_ref[0, :, rows], masked=masked,
-                q_pos0=q_offset + iq * major_q + i * tile_q, k_pos0=k_pos0,
-                transposed=True, window=window)
-            dv_acc[...] += jax.lax.dot_general(  # P^T dO  [tile_k, d]
-                p_t, do_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp_t = jax.lax.dot_general(  # V dO^T  [tile_k, tile_q]
-                v_blk, do_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds_t = p_t * (dp_t - delta_ref[0, :, rows]) * scale
-            dk_acc[...] += jax.lax.dot_general(  # dS^T Q  [tile_k, d]
-                ds_t, q_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        def update(i, parts):
+            for rows, first_col, count, mask in parts:
+                cols = _tile_slice(i, tile_q, first_col, count)
+                q_blk = q_ref[0, cols, :].astype(jnp.float32)
+                do_blk = do_ref[0, cols, :].astype(jnp.float32)
+                p_t = _recompute_p(
+                    q_blk * scale, k_blk[rows], lse_ref[0, :, cols],
+                    mask=mask,
+                    q_pos0=q_offset + iq * major_q + i * tile_q + first_col,
+                    k_pos0=k_pos0 + rows.start, transposed=True,
+                    window=window)
+                dv_acc[rows, :] += jax.lax.dot_general(  # P^T dO [rows, d]
+                    p_t, do_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp_t = jax.lax.dot_general(  # V dO^T  [rows, count]
+                    v_blk[rows], do_blk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds_t = p_t * (dp_t - delta_ref[0, :, cols]) * scale
+                dk_acc[rows, :] += jax.lax.dot_general(  # dS^T Q [rows, d]
+                    ds_t, q_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
+        clear, diagonal, edge = pieces
         if causal:
             _for_tiles(start, a, first, tiles_per_major,
-                       functools.partial(update, masked=True))
+                       functools.partial(update, parts=diagonal))
         _for_tiles(a, b, first, tiles_per_major,
-                   functools.partial(update, masked=False))
+                   functools.partial(update, parts=clear))
         if window is not None:
             _for_tiles(b, stop, first, tiles_per_major,
-                       functools.partial(update, masked=True))
+                       functools.partial(update, parts=edge))
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
@@ -660,12 +805,16 @@ def _tiles(seq_q: int, seq_k: int, head_dim: int, dtype,
              _tile(seq_k, block_k, bwd, row_bytes)))
 
 
-def _major(seq: int, tile: int, row_bytes: int) -> int:
+def _major(seq: int, tile: int, row_bytes: int,
+           reach: Optional[int] = None) -> int:
     """Rows of the streamed operands that stay resident in VMEM: the most
     tiles that divide ``seq`` and fit ``_RESIDENT_BYTES`` at ``row_bytes``
-    a row, at least one tile. The whole sequence where it fits."""
+    a row, at least one tile. The whole sequence where it fits — or, given
+    the ``reach`` of a window in rows, no more tiles than that spans."""
     num_tiles = seq // tile
     fit = max(1, min(num_tiles, _RESIDENT_BYTES // (row_bytes * tile)))
+    if reach is not None:
+        fit = min(fit, -(-reach // tile))
     while num_tiles % fit:
         fit -= 1
     return fit * tile
@@ -734,6 +883,7 @@ def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, major_k=major_k,
                           num_k_tiles=num_k_tiles, grid_majors=grid_majors,
+                          pieces=_pieces(**schedule),
                           **schedule),
         grid=(batch * heads, seq_q // tile_q, grid_majors),
         in_specs=[q_spec, kv_spec, kv_spec],
@@ -771,7 +921,12 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
     num_q_tiles = seq_q // tile_q
     num_k_tiles = seq_k // tile_k
     major_k = _major(seq_k, tile_k, _operand_row_bytes(head_dim, k.dtype))
-    major_q = _major(seq_q, tile_q, _operand_row_bytes(head_dim, q.dtype))
+    # the dK/dV grid takes another query head every step, so its q-side
+    # block is fetched anew every step: under a window no more of it than a
+    # k tile's window reaches (PERF.md, PR 29: a head's whole Q and dO, 4 MB
+    # a step for two tiles of work, bound flash_win_bwd_dkv by HBM)
+    major_q = _major(seq_q, tile_q, _operand_row_bytes(head_dim, q.dtype),
+                     None if window is None else tile_k + window - 1)
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     ob, dob = _to_bh(o), _to_bh(do)
     names = _kernel_names(window)
@@ -801,6 +956,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, major_k=major_k,
                           num_k_tiles=num_k_tiles, grid_majors=k_majors,
+                          pieces=_pieces(**schedule),
                           **schedule),
         grid=(batch * heads, num_q_tiles, k_majors),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
@@ -850,7 +1006,9 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, major_q=major_q,
                           num_q_tiles=num_q_tiles, group=group,
-                          grid_majors=q_majors, **schedule),
+                          grid_majors=q_majors,
+                          pieces=_pieces(transposed=True, **schedule),
+                          **schedule),
         grid=(batch * kv_heads, num_k_tiles, group * q_majors),
         in_specs=[kv_q_spec, kv_k_spec, kv_k_spec, kv_q_spec,
                   kv_row_spec, kv_row_spec],

@@ -52,7 +52,7 @@ def no_compile_cache():
 
 
 _CASES = {
-    # id: (q shape, seq_k, dtype, causal, q_offset)
+    # id: (q shape, seq_k, dtype, causal, q_offset[, kv heads, window])
     "gpt2m_cell": ((4, 1024, 16, 64), 1024, "bfloat16", True, 0),
     "chip_smoke_f32": ((8, 1024, 12, 64), 1024, "float32", True, 0),
     "past_the_resident_cap": ((1, 16384, 2, 128), 16384, "bfloat16", True, 0),
@@ -64,6 +64,20 @@ _CASES = {
     "later_q_shard": ((2, 1024, 4, 64), 2048, "bfloat16", True, 1024),
     "offset_inside_a_tile": ((2, 1024, 4, 64), 2048, "bfloat16", True, 1000),
     "shorter_than_the_lanes": ((2, 64, 4, 64), 64, "bfloat16", True, 0),
+    # the masked tiles as static strips (PR 29) at the shapes of the
+    # gpt2m_* cells (16 sequences of 4 heads are their 64 programs) and of
+    # laguna_xs2_8k_1chip's full and sliding layers
+    "strips_gpt2m": ((16, 1024, 4, 64), 1024, "bfloat16", True, 0),
+    "strips_laguna_full": ((2, 8192, 48, 128), 8192, "bfloat16", True, 0,
+                           8, None),
+    "strips_laguna_window": ((2, 8192, 64, 128), 8192, "bfloat16", True, 0,
+                             8, 512),
+    # the forward's 1024-row tiles have the window's edge on two of them
+    # and cut the diagonal tile alone; the backward's 512 cut both
+    "strips_window_no_multiple_of_the_tile": (
+        (1, 4096, 8, 128), 4096, "bfloat16", True, 0, 2, 1536),
+    "strips_later_q_shard_f32": ((1, 1024, 4, 128), 2048, "float32", True,
+                                 1024),
 }
 
 
@@ -74,20 +88,32 @@ def test_kernels_compile_for_v5e(one_chip, no_compile_cache, case):
 
     from horovod_tpu.ops import pallas_attention as pa
 
-    q_shape, seq_k, dtype, causal, q_offset = _CASES[case]
+    q_shape, seq_k, dtype, causal, q_offset, *grouped = _CASES[case]
     batch, seq_q, heads, head_dim = q_shape
+    kv_heads, window = grouped or (heads, None)
     q = jax.ShapeDtypeStruct(q_shape, dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((batch, seq_k, heads, head_dim), dtype,
+    kv = jax.ShapeDtypeStruct((batch, seq_k, kv_heads, head_dim), dtype,
                               sharding=one_chip)
     attention = functools.partial(pa.flash_attention, causal=causal,
-                                  q_offset=q_offset, interpret=False)
+                                  q_offset=q_offset, window=window,
+                                  interpret=False)
     compiled = jax.jit(jax.grad(
         lambda q, k, v: attention(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))).lower(q, kv, kv).compile()
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in pa._kernel_names(window).values():
         assert f"%{name}" in hlo, name
+    if case.startswith("strips_"):
+        fwd, bwd = pa._tiles(seq_q, seq_k, head_dim, dtype, None, None,
+                             window)
+        trimmed = {
+            kernel: pa.causal_schedule(
+                seq_q, seq_k, q_offset, *tiles, causal, window)[kernel]
+            ["trimmed"] for kernel, tiles in (
+                ("flash_fwd", fwd), ("flash_bwd_dq", bwd),
+                ("flash_bwd_dkv", bwd))}
+        assert all(trimmed.values()), trimmed
     if case == "past_the_resident_cap":
         fwd, bwd = pa._tiles(seq_q, seq_k, head_dim, dtype, None, None)
         rows = pa._operand_row_bytes(head_dim, dtype)

@@ -55,6 +55,23 @@ def assert_close(got, want, tol):
             rtol=tol, atol=tol, err_msg=name)
 
 
+def assert_flash_equals_dense(batch, seq_q, seq_k, q_offset, block, window,
+                              heads, kv_heads, head_dim, dtype):
+    """Forward and the three gradients against dense attention, dK and dV
+    in K's and V's own shapes (summed over each group)."""
+    q, k, v, cot = operands(seq_q + 7 * seq_k + 13 * q_offset + heads,
+                            seq_q, seq_k, heads, kv_heads, head_dim, dtype,
+                            batch)
+    got = forward_and_gradients(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            q_offset=q_offset, window=window), q, k, v, cot)
+    want = forward_and_gradients(
+        lambda q, k, v: dense(q, k, v, q_offset, window), q, k, v, cot)
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+    assert_close(got, want, 5e-4 if dtype == "float32" else 3e-2)
+
+
 _CASES = [
     # id, seq_q, seq_k, q_offset, block, window, heads, kv_heads, head_dim,
     # dtype, resident
@@ -100,16 +117,73 @@ def test_window_and_groups_against_dense(monkeypatch, seq_q, seq_k, q_offset,
         monkeypatch.setattr(pa, "_RESIDENT_BYTES", resident)
         assert seq_k // pa._major(
             seq_k, block, pa._operand_row_bytes(head_dim, dtype)) > 1
-    q, k, v, cot = operands(seq_q + 7 * seq_k + 13 * q_offset + heads,
-                            seq_q, seq_k, heads, kv_heads, head_dim, dtype)
-    got = forward_and_gradients(
-        lambda q, k, v: flash_attention(
-            q, k, v, causal=True, block_q=block, block_k=block,
-            q_offset=q_offset, window=window), q, k, v, cot)
-    want = forward_and_gradients(
-        lambda q, k, v: dense(q, k, v, q_offset, window), q, k, v, cot)
-    assert got[2].shape == k.shape and got[3].shape == v.shape
-    assert_close(got, want, 5e-4 if dtype == "float32" else 3e-2)
+    assert_flash_equals_dense(2, seq_q, seq_k, q_offset, block, window,
+                              heads, kv_heads, head_dim, dtype)
+
+
+# id, seq_q, seq_k, q_offset, block, window, heads, kv_heads, head_dim,
+# dtype, resident, which masked tiles run as strips: tiles of whole lanes.
+# The window's edge tile is cut when the window is a multiple of the tile
+# (then one tile holds the edge), the diagonal tile when the window is no
+# narrower than the tile (else it holds the edge too)
+_STRIP_CASES = [
+    ("window_equals_tile", 1024, 1024, 0, 256, 256, 4, 2, 64, "float32",
+     None, "both"),
+    ("window_of_two_tiles_d128", 1024, 1024, 0, 256, 512, 4, 2, 128,
+     "float32", None, "both"),
+    ("the_cells_tiles_and_window", 2048, 2048, 0, None, 512, 4, 1, 128,
+     "bfloat16", None, "both"),
+    ("window_groups_of_four_d64", 768, 768, 0, 256, 256, 8, 2, 64,
+     "bfloat16", None, "both"),
+    # one q tile: a diagonal tile and no edge tile
+    ("window_first_q_tile_alone", 256, 256, 0, 256, 256, 4, 2, 64,
+     "float32", None, "both"),
+    ("window_offset_of_two_tiles", 512, 1024, 512, 256, 256, 4, 2, 64,
+     "float32", None, "both"),
+    ("window_one_tile_a_major", 1024, 1024, 0, 256, 256, 4, 2, 64,
+     "float32", 1, "both"),
+    ("window_two_tiles_a_major", 1024, 1024, 0, 256, 512, 2, 1, 128,
+     "float32", 2 * 256 * 3072, "both"),
+    ("window_of_three_strips", 1024, 1024, 0, 256, 384, 4, 2, 64, "float32",
+     None, "diagonal"),
+    ("window_no_multiple_of_the_strip", 1024, 1024, 0, 256, 300, 4, 2, 64,
+     "float32", None, "diagonal"),
+    ("window_narrower_than_the_tile", 512, 512, 0, 256, 128, 4, 2, 64,
+     "float32", None, "none"),
+    ("window_offset_inside_a_tile", 512, 1024, 384, 256, 256, 4, 2, 64,
+     "float32", None, "none"),
+]
+
+
+@pytest.mark.parametrize(
+    "seq_q,seq_k,q_offset,block,window,heads,kv_heads,head_dim,dtype,"
+    "resident,strips", [case[1:] for case in _STRIP_CASES],
+    ids=[case[0] for case in _STRIP_CASES])
+def test_window_strips_against_dense(monkeypatch, seq_q, seq_k, q_offset,
+                                     block, window, heads, kv_heads,
+                                     head_dim, dtype, resident, strips):
+    """Forward and the three gradients against dense attention, dK and dV
+    summed over the group, where the window's edge tile and the diagonal
+    tile run as static strips, and at the neighbouring shapes that keep one
+    or both whole; ``causal_schedule``'s ``trimmed`` says which."""
+    fwd, bwd = pa._tiles(seq_q, seq_k, head_dim, dtype, block, block, window)
+    assert fwd == bwd and fwd[0] == fwd[1]
+    if resident is not None:
+        monkeypatch.setattr(pa, "_RESIDENT_BYTES", resident)
+        assert seq_k // pa._major(
+            seq_k, bwd[1], pa._operand_row_bytes(head_dim, dtype)) > 1
+    schedule = pa.causal_schedule(seq_q, seq_k, q_offset, *fwd, True, window)
+    shared = dict(q_offset=q_offset, tile_q=fwd[0], tile_k=fwd[1],
+                  causal=True, window=window)
+    diagonal = sum(end - b for _, _, b, end in (
+        pa._k_walk(i, num_k_tiles=seq_k // fwd[1], **shared)
+        for i in range(seq_q // fwd[0])))
+    for kernel, entry in schedule.items():
+        assert 0 < entry["diagonal"] >= diagonal
+        assert entry["trimmed"] == {"both": entry["diagonal"],
+                                    "diagonal": diagonal, "none": 0}[strips]
+    assert_flash_equals_dense(1, seq_q, seq_k, q_offset, block, window,
+                              heads, kv_heads, head_dim, dtype)
 
 
 def test_grouped_heads_equal_repeated_heads():
@@ -185,6 +259,47 @@ def test_window_schedule_against_brute_force(seq_q, seq_k, q_offset, tile_q,
             len(by_k_walk) * tile_q * tile_k / kept.sum())
 
 
+_STRIP_SCHEDULE_CASES = [
+    # seq_q, seq_k, q_offset, tile, window
+    (2048, 2048, 0, 512, 512), (2048, 2048, 0, 256, 512),
+    (1536, 1536, 0, 256, 768), (1024, 2048, 1024, 512, 512),
+    (1024, 1024, 0, 1024, 1024), (1536, 1536, 0, 384, 384),
+    # the edge on two tiles, or on the diagonal tile, or an offset diagonal
+    (1024, 1024, 0, 256, 384), (1024, 1024, 0, 256, 300),
+    (1024, 1024, 0, 256, 128), (1024, 1024, 0, 256, 1),
+    (512, 1024, 384, 256, 256), (512, 512, 0, 128, 128),
+]
+
+
+@pytest.mark.parametrize("seq_q,seq_k,q_offset,tile,window",
+                         _STRIP_SCHEDULE_CASES)
+def test_window_strip_schedule_against_brute_force(seq_q, seq_k, q_offset,
+                                                   tile, window):
+    """Under a window too ``causal_schedule``'s pairs and ``trimmed`` equal
+    an enumeration of the ``strip x strip`` blocks that hold a visible
+    pair in the tiles that run as strips, and whole tiles elsewhere."""
+    from test_pallas_attention import executed_pairs_by_enumeration
+
+    q_pos = q_offset + np.arange(seq_q)[:, None]
+    k_pos = np.arange(seq_k)[None, :]
+    kept = (q_pos >= k_pos) & (q_pos - k_pos < window)
+    schedule = pa.causal_schedule(seq_q, seq_k, q_offset, tile, tile, True,
+                                  window)
+    for kernel, entry in schedule.items():
+        strips = pa._strips(q_offset=q_offset, tile_q=tile, tile_k=tile,
+                            causal=True, window=window)
+        pairs, trimmed = executed_pairs_by_enumeration(
+            kept, tile, tile, strips)
+        assert entry["trimmed"] == trimmed, kernel
+        assert entry["pair_ratio"] == pytest.approx(pairs / kept.sum())
+        if q_offset % tile or tile < 256 or window < tile:
+            assert strips == (None, None) and trimmed == 0
+        elif window % tile:
+            assert strips[1] is None and 0 < trimmed < entry["diagonal"]
+        else:
+            assert trimmed == entry["diagonal"] > 0
+
+
 def test_the_window_kernels_at_8k_their_names_and_their_gauge():
     """Laguna's sliding call — T = 8192, head_dim 128, 64 query heads on 8
     K/V heads, window 512: tiles no larger than the window, a q tile's
@@ -230,19 +345,29 @@ def test_the_window_kernels_at_8k_their_names_and_their_gauge():
         window["flash_fwd"]["pair_ratio"])
     assert read["flash_win_bwd_dkv"] == pytest.approx(
         window["flash_bwd_dkv"]["pair_ratio"])
-    assert 1.9 < read["flash_win_bwd_dq"] < 2.0
+    # every executed tile is an edge or a diagonal tile, and runs as strips
+    # of 128 rows: 10 of a tile's 16 blocks (2.0 x the needed pairs whole)
+    for entry in window.values():
+        assert entry["trimmed"] == entry["diagonal"] == entry["tiles"] == 31
+    assert 1.24 < read["flash_win_bwd_dq"] < 1.25
+    # the dK/dV kernel fetches a query head's rows anew every step: no more
+    # of them than a k tile's window reaches
+    assert pa._major(8192, 512, rows, reach=512 + 512 - 1) == 1024
+    assert pa._major(8192, 512, rows) == 8192
 
 
 # sha256 of ``str(jax.make_jaxpr(...))`` of the gpt2m cells' call with the
-# addresses taken out, as commit 8ed9e9b (PR 25) traces it: the program
-# text, kernels' bodies, grids and index maps included
-_GPT2M_CALL = "8bf9359515c9f8c3d7b6d0fc8954c82c0b26a0e7a96d43b1de0c3558d624fdb3"
+# addresses taken out, as PR 29 traces it (its masked tiles as strips; PR
+# 25's program until then): the program text, kernels' bodies, grids and
+# index maps included
+_GPT2M_CALL = "26514842542ebafaf043c57f8d02dad633d8c46a613139c9055a17e0cef07d2b"
 
 
 def test_the_gpt2m_call_traces_to_the_program_it_was():
     """``(4, 1024, 16, 64)`` causal bfloat16, no window, equal heads:
-    forward and backward trace to the very program PR 25 left, so its
-    results are that program's bit for bit."""
+    forward and backward trace to the very program the benchmark's cells
+    were last measured with, so a change that is not meant to move them
+    leaves their results that program's bit for bit."""
     x = jax.ShapeDtypeStruct((4, 1024, 16, 64), jnp.bfloat16)
     text = str(jax.make_jaxpr(jax.grad(
         lambda q, k, v: flash_attention(

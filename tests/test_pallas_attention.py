@@ -184,6 +184,34 @@ def _shifted_dense(q, k, v, causal, q_offset):
     return dense_attention(padded, k, v, causal=causal)[:, q_offset:]
 
 
+def _assert_flash_equals_dense(batch, seq_q, seq_k, q_offset, block_q,
+                               block_k, causal, head_dim, dtype):
+    """Forward and the three gradients of a two-head call against dense
+    attention."""
+    import jax
+
+    rng = np.random.default_rng(seq_q + 7 * seq_k + 13 * q_offset)
+    q, cot = (jnp.asarray(rng.standard_normal((batch, seq_q, 2, head_dim)),
+                          dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((batch, seq_k, 2, head_dim)),
+                        dtype) for _ in range(2))
+
+    def run(attention):
+        out, vjp = jax.vjp(attention, q, k, v)
+        return (out, *vjp(cot))
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        q_offset=q_offset))
+    want = run(lambda q, k, v: _shifted_dense(q, k, v, causal, q_offset))
+    tol = 5e-4 if dtype == "float32" else 3e-2
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=tol, atol=tol, err_msg=name)
+
+
 # (id, seq_q, seq_k, q_offset, block_q, block_k, causal, head_dim, dtype,
 #  resident bytes or None): the shapes that move the diagonal through the
 # kernels' loops. Tiles of 16 keep the interpreter quick.
@@ -226,8 +254,6 @@ def test_flash_follows_the_diagonal(monkeypatch, seq_q, seq_k, q_offset,
     """Forward and all three gradients against dense attention wherever
     the loop bounds, the masked / unmasked split and the major-block grid
     take another branch."""
-    import jax
-
     from horovod_tpu.ops import pallas_attention
 
     if resident is not None:
@@ -238,26 +264,71 @@ def test_flash_follows_the_diagonal(monkeypatch, seq_q, seq_k, q_offset,
             seq_k, block_k,
             pallas_attention._operand_row_bytes(head_dim, dtype))
         assert majors > 1, majors
-    rng = np.random.default_rng(seq_q + 7 * seq_k + 13 * q_offset)
-    q, cot = (jnp.asarray(rng.standard_normal((2, seq_q, 2, head_dim)),
-                          dtype) for _ in range(2))
-    k, v = (jnp.asarray(rng.standard_normal((2, seq_k, 2, head_dim)), dtype)
-            for _ in range(2))
+    _assert_flash_equals_dense(2, seq_q, seq_k, q_offset, block_q, block_k,
+                               causal, head_dim, dtype)
 
-    def run(attention):
-        out, vjp = jax.vjp(attention, q, k, v)
-        return (out, *vjp(cot))
 
-    got = run(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        q_offset=q_offset))
-    want = run(lambda q, k, v: _shifted_dense(q, k, v, causal, q_offset))
-    tol = 5e-4 if dtype == "float32" else 3e-2
-    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
-        assert g.dtype == w.dtype and g.shape == w.shape, name
-        np.testing.assert_allclose(
-            np.asarray(g, np.float32), np.asarray(w, np.float32),
-            rtol=tol, atol=tol, err_msg=name)
+# (id, seq_q, seq_k, q_offset, block_q, block_k, head_dim, dtype, resident
+#  bytes or None, whether the masked tiles run as strips): tiles of whole
+# lanes, so that the diagonal tile's products are cut where the shapes put
+# the diagonal on a tile's own (``_strips``), and run whole where they do
+# not
+_STRIP_CASES = [
+    ("one_tile_256", 256, 256, 0, None, None, 64, "float32", None, True),
+    ("one_tile_512_d128", 512, 512, 0, None, None, 128, "float32", None,
+     True),
+    ("the_cells_tiles_at_1024", 1024, 1024, 0, None, None, 64, "bfloat16",
+     None, True),
+    ("default_tiles_at_2048_d128", 2048, 2048, 0, None, None, 128,
+     "bfloat16", None, True),
+    ("four_tiles_of_256", 1024, 1024, 0, 256, 256, 64, "float32", None,
+     True),
+    ("two_tiles_of_384", 768, 768, 0, 384, 384, 128, "float32", None, True),
+    # 3072 bytes a row of the forward's residents at 256 rows a tile, 2048
+    # of the backward's
+    ("one_tile_a_major", 1024, 1024, 0, 256, 256, 64, "float32", 1, True),
+    ("two_tiles_a_major", 1024, 1024, 0, 256, 256, 64, "float32",
+     2 * 256 * 3072, True),
+    ("offset_of_two_tiles", 512, 1024, 512, 256, 256, 64, "float32", None,
+     True),
+    ("offset_majors", 512, 1024, 256, 256, 256, 128, "float32", 1, True),
+    ("offset_of_a_strip", 512, 1024, 384, 256, 256, 64, "float32", None,
+     False),
+    ("offset_inside_a_strip", 512, 1024, 100, 256, 256, 64, "float32", None,
+     False),
+    ("wide_q_tile", 512, 512, 0, 256, 128, 64, "float32", None, False),
+    ("wide_k_tile", 512, 512, 0, 256, 512, 128, "float32", None, False),
+    ("one_lane_block_a_tile", 256, 256, 0, 128, 128, 64, "float32", None,
+     False),
+]
+
+
+@pytest.mark.parametrize(
+    "seq_q,seq_k,q_offset,block_q,block_k,head_dim,dtype,resident,strips",
+    [case[1:] for case in _STRIP_CASES],
+    ids=[case[0] for case in _STRIP_CASES])
+def test_flash_strips_against_dense(monkeypatch, seq_q, seq_k, q_offset,
+                                    block_q, block_k, head_dim, dtype,
+                                    resident, strips):
+    """Forward and the three gradients against dense attention where the
+    diagonal tile runs as static strips, and at the neighbouring shapes
+    that must fall back to the whole masked tile; ``causal_schedule``'s
+    ``trimmed`` says which of the two a shape took."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    fwd, bwd = pa._tiles(seq_q, seq_k, head_dim, dtype, block_q, block_k)
+    if resident is not None:
+        monkeypatch.setattr(pa, "_RESIDENT_BYTES", resident)
+        assert seq_k // pa._major(
+            seq_k, bwd[1], pa._operand_row_bytes(head_dim, dtype)) > 1
+    for kernel, tiles in (("flash_fwd", fwd), ("flash_bwd_dq", bwd),
+                          ("flash_bwd_dkv", bwd)):
+        entry = pa.causal_schedule(seq_q, seq_k, q_offset, *tiles,
+                                   True)[kernel]
+        assert entry["trimmed"] == (entry["diagonal"] if strips else 0)
+        assert entry["diagonal"] > 0
+    _assert_flash_equals_dense(1, seq_q, seq_k, q_offset, block_q, block_k,
+                               True, head_dim, dtype)
 
 
 # -- causal_schedule alone ---------------------------------------------------
@@ -318,27 +389,99 @@ def test_causal_schedule_against_brute_force(seq_q, seq_k, q_offset, tile_q,
             len(by_k_walk) * tile_q * tile_k / kept.sum())
 
 
+def executed_pairs_by_enumeration(kept, tile_q, tile_k, strips):
+    """What a kernel executes, block by block: a tile with no visible pair
+    not at all; a tile that holds masked pairs and runs as strips
+    (``strips`` = ``_strips``' rows on a diagonal and on a window-edge tile)
+    every ``strip x strip`` block of it that holds a visible pair; any other
+    tile whole. Returns ``(pairs, tiles that ran as strips)``. The tile's
+    kind is read off ``kept`` too: one that loses a pair above its rows'
+    positions is a diagonal tile, one that loses a pair below them an edge
+    tile."""
+    pairs = trimmed = 0
+    for i in range(0, kept.shape[0], tile_q):
+        for j in range(0, kept.shape[1], tile_k):
+            tile = kept[i:i + tile_q, j:j + tile_k]
+            if not tile.any():
+                continue
+            diagonal = bool((~tile[:, -1]).any() and tile[-1, 0])
+            edge = bool((~tile[:, 0]).any() and tile[0, -1])
+            strip = strips[0] if diagonal and not edge else \
+                strips[1] if edge and not diagonal else None
+            if not strip:
+                pairs += tile_q * tile_k
+                continue
+            trimmed += 1
+            pairs += strip * strip * sum(
+                tile[r:r + strip, c:c + strip].any()
+                for r in range(0, tile_q, strip)
+                for c in range(0, tile_k, strip))
+    return pairs, trimmed
+
+
+_STRIP_SCHEDULE_CASES = [
+    # seq_q, seq_k, q_offset, tile_q, tile_k
+    (1024, 1024, 0, 1024, 1024), (1024, 1024, 0, 512, 512),
+    (1024, 1024, 0, 256, 256), (768, 768, 0, 384, 384),
+    (512, 2048, 1024, 512, 512), (512, 1024, 512, 256, 256),
+    (1024, 512, 0, 256, 256), (2048, 2048, 0, 1024, 1024),
+    # shapes that fall back: trimmed 0, every executed tile whole
+    (512, 1024, 384, 256, 256), (512, 512, 0, 256, 128),
+    (512, 512, 0, 128, 256), (256, 256, 0, 128, 128), (96, 96, 0, 32, 32),
+]
+
+
+@pytest.mark.parametrize("seq_q,seq_k,q_offset,tile_q,tile_k",
+                         _STRIP_SCHEDULE_CASES)
+def test_strip_schedule_against_brute_force(seq_q, seq_k, q_offset, tile_q,
+                                            tile_k):
+    """``causal_schedule`` counts what the kernels execute strip by strip:
+    its pairs and its ``trimmed`` equal an enumeration of the blocks that
+    hold a visible pair."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    kept = q_offset + np.arange(seq_q)[:, None] >= np.arange(seq_k)[None, :]
+    schedule = pa.causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k,
+                                  True)
+    for kernel, entry in schedule.items():
+        strips = pa._strips(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
+                            causal=True, window=None)
+        pairs, trimmed = executed_pairs_by_enumeration(
+            kept, tile_q, tile_k, strips)
+        assert entry["trimmed"] == trimmed, kernel
+        assert entry["pair_ratio"] == pytest.approx(pairs / kept.sum())
+        if tile_q != tile_k or q_offset % tile_q or tile_q < 256:
+            assert trimmed == 0 and strips == (None, None)
+        else:
+            assert trimmed == entry["diagonal"] > 0
+
+
 def test_schedule_at_the_cells_shape_and_its_gauge():
     """GPT-2-medium's call — T = 1024, head_dim 64, no bounds passed: the
-    parent's 512-row grid executed 1.50 x the needed pairs; the forward now
-    takes the sequence as one tile (2.0 x: a step costs more than its
-    wasted pairs, PERF.md PR 25), the backward kernels stay at 512, and the
-    gauge holds what was traced."""
+    forward takes the sequence as one tile and the backward kernels tiles
+    of 512 (PERF.md, PR 25), which run whole would execute 2.0 / 1.5 / 1.5 x
+    the needed pairs; every masked tile of theirs runs as strips of 128
+    rows (PR 29), 1.12 x in all three, and the gauge holds what was
+    traced."""
     import jax
 
     from horovod_tpu.obs import registry
     from horovod_tpu.ops import pallas_attention as pa
 
-    parent = pa.causal_schedule(1024, 1024, 0, 512, 512, True)
-    assert parent["flash_fwd"] == {
-        "tiles": 3, "diagonal": 2,
-        "pair_ratio": pytest.approx(1.4985, rel=1e-4)}
     fwd, bwd = pa._tiles(1024, 1024, 64, "bfloat16", None, None)
     assert (fwd, bwd) == ((1024, 1024), (512, 512))
-    want = {"flash_fwd": pa.causal_schedule(1024, 1024, 0, *fwd, True),
-            "flash_bwd_dq": parent, "flash_bwd_dkv": parent}
-    assert want["flash_fwd"]["flash_fwd"]["pair_ratio"] == pytest.approx(
-        1.998, rel=1e-3)
+    forward = pa.causal_schedule(1024, 1024, 0, *fwd, True)["flash_fwd"]
+    backward = pa.causal_schedule(1024, 1024, 0, *bwd, True)
+    assert forward == {"tiles": 1, "diagonal": 1, "trimmed": 1,
+                       "pair_ratio": pytest.approx(1.998 * 36 / 64,
+                                                   rel=1e-3)}
+    assert backward["flash_bwd_dq"] == backward["flash_bwd_dkv"] == {
+        "tiles": 3, "diagonal": 2, "trimmed": 2,
+        "pair_ratio": pytest.approx(1.4985 * (1 + 2 * 10 / 16) / 3,
+                                    rel=1e-4)}
+    want = {"flash_fwd": forward, **{name: backward[name] for name in
+                                     ("flash_bwd_dq", "flash_bwd_dkv")}}
+    assert all(entry["pair_ratio"] <= 1.25 for entry in want.values())
 
     x = jax.ShapeDtypeStruct((4, 1024, 16, 64), jnp.bfloat16)
     jax.eval_shape(jax.grad(lambda q, k, v: pa.flash_attention(
@@ -347,8 +490,15 @@ def test_schedule_at_the_cells_shape_and_its_gauge():
     samples = registry().snapshot()[
         "horovod_flash_executed_pair_ratio"]["samples"]
     read = {s["labels"]["kernel"]: s["value"] for s in samples}
-    assert read == {name: pytest.approx(entry[name]["pair_ratio"])
-                    for name, entry in want.items()}
+    assert {name: read[name] for name in want} == {
+        name: pytest.approx(entry["pair_ratio"])
+        for name, entry in want.items()}
+    # a q_offset inside a tile leaves the diagonal's place in a tile
+    # unknown: the masked tiles run whole, and the gauge says so
+    offset = pa.causal_schedule(1024, 2048, 1000, *bwd, True)["flash_bwd_dq"]
+    assert offset["trimmed"] == 0 < offset["diagonal"]
+    assert offset["pair_ratio"] == pytest.approx(
+        offset["tiles"] * 512 * 512 / (1024 * 1000 + 1024 * 1025 / 2))
 
 
 @pytest.mark.parametrize("args,want", [

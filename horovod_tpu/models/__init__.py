@@ -8,6 +8,7 @@ benchmark protocol (``examples/pytorch_synthetic_benchmark.py``).
 """
 
 from .inception import InceptionV3
+from .kimi_linear import KimiLinearLM
 from .laguna import ExpertLayer, LagunaLM
 from .mnist import MnistCNN
 from .resnet import ResNet, ResNet50, ResNet101
@@ -16,4 +17,4 @@ from .vgg import VGG16, VGG19
 
 __all__ = ["MnistCNN", "ResNet", "ResNet50", "ResNet101",
            "TransformerLM", "lm_loss", "VGG16", "VGG19", "InceptionV3",
-           "LagunaLM", "ExpertLayer"]
+           "LagunaLM", "ExpertLayer", "KimiLinearLM"]

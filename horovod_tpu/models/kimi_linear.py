@@ -1,0 +1,321 @@
+"""Kimi-Linear-style decoder: layers of Kimi Delta Attention (KDA, a gated
+delta-rule linear attention over a short causal convolution) mixed 3 : 1
+with latent attention (MLA, no positional encoding), gated SiLU MLPs and,
+after a leading dense layer, the routed expert layer of ``models.laguna``
+(docs/kimi-linear.md).
+
+The third decoder beside ``transformer.TransformerLM`` and
+``laguna.LagunaLM``: the same call (``model(tokens) -> float32 logits``),
+so ``make_lm_train_step`` and ``lm_loss`` take it unchanged.
+``KimiLinearLM.from_config`` reads the keys of the published
+``config.json`` (moonshotai/Kimi-Linear-48B-A3B-Instruct) plus
+``experts_held``. Two kernels carry it: ``ops.kda.kda`` (the chunked delta
+rule) and ``ops.pallas_attention.flash_attention`` with a v narrower than
+its q and k (192 against 128 as published).
+
+Per KDA layer, two numbers say whether the recurrence forgets or blows up:
+the mean decay ``mean(alpha)`` and the largest ``|S|`` at the sequence's
+end, sown into the collection ``kda_stats`` (``obs.kda.publish``).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from .laguna import (_INIT, ATTENTION_BACKENDS, ExpertLayer, GatedMLP,
+                     dense_attention)
+
+KDA_BACKENDS = ("chunked", "recurrent")
+
+
+def _dense(features, name, dtype, axis=-1):
+    return nn.DenseGeneral(features, axis=axis, use_bias=False, dtype=dtype,
+                           kernel_init=_INIT, name=name)
+
+
+def _taps_init(key, shape, dtype=jnp.float32):
+    """Uniform in +-1/sqrt(taps): a depthwise convolution's usual start."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _decay_rate_init(key, shape, dtype=jnp.float32):
+    """``A``: the log of a rate drawn uniformly from [1, 16], a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _decay_bias_init(key, shape, dtype=jnp.float32):
+    """``b``: softplus(b) is a step drawn log-uniformly from [0.001, 0.1]."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                      math.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def causal_conv(x, taps):
+    """Depthwise causal convolution along the sequence: ``y_t = sum_j
+    taps[j] * x_{t - (n - 1) + j}`` for x ``[B, T, C]`` and taps ``[n, C]``,
+    the last tap on the token itself, zeros before the sequence."""
+    n = taps.shape[0]
+    padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + x.shape[1]] * taps[j].astype(x.dtype)
+               for j in range(n))
+
+
+def _l2norm(x):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int, dtype):
+    """What lies between a KDA layer's projections and its delta rule: the
+    short convolutions and SiLU on q, k and v, q and k normalised a head,
+    the per-channel log-decay ``g = -exp(rate) * softplus(raw + bias)`` in
+    float32 and ``beta = sigmoid(write)``. ``[B, T, heads * d]`` in,
+    ``(q, k, v, g [B, T, heads, d], beta [B, T, heads])`` out."""
+    by_head = lambda a: a.reshape(*a.shape[:2], heads, -1)  # noqa: E731
+    with jax.named_scope("hvd.kda.conv"):
+        q, k, v = (nn.silu(causal_conv(a, t)) for a, t in zip((q, k, v), taps))
+    g = -jnp.exp(rate)[:, None] * by_head(jax.nn.softplus(
+        raw.astype(jnp.float32) + bias))
+    q, k = (_l2norm(by_head(a)).astype(dtype) for a in (q, k))
+    return q, k, by_head(v), g, nn.sigmoid(write.astype(jnp.float32))
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention: q, k, v through a short convolution and SiLU,
+    q and k normalised, a per-channel decay and a per-head write strength
+    from the input, the delta rule, a normalised and gated output."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    kda: str = "chunked"
+
+    @nn.compact
+    def __call__(self, x):
+        if self.kda not in KDA_BACKENDS:
+            raise ValueError(f"kda must be one of {KDA_BACKENDS}, got "
+                             f"{self.kda!r}")
+        from ..ops.kda import kda_fed, kda_recurrent
+
+        heads, dh = self.num_heads, self.head_dim
+        width = heads * dh
+        with jax.named_scope("hvd.kda"):
+            q, k, v = (_dense(width, name, self.dtype)(x)
+                       for name in ("query", "key", "value"))
+            taps = tuple(self.param(name, _taps_init, (self.conv_size, width))
+                         for name in ("conv_q", "conv_k", "conv_v"))
+            rate = self.param("decay_rate", _decay_rate_init, (heads,))
+            bias = self.param("decay_bias", _decay_bias_init, (width,))
+            raw = _dense(width, "decay_b", self.dtype)(
+                _dense(dh, "decay_a", self.dtype)(x))
+            write = _dense(heads, "beta", self.dtype)(x)
+            feed = functools.partial(_conditioned, heads=heads,
+                                     dtype=self.dtype)
+            projected = (q, k, v, raw, write, taps, rate, bias)
+            with jax.named_scope("hvd.kda.scan"):
+                # the kernel's backward keeps the projections and forms
+                # what ``feed`` makes of them again (``ops.kda.kda_fed``)
+                o, state = kda_fed(feed, *projected) \
+                    if self.kda == "chunked" else \
+                    kda_recurrent(*feed(*projected))
+            if self.is_mutable_collection("kda_stats"):
+                self.sow("kda_stats", "mean_decay",
+                         jnp.mean(jnp.exp(feed(*projected)[3])))
+                self.sow("kda_stats", "state_max", jnp.max(jnp.abs(state)))
+            gate = _dense(width, "gate_b", self.dtype)(
+                _dense(dh, "gate_a", self.dtype)(x))
+            o = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                           name="out_norm")(o.astype(self.dtype))
+            o = o.reshape(*x.shape[:2], width) * nn.sigmoid(gate)
+            return _dense(x.shape[-1], "out", self.dtype)(o)
+
+
+class LatentAttention(nn.Module):
+    """Causal attention whose keys and values come from one compressed
+    latent: ``kv_rank`` dims normalised and expanded to each head's
+    ``nope_dim`` key dims and ``v_dim`` value dims, plus ``rope_dim`` key
+    dims shared by all heads; queries uncompressed. No rotation is applied
+    to any of them (``mla_use_nope``)."""
+
+    num_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention: str = "flash"
+
+    @nn.compact
+    def __call__(self, x):
+        if self.attention not in ATTENTION_BACKENDS:
+            raise ValueError(f"attention must be one of {ATTENTION_BACKENDS},"
+                             f" got {self.attention!r}")
+        heads = self.num_heads
+        with jax.named_scope("hvd.mla"):
+            q = _dense((heads, self.nope_dim + self.rope_dim), "query",
+                       self.dtype)(x)
+            latent, k_pe = jnp.split(
+                _dense(self.kv_rank + self.rope_dim, "kv_a", self.dtype)(x),
+                [self.kv_rank], axis=-1)
+            latent = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                name="kv_norm")(latent)
+            k_nope, v = jnp.split(
+                _dense((heads, self.nope_dim + self.v_dim), "kv_b",
+                       self.dtype)(latent), [self.nope_dim], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_pe[:, :, None, :], (*k_nope.shape[:3], self.rope_dim))],
+                axis=-1)
+            with jax.named_scope("hvd.mla.attn"):
+                if self.attention == "flash":
+                    from ..ops.pallas_attention import flash_attention
+
+                    out = flash_attention(q, k, v, causal=True)
+                else:
+                    out = dense_attention(q, k, v)
+            return _dense(x.shape[-1], "out", self.dtype, axis=(-2, -1))(
+                out.astype(self.dtype))
+
+
+class KimiBlock(nn.Module):
+    """Pre-RMSNorm residual block: a KDA or a latent-attention mixer, then
+    a dense gated MLP (``dense_width``) or, where that is ``None``, the
+    expert layer."""
+
+    mixer: str          # "kda" | "mla"
+    kda: dict           # KDAMixer's fields
+    mla: dict           # LatentAttention's fields
+    dense_width: Optional[int]
+    experts: dict       # ExpertLayer's fields
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        norm = lambda name: nn.RMSNorm(  # noqa: E731
+            epsilon=self.eps, dtype=self.dtype, name=name)
+        h = norm("ln_attn")(x)
+        if self.mixer == "kda":
+            x = x + KDAMixer(eps=self.eps, dtype=self.dtype, name="kda",
+                             **self.kda)(h)
+        else:
+            x = x + LatentAttention(eps=self.eps, dtype=self.dtype,
+                                    name="mla", **self.mla)(h)
+        h = norm("ln_mlp")(x)
+        if self.dense_width is not None:
+            return x + GatedMLP(self.dense_width, self.dtype, name="mlp")(h)
+        return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
+
+
+class KimiLinearLM(nn.Module):
+    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
+    Layer ``i`` mixes with ``mixers[i]`` (``"kda"`` or ``"mla"``) and its
+    MLP is ``mlp_layer_types[i]`` (``"dense"`` or ``"sparse"``). No
+    positional encoding of any kind."""
+
+    vocab_size: int
+    d_model: int
+    mixers: Tuple[str, ...]
+    mlp_layer_types: Tuple[str, ...]
+    num_heads: int
+    kda_head_dim: int
+    conv_size: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    dense_width: int
+    expert_width: int
+    shared_width: int
+    num_experts: int
+    experts_per_token: int
+    experts_held: Tuple[int, int]
+    routed_scaling: float
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention: str = "flash"    # "dense": the tests' written-out attention
+    kda: str = "chunked"        # "recurrent": the tests' token-by-token scan
+    # jax.checkpoint each block: only the block-boundary activations are
+    # stored, a block's interior is recomputed in backward
+    remat: bool = False
+
+    @classmethod
+    def from_config(cls, config: dict, **overrides) -> "KimiLinearLM":
+        """The model of a published ``config.json``'s keys, cut to
+        ``num_hidden_layers`` leading layers, with ``experts_held``
+        ``{"first": .., "count": ..}`` (all of them when absent). The
+        config counts layers from 1: ``linear_attn_config.kda_layers`` and
+        ``full_attn_layers`` name them, the first
+        ``first_k_dense_replace`` have a dense MLP."""
+        depth = config["num_hidden_layers"]
+        linear = config["linear_attn_config"]
+        kinds = {**{i: "kda" for i in linear["kda_layers"]},
+                 **{i: "mla" for i in linear["full_attn_layers"]}}
+        held = config.get("experts_held",
+                          {"first": 0, "count": config["num_experts"]})
+        fields = dict(
+            vocab_size=config["vocab_size"], d_model=config["hidden_size"],
+            mixers=tuple(kinds[i] for i in range(1, depth + 1)),
+            mlp_layer_types=tuple(
+                "dense" if i < config["first_k_dense_replace"] else "sparse"
+                for i in range(depth)),
+            num_heads=config["num_attention_heads"],
+            kda_head_dim=linear["head_dim"],
+            conv_size=linear["short_conv_kernel_size"],
+            nope_dim=config["qk_nope_head_dim"],
+            rope_dim=config["qk_rope_head_dim"],
+            v_dim=config["v_head_dim"], kv_rank=config["kv_lora_rank"],
+            dense_width=config["intermediate_size"],
+            expert_width=config["moe_intermediate_size"],
+            shared_width=config["moe_intermediate_size"]
+            * config["num_shared_experts"],
+            num_experts=config["num_experts"],
+            experts_per_token=config["num_experts_per_token"],
+            experts_held=(held["first"], held["count"]),
+            routed_scaling=config["routed_scaling_factor"],
+            eps=config["rms_norm_eps"])
+        if linear["num_heads"] != fields["num_heads"]:
+            raise ValueError("KDA and latent-attention layers with head "
+                             "counts of their own are not supported")
+        fields.update(overrides)
+        return cls(**fields)
+
+    @nn.compact
+    def __call__(self, tokens):
+        if len(self.mixers) != len(self.mlp_layer_types):
+            raise ValueError("mixers and mlp_layer_types must be equally "
+                             "long")
+        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                     embedding_init=_INIT, name="tok_embed")(tokens)
+        block_cls = nn.remat(KimiBlock) if self.remat else KimiBlock
+        kda = dict(num_heads=self.num_heads, head_dim=self.kda_head_dim,
+                   conv_size=self.conv_size, kda=self.kda)
+        mla = dict(num_heads=self.num_heads, nope_dim=self.nope_dim,
+                   rope_dim=self.rope_dim, v_dim=self.v_dim,
+                   kv_rank=self.kv_rank, attention=self.attention)
+        experts = dict(
+            num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            experts_held=self.experts_held, width=self.expert_width,
+            shared_width=self.shared_width, scaling=self.routed_scaling)
+        for i, (mixer, mlp) in enumerate(zip(self.mixers,
+                                             self.mlp_layer_types)):
+            x = block_cls(
+                mixer=mixer, kda=kda, mla=mla, experts=experts, eps=self.eps,
+                dtype=self.dtype,
+                dense_width=self.dense_width if mlp == "dense" else None,
+                name=f"block_{i}")(x)
+        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                       name="ln_final")(x)
+        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                          kernel_init=_INIT, name="lm_head")(x)
+        return logits.astype(jnp.float32)

@@ -22,6 +22,8 @@ The subsystem docs live in docs/metrics.md; the pieces:
   collectives can run beside compute);
 * :mod:`.moe` — the expert layer's routing gauges, from the flax
   collection it sows (docs/laguna.md);
+* :mod:`.kda` — the delta-rule layers' decay and state gauges, likewise
+  (docs/kimi-linear.md);
 * :func:`metrics_snapshot` — the Python API: this process's families, or
   the world-aggregated view rank 0's coordinator assembled from the
   per-rank pushes riding the HMAC control wire.
@@ -45,6 +47,7 @@ from .compiles import (CompileEvent, compile_events,  # noqa: F401
                        record_exchange_collectives)
 from . import exposition  # noqa: F401
 from . import flightrec  # noqa: F401 - public surface (docs/blackbox.md)
+from . import kda  # noqa: F401 - public surface (docs/kimi-linear.md)
 from . import moe  # noqa: F401 - public surface (docs/laguna.md)
 from . import tensorwatch  # noqa: F401 - public surface (docs/tensorwatch.md)
 from .tensorwatch import tensor_report  # noqa: F401

@@ -79,9 +79,10 @@ left operand.
 
 The three ``pallas_call``s are named ``flash_fwd``, ``flash_bwd_dq`` and
 ``flash_bwd_dkv`` (``flash_win_fwd``, ``flash_win_bwd_dq``,
-``flash_win_bwd_dkv`` for a call with a window): the names a compiled
-program's custom calls and a profiler trace show them under
-(docs/tracing.md).
+``flash_win_bwd_dkv`` for a call with a window; ``flash_mla_fwd``,
+``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv`` for one whose v is of another
+width than its q and k): the names a compiled program's custom calls and a
+profiler trace show them under (docs/tracing.md).
 
 ``interpret=True`` (automatic on the CPU backend only) runs the same
 kernels through the Pallas interpreter, which is how the CPU test suite
@@ -785,7 +786,7 @@ def _tile(seq: int, bound: Optional[int], prefer: int, row_bytes: int) -> int:
 
 def _tiles(seq_q: int, seq_k: int, head_dim: int, dtype,
            block_q: Optional[int], block_k: Optional[int],
-           window: Optional[int] = None):
+           window: Optional[int] = None, v_dim: Optional[int] = None):
     """``((tile_q, tile_k) of the forward, (tile_q, tile_k) of the two
     backward kernels)``. What the v5e measured (PERF.md, PR 25): a loop
     step, a grid step and a row's statistics cost the same whatever the
@@ -795,7 +796,7 @@ def _tiles(seq_q: int, seq_k: int, head_dim: int, dtype,
     three and four are cheapest at 512. Under a window no tile is
     preferred larger than the window: every tile a row's window touches
     would be an edge tile, most of its pairs masked."""
-    row_bytes = _operand_row_bytes(head_dim, dtype)
+    row_bytes = _operand_row_bytes(head_dim, dtype, v_dim)
     fwd, bwd = 1024, 512
     if window is not None:
         fwd, bwd = (min(t, max(_LANES, window)) for t in (fwd, bwd))
@@ -820,10 +821,14 @@ def _major(seq: int, tile: int, row_bytes: int,
     return fit * tile
 
 
-def _operand_row_bytes(head_dim: int, dtype) -> int:
-    """VMEM bytes a row of two resident operands takes: two pipeline
-    buffers each, ``head_dim`` padded to the lanes."""
-    return 2 * 2 * -(-head_dim // _LANES) * _LANES * jnp.dtype(dtype).itemsize
+def _operand_row_bytes(head_dim: int, dtype,
+                       v_dim: Optional[int] = None) -> int:
+    """VMEM bytes a row of two resident operands takes — one ``head_dim``
+    wide (K, or Q), the other ``v_dim`` (V, or dO; ``head_dim`` where they
+    agree): two pipeline buffers each, the widths padded to the lanes."""
+    lanes = sum(-(-d // _LANES) * _LANES
+                for d in (head_dim, head_dim if v_dim is None else v_dim))
+    return 2 * lanes * jnp.dtype(dtype).itemsize
 
 
 def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, group: int,
@@ -849,54 +854,56 @@ def _compiler_params(interpret: bool):
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _kernel_names(window: Optional[int]) -> dict:
+def _kernel_names(window: Optional[int], split: bool = False) -> dict:
     """The ``pallas_call`` names by ``causal_schedule``'s keys: a call with
-    a window is named apart, so that a trace tells the two apart."""
-    if window is None:
-        return {name: name for name in
-                ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
-    return {"flash_fwd": "flash_win_fwd", "flash_bwd_dq": "flash_win_bwd_dq",
-            "flash_bwd_dkv": "flash_win_bwd_dkv"}
+    a window, and one whose v is of another width than its q (``split``:
+    latent attention), are named apart, so that a trace tells them apart."""
+    prefix = "flash_mla" if split else \
+        "flash" if window is None else "flash_win"
+    return {name: prefix + name[len("flash"):] for name in
+            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
 
 
 def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
-    seq_k, kv_heads = k.shape[1], k.shape[2]
+    seq_k, kv_heads, v_dim = k.shape[1], k.shape[2], v.shape[-1]
     num_k_tiles = seq_k // tile_k
     # beside K and V, a row of the major block holds its f32 scores
-    major_k = _major(seq_k, tile_k,
-                     _operand_row_bytes(head_dim, k.dtype) + 4 * tile_q)
+    major_k = _major(seq_k, tile_k, _operand_row_bytes(
+        head_dim, k.dtype, v_dim) + 4 * tile_q)
     grid_majors = _grid_majors(seq_k // major_k, major_k, tile_q, window)
     lanes = _LANES if tile_k % _LANES == 0 else tile_k
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
-    name = _kernel_names(window)["flash_fwd"]
+    name = _kernel_names(window, v_dim != head_dim)["flash_fwd"]
     _PAIR_RATIO.labels(kernel=name).set(causal_schedule(
         seq_q, seq_k, q_offset, tile_q, tile_k, causal, window)
         ["flash_fwd"]["pair_ratio"])
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
                     causal=causal, window=window)
 
-    q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
-    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles,
-                             heads // kv_heads, grid_majors, **schedule)
+    q_spec, o_spec = (pl.BlockSpec((1, tile_q, d), lambda bh, i, kk: (bh, i, 0))
+                      for d in (head_dim, v_dim))
+    k_spec, v_spec = (_kv_major_spec(major_k, d, num_k_tiles,
+                                     heads // kv_heads, grid_majors,
+                                     **schedule) for d in (head_dim, v_dim))
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, major_k=major_k,
                           num_k_tiles=num_k_tiles, grid_majors=grid_majors,
                           pieces=_pieces(**schedule),
                           **schedule),
         grid=(batch * heads, seq_q // tile_q, grid_majors),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0)),
         ],
         out_shape=[
-            _sds((batch * heads, seq_q, head_dim), q.dtype, q, k, v),
+            _sds((batch * heads, seq_q, v_dim), q.dtype, q, k, v),
             _sds((batch * heads, seq_q, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tile_q, head_dim), jnp.float32),
+            pltpu.VMEM((tile_q, v_dim), jnp.float32),
             pltpu.VMEM((tile_q, _LANES), jnp.float32),
             pltpu.VMEM((tile_q, _LANES), jnp.float32),
             pltpu.VMEM((tile_q, major_k), jnp.float32),
@@ -916,20 +923,21 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
               q_offset, window):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
-    seq_k, kv_heads = k.shape[1], k.shape[2]
+    seq_k, kv_heads, v_dim = k.shape[1], k.shape[2], v.shape[-1]
     group = heads // kv_heads
     num_q_tiles = seq_q // tile_q
     num_k_tiles = seq_k // tile_k
-    major_k = _major(seq_k, tile_k, _operand_row_bytes(head_dim, k.dtype))
+    row_bytes = _operand_row_bytes(head_dim, k.dtype, v_dim)
+    major_k = _major(seq_k, tile_k, row_bytes)
     # the dK/dV grid takes another query head every step, so its q-side
     # block is fetched anew every step: under a window no more of it than a
     # k tile's window reaches (PERF.md, PR 29: a head's whole Q and dO, 4 MB
     # a step for two tiles of work, bound flash_win_bwd_dkv by HBM)
-    major_q = _major(seq_q, tile_q, _operand_row_bytes(head_dim, q.dtype),
+    major_q = _major(seq_q, tile_q, row_bytes,
                      None if window is None else tile_k + window - 1)
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     ob, dob = _to_bh(o), _to_bh(do)
-    names = _kernel_names(window)
+    names = _kernel_names(window, v_dim != head_dim)
     executed = causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k,
                                causal, window)
     for key in ("flash_bwd_dq", "flash_bwd_dkv"):
@@ -949,9 +957,11 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
     lse_rows, delta_rows = lse[:, None, :], delta[:, None, :]
 
     k_majors = _grid_majors(seq_k // major_k, major_k, tile_q, window)
-    q_spec = pl.BlockSpec((1, tile_q, head_dim), lambda bh, i, kk: (bh, i, 0))
-    kv_spec = _kv_major_spec(major_k, head_dim, num_k_tiles, group, k_majors,
-                             **schedule)
+    q_spec, do_spec = (pl.BlockSpec((1, tile_q, d),
+                                    lambda bh, i, kk: (bh, i, 0))
+                       for d in (head_dim, v_dim))
+    k_spec, v_spec = (_kv_major_spec(major_k, d, num_k_tiles, group, k_majors,
+                                     **schedule) for d in (head_dim, v_dim))
     col_spec = pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, major_k=major_k,
@@ -959,7 +969,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
                           pieces=_pieces(**schedule),
                           **schedule),
         grid=(batch * heads, num_q_tiles, k_majors),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, col_spec, col_spec],
         out_specs=q_spec,
         out_shape=_sds((batch * heads, seq_q, head_dim), q.dtype,
                        q, k, v, do),
@@ -995,11 +1005,13 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
         last = jnp.maximum((stop - 1) // tiles_per_major, first)
         return jnp.clip(iq, first, last)
 
-    kv_q_spec = pl.BlockSpec(
-        (1, major_q, head_dim),
+    kv_q_spec, kv_do_spec = (pl.BlockSpec(
+        (1, major_q, d),
         lambda bh, kk, step: (q_head(bh, step), q_major(kk, step), 0))
-    kv_k_spec = pl.BlockSpec((1, tile_k, head_dim),
-                             lambda bh, kk, step: (bh, kk, 0))
+        for d in (head_dim, v_dim))
+    kv_k_spec, kv_v_spec = (pl.BlockSpec((1, tile_k, d),
+                                         lambda bh, kk, step: (bh, kk, 0))
+                            for d in (head_dim, v_dim))
     kv_row_spec = pl.BlockSpec(
         (1, 1, major_q),
         lambda bh, kk, step: (q_head(bh, step), 0, q_major(kk, step)))
@@ -1010,15 +1022,15 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
                           pieces=_pieces(transposed=True, **schedule),
                           **schedule),
         grid=(batch * kv_heads, num_k_tiles, group * q_majors),
-        in_specs=[kv_q_spec, kv_k_spec, kv_k_spec, kv_q_spec,
+        in_specs=[kv_q_spec, kv_k_spec, kv_v_spec, kv_do_spec,
                   kv_row_spec, kv_row_spec],
-        out_specs=[kv_k_spec, kv_k_spec],
+        out_specs=[kv_k_spec, kv_v_spec],
         out_shape=[
             _sds((batch * kv_heads, seq_k, head_dim), k.dtype, q, k, v, do),
-            _sds((batch * kv_heads, seq_k, head_dim), v.dtype, q, k, v, do),
+            _sds((batch * kv_heads, seq_k, v_dim), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[pltpu.VMEM((tile_k, head_dim), jnp.float32),
-                        pltpu.VMEM((tile_k, head_dim), jnp.float32)],
+                        pltpu.VMEM((tile_k, v_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name=names["flash_bwd_dkv"],
@@ -1064,9 +1076,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None,
                     q_offset: int = 0,
                     window: Optional[int] = None) -> jax.Array:
-    """Fused attention, q ``[batch, seq, heads, head_dim]`` and k, v
-    ``[batch, seq_k, kv_heads, head_dim]``. Differentiable (custom VJP with
-    FlashAttention-2 recomputation kernels).
+    """Fused attention, q ``[batch, seq, heads, head_dim]``, k ``[batch,
+    seq_k, kv_heads, head_dim]`` and v ``[batch, seq_k, kv_heads, v_dim]``;
+    the result is ``[batch, seq, heads, v_dim]``. Differentiable (custom VJP
+    with FlashAttention-2 recomputation kernels).
+
+    ``v_dim`` need not be ``head_dim`` (latent attention: q and k carry
+    positional dims that v does not). The scale is ``head_dim``'s, each
+    operand's blocks are of its own width, the tiles and the VMEM budget
+    count both, and the three calls are then named ``flash_mla_*``.
 
     Grouped heads: ``heads`` is a multiple of ``kv_heads``, and query head
     ``h`` reads K/V head ``h // (heads // kv_heads)``; K and V are not
@@ -1089,10 +1107,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if q_offset < 0:
         raise ValueError("q_offset must be non-negative")
     seq_q, seq_k = q.shape[1], k.shape[1]
-    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+    if k.shape[:-1] != v.shape[:-1] or q.shape[-1] != k.shape[-1] \
+            or q.shape[2] % k.shape[2]:
         raise ValueError(
-            f"k {k.shape} and v {v.shape} must agree, and their heads must "
-            f"divide q's {q.shape[2]}")
+            f"k {k.shape} and v {v.shape} must agree but for v's width, k's "
+            f"width must be q's {q.shape[-1]}, and their heads must divide "
+            f"q's {q.shape[2]}")
     if window is not None and (not causal or window < 1
                                or q_offset + seq_q > seq_k):
         raise ValueError(
@@ -1100,7 +1120,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"position among the keys (q_offset {q_offset} + {seq_q} "
             f"queries > {seq_k} keys)")
     fwd_tiles, bwd_tiles = _tiles(seq_q, seq_k, q.shape[-1], k.dtype,
-                                  block_q, block_k, window)
+                                  block_q, block_k, window, v.shape[-1])
     for tile_q, tile_k in (fwd_tiles, bwd_tiles):
         if seq_q % tile_q or seq_k % tile_k:
             raise ValueError(

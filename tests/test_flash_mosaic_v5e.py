@@ -119,3 +119,55 @@ def test_kernels_compile_for_v5e(one_chip, no_compile_cache, case):
         rows = pa._operand_row_bytes(head_dim, dtype)
         assert pa._major(seq_k, fwd[1], rows + 4 * fwd[0]) < seq_k
         assert pa._major(seq_k, bwd[1], rows) < seq_k
+
+
+# the kernels of kimi_linear_16k_1chip at the cell's shapes: latent
+# attention's flash calls with q and k 192 wide and v 128 (nothing padded,
+# the calls named apart), and the delta rule's chain, forward and transposed
+def test_split_width_kernels_compile_for_v5e(one_chip, no_compile_cache):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    qk = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(qk, qk, v).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    for name in pa._kernel_names(None, split=True).values():
+        assert f"%{name}" in hlo, name
+    assert "bf16[32,16384,256]" not in hlo      # v is not padded to q's lanes
+    fwd, bwd = pa._tiles(16384, 16384, 192, jnp.bfloat16, None, None, None,
+                         128)
+    assert (fwd, bwd) == ((1024, 1024), (512, 512))
+    trimmed = pa.causal_schedule(16384, 16384, 0, *bwd, True)
+    assert trimmed["flash_bwd_dkv"]["trimmed"] > 0
+
+
+def test_delta_rule_kernels_compile_for_v5e(one_chip, no_compile_cache):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.kda import kda
+
+    def placed(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qkv = placed((1, 16384, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(
+        lambda *a: kda(*a, interpret=False)[0].astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            qkv, qkv, qkv, placed((1, 16384, 32, 128), jnp.float32),
+            placed((1, 16384, 32), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("kda_fwd", "kda_bwd"):
+        assert f"%{name}" in hlo, name
+    # the chunks' starting states are kept in the operands' type
+    assert "bf16[256,32,128,128]" in hlo

@@ -1,0 +1,234 @@
+"""``ops.kda``: the chunked gated delta rule against the recurrence token
+by token — values, the final state and every gradient — at sequences that
+are no whole number of chunks, with a decay near 0 and near 1 and beta at
+both ends; the pieces it is made of (the unit lower-triangular inverse, the
+decayed products) against their definitions; and ``flash_attention`` with
+a v narrower than its q and k against attention written out."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import kda as kda_ops
+from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.ops.kda import kda, kda_recurrent
+
+
+def operands(seed, batch=1, seq=150, heads=2, d_k=32, d_v=16, log_decay=0.1,
+             beta=None, dtype=jnp.float32):
+    """Unit q and k, normal v; ``g`` is ``-log_decay * softplus(normal)``
+    and ``beta`` a sigmoid of normals unless it is given."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = (jax.random.normal(key, (batch, seq, heads, d_k))
+            for key in keys[:2])
+    q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
+    v = jax.random.normal(keys[2], (batch, seq, heads, d_v))
+    g = -log_decay * jax.nn.softplus(jax.random.normal(keys[3], q.shape))
+    if beta is None:
+        write = jax.nn.sigmoid(jax.random.normal(keys[4], q.shape[:3]))
+    else:
+        write = jnp.full(q.shape[:3], beta, jnp.float32)
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, write)
+
+
+def _gradients(fn, args, weight):
+    return jax.grad(lambda *a: jnp.sum(fn(*a)[0].astype(jnp.float32)
+                                       * weight), argnums=(0, 1, 2, 3, 4))(
+                                           *args)
+
+
+_CASES = {
+    # id: operands' keywords
+    "a_chunk_and_a_part": dict(seq=150),
+    "shorter_than_a_chunk": dict(seq=40),
+    "whole_chunks_two_sequences": dict(seq=128, batch=2),
+    # alpha = exp(g): about 0.999 a token, and e^-14 (a millionth) a token —
+    # the second overflows any form that divides by a cumulative decay
+    "decay_near_one": dict(log_decay=0.001),
+    "decay_near_zero": dict(log_decay=20.0),
+    "never_writes": dict(beta=0.0),
+    "always_overwrites": dict(beta=1.0),
+    "values_wider_than_keys": dict(d_k=16, d_v=48, heads=3),
+}
+
+
+@pytest.mark.parametrize("case", _CASES)
+def test_chunked_against_token_by_token(case):
+    args = operands(3, **_CASES[case])
+    o, state = kda(*args)
+    want_o, want_state = kda_recurrent(*args)
+    scale = float(jnp.abs(want_o).max()) or 1.0
+    np.testing.assert_allclose(o, want_o, atol=2e-6 * max(scale, 1.0))
+    np.testing.assert_allclose(state, want_state, atol=2e-6)
+    if case == "never_writes":
+        assert float(jnp.abs(o).max()) == 0.0
+    weight = jax.random.normal(jax.random.PRNGKey(9), o.shape)
+    for name, got, want in zip("q k v g beta".split(),
+                               _gradients(kda, args, weight),
+                               _gradients(kda_recurrent, args, weight)):
+        np.testing.assert_allclose(
+            got, want, atol=1e-5 * max(1.0, float(jnp.abs(want).max())),
+            err_msg=name)
+
+
+def test_repeated_keys_do_not_cancel():
+    """Every token the same key, beta 1, no decay: ``I + A`` is all ones
+    below the diagonal, whose inverse by a product of powers cancels
+    catastrophically; by substitution it is the bidiagonal (1, -1)."""
+    q, k, v, g, beta = operands(5, seq=64, heads=1, beta=1.0)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    args = (q, k, v, jnp.zeros_like(g), beta)
+    o, _ = kda(*args)
+    np.testing.assert_allclose(o, kda_recurrent(*args)[0], atol=1e-5)
+    lower = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
+    inverse = kda_ops._unit_lower_inverse(lower)
+    want = np.eye(64) - np.eye(64, k=-1)
+    np.testing.assert_allclose(inverse, want, atol=1e-6)
+
+
+def test_unit_lower_inverse_and_its_gradient():
+    rng = np.random.default_rng(0)
+    lower = jnp.asarray(np.tril(rng.standard_normal((3, 64, 64)), -1),
+                        jnp.float32) * 0.3
+    weight = jnp.asarray(rng.standard_normal((3, 64, 64)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        def plain(x):
+            return jnp.linalg.inv(jnp.eye(64) + jnp.tril(x, -1))
+
+        np.testing.assert_allclose(kda_ops._unit_lower_inverse(lower),
+                                   plain(lower), rtol=1e-4, atol=1e-5)
+        got, want = (jax.grad(lambda x: jnp.sum(f(x) * weight))(lower)
+                     for f in (kda_ops._unit_lower_inverse, plain))
+    np.testing.assert_allclose(got, jnp.tril(want, -1), rtol=1e-3, atol=1e-4)
+
+
+def test_decayed_products_against_the_definition():
+    """``sum_c x_rc k_ic exp(G_rc - G_ic)`` on and below the diagonal, by
+    the pair; no exponent taken is positive even where ``G`` falls by
+    hundreds inside a chunk."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((2, 64, 8)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
+    cum = jnp.cumsum(-jnp.asarray(rng.uniform(0, 12, (64, 8)), jnp.float32),
+                     axis=0)
+    got = kda_ops._decayed_products(x, k, cum)
+    gap = cum[:, None, :] - cum[None, :, :]
+    visible = np.tril(np.ones((64, 64), bool))
+    pairs = jnp.exp(jnp.where(visible[..., None], gap, -jnp.inf))
+    want = jnp.einsum("xrc,ic,ric->xri", x, k, pairs)
+    assert float(-cum.min()) > 300 and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_bfloat16_operands_and_a_chunk_that_is_not_the_default():
+    args = operands(7, seq=96, dtype=jnp.bfloat16)
+    want, _ = kda_recurrent(*args)
+    for chunk in (32, 64):
+        o, state = kda(*args, chunk=chunk)
+        assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+        np.testing.assert_allclose(o.astype(jnp.float32),
+                                   want.astype(jnp.float32), atol=0.03)
+    with pytest.raises(ValueError, match="multiple of 16"):
+        kda(*args, chunk=24)
+
+
+def test_the_chain_is_two_named_kernels():
+    args = operands(1, seq=64)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: kda(*a)[0].sum(),
+                                       argnums=(0, 3)))(*args))
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
+# -- flash attention with a v of its own width --------------------------------
+
+
+def _dense(q, k, v):
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    keep = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+    weights = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+@pytest.mark.parametrize("widths,kv_heads,blocks", [
+    ((48, 32), 4, 128),     # v narrower than q: latent attention's shape
+    ((24, 16), 4, None),    # one tile, the whole sequence
+    ((32, 64), 2, 128),     # wider, and grouped heads
+])
+def test_flash_with_another_v_width_against_dense(widths, kv_heads, blocks):
+    qk, dv = widths
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (2, 256, 4, qk))
+    k = jax.random.normal(keys[1], (2, 256, kv_heads, qk))
+    v = jax.random.normal(keys[2], (2, 256, kv_heads, dv))
+    weight = jax.random.normal(keys[3], (2, 256, 4, dv))
+    flash = lambda *a: pa.flash_attention(  # noqa: E731
+        *a, causal=True, block_q=blocks, block_k=blocks)
+    dense = lambda q, k, v: _dense(  # noqa: E731
+        q, *(jnp.repeat(x, 4 // kv_heads, axis=2) for x in (k, v)))
+    out = flash(q, k, v)
+    assert out.shape == (2, 256, 4, dv)
+    np.testing.assert_allclose(out, dense(q, k, v), atol=2e-5)
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * weight),
+                          argnums=(0, 1, 2))(q, k, v) for f in (flash, dense))
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_split_widths_name_their_kernels_and_count_both_in_the_budget():
+    assert pa._kernel_names(None) == {
+        "flash_fwd": "flash_fwd", "flash_bwd_dq": "flash_bwd_dq",
+        "flash_bwd_dkv": "flash_bwd_dkv"}
+    assert pa._kernel_names(512)["flash_bwd_dq"] == "flash_win_bwd_dq"
+    assert pa._kernel_names(None, True) == {
+        "flash_fwd": "flash_mla_fwd", "flash_bwd_dq": "flash_mla_bwd_dq",
+        "flash_bwd_dkv": "flash_mla_bwd_dkv"}
+    # two operands, two pipeline buffers each, widths padded to the lanes
+    assert pa._operand_row_bytes(128, jnp.bfloat16) == 2 * 2 * 128 * 2
+    assert pa._operand_row_bytes(128, jnp.bfloat16, 128) \
+        == pa._operand_row_bytes(128, jnp.bfloat16)
+    assert pa._operand_row_bytes(192, jnp.bfloat16, 128) \
+        == 2 * (256 + 128) * 2
+    # where the widths agree the tiles are what they were
+    assert pa._tiles(8192, 8192, 128, jnp.bfloat16, None, None, None, 128) \
+        == pa._tiles(8192, 8192, 128, jnp.bfloat16, None, None)
+    q = jnp.zeros((1, 128, 2, 48))
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: pa.flash_attention(
+        q, k, v, causal=True).sum()))(q, q, jnp.zeros((1, 128, 2, 32))))
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+        assert name in text, name
+    with pytest.raises(ValueError, match="k's width must be q's"):
+        pa.flash_attention(q, jnp.zeros((1, 128, 2, 32)),
+                           jnp.zeros((1, 128, 2, 32)))
+
+
+def test_a_feed_is_recomputed_not_kept():
+    """``kda_fed(feed, *args)`` is ``kda(*feed(*args))`` in value and in
+    every gradient, and what its backward keeps is ``args``: nothing of the
+    feed's results is among a gradient program's saved arrays."""
+    q, k, v, g, beta = operands(2, seq=80)
+    gain = jnp.linspace(0.5, 1.5, q.shape[-1])
+
+    def feed(q, k, v, g, beta, gain):
+        q = q * gain
+        return (q / jnp.linalg.norm(q, axis=-1, keepdims=True), jnp.tanh(k),
+                v, g, beta)
+
+    args = (q, k, v, g, beta, gain)
+    weight = jax.random.normal(jax.random.PRNGKey(4), v.shape)
+    fed = lambda *a: kda_ops.kda_fed(feed, *a)[0]  # noqa: E731
+    plain = lambda *a: kda(*feed(*a))[0]           # noqa: E731
+    np.testing.assert_allclose(fed(*args), plain(*args), atol=1e-6)
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * weight),
+                          argnums=tuple(range(6)))(*args)
+                 for f in (fed, plain))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+    _, residuals = jax.vjp(fed, *args)
+    kept = [x.shape for x in jax.tree_util.tree_leaves(residuals)
+            if hasattr(x, "shape") and x.ndim == 4
+            and x.shape[:3] == q.shape[:3]]
+    assert len(kept) == 4, kept     # q, k, v and g as given, no more

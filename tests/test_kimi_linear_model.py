@@ -1,0 +1,235 @@
+"""``models.kimi_linear``: the layout ``from_config`` gives the published
+pattern, the kernels against the written-out backends (the chunked delta
+rule against the token-by-token scan, flash against dense attention), the
+causal convolution against its definition, the 32 shares of an expert layer
+adding up to the uncut one at this model's router, the scopes in a compiled
+step, ``kda_stats`` as gauges, and the model through the data-parallel step
+on two devices."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import ExpertLayer, KimiLinearLM, lm_loss
+from horovod_tpu.models import kimi_linear, laguna
+
+TOY = {
+    "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
+    "num_hidden_layers": 5, "num_attention_heads": 2, "rms_norm_eps": 1e-5,
+    "linear_attn_config": {
+        "kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
+        "head_dim": 32, "num_heads": 2, "short_conv_kernel_size": 4},
+    "first_k_dense_replace": 1, "kv_lora_rank": 48, "qk_nope_head_dim": 32,
+    "qk_rope_head_dim": 16, "v_head_dim": 32, "moe_intermediate_size": 32,
+    "num_shared_experts": 1, "num_experts": 16, "num_experts_per_token": 4,
+    "routed_scaling_factor": 2.446,
+    "experts_held": {"first": 4, "count": 4},
+}
+
+
+# one layer of each kind the model has: KDA + dense, latent + experts,
+# KDA + experts (the tests that compile gradients run on these three)
+SMALL = dict(TOY, num_hidden_layers=3, linear_attn_config=dict(
+    TOY["linear_attn_config"], kda_layers=[1, 3], full_attn_layers=[2]))
+
+
+@pytest.fixture(scope="module")
+def toy():
+    model = KimiLinearLM.from_config(SMALL, dtype=jnp.float32)
+    # a chunk and a half of the delta rule
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 96), 0, 512)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    return model, params, tokens
+
+
+def test_from_config_lays_out_the_published_pattern():
+    model = KimiLinearLM.from_config(TOY, dtype=jnp.float32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(1),
+                            jnp.zeros((2, 96), jnp.int32))["params"]
+    assert model.mixers == ("kda", "kda", "kda", "mla", "kda")
+    assert model.mlp_layer_types == ("dense",) + ("sparse",) * 4
+    whole = KimiLinearLM.from_config(dict(TOY, num_hidden_layers=8))
+    assert whole.mixers == ("kda", "kda", "kda", "mla") * 2
+    assert "mlp" in params["block_0"] and "moe" not in params["block_0"]
+    mixer = params["block_0"]["kda"]
+    assert mixer["query"]["kernel"].shape == (64, 64)      # 2 heads x 32
+    assert mixer["conv_k"].shape == (4, 64)
+    assert mixer["decay_a"]["kernel"].shape == (64, 32)    # the head width
+    assert mixer["decay_b"]["kernel"].shape == (32, 64)
+    assert mixer["decay_rate"].shape == (2,)
+    assert mixer["decay_bias"].shape == (64,)
+    assert mixer["beta"]["kernel"].shape == (64, 2)
+    assert mixer["out_norm"]["scale"].shape == (32,)
+    latent = params["block_3"]["mla"]
+    assert latent["query"]["kernel"].shape == (64, 2, 48)   # 32 + 16
+    assert latent["kv_a"]["kernel"].shape == (64, 48 + 16)
+    assert latent["kv_b"]["kernel"].shape == (48, 2, 32 + 32)
+    assert latent["out"]["kernel"].shape == (2, 32, 64)
+    moe = params["block_3"]["moe"]
+    assert moe["router"]["kernel"].shape == (64, 16)   # all the experts
+    assert moe["experts_w1"].shape == (4, 64, 32)      # the held ones
+    biases = [jax.tree_util.keystr(path) for path, _ in
+              jax.tree_util.tree_leaves_with_path(params)
+              if "bias" in jax.tree_util.keystr(path)]
+    assert len(biases) == 4 and all("decay_bias" in b for b in biases)
+    with pytest.raises(ValueError, match="head counts of their own"):
+        KimiLinearLM.from_config(dict(TOY, linear_attn_config=dict(
+            TOY["linear_attn_config"], num_heads=4)))
+
+
+def test_seeded_decay_forgets_neither_everything_nor_nothing(toy):
+    """``A`` in log [1, 16] and softplus(b) in [0.001, 0.1]: alpha between
+    e^-1.6 and e^-0.001 a token."""
+    mixer = toy[1]["block_0"]["kda"]
+    assert toy[0].mixers == ("kda", "mla", "kda")
+    rate = np.exp(np.asarray(mixer["decay_rate"]))
+    step = np.asarray(jax.nn.softplus(mixer["decay_bias"]))
+    assert rate.min() >= 1.0 and rate.max() <= 16.0
+    assert step.min() >= 1e-3 * 0.999 and step.max() <= 1e-1 * 1.001
+
+
+def test_causal_conv_against_its_definition():
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((1, 9, 3)),
+                    jnp.float32)
+    taps = jnp.asarray([[1.0, 0.0, 2.0], [0.0, 1.0, 0.0],
+                        [0.0, 0.0, 0.0], [0.5, 3.0, 1.0]])
+    out = np.asarray(kimi_linear.causal_conv(x, taps))
+    x = np.asarray(x)
+    # the last tap is the token's own; nothing before the sequence
+    np.testing.assert_allclose(out[0, 0], [0.5, 3.0, 1.0] * x[0, 0],
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        out[0, 5, 0], 1.0 * x[0, 2, 0] + 0.5 * x[0, 5, 0], rtol=1e-6)
+    np.testing.assert_allclose(
+        out[0, 5, 1], 1.0 * x[0, 3, 1] + 3.0 * x[0, 5, 1], rtol=1e-6)
+    moved = np.asarray(kimi_linear.causal_conv(
+        jnp.asarray(x).at[0, 6].add(1.0), taps))
+    assert np.abs(moved - out)[0, :6].max() == 0.0
+
+
+def test_kernels_and_written_out_backends_agree_and_remat_changes_nothing(
+        toy):
+    model, params, tokens = toy
+
+    def loss_and_grad(m):
+        return jax.value_and_grad(lambda p: lm_loss(
+            m.apply({"params": p}, tokens), tokens))(params)
+
+    kernels = loss_and_grad(model)
+    assert (model.attention, model.kda) == ("flash", "chunked")
+    for other in (model.clone(attention="dense", kda="recurrent"),
+                  model.clone(remat=True)):
+        loss, grad = loss_and_grad(other)
+        assert float(loss) == pytest.approx(float(kernels[0]), rel=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(grad),
+                        jax.tree_util.tree_leaves(kernels[1])):
+            np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-6)
+    for wrong in (dict(kda="scan"), dict(attention="ring")):
+        with pytest.raises(ValueError, match="must be one of"):
+            model.clone(**wrong).apply({"params": params}, tokens)
+
+
+def test_nothing_sees_the_future(toy):
+    """Change the last token: no logit before it moves, through the
+    convolution, the delta rule and latent attention alike."""
+    model, params, tokens = toy
+    moved = tokens.at[:, -1].set((tokens[:, -1] + 1) % 512)
+    delta = np.abs(np.asarray(model.apply({"params": params}, tokens)
+                              - model.apply({"params": params}, moved)))
+    assert delta[:, :-1].max() == 0.0 and delta[:, -1].max() > 0
+
+
+def test_the_32_shares_add_up_to_the_whole_layer():
+    """This model's cut: 32 chips share a layer's 256 experts, 8 a chip, 8
+    a token, scores renormalised and scaled by 2.446. Over all 32 shares,
+    the shared expert counted once, the parts sum to the uncut layer."""
+    def layer(held):
+        return ExpertLayer(num_experts=256, experts_per_token=8,
+                           experts_held=held, width=8, shared_width=8,
+                           scaling=2.446, dtype=jnp.float32)
+
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((1, 48, 16)), jnp.float32)
+    whole = layer((0, 256))
+    params = whole.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.tree_util.tree_map(lambda p: 10.0 * p, params)
+    with jax.default_matmul_precision("highest"):
+        want = whole.apply({"params": params}, x)
+        shared_alone = laguna.GatedMLP(8, jnp.float32).apply(
+            {"params": params["shared"]}, x)
+        total = shared_alone
+        for share in range(32):
+            cut = dict(params, **{
+                name: params[name][8 * share:8 * share + 8]
+                for name in ("experts_w1", "experts_w3", "experts_w2")})
+            part = layer((8 * share, 8)).apply({"params": cut}, x)
+            total = total + (part - shared_alone)
+    assert float(jnp.abs(want - shared_alone).max()) > 0.1
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+
+
+def test_kda_stats_become_gauges(toy):
+    from horovod_tpu import obs
+
+    model, params, tokens = toy
+    _, state = model.apply({"params": params}, tokens,
+                           mutable=["kda_stats"])
+    published = obs.kda.publish(state["kda_stats"])
+    assert sorted(published) == ["block_0/kda", "block_2/kda"]
+    for stats in published.values():
+        assert 0.2 < stats["mean_decay"] < 1.0
+        assert 0.0 < stats["state_abs_max"] < 10.0
+    want = published["block_2/kda"]
+    assert want["mean_decay"] == pytest.approx(float(
+        state["kda_stats"]["block_2"]["kda"]["mean_decay"][0]))
+    snapshot = obs.registry().snapshot()
+    for family, key in (("horovod_kda_mean_decay", "mean_decay"),
+                        ("horovod_kda_state_abs_max", "state_abs_max")):
+        read = {s["labels"]["layer"]: s["value"]
+                for s in snapshot[family]["samples"]}
+        assert read["block_2/kda"] == pytest.approx(want[key])
+    # the routing gauges, for this model's layers
+    _, routed = model.apply({"params": params}, tokens,
+                            mutable=["moe_stats"])
+    assert sorted(obs.moe.publish(routed["moe_stats"])) == [
+        "block_1/moe", "block_2/moe"]
+    # a training step does not carry the collection
+    assert "kda_stats" not in model.apply({"params": params}, tokens,
+                                          mutable=["intermediates"])[1]
+
+
+def test_the_scopes_reach_the_compiled_step(toy):
+    model, params, tokens = toy
+    text = jax.jit(jax.grad(lambda p: lm_loss(
+        model.apply({"params": p}, tokens), tokens))).lower(
+            params).compile().as_text()
+    for scope in ("hvd.kda/", "hvd.kda.conv", "hvd.kda.scan", "hvd.mla/",
+                  "hvd.mla.attn", "hvd.moe.route", "hvd.moe.experts"):
+        assert scope in text, scope
+
+
+def test_two_devices_train_as_one(toy):
+    """Through ``make_lm_train_step`` and ``hvd.DistributedOptimizer`` on a
+    data mesh of two: the loss and the updated parameters are those of one
+    device on the whole batch (the chunks' preparation, the two chain
+    kernels and the split-width flash kernels under a vma-checking
+    ``shard_map``)."""
+    import optax
+
+    import horovod_tpu as hvd
+    from benchmarks._dp_step import make_lm_train_step
+
+    model, params, tokens = toy
+    results = []
+    for n in (1, 2):
+        mesh = hvd.parallel.data_parallel_mesh(jax.devices()[:n])
+        opt = hvd.DistributedOptimizer(optax.adamw(1e-2), axis_name="data")
+        copy = jax.tree_util.tree_map(jnp.copy, params)
+        step = make_lm_train_step(model, opt, mesh)
+        new, _, loss = step(copy, jax.jit(opt.init)(copy), tokens)
+        results.append((float(loss), new))
+    assert results[0][0] == pytest.approx(results[1][0], rel=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(results[0][1]),
+                    jax.tree_util.tree_leaves(results[1][1])):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-4)

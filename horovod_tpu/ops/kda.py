@@ -33,24 +33,33 @@ No exponent above is positive where it is used, so nothing overflows
 however fast a channel forgets: ``A`` and ``B`` are formed in sub-blocks of
 ``_SUB`` rows — an off-diagonal sub-block as a product of rows decayed down
 to the sub-block's first row and keys decayed up to it, a diagonal one pair
-by pair (``_decayed_products``).
+by pair, a key at a time (``_Chunk``).
 
-Who does what. ``_prepare`` (XLA, differentiated by JAX, a batch of chunks
-at a time under ``jax.checkpoint`` so that its pair-by-pair tensors never
-exist for a whole sequence) turns q, k, v, g, beta into the chain's
-operands ``W, U~, Q+, K-, B, gamma``. The chain itself, the only
-sequential part, is two Pallas kernels: ``kda_fwd`` walks a head's chunks
-with ``S`` (kept transposed, [d_v, d_k], so the decay scales lanes) in VMEM
-and, when a gradient is wanted, leaves each chunk's starting state in HBM;
-``kda_bwd`` walks them in reverse with the state's gradient in VMEM,
-recomputes ``U`` from the saved state and returns the operands' gradients,
-which JAX pulls back through ``_prepare``. ``kda`` ties them with a
-``custom_vjp`` that keeps q, k, v, g, beta and the chunk-boundary states —
-0.54 GB a layer at 16,384 tokens x 32 heads x 128^2 — and nothing of a
-chunk's interior; the saved states are in the operands' type, which is all
-the backward's products take of them. ``kda_fed(feed, *args)`` keeps still
-less: the ``args`` of whatever makes q, k, v, g, beta (a layer's
-projections), which its backward runs again.
+Who does what. Two Pallas kernels, and nothing beside them. ``kda_fwd``
+walks the chunks of a group of ``_HEADS_A_STEP`` heads with their states
+(kept transposed, [d_v, d_k], so the decay scales lanes) in VMEM. A grid
+step reads a chunk of q, k, v, g and beta out of the arguments as they lie
+(``_blocks``), forms the chunk's operands in VMEM — the cumulative sums of
+g, ``A`` and ``B``, ``(I + Diag(beta) A)^-1`` by substitution, ``W``,
+``U~``, ``Q+``, ``K-``, ``gamma``: ``_Chunk`` — and advances the chain with
+them (``_advance``, a head at a time); when a gradient is wanted it leaves
+each chunk's starting state in HBM. The five operands never reach HBM.
+``kda_bwd`` walks the chunks in reverse with the state's gradient in VMEM:
+it forms the same operands again, recomputes ``U`` from the saved state,
+transposes the chain (``_retreat``), and pulls the operands' gradients back
+through the operands in the same grid step (``_Chunk.pull_back``: the
+inverse's ``-M^T G M^T`` below the diagonal, the decayed products' two-sided
+gradient, a sum from the chunk's end for ``dg``) to ``dq, dk, dv, dg,
+dbeta``, which are all it writes. g, its cumulative sums, every exponent,
+``A``, ``B``, the substitution, ``W``/``U~`` and the carried state are
+float32, their products at full precision (multi-pass on the MXU); the
+chain's own products take the five operands in the compute type. ``kda``
+ties the two with a ``custom_vjp`` that keeps q, k, v, g, beta and the
+chunk-boundary states — 0.54 GB a layer at 16,384 tokens x 32 heads x 128^2
+— and nothing of a chunk's interior; the saved states are in the operands'
+type, which is all the backward's products take of them. ``kda_fed(feed,
+*args)`` keeps still less: the ``args`` of whatever makes q, k, v, g, beta
+(a layer's projections), which its backward runs again.
 
 ``interpret`` as in ``pallas_attention``; left ``None`` the choice follows
 the platform the program is *lowered* for (``lax.platform_dependent``), so
@@ -69,15 +78,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_attention import _sds
-from .spmd import vary_like
 
 CHUNK = 64
 _SUB = 16            # rows of a sub-block of A and B
 _HEADS_A_STEP = 4    # independent chains a grid step interleaves
-# (chunk, head) pairs whose operands are prepared together: bounds the
-# pair-by-pair tensors of the diagonal sub-blocks ([pairs, C/16, 16, 16, d_k]
-# float32: 134 MB at d_k 128) and the float32 intermediates of the transpose
-_PREPARE_TOGETHER = 256
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -108,160 +112,259 @@ def kda_recurrent(q, k, v, g, beta, scale: Optional[float] = None):
     return jnp.moveaxis(o, 0, 1).astype(v.dtype), state
 
 
-# -- a chunk's operands (XLA) -----------------------------------------------
+# -- a chunk's operands, formed inside the kernels ---------------------------
 
 
-@jax.custom_vjp
-def _unit_lower_inverse(lower):
-    """``(I + L)^-1`` for strictly lower-triangular ``L`` [..., C, C],
-    float32, by substitution: row by row inside diagonal blocks of ``_SUB``,
-    block by block below them. (The product form ``(I - L)(I + L^2)...``
-    is exact too, but cancels catastrophically where keys repeat.)"""
-    size = lower.shape[-1]
-    blocks = range(0, size, _SUB)
-    eye = jnp.eye(_SUB, dtype=lower.dtype)
-    diagonal = jnp.stack([lower[..., i:i + _SUB, i:i + _SUB]
-                          for i in blocks], axis=-3)
-
-    def row(r, inverse):
-        # rows from r on are still the identity's, and L[r, r:] is zero
-        new = eye[r] - jnp.einsum("...j,...jk->...k", diagonal[..., r, :],
-                                  inverse, precision=_HI)
-        return jax.lax.dynamic_update_index_in_dim(inverse, new, r, -2)
-
-    # the carry varies over mesh axes as the operand does (shard_map)
-    diagonal = jax.lax.fori_loop(
-        1, _SUB, row, *vary_like(lower, jnp.broadcast_to(eye, diagonal.shape)))
-    rows = []
-    for n, i in enumerate(blocks):
-        own = diagonal[..., n, :, :]
-        parts = [own, jnp.zeros((*own.shape[:-1], size - i - _SUB),
-                                own.dtype)]
-        if i:
-            above = jnp.concatenate([r[..., :i] for r in rows], axis=-2)
-            parts.insert(0, -jnp.matmul(
-                own, jnp.matmul(lower[..., i:i + _SUB, :i], above,
-                                precision=_HI), precision=_HI))
-        rows.append(jnp.concatenate(parts, axis=-1))
-    return jnp.concatenate(rows, axis=-2)
-
-
-def _inverse_fwd(lower):
-    inverse = _unit_lower_inverse(lower)
-    return inverse, inverse
-
-
-def _inverse_bwd(inverse, g):
-    transposed = jnp.swapaxes(inverse, -1, -2)
-    grad = -jnp.matmul(transposed, jnp.matmul(g, transposed, precision=_HI),
-                       precision=_HI)
-    return (jnp.tril(grad, -1),)
-
-
-_unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
-
-
-def _decayed_products(x, k, cum):
-    """``P_ri = sum_c x_rc k_ic exp(cum_rc - cum_ic)`` for ``i <= r`` inside
-    each chunk, zero above the diagonal. x ``[..., X, C, d]`` (X kinds of
-    rows against the same keys), k and cum ``[..., C, d]``, ``cum``
-    non-increasing along C. Every exponent taken is <= 0."""
-    *lead, kinds, size, d = x.shape
-    n = size // _SUB
-    blocked = lambda a: a.reshape(*a.shape[:-2], n, _SUB, d)  # noqa: E731
-    cum_b, k_b = blocked(cum), blocked(k)
-    x_b = x.reshape(*lead, kinds, n, _SUB, d)
-    first = cum_b[..., :1, :]                       # [..., n, 1, d]
-    # below the diagonal sub-blocks: rows decayed down to their sub-block's
-    # first row, keys of earlier sub-blocks decayed up to it
-    x_hat = x_b * jnp.exp(cum_b - first)[..., None, :, :, :]
-    k_up = k[..., None, :, :] * jnp.exp(jnp.minimum(
-        first - cum[..., None, :, :], 0.0))         # [..., n, C, d]
-    below = jnp.einsum("...xnrd,...nid->...xnri", x_hat, k_up,
-                       precision=_HI).reshape(*lead, kinds, size, size)
-    # the diagonal sub-blocks pair by pair
-    pair = jnp.exp(jnp.minimum(
-        cum_b[..., :, None, :] - cum_b[..., None, :, :], 0.0))
-    keyed = (k_b[..., None, :, :] * pair)[..., None, :, :, :, :]
-    own = jnp.sum(x_b[..., None, :] * keyed, axis=-1)  # [..., X, n, SUB, SUB]
-    placed = jnp.einsum("...nri,nm->...nrmi", own, jnp.eye(n, dtype=x.dtype)
-                        ).reshape(*lead, kinds, size, size)
-    r = jnp.arange(size)
-    earlier = (r[None, :] // _SUB) < (r[:, None] // _SUB)
-    return jnp.where(earlier, below,
-                     jnp.where(r[None, :] <= r[:, None], placed, 0.0))
-
-
-def _chunk_operands(q, k, v, g, beta, *, scale: float):
-    """The chain's operands for chunks ``[..., C, d]``: ``(W, U~, Q+, K-,
-    B, gamma)``, float32 inside, the first five cast to q's dtype."""
-    dtype = q.dtype
-    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-    cum = jnp.cumsum(g, axis=-2)
-    decay = jnp.exp(cum)
-    last = cum[..., -1:, :]
-    products = _decayed_products(jnp.stack([k, q], axis=-3), k, cum)
-    a = jnp.tril(products[..., 0, :, :], -1)
-    b = scale * products[..., 1, :, :]
-    solve = _unit_lower_inverse(beta[..., :, None] * a)
-    wu = jnp.matmul(solve, beta[..., :, None] * jnp.concatenate(
-        [k * decay, v], axis=-1), precision=_HI)
-    operands = (wu[..., :k.shape[-1]], wu[..., k.shape[-1]:],
-                scale * q * decay, k * jnp.exp(last - cum), b)
-    return (*(x.astype(dtype) for x in operands), jnp.exp(last))
-
-
-def _to_chunks(x, chunk: int):
-    """``[B, T, H, d]`` -> ``[N, B*H, C, d]`` for the ``N`` chunks of ``C =
-    chunk`` tokens, the sequence padded with zeros to a whole number of
-    them."""
-    batch, seq, heads, d = x.shape
-    x = jnp.pad(x, ((0, 0), (0, -seq % chunk), (0, 0), (0, 0)))
-    x = x.reshape(batch, -1, chunk, heads, d)
-    return x.transpose(1, 0, 3, 2, 4).reshape(-1, batch * heads, chunk, d)
-
-
-def _to_tokens(o, batch: int, seq: int):
-    """``_to_chunks`` undone, the padding dropped."""
-    n, lanes, chunk, d = o.shape
-    o = o.reshape(n, batch, lanes // batch, chunk, d).transpose(1, 0, 3, 2, 4)
-    return o.reshape(batch, n * chunk, lanes // batch, d)[:, :seq]
-
-
-def _prepare(q, k, v, g, beta, *, scale: float, chunk: int):
-    """q, k, g ``[B, T, H, d_k]``, v ``[B, T, H, d_v]``, beta ``[B, T, H]``
-    -> the chain's operands, each ``[N, B*H, C, .]`` (gamma ``[N, B*H, 1,
-    d_k]``). A sequence that is no whole number of chunks is padded with
-    tokens that neither decay nor write (g = 0, beta = 0)."""
-    batch, _, heads, _ = q.shape
-    chunks = functools.partial(_to_chunks, chunk=chunk)
-    xs = (chunks(q), chunks(k), chunks(v), chunks(g.astype(jnp.float32)),
-          chunks(beta.astype(jnp.float32)[..., None])[..., 0])
-    n = xs[0].shape[0]
-    # a few chunks at a time: XLA fuses the pair-by-pair tensors into their
-    # sums going forward, but keeps them for the transpose (4 GB for a layer
-    # of 16,384 tokens x 32 heads) unless they are this small
-    together = math.gcd(n, max(1, _PREPARE_TOGETHER // (batch * heads)))
-    body = jax.checkpoint(functools.partial(_chunk_operands, scale=scale))
-    out = jax.lax.map(
-        lambda a: body(*a),
-        tuple(x.reshape(n // together, together, *x.shape[1:]) for x in xs))
-    return tuple(x.reshape(n, *x.shape[2:]) for x in out)
-
-
-# -- the chain (Pallas) -----------------------------------------------------
-
-
-def _dot(a, b, contract):
+def _dot(a, b, contract, precision=None):
     return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               precision=precision,
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(w_ref, u_ref, q_ref, k_ref, b_ref, gamma_ref, o_ref,
-                final_ref, *rest, heads: int):
-    """One chunk of ``heads`` heads' chains. Grid (head groups, chunks), the
-    chunks sequential; ``state`` [heads, d_v, d_k] persists across them. With
-    a ``starts_ref`` each chunk's starting state is left in HBM."""
+def _dot32(a, b, contract):
+    """A float32 product at full precision (multi-pass on the MXU)."""
+    return _dot(a, b, contract, _HI)
+
+
+def _iota(shape, axis: int):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+class _Chunk:
+    """One head's chunk: what q, k, v, the cumulative sums of g [C, .]
+    (float32) and beta ``[C, 1]`` imply for the chain, and the pieces its
+    transpose needs again. Every exponent taken is <= 0."""
+
+    def __init__(self, q, k, v, cum, beta, scale: float):
+        size, d = k.shape
+        n = size // _SUB
+        self.q, self.k, self.v, self.scale = q, k, v, scale
+        # beta across the lanes once: a [C, 1] column read out of lane h
+        # costs a lane permutation a vreg wherever it meets a matrix
+        across = jnp.broadcast_to(beta, (size, max(size, d, v.shape[1])))
+        self.by_beta = lambda x: across[:, :x.shape[1]] * x  # noqa: E731
+        row, col = _iota((size, size), 0), _iota((size, size), 1)
+        self.lower = col <= row
+        self.strictly = col < row
+        self.earlier = (col // _SUB) < (row // _SUB)
+        self.own = (col // _SUB) == (row // _SUB)
+        self.tri = self.lower.astype(jnp.float32)
+        blocked = lambda a: a.reshape(n, _SUB, a.shape[-1])  # noqa: E731
+        self.blocked = blocked
+        last = cum[size - 1:]
+        self.decay = jnp.exp(cum)
+        self.to_last = jnp.exp(last - cum)
+        self.gamma = jnp.exp(last)
+        self.k_plus = k * self.decay
+        self.q_plus = scale * q * self.decay
+        self.k_minus = k * self.to_last
+        # below the diagonal sub-blocks: rows decayed down to their
+        # sub-block's first row, keys of earlier sub-blocks decayed up to it
+        self.k3, self.q3, self.cum3 = blocked(k), blocked(q), blocked(cum)
+        first = self.cum3[:, :1]
+        self.down = jnp.exp(self.cum3 - first)                 # [n, SUB, d]
+        self.k_hat, self.q_hat = self.k3 * self.down, self.q3 * self.down
+        self.up = [None] + [jnp.exp(jnp.minimum(first[a] - cum, 0.0))
+                            for a in range(1, n)]              # [C, d] each
+        below = [jnp.zeros((2 * _SUB, size), jnp.float32)] + [
+            _dot32(jnp.concatenate([self.k_hat[a], self.q_hat[a]], axis=0),
+                   k * self.up[a], (1, 1)) for a in range(1, n)]
+        below_a = jnp.concatenate([x[:_SUB] for x in below], axis=0)
+        below_b = jnp.concatenate([x[_SUB:] for x in below], axis=0)
+        # the diagonal sub-blocks pair by pair, a key at a time: column j of
+        # every sub-block at once
+        lane = _iota((n, _SUB, size), 2) % _SUB
+        rank = _iota((n, _SUB, 1), 1)
+        own_a = own_b = jnp.zeros((n, _SUB, size), jnp.float32)
+        columns = []
+        for j in range(_SUB):
+            t = self.pair(j) * self.k3[:, j:j + 1]
+            a_col = jnp.sum(self.k3 * t, axis=-1, keepdims=True)
+            b_col = jnp.sum(self.q3 * t, axis=-1, keepdims=True)
+            own_a = jnp.where(lane == j, a_col, own_a)
+            own_b = jnp.where(lane == j, b_col, own_b)
+            columns.append(jnp.where(rank > j, a_col, 0.0))
+        self.a = jnp.where(self.earlier, below_a, jnp.where(
+            self.own & self.strictly, own_a.reshape(size, size), 0.0))
+        self.b = scale * jnp.where(self.earlier, below_b, jnp.where(
+            self.own & self.lower, own_b.reshape(size, size), 0.0))
+        # (I + Diag(beta) A)^-1 by substitution: inside the diagonal
+        # sub-blocks a column at a time (row r loses L[r, j] times row j,
+        # which is final by then), then sub-block against sub-block
+        beta3 = blocked(across[:, :size])
+        inverse = blocked((row == col).astype(jnp.float32))
+        for j in range(_SUB - 1):
+            inverse = inverse - (beta3 * columns[j]) * inverse[:, j:j + 1]
+        inverse = inverse.reshape(size, size)
+        strip = self.by_beta(self.a)
+        width = _SUB
+        while width < size:
+            coupling = jnp.where(
+                (col // (2 * width) == row // (2 * width))
+                & (col // width < row // width), strip, 0.0)
+            # only the second sub-block of a pair gains entries: its rows
+            # alone go through the two products
+            blocks = [inverse[r:r + width] for r in range(0, size, width)]
+            gain = _dot32(_dot32(jnp.concatenate(blocks[1::2], axis=0),
+                                 coupling, (1, 0)), inverse, (1, 0))
+            done = 0
+            for i in range(1, len(blocks), 2):
+                rows = blocks[i].shape[0]
+                blocks[i] = blocks[i] - gain[done:done + rows]
+                done += rows
+            inverse = jnp.concatenate(blocks, axis=0)
+            width *= 2
+        self.inverse = inverse
+        self.w = _dot32(inverse, self.by_beta(self.k_plus), (1, 0))
+        self.u = _dot32(inverse, self.by_beta(v), (1, 0))
+
+    def pair(self, j: int):
+        """``exp(G_r - G_j)`` for key ``j`` of every sub-block against its
+        rows, [n, SUB, d]; 1 where r < j, which the masks drop."""
+        return jnp.exp(jnp.minimum(self.cum3 - self.cum3[:, j:j + 1], 0.0))
+
+    def chain(self, dtype):
+        """``(W, U~, Q+, K-, B)`` in the chain's type."""
+        return tuple(x.astype(dtype) for x in (
+            self.w, self.u, self.q_plus, self.k_minus, self.b))
+
+    def pull_back(self, dw, du, dq_plus, dk_minus, db, dgamma):
+        """The operands' transpose: their gradients (float32) to ``(dq, dk,
+        dv, dg, dbeta)``."""
+        size, d = self.k.shape
+        n = size // _SUB
+        k, q, by_beta, blocked = self.k, self.q, self.by_beta, self.blocked
+        # W, U~ = inverse @ (beta * [K+, V]); the inverse's transpose is
+        # -M^T G M^T below the diagonal, G = [dW, dU~] [beta K+, beta V]^T
+        dr_k = _dot32(self.inverse, dw, (0, 0))
+        dr_v = _dot32(self.inverse, du, (0, 0))
+        dstrip = -jnp.where(self.strictly, _dot32(dr_k, self.w, (1, 1))
+                            + _dot32(dr_v, self.u, (1, 1)), 0.0)
+        dbeta = jnp.sum(dr_k * self.k_plus, axis=-1, keepdims=True) \
+            + jnp.sum(dr_v * self.v, axis=-1, keepdims=True) \
+            + jnp.sum(dstrip * self.a, axis=-1, keepdims=True)
+        dk_plus = by_beta(dr_k)
+        da = by_beta(dstrip)
+        db = self.scale * jnp.where(self.lower, db, 0.0)
+        dk = dk_plus * self.decay + dk_minus * self.to_last
+        dq = self.scale * self.decay * dq_plus
+        carried = dk_minus * self.k_minus
+        dcum = dk_plus * self.k_plus + dq_plus * self.q_plus - carried
+        dlast = jnp.sum(carried, axis=0, keepdims=True) + dgamma * self.gamma
+        dcum = dcum + jnp.where(_iota((size, 1), 0) == size - 1, dlast, 0.0)
+        # the decayed products, two-sided: a pair's gradient reaches its row
+        # (rows) and its key (keys), and G through both
+        da_off = jnp.where(self.earlier, da, 0.0)
+        db_off = jnp.where(self.earlier, db, 0.0)
+        rows, keys = [jnp.zeros((2, _SUB, d), jnp.float32)], 0.0
+        for a in range(1, n):
+            grads = jnp.concatenate([da_off[a * _SUB:(a + 1) * _SUB],
+                                     db_off[a * _SUB:(a + 1) * _SUB]], axis=0)
+            rows.append(_dot32(grads, k * self.up[a], (1, 0)).reshape(
+                2, _SUB, d) * self.down[a])
+            keys = keys + self.up[a] * _dot32(grads, jnp.concatenate(
+                [self.k_hat[a], self.q_hat[a]], axis=0), (0, 0))
+        k_rows = jnp.stack([x[0] for x in rows])               # [n, SUB, d]
+        q_rows = jnp.stack([x[1] for x in rows])
+        # the diagonal sub-blocks: their gradients' columns side by side
+        gather = (_iota((size, _SUB), 0) % _SUB
+                  == _iota((size, _SUB), 1)).astype(jnp.float32)
+        da_own = blocked(_dot32(jnp.where(self.own, da, 0.0), gather, (1, 0)))
+        db_own = blocked(_dot32(jnp.where(self.own, db, 0.0), gather, (1, 0)))
+        rank = _iota((n, _SUB, 1), 1)
+        key_rows = jnp.zeros((n, _SUB, d), jnp.float32)
+        for j in range(_SUB):
+            # from the tile of 8 rows that holds key j on: the rows before
+            # it take nothing from it, and half the keys lie in the second
+            first = j - j % 8
+            e, k_j, q_j, da_j, db_j = (x[:, first:] for x in (
+                self.pair(j), self.k3, self.q3, da_own[:, :, j:j + 1],
+                db_own[:, :, j:j + 1]))
+            t = e * self.k3[:, j:j + 1]
+            skipped = ((0, 0), (first, 0), (0, 0))
+            k_rows = k_rows + jnp.pad(da_j * t, skipped)
+            q_rows = q_rows + jnp.pad(db_j * t, skipped)
+            to_key = jnp.sum((da_j * k_j + db_j * q_j) * e, axis=1,
+                             keepdims=True)
+            key_rows = key_rows + jnp.where(rank == j, to_key, 0.0)
+        k_rows, q_rows = k_rows.reshape(size, d), q_rows.reshape(size, d)
+        keys = keys + key_rows.reshape(size, d)
+        dk = dk + k_rows + keys
+        dq = dq + q_rows
+        dcum = dcum + k * (k_rows - keys) + q * q_rows
+        # g's cumulative sum, transposed: a sum from the chunk's end
+        return dq, dk, by_beta(dr_v), _dot32(self.tri, dcum, (0, 0)), dbeta
+
+
+# One head's step of each kernel, jitted so that a step's heads (and both
+# branches of ``_by_platform``) share one trace; inside a kernel the call is
+# inlined.
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "dtype"))
+def _advance(q, k, v, cum, beta, s, *, scale: float, dtype):
+    """A chunk of the chain from the state ``s`` [d_v, d_k] it starts from:
+    ``(s in the chain's type, O, the state it leaves)``."""
+    chunk = _Chunk(q, k, v, cum, beta, scale)
+    w, u_c, q, k, b = chunk.chain(dtype)
+    s_c = s.astype(dtype)
+    u = u_c.astype(jnp.float32) - _dot(w, s_c, (1, 1))
+    u_c = u.astype(dtype)
+    o = _dot(q, s_c, (1, 1)) + _dot(b, u_c, (1, 0))
+    return s_c, o, s * chunk.gamma + _dot(u_c, k, (0, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _retreat(q, k, v, cum, beta, s_c, ds, do, *, scale: float):
+    """The transpose of ``_advance`` for one chunk: ``s_c`` the state it
+    started from, ``ds`` the gradient of the state it left, ``do`` of its
+    output. Returns ``(dq, dk, dv, dg, dbeta, the gradient of the state it
+    started from)``."""
+    dtype = s_c.dtype
+    chunk = _Chunk(q, k, v, cum, beta, scale)
+    w, u_c, q, k, b = chunk.chain(dtype)
+    ds_c = ds.astype(dtype)
+    u = u_c.astype(jnp.float32) - _dot(w, s_c, (1, 1))
+    u_c = u.astype(dtype)
+    du = _dot(b, do, (0, 0)) + _dot(k, ds_c, (1, 1))
+    du_c = du.astype(dtype)
+    grads = chunk.pull_back(
+        -_dot(du_c, s_c, (1, 0)), du, _dot(do, s_c, (1, 0)),
+        _dot(u_c, ds_c, (1, 0)), _dot(do, u_c, (1, 1)),
+        jnp.sum(ds * s_c.astype(jnp.float32), axis=0, keepdims=True))
+    return (*grads, ds * chunk.gamma + _dot(do, q, (0, 0))
+            - _dot(du_c, w, (0, 0)))
+
+
+# -- the two kernels ---------------------------------------------------------
+
+
+def _heads(q_ref, k_ref, v_ref, g_ref, beta_ref):
+    """A grid step's operands ``(q, k, v, cum, beta)``, head by head, from
+    its blocks of q, k, v, g ``[1, C, heads * d]`` and beta ``[1, 1, C,
+    heads]``. The cumulative sums of g are one product for the whole group,
+    ahead of every head's own work: a head's pair-by-pair part needs
+    nothing else, and with its sums queued on the MXU behind the products
+    of the head before, the schedule ran the heads one after another."""
+    size, heads = beta_ref.shape[2:]
+    tri = (_iota((size, size), 1) <= _iota((size, size), 0))
+    cum = _dot32(tri.astype(jnp.float32), g_ref[0], (1, 0))
+    d_k, d_v = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    for h in range(heads):
+        keys = slice(h * d_k, (h + 1) * d_k)
+        values = slice(h * d_v, (h + 1) * d_v)
+        yield (q_ref[0, :, keys].astype(jnp.float32),
+               k_ref[0, :, keys].astype(jnp.float32),
+               v_ref[0, :, values].astype(jnp.float32), cum[:, keys],
+               beta_ref[0, 0][:, h:h + 1])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, final_ref,
+                *rest, scale: float):
+    """One chunk of a group of heads: their operands formed, their chains
+    advanced. Grid (head groups, chunks), the chunks sequential; ``state``
+    [heads, d_v, d_k] persists across them. With a ``starts_ref`` each
+    chunk's starting state is left in HBM."""
     *starts_ref, state = rest
     n = pl.program_id(1)
 
@@ -273,31 +376,27 @@ def _fwd_kernel(w_ref, u_ref, q_ref, k_ref, b_ref, gamma_ref, o_ref,
     # top level under a vma-tracking shard_map (``pallas_attention``)
     @pl.when(n >= 0)
     def _run():
-        dtype = w_ref.dtype
-        for h in range(heads):
-            s = state[h]                                   # [d_v, d_k]
-            s_c = s.astype(dtype)
+        d_v = state.shape[1]
+        for h, operands in enumerate(
+                _heads(q_ref, k_ref, v_ref, g_ref, beta_ref)):
+            start, o, state[h] = _advance(*operands, state[h], scale=scale,
+                                          dtype=q_ref.dtype)
             if starts_ref:
-                starts_ref[0][0, h] = s_c
-            u = u_ref[0, h].astype(jnp.float32) - _dot(w_ref[0, h], s_c,
-                                                       (1, 1))
-            u_c = u.astype(dtype)
-            o = _dot(q_ref[0, h], s_c, (1, 1)) + _dot(b_ref[0, h], u_c,
-                                                      (1, 0))
-            o_ref[0, h] = o.astype(o_ref.dtype)
-            state[h] = s * gamma_ref[0, h] + _dot(u_c, k_ref[0, h], (0, 0))
+                starts_ref[0][0, h] = start
+            o_ref[0, :, h * d_v:(h + 1) * d_v] = o.astype(o_ref.dtype)
 
     @pl.when(n == pl.num_programs(1) - 1)
     def _finalize():
         final_ref[...] = state[...]
 
 
-def _bwd_kernel(w_ref, u_ref, q_ref, k_ref, bt_ref, gamma_ref, starts_ref,
-                do_ref, dw_ref, du_ref, dq_ref, dk_ref, db_ref, dgamma_ref,
-                dstate, *, heads: int):
-    """The chain's transpose for one chunk of ``heads`` heads, the chunks
-    walked last to first (the index maps reverse them); ``dstate`` is the
-    gradient of the state the chunk leaves, transposed like the state."""
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
+                scale: float):
+    """The transpose for one chunk of a group of heads, the chunks walked
+    last to first (the index maps reverse them): the operands formed again,
+    the chain's transpose, and the operands' own, in one step. ``dstate``
+    is the gradient of the state the chunk leaves, transposed like it."""
     step = pl.program_id(1)
 
     @pl.when(step == 0)
@@ -306,28 +405,23 @@ def _bwd_kernel(w_ref, u_ref, q_ref, k_ref, bt_ref, gamma_ref, starts_ref,
 
     @pl.when(step >= 0)
     def _run():
-        dtype = w_ref.dtype
-        for h in range(heads):
-            s_c, ds = starts_ref[0, h], dstate[h]          # [d_v, d_k]
-            ds_c = ds.astype(dtype)
-            w, q, k, do = w_ref[0, h], q_ref[0, h], k_ref[0, h], do_ref[0, h]
-            u = u_ref[0, h].astype(jnp.float32) - _dot(w, s_c, (1, 1))
-            u_c = u.astype(dtype)
-            du = _dot(bt_ref[0, h], do, (1, 0)) + _dot(k, ds_c, (1, 1))
-            du_c = du.astype(dtype)
-            du_ref[0, h] = du_c
-            db_ref[0, h] = _dot(do, u_c, (1, 1)).astype(db_ref.dtype)
-            dq_ref[0, h] = _dot(do, s_c, (1, 0)).astype(dq_ref.dtype)
-            dk_ref[0, h] = _dot(u_c, ds_c, (1, 0)).astype(dk_ref.dtype)
-            dw_ref[0, h] = (-_dot(du_c, s_c, (1, 0))).astype(dw_ref.dtype)
-            dgamma_ref[0, h] = jnp.sum(ds * s_c.astype(jnp.float32), axis=0,
-                                       keepdims=True)
-            dstate[h] = ds * gamma_ref[0, h] + _dot(do, q, (0, 0)) \
-                - _dot(du_c, w, (0, 0))
+        _, d_v, d_k = dstate.shape
+        dbeta = []
+        for h, operands in enumerate(
+                _heads(q_ref, k_ref, v_ref, g_ref, beta_ref)):
+            dq, dk, dv, dg, dbeta_h, dstate[h] = _retreat(
+                *operands, starts_ref[0, h], dstate[h],
+                do_ref[0, :, h * d_v:(h + 1) * d_v], scale=scale)
+            dq_ref[0, :, h * d_k:(h + 1) * d_k] = dq.astype(dq_ref.dtype)
+            dk_ref[0, :, h * d_k:(h + 1) * d_k] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, h * d_v:(h + 1) * d_v] = dv.astype(dv_ref.dtype)
+            dg_ref[0, :, h * d_k:(h + 1) * d_k] = dg
+            dbeta.append(dbeta_h)
+        dbeta_ref[0, 0] = jnp.concatenate(dbeta, axis=-1)
 
 
-def _heads_a_step(lanes: int) -> int:
-    return math.gcd(lanes, _HEADS_A_STEP)
+def _heads_a_step(heads: int) -> int:
+    return math.gcd(heads, _HEADS_A_STEP)
 
 
 def _compiler_params(interpret: bool):
@@ -335,57 +429,96 @@ def _compiler_params(interpret: bool):
         dimension_semantics=("parallel", "arbitrary"))
 
 
-def _spec(array, heads: int, reverse: Optional[int] = None):
-    """One chunk of ``heads`` heads of ``array`` [N, B*H, ., .]; with
-    ``reverse`` (the number of chunks) the grid walks them last to first."""
-    index = (lambda i, n: (n, i, 0, 0)) if reverse is None else \
-        (lambda i, n: (reverse - 1 - n, i, 0, 0))
-    return pl.BlockSpec((1, heads, *array.shape[2:]), index)
+def _wide(x, chunk: int):
+    """``[B, T, ...]`` as ``[B, T', the rest in one]``, ``T'`` T padded
+    with zeros to whole chunks."""
+    batch, seq = x.shape[:2]
+    return jnp.pad(x.reshape(batch, seq, -1),
+                   ((0, 0), (0, -seq % chunk), (0, 0)))
 
 
-def _chain_fwd(operands, save: bool, interpret: bool):
-    """``(O [N, B*H, C, d_v], final state [B*H, d_v, d_k], the chunks'
-    starting states [N, B*H, d_v, d_k] or None)``."""
-    w, u, q, k, b, gamma = operands
-    n, lanes, _, d_k = w.shape
-    d_v = u.shape[-1]
-    heads = _heads_a_step(lanes)
-    ins = (w, u, q, k, b, gamma)
-    state = jax.ShapeDtypeStruct((n, lanes, d_v, d_k), w.dtype)
-    out_shape = [_sds(u.shape, u.dtype, *ins),
+def _blocks(q, k, v, g, beta, chunk: int):
+    """The kernels' view of the arguments: q, k, v, g ``[B, T', H * d]``
+    (no copy where T is a whole number of chunks: the index maps pick a
+    chunk of a group of heads out of the arguments as they lie) and beta
+    ``[B, H / group, T', group]``; ``T'`` is T padded to whole chunks with
+    tokens that neither decay nor write (g = 0, beta = 0)."""
+    batch, _, heads, _ = q.shape
+    group = _heads_a_step(heads)
+    beta = _wide(beta.astype(jnp.float32), chunk)
+    beta = beta.reshape(batch, -1, heads // group, group).swapaxes(1, 2)
+    return (*(_wide(x, chunk) for x in (q, k, v, g.astype(jnp.float32))),
+            beta)
+
+
+def _specs(heads: int, chunk: int, reverse: Optional[int] = None):
+    """Block specs over a grid (batch x head groups, chunks) for arrays
+    laid out as ``_blocks``': ``wide(d)``, ``beta`` and ``states(d_v, d_k)``
+    ([N, B*H, d_v, d_k]); with ``reverse`` (the number of chunks) the grid
+    walks them last to first."""
+    group = _heads_a_step(heads)
+    groups = heads // group
+    at = (lambda c: c) if reverse is None else (lambda c: reverse - 1 - c)
+    wide = lambda d: pl.BlockSpec(  # noqa: E731
+        (1, chunk, group * d), lambda i, c: (i // groups, at(c), i % groups))
+    beta = pl.BlockSpec((1, 1, chunk, group),
+                        lambda i, c: (i // groups, i % groups, at(c), 0))
+    states = lambda d_v, d_k: pl.BlockSpec(  # noqa: E731
+        (1, group, d_v, d_k), lambda i, c: (at(c), i, 0, 0))
+    return wide, beta, states
+
+
+def _scan_fwd(q, k, v, g, beta, scale, chunk, save: bool, interpret: bool):
+    """``(O [B, T', H * d_v], final state [B*H, d_v, d_k], the chunks'
+    starting states [N, B*H, d_v, d_k] in q's type or None)``."""
+    batch, _, heads, d_k = q.shape
+    d_v = v.shape[-1]
+    group = _heads_a_step(heads)
+    ins = _blocks(q, k, v, g, beta, chunk)
+    n = ins[0].shape[1] // chunk
+    lanes = batch * heads
+    wide, beta_spec, states = _specs(heads, chunk)
+    out_shape = [_sds(ins[2].shape, v.dtype, *ins),
                  _sds((lanes, d_v, d_k), jnp.float32, *ins)]
-    out_specs = [_spec(u, heads),
-                 pl.BlockSpec((heads, d_v, d_k), lambda i, n: (i, 0, 0))]
+    out_specs = [wide(d_v),
+                 pl.BlockSpec((group, d_v, d_k), lambda i, c: (i, 0, 0))]
     if save:
-        out_shape.append(_sds(state.shape, state.dtype, *ins))
-        out_specs.append(_spec(state, heads))
+        out_shape.append(_sds((n, lanes, d_v, d_k), q.dtype, *ins))
+        out_specs.append(states(d_v, d_k))
     o, final, *starts = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads),
-        grid=(lanes // heads, n),
-        in_specs=[_spec(x, heads) for x in ins],
+        functools.partial(_fwd_kernel, scale=scale),
+        grid=(lanes // group, n),
+        in_specs=[wide(d_k), wide(d_k), wide(d_v), wide(d_k), beta_spec],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((heads, d_v, d_k), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((group, d_v, d_k), jnp.float32)],
         compiler_params=_compiler_params(interpret), interpret=interpret,
         name="kda_fwd")(*ins)
     return o, final, (starts[0] if save else None)
 
 
-def _chain_bwd(operands, starts, do, interpret: bool):
-    """The gradients of ``_prepare``'s outputs, in their order."""
-    w, u, q, k, b, gamma = operands
-    n, lanes = w.shape[:2]
-    heads = _heads_a_step(lanes)
-    ins = (w, u, q, k, jnp.swapaxes(b, -1, -2), gamma, starts, do)
-    outs = (w, u, q, k, b, gamma)
-    return tuple(pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
-        grid=(lanes // heads, n),
-        in_specs=[_spec(x, heads, reverse=n) for x in ins],
-        out_specs=[_spec(x, heads, reverse=n) for x in outs],
-        out_shape=[_sds(x.shape, x.dtype, *ins) for x in outs],
-        scratch_shapes=[pltpu.VMEM((heads, *starts.shape[2:]), jnp.float32)],
+def _scan_bwd(q, k, v, g, beta, starts, do, scale, chunk, interpret: bool):
+    """``(dq, dk, dv, dg, dbeta)`` in the arguments' shapes; dq, dk and dv
+    in their types, dg and dbeta float32."""
+    batch, seq, heads, d_k = q.shape
+    d_v = v.shape[-1]
+    group = _heads_a_step(heads)
+    blocks = _blocks(q, k, v, g, beta, chunk)
+    ins = (*blocks, starts, _wide(do.astype(q.dtype), chunk))
+    n = starts.shape[0]
+    wide, beta_spec, states = _specs(heads, chunk, reverse=n)
+    specs = [wide(d_k), wide(d_k), wide(d_v), wide(d_k), beta_spec]
+    *grads, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale),
+        grid=(batch * heads // group, n),
+        in_specs=[*specs, states(d_v, d_k), wide(d_v)],
+        out_specs=specs,
+        out_shape=[_sds(x.shape, x.dtype, *ins) for x in blocks],
+        scratch_shapes=[pltpu.VMEM((group, d_v, d_k), jnp.float32)],
         compiler_params=_compiler_params(interpret), interpret=interpret,
-        name="kda_bwd")(*ins))
+        name="kda_bwd")(*ins)
+    dbeta = dbeta.swapaxes(1, 2).reshape(batch, -1, heads)
+    return (*(x[:, :seq].reshape(batch, seq, heads, -1) for x in grads),
+            dbeta[:, :seq])
 
 
 def _by_platform(fn, interpret: Optional[bool], *args):
@@ -397,14 +530,17 @@ def _by_platform(fn, interpret: Optional[bool], *args):
         *args, cpu=lambda *a: fn(*a, True), default=lambda *a: fn(*a, False))
 
 
+def _scale(scale: Optional[float], q) -> float:
+    return 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+
+
 def _forward(q, k, v, g, beta, scale, chunk, interpret, save: bool):
     batch, seq, heads, d_k = q.shape
-    scale = 1.0 / math.sqrt(d_k) if scale is None else scale
-    operands = _prepare(q, k, v, g, beta, scale=scale, chunk=chunk)
     o, final, starts = _by_platform(
-        lambda *a: _chain_fwd(a[:-1], save, a[-1]), interpret, *operands)
+        lambda *a: _scan_fwd(*a[:-1], _scale(scale, q), chunk, save, a[-1]),
+        interpret, q, k, v, g, beta)
     final = final.reshape(batch, heads, v.shape[-1], d_k).swapaxes(-1, -2)
-    return _to_tokens(o, batch, seq), final, starts
+    return o[:, :seq].reshape(batch, seq, heads, -1), final, starts
 
 
 def _as_given(*operands):
@@ -426,15 +562,14 @@ def _kda_bwd(feed, scale, chunk, interpret, res, cotangents):
     args, starts = res
     do, _ = cotangents      # the final state is a reading, not a result
     # the feed's results again, nothing of its interior kept: its transpose
-    # recomputes it once the chain's own is done
+    # recomputes it once the kernel's own is done
     inputs, feed_back = (args, lambda grads: grads) if feed is _as_given \
         else jax.vjp(jax.checkpoint(feed), *args)
-    scale = 1.0 / math.sqrt(inputs[0].shape[-1]) if scale is None else scale
-    operands, pull_back = jax.vjp(
-        functools.partial(_prepare, scale=scale, chunk=chunk), *inputs)
-    return feed_back(pull_back(_by_platform(
-        lambda *a: _chain_bwd(a[:6], a[6], a[7], a[-1]), interpret,
-        *operands, starts, _to_chunks(do, chunk).astype(operands[1].dtype))))
+    grads = _by_platform(
+        lambda *a: _scan_bwd(*a[:-1], _scale(scale, inputs[0]), chunk, a[-1]),
+        interpret, *inputs, starts, do)
+    return feed_back(tuple(x.astype(like.dtype)
+                           for x, like in zip(grads, inputs)))
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)

@@ -15,6 +15,7 @@ Each case takes a second or two.
 """
 
 import functools
+import re
 
 import pytest
 
@@ -171,3 +172,45 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, no_compile_cache):
         assert f"%{name}" in hlo, name
     # the chunks' starting states are kept in the operands' type
     assert "bf16[256,32,128,128]" in hlo
+    # and nothing of a chunk's operands is prepared outside the kernels: no
+    # loop, no pair-by-pair tensor of the diagonal sub-blocks
+    assert " while(" not in hlo
+    assert not re.search(r"f32\[[\d,]*4,16,16,128\]", hlo)
+
+
+def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
+    """``obs.kda.record_scan_program`` on a compiled toy step (one KDA
+    layer's gradient, lowered for the v5e with no ``interpret`` given): no
+    loop under ``hvd.kda.scan``, one call of each kernel; and on a text
+    that prepares the operands in loops, as before PR 31, it counts them."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import obs
+    from horovod_tpu.models.kimi_linear import KDAMixer
+
+    layer = KDAMixer(num_heads=4, head_dim=128)
+    x = jax.ShapeDtypeStruct((1, 256, 64), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    text = jax.jit(jax.grad(lambda p, x: layer.apply(p, x).astype(
+        jnp.float32).sum())).lower(params, x).compile().as_text()
+    assert obs.kda.record_scan_program("toy", text) == (
+        0, {"kda_fwd": 1, "kda_bwd": 1})
+
+    def gauge(family, **labels):
+        return next(s["value"]
+                    for s in obs.registry().snapshot()[family]["samples"]
+                    if s["labels"] == labels)
+
+    assert gauge("horovod_kda_scan_loops", program="toy") == 0
+    assert gauge("horovod_kda_kernel_calls", program="toy",
+                 kernel="kda_bwd") == 1
+    looped = text + (
+        '\n  %while.7 = (s32[], bf16[32,8,32,64,128]{4,3,2,1,0}) '
+        'while(%tuple.3), condition=%cond, body=%body, '
+        'metadata={op_name="jit(step)/hvd.kda.scan/while"}'
+        '\n  %while.8 = (s32[], f32[8]{0}) while(%tuple.4), condition=%c, '
+        'body=%b, metadata={op_name="jit(step)/hvd.optimizer/while"}\n')
+    assert obs.kda.record_scan_program("looped", looped)[0] == 1

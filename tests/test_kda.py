@@ -1,8 +1,8 @@
 """``ops.kda``: the chunked gated delta rule against the recurrence token
 by token — values, the final state and every gradient — at sequences that
 are no whole number of chunks, with a decay near 0 and near 1 and beta at
-both ends; the pieces it is made of (the unit lower-triangular inverse, the
-decayed products) against their definitions; and ``flash_attention`` with
+both ends; what its kernels form in VMEM (the unit lower-triangular inverse,
+the decayed products) against the closed form of one chunk; and ``flash_attention`` with
 a v narrower than its q and k against attention written out."""
 
 import math
@@ -77,50 +77,81 @@ def test_chunked_against_token_by_token(case):
 def test_repeated_keys_do_not_cancel():
     """Every token the same key, beta 1, no decay: ``I + A`` is all ones
     below the diagonal, whose inverse by a product of powers cancels
-    catastrophically; by substitution it is the bidiagonal (1, -1)."""
+    catastrophically; by substitution it is the bidiagonal (1, -1). With
+    q = k, a scale of 1 and v the identity a one-chunk call returns ``B``
+    (all ones on and below the diagonal) times that inverse: the
+    identity."""
     q, k, v, g, beta = operands(5, seq=64, heads=1, beta=1.0)
     k = jnp.broadcast_to(k[:, :1], k.shape)
     args = (q, k, v, jnp.zeros_like(g), beta)
     o, _ = kda(*args)
     np.testing.assert_allclose(o, kda_recurrent(*args)[0], atol=1e-5)
-    lower = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
-    inverse = kda_ops._unit_lower_inverse(lower)
-    want = np.eye(64) - np.eye(64, k=-1)
-    np.testing.assert_allclose(inverse, want, atol=1e-6)
+    eye = jnp.eye(64).reshape(1, 64, 1, 64)
+    o, _ = kda(k, k, eye, jnp.zeros_like(g), beta, scale=1.0)
+    np.testing.assert_allclose(o[0, :, 0], np.eye(64), atol=1e-6)
 
 
-def test_unit_lower_inverse_and_its_gradient():
-    rng = np.random.default_rng(0)
-    lower = jnp.asarray(np.tril(rng.standard_normal((3, 64, 64)), -1),
-                        jnp.float32) * 0.3
-    weight = jnp.asarray(rng.standard_normal((3, 64, 64)), jnp.float32)
+def _one_chunk(q, k, v, g, beta, scale, xp=jnp):
+    """A chunk from a zero state in closed form, one head: ``(O, S') = (B M
+    V, K-^T M V)``, ``M = (I + Diag(beta) A)^-1 Diag(beta)``, with ``A``
+    and ``B`` by the pair (module docstring); q, k, g ``[C, d_k]``, v ``[C,
+    d_v]``, beta ``[C]``."""
+    size = q.shape[0]
+    cum = xp.cumsum(g, axis=0)
+    visible = np.tril(np.ones((size, size), bool))
+    pairs = xp.exp(xp.where(visible[..., None],
+                            cum[:, None, :] - cum[None, :, :], -xp.inf))
+    a = xp.einsum("rc,ic,ric->ri", k, k, pairs) * np.tril(
+        np.ones((size, size)), -1)
+    b = scale * xp.einsum("rc,ic,ric->ri", q, k, pairs)
+    solve = xp.linalg.inv(xp.eye(size) + beta[:, None] * a) * beta[None, :]
+    u = solve @ v
+    return b @ u, (k * xp.exp(cum[-1:] - cum)).T @ u
+
+
+def test_one_chunk_against_the_closed_form_and_its_gradient():
+    """What ``kda_fwd`` forms in VMEM — the decayed products, the unit
+    lower-triangular inverse — read through a one-chunk call, and what
+    ``kda_bwd`` pulls back through them against JAX's derivative of the
+    closed form (``jnp.linalg.inv``'s among it)."""
+    args = operands(11, seq=64, heads=1, log_decay=0.3)
+    alone = lambda x: x[0, :, 0]  # noqa: E731
+    weight = jax.random.normal(jax.random.PRNGKey(2), (64, 16))
+    scale = 32 ** -0.5
     with jax.default_matmul_precision("highest"):
-        def plain(x):
-            return jnp.linalg.inv(jnp.eye(64) + jnp.tril(x, -1))
+        def closed(*a):
+            return _one_chunk(*map(alone, a), scale)
 
-        np.testing.assert_allclose(kda_ops._unit_lower_inverse(lower),
-                                   plain(lower), rtol=1e-4, atol=1e-5)
-        got, want = (jax.grad(lambda x: jnp.sum(f(x) * weight))(lower)
-                     for f in (kda_ops._unit_lower_inverse, plain))
-    np.testing.assert_allclose(got, jnp.tril(want, -1), rtol=1e-3, atol=1e-4)
+        want_o, want_state = closed(*args)
+        o, state = kda(*args)
+        np.testing.assert_allclose(alone(o), want_o, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(state[0, 0], want_state, rtol=1e-4,
+                                   atol=1e-5)
+        got, want = (jax.grad(lambda *a: jnp.sum(alone(f(*a)[0]) * weight),
+                              argnums=(0, 1, 2, 3, 4))(*args)
+                     for f in (kda, lambda *a: (closed(*a)[0][None, :, None],)))
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4, err_msg=name)
 
 
 def test_decayed_products_against_the_definition():
     """``sum_c x_rc k_ic exp(G_rc - G_ic)`` on and below the diagonal, by
-    the pair; no exponent taken is positive even where ``G`` falls by
-    hundreds inside a chunk."""
+    the pair in float64, read through a one-chunk call; no exponent the
+    kernel takes is positive even where ``G`` falls by hundreds inside a
+    chunk."""
     rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.standard_normal((2, 64, 8)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
-    cum = jnp.cumsum(-jnp.asarray(rng.uniform(0, 12, (64, 8)), jnp.float32),
-                     axis=0)
-    got = kda_ops._decayed_products(x, k, cum)
-    gap = cum[:, None, :] - cum[None, :, :]
-    visible = np.tril(np.ones((64, 64), bool))
-    pairs = jnp.exp(jnp.where(visible[..., None], gap, -jnp.inf))
-    want = jnp.einsum("xrc,ic,ric->xri", x, k, pairs)
-    assert float(-cum.min()) > 300 and np.isfinite(np.asarray(got)).all()
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    q, k = (rng.standard_normal((64, 8)) for _ in range(2))
+    v = rng.standard_normal((64, 8))
+    g = -rng.uniform(0, 12, (64, 8))
+    beta = rng.uniform(0, 1, 64)
+    want_o, want_state = _one_chunk(q, k, v, g, beta, 1.0, xp=np)
+    o, state = kda(*(jnp.asarray(x, jnp.float32)[None, :, None]
+                     for x in (q, k, v, g)),
+                   jnp.asarray(beta, jnp.float32)[None, :, None], scale=1.0)
+    assert float(-np.cumsum(g, 0).min()) > 300
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(o[0, :, 0], want_o, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(state[0, 0], want_state, rtol=1e-4, atol=1e-6)
 
 
 def test_bfloat16_operands_and_a_chunk_that_is_not_the_default():
@@ -140,6 +171,27 @@ def test_the_chain_is_two_named_kernels():
     text = str(jax.make_jaxpr(jax.grad(lambda *a: kda(*a)[0].sum(),
                                        argnums=(0, 3)))(*args))
     assert "kda_fwd" in text and "kda_bwd" in text
+
+
+def test_no_loop_outside_the_two_kernels():
+    """Nothing of the gradient program iterates but the kernels' own
+    grids: what fed the chain from XLA loops (``lax.map`` over batches of
+    chunks, a ``fori_loop`` in the triangular inverse) is inside
+    ``kda_fwd`` and ``kda_bwd``."""
+    def walk(jaxpr, seen):
+        for eqn in jaxpr.eqns:
+            seen.append(eqn.primitive.name)
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, seen)
+        return seen
+
+    args = operands(1, seq=150, dtype=jnp.bfloat16)
+    seen = walk(jax.make_jaxpr(jax.grad(
+        lambda *a: kda(*a, interpret=True)[0].astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4)))(*args).jaxpr, [])
+    assert seen.count("pallas_call") == 2, seen
+    assert not {"while", "scan"} & set(seen), seen
 
 
 # -- flash attention with a v of its own width --------------------------------

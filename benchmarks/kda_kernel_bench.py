@@ -8,11 +8,17 @@
 Each ``--case`` is ``batch,seq,heads,head_dim`` with optional
 ``,dtype=float32`` and ``,chunk=<n>``, or ``kimi``: a KDA layer's call on
 ``kimi_linear_16k_1chip`` (one sequence of 16,384 tokens, 32 heads of
-128). The times are read from a profiler trace of ``--iters`` calls, by the
-kernels' names (docs/tracing.md); ``call_ms`` is the whole device program,
-so what XLA does round the kernels — layout copies; in a checkout from
-before PR 31 the loops that prepared the kernels' operands — is ``call_ms``
-less ``kernels_ms``. ``check_*`` are the largest errors of the values and
+128). The kernels are handed q, k, v, g as ``[B, T, H * d]``, heads side by
+side, as ``models.kimi_linear.KDAMixer`` hands them (``ops.kda.kda_fed``):
+the measured program is then the two kernels and nothing round them, and
+its per-call times are the cell's. A ``--tree`` from before PR 36 knows only
+the four-axis form of ``ops.kda.kda``, whose reshapes are copies on the
+TPU, and is measured through it (``layout`` says which). The times are read
+from a profiler trace of ``--iters`` calls, by the kernels' names
+(docs/tracing.md); ``call_ms`` is the whole device program, so what XLA
+does round the kernels — layout copies; in a checkout from before PR 31
+the loops that prepared the kernels' operands — is ``call_ms`` less
+``kernels_ms``. ``check_*`` are the largest errors of the values and
 of each gradient against ``kda_recurrent`` in float32 over the first
 ``--check`` tokens, as shares of the largest magnitude: the Mosaic
 lowering checked on the chip, which the interpreter's tests cannot. Each
@@ -72,13 +78,29 @@ def operands(shape, dtype):
     return tuple(x.astype(dtype) for x in (q, k, v)), g, beta, cot
 
 
+def takes_heads_side_by_side(ops_kda) -> bool:
+    """Whether that checkout's ``kda_fed`` takes ``[B, T, H * d]`` (since
+    PR 36) and not ``[B, T, H, d]``: asked of it by shape, nothing runs."""
+    import jax
+
+    flat = jax.ShapeDtypeStruct((1, 16, 2 * 16), "float32")
+    try:
+        jax.eval_shape(lambda *a: ops_kda.kda_fed(ops_kda._as_given, *a),
+                       flat, flat, flat, flat,
+                       jax.ShapeDtypeStruct((1, 16, 2), "float32"))
+    except (ValueError, TypeError):
+        return False
+    return True
+
+
 def measure(case: dict, iters: int, check: int, trace_root: str) -> dict:
     import jax
     import jax.numpy as jnp
 
     from chipbench import trace_reduce
-    from horovod_tpu.ops.kda import kda, kda_recurrent
+    from horovod_tpu.ops import kda as ops_kda
 
+    kda, kda_recurrent = ops_kda.kda, ops_kda.kda_recurrent
     (q, k, v), g, beta, cot = operands(case["shape"],
                                        jnp.dtype(case["dtype"]))
 
@@ -86,18 +108,27 @@ def measure(case: dict, iters: int, check: int, trace_root: str) -> dict:
         return jnp.vdot(fn(*args)[0].astype(jnp.float32), cot)
 
     chunked = lambda *a: kda(*a, chunk=case["chunk"])  # noqa: E731
-    call = jax.jit(jax.value_and_grad(
-        lambda *a: loss(chunked, cot, *a), argnums=(0, 1, 2, 3, 4)))
     args = (q, k, v, g, beta)
+    flat = takes_heads_side_by_side(ops_kda)
+    if flat:
+        as_timed = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
+        rule = lambda *a: ops_kda.kda_fed(  # noqa: E731
+            ops_kda._as_given, *a, chunk=case["chunk"])
+    else:
+        as_timed, rule = (lambda x: x), chunked
+    call = jax.jit(jax.value_and_grad(
+        lambda *a: loss(rule, as_timed(cot), *a), argnums=(0, 1, 2, 3, 4)))
+    timed = (*map(as_timed, args[:4]), beta)
     for _ in range(3):
-        jax.block_until_ready(call(*args))
+        jax.block_until_ready(call(*timed))
     trace_dir = tempfile.mkdtemp(dir=trace_root)
     with jax.profiler.trace(trace_dir):
         for _ in range(iters):
-            result = call(*args)
+            result = call(*timed)
         jax.block_until_ready(result)
 
-    line = {**case, "iters": iters}
+    line = {**case, "iters": iters,
+            "layout": "heads_side_by_side" if flat else "by_head"}
     if check:
         head = tuple(x[:, :check] for x in args)
         got, want = (jax.jit(jax.value_and_grad(

@@ -9,9 +9,10 @@ The third decoder beside ``transformer.TransformerLM`` and
 so ``make_lm_train_step`` and ``lm_loss`` take it unchanged.
 ``KimiLinearLM.from_config`` reads the keys of the published
 ``config.json`` (moonshotai/Kimi-Linear-48B-A3B-Instruct) plus
-``experts_held``. Two kernels carry it: ``ops.kda.kda`` (the chunked delta
-rule) and ``ops.pallas_attention.flash_attention`` with a v narrower than
-its q and k (192 against 128 as published).
+``experts_held``. Two kernels carry it: ``ops.kda.kda_fed`` (the chunked
+delta rule, its tensors ``[B, T, heads * d]`` as the projections make them)
+and ``ops.pallas_attention.flash_attention`` with a v narrower than its q
+and k (192 against 128 as published).
 
 Per KDA layer, two numbers say whether the recurrence forgets or blows up:
 the mean decay ``mean(alpha)`` and the largest ``|S|`` at the sequence's
@@ -67,9 +68,44 @@ def causal_conv(x, taps):
                for j in range(n))
 
 
-def _l2norm(x):
+# A head's channels lie side by side in the last axis, ``[B, T, heads * d]``,
+# from the projections to the delta rule's kernels and back: on the TPU that
+# form tiles (tokens, lanes) — with d = 128 a head of a token is the lanes of
+# one vreg — where ``[B, T, heads, d]`` tiles (heads, lanes), so a reshape
+# between the two copies the whole tensor, and a reduction written over a
+# split last axis makes XLA move the heads into sublanes first. What a head
+# needs summed is therefore summed where it lies: a product with the 0/1
+# matrix of which channel is whose (the MXU adds a head's lanes), and the
+# same matrix transposed hands a head's number back to its channels. The
+# matrix is exact in bfloat16, so the six passes of ``Precision.HIGHEST``
+# are a float32 sum (8.9e-8 from the sum by head on the chip; ``HIGH``'s
+# three keep sixteen bits, 5.6e-6) and, the products reading their tensor
+# from HBM once either way, cost 0.2 ms a layer over ``HIGH`` (PERF.md §6).
+_BY_HEAD = jax.lax.Precision.HIGHEST
+
+
+def _whose(width: int, heads: int):
+    """``[width, heads]`` float32: 1 where a channel is of that head."""
+    return (jnp.arange(width)[:, None] // (width // heads)
+            == jnp.arange(heads)).astype(jnp.float32)
+
+
+def _head_sums(x, whose):
+    """Each head's sum of ``x [..., heads * d]``, float32: ``[..., heads]``."""
+    return jnp.dot(x, whose, precision=_BY_HEAD)
+
+
+def _to_channels(x, whose):
+    """``x [..., heads]`` repeated over each head's channels."""
+    return jnp.dot(x, whose.T, precision=_BY_HEAD)
+
+
+def _l2norm(x, whose):
+    """``x [..., heads * d]`` with each head's channels scaled to unit
+    length, float32."""
     x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+    return x * _to_channels(jax.lax.rsqrt(
+        _head_sums(jnp.square(x), whose) + 1e-6), whose)
 
 
 def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int, dtype):
@@ -77,20 +113,42 @@ def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int, dtype):
     short convolutions and SiLU on q, k and v, q and k normalised a head,
     the per-channel log-decay ``g = -exp(rate) * softplus(raw + bias)`` in
     float32 and ``beta = sigmoid(write)``. ``[B, T, heads * d]`` in,
-    ``(q, k, v, g [B, T, heads, d], beta [B, T, heads])`` out."""
-    by_head = lambda a: a.reshape(*a.shape[:2], heads, -1)  # noqa: E731
+    ``(q, k, v, g [B, T, heads * d], beta [B, T, heads])`` out: what
+    ``ops.kda.kda_fed`` takes, no tensor reshaped on the way."""
+    whose = _whose(q.shape[-1], heads)
     with jax.named_scope("hvd.kda.conv"):
         q, k, v = (nn.silu(causal_conv(a, t)) for a, t in zip((q, k, v), taps))
-    g = -jnp.exp(rate)[:, None] * by_head(jax.nn.softplus(
-        raw.astype(jnp.float32) + bias))
-    q, k = (_l2norm(by_head(a)).astype(dtype) for a in (q, k))
-    return q, k, by_head(v), g, nn.sigmoid(write.astype(jnp.float32))
+    g = -jnp.repeat(jnp.exp(rate), raw.shape[-1] // heads) * jax.nn.softplus(
+        raw.astype(jnp.float32) + bias)
+    q, k = (_l2norm(a, whose).astype(dtype) for a in (q, k))
+    return q, k, v, g, nn.sigmoid(write.astype(jnp.float32))
+
+
+class _HeadRMSNorm(nn.Module):
+    """``nn.RMSNorm`` over each head of ``x [B, T, heads * d]`` with one
+    learned ``scale [d]`` shared by the heads: the mean square in float32,
+    ``x * (rsqrt(. + epsilon) * scale)`` cast to ``dtype``."""
+
+    heads: int
+    epsilon: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1] // self.heads
+        scale = self.param("scale", nn.initializers.ones, (d,), jnp.float32)
+        whose = _whose(x.shape[-1], self.heads)
+        mean_square = _head_sums(
+            jnp.square(x.astype(jnp.float32)), whose) / d
+        by = _to_channels(jax.lax.rsqrt(mean_square + self.epsilon), whose)
+        return (x * (by * jnp.tile(scale, self.heads))).astype(self.dtype)
 
 
 class KDAMixer(nn.Module):
     """Kimi Delta Attention: q, k, v through a short convolution and SiLU,
     q and k normalised, a per-channel decay and a per-head write strength
-    from the input, the delta rule, a normalised and gated output."""
+    from the input, the delta rule, a normalised and gated output. Every
+    tensor keeps its heads side by side, ``[B, T, heads * head_dim]``."""
 
     num_heads: int
     head_dim: int
@@ -124,18 +182,22 @@ class KDAMixer(nn.Module):
             with jax.named_scope("hvd.kda.scan"):
                 # the kernel's backward keeps the projections and forms
                 # what ``feed`` makes of them again (``ops.kda.kda_fed``)
-                o, state = kda_fed(feed, *projected) \
-                    if self.kda == "chunked" else \
-                    kda_recurrent(*feed(*projected))
+                if self.kda == "chunked":
+                    o, state = kda_fed(feed, *projected)
+                else:   # the definition takes heads on an axis of their own
+                    *fed, beta = feed(*projected)
+                    o, state = kda_recurrent(*(
+                        a.reshape(*a.shape[:2], heads, dh) for a in fed),
+                        beta)
+                    o = o.reshape(*o.shape[:2], width)
             if self.is_mutable_collection("kda_stats"):
                 self.sow("kda_stats", "mean_decay",
                          jnp.mean(jnp.exp(feed(*projected)[3])))
                 self.sow("kda_stats", "state_max", jnp.max(jnp.abs(state)))
             gate = _dense(width, "gate_b", self.dtype)(
                 _dense(dh, "gate_a", self.dtype)(x))
-            o = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                           name="out_norm")(o.astype(self.dtype))
-            o = o.reshape(*x.shape[:2], width) * nn.sigmoid(gate)
+            o = _HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
+                o.astype(self.dtype)) * nn.sigmoid(gate)
             return _dense(x.shape[-1], "out", self.dtype)(o)
 
 

@@ -8,17 +8,22 @@ forgets or blows up. A training step does not carry the collection; a
 caller who wants the numbers applies the model with
 ``mutable=["kda_stats"]`` and hands the collection to :func:`publish`.
 
-And two gauges of a *compiled* step's text, set by
+And three gauges of a *compiled* step's text, set by
 :func:`record_scan_program` (whoever holds the compiled step calls it, as
 with ``obs.compiles.record_exchange_collectives``): whether the recurrence
 engaged its kernels. ``ops.kda`` forms a chunk's operands inside
 ``kda_fwd`` and ``kda_bwd``; a lowering that fell back to XLA loops under
 ``hvd.kda.scan`` would show here as loops, and as calls that are missing.
+And whether the layout held: the kernels read q, k, v, g as ``[B, T, H *
+d]`` and the layer keeps them so from its projections on; a tensor that is
+taken to ``[B, T, H, d]`` on the way is copied whole on the TPU, and shows
+here as a relayout.
 """
 
 from __future__ import annotations
 
 import collections
+import math
 import re
 
 from .registry import registry as _metrics
@@ -44,20 +49,60 @@ _KERNEL_CALLS = _metrics().gauge(
     "kda_bwd) in a compiled step's text",
     labels=("program", "kernel"))
 
+_RELAYOUTS = _metrics().gauge(
+    "horovod_kda_relayouts",
+    "copy, reshape and transpose instructions of a compiled step's entry "
+    "computation that move a tensor as large as the delta rule's q between "
+    "heads side by side and heads on an axis of their own (0 where the "
+    "layer keeps [B, T, H * d] from its projections to the kernels and back)",
+    labels=("program",))
+
 _KERNEL_CALL = re.compile(r"%(kda_\w+?)(?:\.\d+)? = .*\bcustom-call\(")
+_OPERAND_SHAPES = re.compile(
+    r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
+_INSTRUCTION = re.compile(
+    r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (copy|reshape|transpose)\(")
+
+
+def _dims(text: str) -> tuple:
+    return tuple(int(n) for n in text.split(",") if n)
+
+
+def _relayouts(entry_lines, q_shape, beta_shape) -> int:
+    """The instructions of :func:`record_scan_program`'s third count, given
+    the kernels' q ``[B, T, H * d]`` and beta ``[B, H / group, T, group]``
+    as a ``kda_*`` call states them."""
+    heads = beta_shape[1] * beta_shape[3]
+    by_head = (heads, q_shape[-1] // heads)
+    count = 0
+    for line in entry_lines:
+        m = _INSTRUCTION.match(line)
+        if m is None or math.prod(_dims(m.group(1))) < math.prod(q_shape):
+            continue
+        if "op_name=" in line:
+            count += "hvd.kda" in line
+        else:   # the compiler's own copies carry no metadata
+            count += _dims(m.group(1))[-2:] == by_head
+    return count
 
 
 def record_scan_program(program: str, hlo_text: str) -> tuple:
-    """``(loops, {kernel: calls})`` of a compiled step's text
-    (``compiled.as_text()``), set on the two gauges under ``program``:
-    the ``while`` instructions whose ``op_name`` holds ``hvd.kda.scan``,
-    and the ``tpu_custom_call``s named ``kda_*`` by kernel. On
-    ``kimi_linear_16k_1chip`` that is 0 and ``{"kda_fwd": 8, "kda_bwd":
-    4}`` (four layers, the forward run again where a block is
-    recomputed); before the kernels formed their operands it was 12
-    loops (PERF.md §6, PR 31)."""
+    """``(loops, {kernel: calls}, relayouts)`` of a compiled step's text
+    (``compiled.as_text()``), set on the three gauges under ``program``:
+    the ``while`` instructions whose ``op_name`` holds ``hvd.kda.scan``;
+    the ``tpu_custom_call``s named ``kda_*`` by kernel; and the entry
+    computation's ``copy``, ``reshape`` and ``transpose`` instructions (a
+    ``bitcast`` moves nothing) whose result holds at least as many elements
+    as the kernels' q and whose ``op_name`` holds ``hvd.kda`` or, where it
+    has none (the compiler's own copies), whose shape ends in ``[H, d]``.
+    On ``kimi_linear_16k_1chip`` that is 0, ``{"kda_fwd": 8, "kda_bwd":
+    4}`` (four layers, the forward run again where a block is recomputed)
+    and 0; before the kernels formed their operands it was 12 loops, and
+    while the layer held its tensors ``[B, T, H, d]`` 100 relayouts, 47 GB
+    moved a step (PERF.md §6, PR 31 and PR 36)."""
     loops = 0
     calls = collections.Counter()
+    shapes = None
     for line in hlo_text.splitlines():
         if "hvd.kda.scan" in line and " while(" in line:
             loops += 1
@@ -65,11 +110,19 @@ def record_scan_program(program: str, hlo_text: str) -> tuple:
             m = _KERNEL_CALL.search(line)
             if m is not None:
                 calls[m.group(1)] += 1
+                given = _OPERAND_SHAPES.search(line)
+                if shapes is None and given is not None:
+                    shapes = [_dims(dims) for dims in re.findall(
+                        r"\w+\[([\d,]*)\]", given.group(1))]
+    entry = hlo_text.partition("\nENTRY ")[2].partition("\n}")[0]
+    relayouts = 0 if shapes is None else _relayouts(
+        entry.splitlines(), shapes[0], shapes[4])
     _SCAN_LOOPS.labels(program=program).set(loops)
     for kernel in {"kda_fwd", "kda_bwd", *calls}:
         _KERNEL_CALLS.labels(program=program, kernel=kernel).set(
             calls[kernel])
-    return loops, dict(calls)
+    _RELAYOUTS.labels(program=program).set(relayouts)
+    return loops, dict(calls), relayouts
 
 
 def publish(kda_stats) -> dict:

@@ -61,6 +61,16 @@ type, which is all the backward's products take of them. ``kda_fed(feed,
 *args)`` keeps still less: the ``args`` of whatever makes q, k, v, g, beta
 (a layer's projections), which its backward runs again.
 
+The layout. The kernels read and write q, k, v, g and their gradients as
+``[B, T, H * d]``, a head's channels side by side (``_specs``: blocks of
+``(1, chunk, group * d)``), and that is ``kda_fed``'s contract, arguments
+and result: nothing between a layer's projections and the kernels, or
+between the kernels and the layer's output projection, holds heads on an
+axis of their own. ``kda`` is the four-axis form of the definition
+(``[B, T, H, d]``, as ``kda_recurrent`` takes them), a reshape round
+``kda_fed`` for tests and benchmarks; on the TPU that reshape is a copy, so
+a model calls ``kda_fed``.
+
 ``interpret`` as in ``pallas_attention``; left ``None`` the choice follows
 the platform the program is *lowered* for (``lax.platform_dependent``), so
 a step compiled for a described TPU from a CPU box gets the kernels.
@@ -429,26 +439,23 @@ def _compiler_params(interpret: bool):
         dimension_semantics=("parallel", "arbitrary"))
 
 
-def _wide(x, chunk: int):
-    """``[B, T, ...]`` as ``[B, T', the rest in one]``, ``T'`` T padded
-    with zeros to whole chunks."""
-    batch, seq = x.shape[:2]
-    return jnp.pad(x.reshape(batch, seq, -1),
-                   ((0, 0), (0, -seq % chunk), (0, 0)))
+def _whole_chunks(x, chunk: int):
+    """``[B, T, .]`` with T padded with zeros to whole chunks."""
+    return jnp.pad(x, ((0, 0), (0, -x.shape[1] % chunk), (0, 0)))
 
 
 def _blocks(q, k, v, g, beta, chunk: int):
-    """The kernels' view of the arguments: q, k, v, g ``[B, T', H * d]``
-    (no copy where T is a whole number of chunks: the index maps pick a
-    chunk of a group of heads out of the arguments as they lie) and beta
-    ``[B, H / group, T', group]``; ``T'`` is T padded to whole chunks with
-    tokens that neither decay nor write (g = 0, beta = 0)."""
-    batch, _, heads, _ = q.shape
+    """The kernels' view of the arguments: q, k, v, g ``[B, T', H * d]`` as
+    they were given (the index maps pick a chunk of a group of heads out of
+    them as they lie) and beta ``[B, H / group, T', group]``; ``T'`` is T
+    padded to whole chunks with tokens that neither decay nor write (g = 0,
+    beta = 0)."""
+    batch, _, heads = beta.shape
     group = _heads_a_step(heads)
-    beta = _wide(beta.astype(jnp.float32), chunk)
+    beta = _whole_chunks(beta.astype(jnp.float32), chunk)
     beta = beta.reshape(batch, -1, heads // group, group).swapaxes(1, 2)
-    return (*(_wide(x, chunk) for x in (q, k, v, g.astype(jnp.float32))),
-            beta)
+    return (*(_whole_chunks(x, chunk)
+              for x in (q, k, v, g.astype(jnp.float32))), beta)
 
 
 def _specs(heads: int, chunk: int, reverse: Optional[int] = None):
@@ -471,8 +478,8 @@ def _specs(heads: int, chunk: int, reverse: Optional[int] = None):
 def _scan_fwd(q, k, v, g, beta, scale, chunk, save: bool, interpret: bool):
     """``(O [B, T', H * d_v], final state [B*H, d_v, d_k], the chunks'
     starting states [N, B*H, d_v, d_k] in q's type or None)``."""
-    batch, _, heads, d_k = q.shape
-    d_v = v.shape[-1]
+    batch, _, heads = beta.shape
+    d_k, d_v = q.shape[-1] // heads, v.shape[-1] // heads
     group = _heads_a_step(heads)
     ins = _blocks(q, k, v, g, beta, chunk)
     n = ins[0].shape[1] // chunk
@@ -497,13 +504,13 @@ def _scan_fwd(q, k, v, g, beta, scale, chunk, save: bool, interpret: bool):
 
 
 def _scan_bwd(q, k, v, g, beta, starts, do, scale, chunk, interpret: bool):
-    """``(dq, dk, dv, dg, dbeta)`` in the arguments' shapes; dq, dk and dv
-    in their types, dg and dbeta float32."""
-    batch, seq, heads, d_k = q.shape
-    d_v = v.shape[-1]
+    """``(dq, dk, dv, dg, dbeta)`` in the arguments' shapes, as the kernel
+    wrote them; dq, dk and dv in their types, dg and dbeta float32."""
+    batch, seq, heads = beta.shape
+    d_k, d_v = q.shape[-1] // heads, v.shape[-1] // heads
     group = _heads_a_step(heads)
     blocks = _blocks(q, k, v, g, beta, chunk)
-    ins = (*blocks, starts, _wide(do.astype(q.dtype), chunk))
+    ins = (*blocks, starts, _whole_chunks(do.astype(q.dtype), chunk))
     n = starts.shape[0]
     wide, beta_spec, states = _specs(heads, chunk, reverse=n)
     specs = [wide(d_k), wide(d_k), wide(d_v), wide(d_k), beta_spec]
@@ -517,8 +524,7 @@ def _scan_bwd(q, k, v, g, beta, starts, do, scale, chunk, interpret: bool):
         compiler_params=_compiler_params(interpret), interpret=interpret,
         name="kda_bwd")(*ins)
     dbeta = dbeta.swapaxes(1, 2).reshape(batch, -1, heads)
-    return (*(x[:, :seq].reshape(batch, seq, heads, -1) for x in grads),
-            dbeta[:, :seq])
+    return (*(x[:, :seq] for x in grads), dbeta[:, :seq])
 
 
 def _by_platform(fn, interpret: Optional[bool], *args):
@@ -530,17 +536,20 @@ def _by_platform(fn, interpret: Optional[bool], *args):
         *args, cpu=lambda *a: fn(*a, True), default=lambda *a: fn(*a, False))
 
 
-def _scale(scale: Optional[float], q) -> float:
-    return 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+def _scale(scale: Optional[float], q, beta) -> float:
+    """``d_k ** -0.5`` unless given; beta's last axis is the head count."""
+    d_k = q.shape[-1] // beta.shape[-1]
+    return 1.0 / math.sqrt(d_k) if scale is None else scale
 
 
 def _forward(q, k, v, g, beta, scale, chunk, interpret, save: bool):
-    batch, seq, heads, d_k = q.shape
+    batch, seq, heads = beta.shape
     o, final, starts = _by_platform(
-        lambda *a: _scan_fwd(*a[:-1], _scale(scale, q), chunk, save, a[-1]),
+        lambda *a: _scan_fwd(*a[:-1], _scale(scale, q, beta), chunk, save,
+                             a[-1]),
         interpret, q, k, v, g, beta)
-    final = final.reshape(batch, heads, v.shape[-1], d_k).swapaxes(-1, -2)
-    return o[:, :seq].reshape(batch, seq, heads, -1), final, starts
+    final = final.reshape(batch, heads, v.shape[-1] // heads, -1)
+    return o[:, :seq], final.swapaxes(-1, -2), starts
 
 
 def _as_given(*operands):
@@ -566,7 +575,8 @@ def _kda_bwd(feed, scale, chunk, interpret, res, cotangents):
     inputs, feed_back = (args, lambda grads: grads) if feed is _as_given \
         else jax.vjp(jax.checkpoint(feed), *args)
     grads = _by_platform(
-        lambda *a: _scan_bwd(*a[:-1], _scale(scale, inputs[0]), chunk, a[-1]),
+        lambda *a: _scan_bwd(*a[:-1], _scale(scale, inputs[0], inputs[4]),
+                             chunk, a[-1]),
         interpret, *inputs, starts, do)
     return feed_back(tuple(x.astype(like.dtype)
                            for x, like in zip(grads, inputs)))
@@ -577,13 +587,23 @@ _kda.defvjp(_kda_fwd, _kda_bwd)
 
 def kda_fed(feed, *args, scale: Optional[float] = None, chunk: int = CHUNK,
             interpret: Optional[bool] = None):
-    """``kda(*feed(*args))`` that keeps ``args`` for its backward pass, not
-    ``feed``'s results: ``feed`` — whatever turns a layer's projections into
-    q, k, v, g and beta: a convolution, a normalisation, the decay's
-    non-linearity — runs again there, and its transpose after the chain's.
-    At 16,384 tokens x 32 heads x 128 that is 1.3 GB a layer less held
-    between the forward and the backward pass. ``feed`` is a function of
-    arrays alone, differentiable in all of them."""
+    """The delta rule on what ``feed(*args)`` returns, **heads side by side
+    in the last axis**: q, k, g ``[B, T, H * d_k]``, v ``[B, T, H * d_v]``
+    and beta ``[B, T, H]`` (whose last axis is the head count). Returns
+    ``(o [B, T, H * d_v] in v's dtype, the final state [B, H, d_k, d_v]
+    float32)``. That is how a layer's projections leave their matmuls and
+    how the kernels' index maps read them; ``[B, T, H, d]`` tiles (heads,
+    lanes) on the TPU where ``[B, T, H * d]`` tiles (tokens, lanes), so a
+    reshape between the two is a copy of the whole tensor (``kda`` makes
+    it, for callers that hold heads on an axis of their own).
+
+    It keeps ``args`` for its backward pass, not ``feed``'s results:
+    ``feed`` — whatever turns a layer's projections into q, k, v, g and
+    beta: a convolution, a normalisation, the decay's non-linearity — runs
+    again there, and its transpose after the chain's. At 16,384 tokens x 32
+    heads x 128 that is 1.3 GB a layer less held between the forward and
+    the backward pass. ``feed`` is a function of arrays alone,
+    differentiable in all of them."""
     if chunk % _SUB:
         raise ValueError(f"chunk must be a multiple of {_SUB}, got {chunk}")
     return _kda(feed, scale, chunk, interpret, *args)
@@ -601,6 +621,9 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     Differentiable in q, k, v, g and beta through ``o``; the final state is
     a reading and carries no gradient. ``chunk`` is a multiple of 16; any
     ``seq`` (padded inside). The state starts at zero. ``scale`` defaults
-    to ``d_k ** -0.5``."""
-    return kda_fed(_as_given, q, k, v, g, beta, scale=scale, chunk=chunk,
-                   interpret=interpret)
+    to ``d_k ** -0.5``. A reshape round ``kda_fed``, which takes and
+    returns the heads side by side."""
+    flat = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
+    o, final = kda_fed(_as_given, flat(q), flat(k), flat(v), flat(g), beta,
+                       scale=scale, chunk=chunk, interpret=interpret)
+    return o.reshape(v.shape), final

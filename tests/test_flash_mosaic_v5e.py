@@ -181,8 +181,11 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, no_compile_cache):
 def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
     """``obs.kda.record_scan_program`` on a compiled toy step (one KDA
     layer's gradient, lowered for the v5e with no ``interpret`` given): no
-    loop under ``hvd.kda.scan``, one call of each kernel; and on a text
-    that prepares the operands in loops, as before PR 31, it counts them."""
+    loop under ``hvd.kda.scan``, one call of each kernel, and no tensor as
+    large as q moved between heads side by side and heads on an axis of
+    their own; on a text that prepares the operands in loops, as before PR
+    31, it counts them, and on one with the compiler's copy of a ``[T, H *
+    d]`` tensor to ``[T, H, d]``, as before PR 36, that."""
     import jax
     import jax.numpy as jnp
 
@@ -197,7 +200,7 @@ def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
     text = jax.jit(jax.grad(lambda p, x: layer.apply(p, x).astype(
         jnp.float32).sum())).lower(params, x).compile().as_text()
     assert obs.kda.record_scan_program("toy", text) == (
-        0, {"kda_fwd": 1, "kda_bwd": 1})
+        0, {"kda_fwd": 1, "kda_bwd": 1}, 0)
 
     def gauge(family, **labels):
         return next(s["value"]
@@ -205,6 +208,7 @@ def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
                     if s["labels"] == labels)
 
     assert gauge("horovod_kda_scan_loops", program="toy") == 0
+    assert gauge("horovod_kda_relayouts", program="toy") == 0
     assert gauge("horovod_kda_kernel_calls", program="toy",
                  kernel="kda_bwd") == 1
     looped = text + (
@@ -214,3 +218,17 @@ def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
         '\n  %while.8 = (s32[], f32[8]{0}) while(%tuple.4), condition=%c, '
         'body=%b, metadata={op_name="jit(step)/hvd.optimizer/while"}\n')
     assert obs.kda.record_scan_program("looped", looped)[0] == 1
+    # the toy's q is [1, 256, 4 * 128]: a copy of as much to heads in
+    # sublanes, one under the scope back, and two that are none (a bitcast;
+    # a copy of a smaller tensor)
+    end = text.rindex("\n}")
+    moved = text[:end] + (
+        '\n  %copy.9 = f32[32,8,4,128]{3,2,1,0:T(8,128)} copy(%bitcast.1)'
+        '\n  %reshape.9 = f32[1,256,512]{2,1,0:T(8,128)} reshape(%copy.9), '
+        'metadata={op_name="jit(step)/hvd.kda/hvd.kda.scan/reshape"}'
+        '\n  %bitcast.9 = f32[1,256,4,128]{3,2,1,0:T(8,128)} '
+        'bitcast(%copy.9), metadata={op_name="jit(step)/hvd.kda/reshape"}'
+        '\n  %copy.10 = f32[32,4,128]{2,1,0:T(8,128)} copy(%bitcast.2)'
+        ) + text[end:]
+    assert obs.kda.record_scan_program("moved", moved)[2] == 2
+    assert gauge("horovod_kda_relayouts", program="moved") == 2

@@ -257,22 +257,32 @@ def test_split_widths_name_their_kernels_and_count_both_in_the_budget():
                            jnp.zeros((1, 128, 2, 32)))
 
 
+def _flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
 def test_a_feed_is_recomputed_not_kept():
-    """``kda_fed(feed, *args)`` is ``kda(*feed(*args))`` in value and in
-    every gradient, and what its backward keeps is ``args``: nothing of the
-    feed's results is among a gradient program's saved arrays."""
+    """``kda_fed(feed, *args)`` is the delta rule on ``feed(*args)``, heads
+    side by side (``[B, T, H * d]``), in value and in every gradient, and
+    what its backward keeps is ``args``: nothing of the feed's results is
+    among a gradient program's saved arrays."""
     q, k, v, g, beta = operands(2, seq=80)
-    gain = jnp.linspace(0.5, 1.5, q.shape[-1])
+    heads, d_k = q.shape[2:]
+    gain = jnp.linspace(0.5, 1.5, heads * d_k)
 
     def feed(q, k, v, g, beta, gain):
-        q = q * gain
-        return (q / jnp.linalg.norm(q, axis=-1, keepdims=True), jnp.tanh(k),
-                v, g, beta)
+        q = (q * gain).reshape(*q.shape[:2], heads, d_k)
+        return (_flat(q / jnp.linalg.norm(q, axis=-1, keepdims=True)),
+                jnp.tanh(k), v, g, beta)
 
-    args = (q, k, v, g, beta, gain)
-    weight = jax.random.normal(jax.random.PRNGKey(4), v.shape)
+    def by_head(q, k, v, g, beta):
+        split = lambda x: x.reshape(*x.shape[:2], heads, -1)  # noqa: E731
+        return _flat(kda(*map(split, (q, k, v, g)), beta)[0])
+
+    args = (*map(_flat, (q, k, v, g)), beta, gain)
+    weight = jax.random.normal(jax.random.PRNGKey(4), args[2].shape)
     fed = lambda *a: kda_ops.kda_fed(feed, *a)[0]  # noqa: E731
-    plain = lambda *a: kda(*feed(*a))[0]           # noqa: E731
+    plain = lambda *a: by_head(*feed(*a))          # noqa: E731
     np.testing.assert_allclose(fed(*args), plain(*args), atol=1e-6)
     got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * weight),
                           argnums=tuple(range(6)))(*args)
@@ -281,6 +291,30 @@ def test_a_feed_is_recomputed_not_kept():
         np.testing.assert_allclose(a, b, atol=2e-5)
     _, residuals = jax.vjp(fed, *args)
     kept = [x.shape for x in jax.tree_util.tree_leaves(residuals)
-            if hasattr(x, "shape") and x.ndim == 4
-            and x.shape[:3] == q.shape[:3]]
+            if hasattr(x, "shape") and x.ndim == 3
+            and x.shape[:2] == q.shape[:2] and x.shape[2] > heads]
     assert len(kept) == 4, kept     # q, k, v and g as given, no more
+
+
+def test_heads_side_by_side_is_the_four_axis_form_bit_for_bit():
+    """``kda_fed`` on ``[B, T, H * d]`` as given (an identity feed) against
+    ``kda`` on the same numbers by head, at a sequence that is no whole
+    number of chunks and a v wider than k: the values, the final state and
+    all five gradients are equal to the bit — ``kda`` is a reshape round
+    the flat path and nothing else."""
+    args = operands(6, seq=90, heads=3, d_k=16, d_v=48)
+    flat = (*map(_flat, args[:4]), args[4])
+    weight = jax.random.normal(jax.random.PRNGKey(8), (1, 90, 3, 48))
+
+    def all_of(fn, args, weight):
+        return jax.jit(lambda *a: (fn(*a), _gradients(fn, a, weight)))(*args)
+
+    (o, state), got = all_of(
+        lambda *a: kda_ops.kda_fed(kda_ops._as_given, *a), flat, _flat(weight))
+    (want_o, want_state), want = all_of(kda, args, weight)
+    assert o.shape == (1, 90, 3 * 48)
+    np.testing.assert_array_equal(o, _flat(want_o))
+    np.testing.assert_array_equal(state, want_state)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.ndim == 3, name
+        np.testing.assert_array_equal(a, b.reshape(a.shape), err_msg=name)

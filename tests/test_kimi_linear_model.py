@@ -1,7 +1,9 @@
 """``models.kimi_linear``: the layout ``from_config`` gives the published
 pattern, the kernels against the written-out backends (the chunked delta
 rule against the token-by-token scan, flash against dense attention), the
-causal convolution against its definition, the 32 shares of an expert layer
+causal convolution against its definition, the seeded parameter tree pinned,
+what feeds the delta rule and what normalises its output with the heads side
+by side against both written out by head, the 32 shares of an expert layer
 adding up to the uncut one at this model's router, the scopes in a compiled
 step, ``kda_stats`` as gauges, and the model through the data-parallel step
 on two devices."""
@@ -106,6 +108,77 @@ def test_causal_conv_against_its_definition():
     moved = np.asarray(kimi_linear.causal_conv(
         jnp.asarray(x).at[0, 6].add(1.0), taps))
     assert np.abs(moved - out)[0, :6].max() == 0.0
+
+
+def test_the_parameter_tree_is_what_it_was(toy):
+    """Leaf paths, shapes and the seeded values, pinned to the tree before
+    the mixer's tensors went ``[B, T, H * d]`` (PR 36): the benchmark's
+    seeded weights, its leaf count and its limits depend on it."""
+    import hashlib
+
+    leaves = jax.tree_util.tree_leaves_with_path(toy[1])
+    digest = hashlib.sha256()
+    for path, leaf in leaves:
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(str(leaf.shape).encode())
+        digest.update(np.asarray(leaf).tobytes())
+    assert len(leaves) == 61
+    mixer = toy[1]["block_0"]["kda"]
+    assert sorted(mixer) == [
+        "beta", "conv_k", "conv_q", "conv_v", "decay_a", "decay_b",
+        "decay_bias", "decay_rate", "gate_a", "gate_b", "key", "out",
+        "out_norm", "query", "value"]
+    assert list(mixer["out_norm"]) == ["scale"]
+    assert digest.hexdigest() == ("7073841aacfad30043ad0f917c34addae636066a"
+                                  "7d26bc9252def0d79b8c8a9f")
+
+
+def test_heads_side_by_side_against_heads_on_an_axis():
+    """``_conditioned`` and the output norm keep ``[B, T, H * d]`` and sum
+    a head's channels where they lie; written out with the heads on an axis
+    of their own, as the model had them, the numbers are the same in
+    float32 — values and the gradient of every argument."""
+    import flax.linen as nn
+
+    heads, d, seq = 3, 16, 10
+    rng = np.random.default_rng(3)
+    normal = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), jnp.float32)
+    args = (*(normal(2, seq, heads * d) for _ in range(4)),
+            normal(2, seq, heads), tuple(normal(4, heads * d) / 2
+                                         for _ in range(3)),
+            normal(heads), normal(heads * d))
+    split = lambda a: a.reshape(*a.shape[:2], heads, -1)  # noqa: E731
+
+    def by_head(q, k, v, raw, write, taps, rate, bias):
+        unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+        q, k, v = (split(nn.silu(kimi_linear.causal_conv(a, t)))
+                   for a, t in zip((q, k, v), taps))
+        g = -jnp.exp(rate)[:, None] * split(jax.nn.softplus(raw + bias))
+        return unit(q), unit(k), v, g, nn.sigmoid(write)
+
+    def flat(*a):
+        *fed, beta = kimi_linear._conditioned(*a, heads=heads,
+                                              dtype=jnp.float32)
+        assert all(x.shape == (2, seq, heads * d) for x in fed)
+        return (*map(split, fed), beta)
+
+    weights = [normal(*x.shape) for x in by_head(*args)]
+    loss = lambda fn: lambda *a: sum(  # noqa: E731
+        jnp.sum(w * x) for w, x in zip(weights, fn(*a)))
+    for got, want in zip(flat(*args), by_head(*args)):
+        np.testing.assert_allclose(got, want, atol=1e-6)
+    for got, want in zip(*(jax.tree_util.tree_leaves(jax.grad(
+            loss(fn), argnums=tuple(range(8)))(*args))
+            for fn in (flat, by_head))):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # the output norm against flax's over the last axis, one scale shared
+    o, scale = normal(2, seq, heads * d), {"scale": 1.0 + normal(d) / 4}
+    want = nn.RMSNorm(epsilon=1e-5).apply({"params": scale}, split(o))
+    got = kimi_linear._HeadRMSNorm(heads, 1e-5, jnp.float32).apply(
+        {"params": scale}, o)
+    np.testing.assert_allclose(split(got), want, atol=1e-6)
 
 
 def test_kernels_and_written_out_backends_agree_and_remat_changes_nothing(

@@ -29,6 +29,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import scopes
 from .laguna import (_INIT, ATTENTION_BACKENDS, ExpertLayer, GatedMLP,
                      dense_attention)
 
@@ -167,15 +168,17 @@ class KDAMixer(nn.Module):
         heads, dh = self.num_heads, self.head_dim
         width = heads * dh
         with jax.named_scope("hvd.kda"):
-            q, k, v = (_dense(width, name, self.dtype)(x)
-                       for name in ("query", "key", "value"))
+            with jax.named_scope(scopes.MIXER_PROJ):
+                q, k, v = (_dense(width, name, self.dtype)(x)
+                           for name in ("query", "key", "value"))
             taps = tuple(self.param(name, _taps_init, (self.conv_size, width))
                          for name in ("conv_q", "conv_k", "conv_v"))
             rate = self.param("decay_rate", _decay_rate_init, (heads,))
             bias = self.param("decay_bias", _decay_bias_init, (width,))
-            raw = _dense(width, "decay_b", self.dtype)(
-                _dense(dh, "decay_a", self.dtype)(x))
-            write = _dense(heads, "beta", self.dtype)(x)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                raw = _dense(width, "decay_b", self.dtype)(
+                    _dense(dh, "decay_a", self.dtype)(x))
+                write = _dense(heads, "beta", self.dtype)(x)
             feed = functools.partial(_conditioned, heads=heads,
                                      dtype=self.dtype)
             projected = (q, k, v, raw, write, taps, rate, bias)
@@ -194,11 +197,13 @@ class KDAMixer(nn.Module):
                 self.sow("kda_stats", "mean_decay",
                          jnp.mean(jnp.exp(feed(*projected)[3])))
                 self.sow("kda_stats", "state_max", jnp.max(jnp.abs(state)))
-            gate = _dense(width, "gate_b", self.dtype)(
-                _dense(dh, "gate_a", self.dtype)(x))
+            with jax.named_scope(scopes.MIXER_PROJ):
+                gate = _dense(width, "gate_b", self.dtype)(
+                    _dense(dh, "gate_a", self.dtype)(x))
             o = _HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
                 o.astype(self.dtype)) * nn.sigmoid(gate)
-            return _dense(x.shape[-1], "out", self.dtype)(o)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                return _dense(x.shape[-1], "out", self.dtype)(o)
 
 
 class LatentAttention(nn.Module):
@@ -224,16 +229,18 @@ class LatentAttention(nn.Module):
                              f" got {self.attention!r}")
         heads = self.num_heads
         with jax.named_scope("hvd.mla"):
-            q = _dense((heads, self.nope_dim + self.rope_dim), "query",
-                       self.dtype)(x)
-            latent, k_pe = jnp.split(
-                _dense(self.kv_rank + self.rope_dim, "kv_a", self.dtype)(x),
-                [self.kv_rank], axis=-1)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                q = _dense((heads, self.nope_dim + self.rope_dim), "query",
+                           self.dtype)(x)
+                down = _dense(self.kv_rank + self.rope_dim, "kv_a",
+                              self.dtype)(x)
+            latent, k_pe = jnp.split(down, [self.kv_rank], axis=-1)
             latent = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                                 name="kv_norm")(latent)
-            k_nope, v = jnp.split(
-                _dense((heads, self.nope_dim + self.v_dim), "kv_b",
-                       self.dtype)(latent), [self.nope_dim], axis=-1)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                up = _dense((heads, self.nope_dim + self.v_dim), "kv_b",
+                            self.dtype)(latent)
+            k_nope, v = jnp.split(up, [self.nope_dim], axis=-1)
             k = jnp.concatenate([k_nope, jnp.broadcast_to(
                 k_pe[:, :, None, :], (*k_nope.shape[:3], self.rope_dim))],
                 axis=-1)
@@ -244,8 +251,10 @@ class LatentAttention(nn.Module):
                     out = flash_attention(q, k, v, causal=True)
                 else:
                     out = dense_attention(q, k, v)
-            return _dense(x.shape[-1], "out", self.dtype, axis=(-2, -1))(
-                out.astype(self.dtype))
+            out = out.astype(self.dtype)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                return _dense(x.shape[-1], "out", self.dtype,
+                              axis=(-2, -1))(out)
 
 
 class KimiBlock(nn.Module):
@@ -263,18 +272,24 @@ class KimiBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        norm = lambda name: nn.RMSNorm(  # noqa: E731
-            epsilon=self.eps, dtype=self.dtype, name=name)
-        h = norm("ln_attn")(x)
-        if self.mixer == "kda":
-            x = x + KDAMixer(eps=self.eps, dtype=self.dtype, name="kda",
-                             **self.kda)(h)
-        else:
-            x = x + LatentAttention(eps=self.eps, dtype=self.dtype,
-                                    name="mla", **self.mla)(h)
-        h = norm("ln_mlp")(x)
+        def norm(name, x):
+            with jax.named_scope(scopes.NORM):
+                return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                  name=name)(x)
+
+        h = norm("ln_attn", x)
+        with jax.named_scope(scopes.MIXER):
+            if self.mixer == "kda":
+                x = x + KDAMixer(eps=self.eps, dtype=self.dtype, name="kda",
+                                 **self.kda)(h)
+            else:
+                x = x + LatentAttention(eps=self.eps, dtype=self.dtype,
+                                        name="mla", **self.mla)(h)
+        h = norm("ln_mlp", x)
         if self.dense_width is not None:
-            return x + GatedMLP(self.dense_width, self.dtype, name="mlp")(h)
+            with jax.named_scope(scopes.MLP):
+                return x + GatedMLP(self.dense_width, self.dtype,
+                                    name="mlp")(h)
         return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
 
 
@@ -356,8 +371,9 @@ class KimiLinearLM(nn.Module):
         if len(self.mixers) != len(self.mlp_layer_types):
             raise ValueError("mixers and mlp_layer_types must be equally "
                              "long")
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     embedding_init=_INIT, name="tok_embed")(tokens)
+        with jax.named_scope(scopes.EMBED):
+            x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                         embedding_init=_INIT, name="tok_embed")(tokens)
         block_cls = nn.remat(KimiBlock) if self.remat else KimiBlock
         kda = dict(num_heads=self.num_heads, head_dim=self.kda_head_dim,
                    conv_size=self.conv_size, kda=self.kda)
@@ -376,8 +392,11 @@ class KimiLinearLM(nn.Module):
                 dtype=self.dtype,
                 dense_width=self.dense_width if mlp == "dense" else None,
                 name=f"block_{i}")(x)
-        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                       name="ln_final")(x)
-        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                          kernel_init=_INIT, name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope(scopes.NORM):
+            x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                           name="ln_final")(x)
+        with jax.named_scope(scopes.HEAD):
+            logits = nn.Dense(self.vocab_size, use_bias=False,
+                              dtype=jnp.float32, kernel_init=_INIT,
+                              name="lm_head")(x)
+            return logits.astype(jnp.float32)

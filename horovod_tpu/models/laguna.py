@@ -33,6 +33,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import scopes
+
 ATTENTION_BACKENDS = ("flash", "dense")
 # rows the expert layer's first, unconditional pass has room for, over the
 # rows an even router would send to the held experts. The gather, the masks
@@ -148,9 +150,10 @@ class GroupedAttention(nn.Module):
                              f" got {self.attention!r}")
 
         def heads(n, name):
-            return nn.DenseGeneral((n, self.head_dim), use_bias=False,
-                                   dtype=self.dtype, kernel_init=_INIT,
-                                   name=name)(x)
+            with jax.named_scope(scopes.MIXER_PROJ):
+                return nn.DenseGeneral((n, self.head_dim), use_bias=False,
+                                       dtype=self.dtype, kernel_init=_INIT,
+                                       name=name)(x)
 
         q = self.rotary(heads(self.num_heads, "query"), positions)
         k = self.rotary(heads(self.num_kv_heads, "key"), positions)
@@ -161,12 +164,14 @@ class GroupedAttention(nn.Module):
             out = flash_attention(q, k, v, causal=True, window=self.window)
         else:
             out = dense_attention(q, k, v, self.window)
-        gate = nn.Dense(self.num_heads, use_bias=False, dtype=self.dtype,
-                        kernel_init=_INIT, name="gate")(x)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            gate = nn.Dense(self.num_heads, use_bias=False, dtype=self.dtype,
+                            kernel_init=_INIT, name="gate")(x)
         out = out.astype(self.dtype) * nn.sigmoid(gate)[..., None]
-        return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
-                               dtype=self.dtype, kernel_init=_INIT,
-                               name="out")(out)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            return nn.DenseGeneral(x.shape[-1], axis=(-2, -1),
+                                   use_bias=False, dtype=self.dtype,
+                                   kernel_init=_INIT, name="out")(out)
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -368,13 +373,20 @@ class LagunaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        norm = lambda name: nn.RMSNorm(  # noqa: E731
-            epsilon=self.eps, dtype=self.dtype, name=name)
-        x = x + GroupedAttention(dtype=self.dtype, name="attn", **self.attn)(
-            norm("ln_attn")(x), positions)
-        h = norm("ln_mlp")(x)
+        def norm(name, x):
+            with jax.named_scope(scopes.NORM):
+                return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                  name=name)(x)
+
+        h = norm("ln_attn", x)
+        with jax.named_scope(scopes.MIXER):
+            x = x + GroupedAttention(dtype=self.dtype, name="attn",
+                                     **self.attn)(h, positions)
+        h = norm("ln_mlp", x)
         if self.dense_width is not None:
-            return x + GatedMLP(self.dense_width, self.dtype, name="mlp")(h)
+            with jax.named_scope(scopes.MLP):
+                return x + GatedMLP(self.dense_width, self.dtype,
+                                    name="mlp")(h)
         return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
 
 
@@ -459,8 +471,9 @@ class LagunaLM(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), tokens.shape)
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     embedding_init=_INIT, name="tok_embed")(tokens)
+        with jax.named_scope(scopes.EMBED):
+            x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                         embedding_init=_INIT, name="tok_embed")(tokens)
         block_cls = nn.remat(LagunaBlock) if self.remat else LagunaBlock
         for i, (kind, heads, mlp) in enumerate(zip(
                 self.layer_types, self.heads_per_layer,
@@ -480,8 +493,11 @@ class LagunaLM(nn.Module):
                 attn=attn, experts=experts, eps=self.eps, dtype=self.dtype,
                 dense_width=self.dense_width if mlp == "dense" else None,
                 name=f"block_{i}")(x, positions)
-        x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                       name="ln_final")(x)
-        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                          kernel_init=_INIT, name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope(scopes.NORM):
+            x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                           name="ln_final")(x)
+        with jax.named_scope(scopes.HEAD):
+            logits = nn.Dense(self.vocab_size, use_bias=False,
+                              dtype=jnp.float32, kernel_init=_INIT,
+                              name="lm_head")(x)
+            return logits.astype(jnp.float32)

@@ -15,9 +15,18 @@ from functools import partial
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from . import scopes
+
 ModuleDef = Any
+
+
+def _normed(norm: ModuleDef, x, **fields):
+    """``norm(**fields)(x)``, the call under the component scope."""
+    with jax.named_scope(scopes.NORM):
+        return norm(**fields)(x)
 
 
 class ResNetBlock(nn.Module):
@@ -33,14 +42,14 @@ class ResNetBlock(nn.Module):
     def __call__(self, x):
         residual = x
         y = self.conv(self.filters, (3, 3), self.strides)(x)
-        y = self.norm()(y)
+        y = _normed(self.norm, y)
         y = self.act(y)
         y = self.conv(self.filters, (3, 3))(y)
-        y = self.norm(scale_init=nn.initializers.zeros_init())(y)
+        y = _normed(self.norm, y, scale_init=nn.initializers.zeros_init())
         if residual.shape != y.shape:
             residual = self.conv(self.filters, (1, 1), self.strides,
                                  name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
+            residual = _normed(self.norm, residual, name="norm_proj")
         return self.act(residual + y)
 
 
@@ -57,17 +66,17 @@ class BottleneckResNetBlock(nn.Module):
     def __call__(self, x):
         residual = x
         y = self.conv(self.filters, (1, 1))(x)
-        y = self.norm()(y)
+        y = _normed(self.norm, y)
         y = self.act(y)
         y = self.conv(self.filters, (3, 3), self.strides)(y)
-        y = self.norm()(y)
+        y = _normed(self.norm, y)
         y = self.act(y)
         y = self.conv(self.filters * 4, (1, 1))(y)
-        y = self.norm(scale_init=nn.initializers.zeros_init())(y)
+        y = _normed(self.norm, y, scale_init=nn.initializers.zeros_init())
         if residual.shape != y.shape:
             residual = self.conv(self.filters * 4, (1, 1), self.strides,
                                  name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
+            residual = _normed(self.norm, residual, name="norm_proj")
         return self.act(residual + y)
 
 
@@ -87,7 +96,7 @@ class ResNet(nn.Module):
         x = x.astype(self.dtype)
         x = conv(self.num_filters, (7, 7), (2, 2),
                  padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        x = norm(name="bn_init")(x)
+        x = _normed(norm, x, name="bn_init")
         x = self.act(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_size in enumerate(self.stage_sizes):
@@ -96,9 +105,10 @@ class ResNet(nn.Module):
                 x = self.block_cls(self.num_filters * 2 ** i,
                                    strides=strides, conv=conv, norm=norm,
                                    act=self.act)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-        return x.astype(jnp.float32)
+        with jax.named_scope(scopes.HEAD):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            return x.astype(jnp.float32)
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=ResNetBlock)

@@ -23,6 +23,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import scopes
+
 ATTENTION_BACKENDS = ("dense", "flash", "ring", "ulysses")
 
 
@@ -47,9 +49,10 @@ class CausalSelfAttention(nn.Module):
         head_dim = d_model // self.num_heads
         dense = partial(nn.DenseGeneral, dtype=self.dtype,
                         features=(self.num_heads, head_dim))
-        q = dense(name="query")(x)
-        k = dense(name="key")(x)
-        v = dense(name="value")(x)  # each [B, T, H, Dh]
+        with jax.named_scope(scopes.MIXER_PROJ):
+            q = dense(name="query")(x)
+            k = dense(name="key")(x)
+            v = dense(name="value")(x)  # each [B, T, H, Dh]
 
         if self.attention == "flash":
             from ..ops.pallas_attention import flash_attention
@@ -73,8 +76,9 @@ class CausalSelfAttention(nn.Module):
             out = dense_attention(q, k, v, causal=True)
         del positions  # causal order is positional by construction
         out = out.astype(self.dtype)
-        return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
-                               name="out")(out)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
+                                   name="out")(out)
 
 
 class TransformerBlock(nn.Module):
@@ -86,15 +90,20 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        h = nn.LayerNorm(dtype=self.dtype, name="ln_attn")(x)
-        x = x + CausalSelfAttention(
-            num_heads=self.num_heads, dtype=self.dtype,
-            attention=self.attention, seq_axis=self.seq_axis,
-            name="attn")(h, positions)
-        h = nn.LayerNorm(dtype=self.dtype, name="ln_mlp")(x)
-        h = nn.Dense(self.d_ff, dtype=self.dtype, name="mlp_in")(h)
-        h = nn.gelu(h)
-        return x + nn.Dense(x.shape[-1], dtype=self.dtype, name="mlp_out")(h)
+        with jax.named_scope(scopes.NORM):
+            h = nn.LayerNorm(dtype=self.dtype, name="ln_attn")(x)
+        with jax.named_scope(scopes.MIXER):
+            x = x + CausalSelfAttention(
+                num_heads=self.num_heads, dtype=self.dtype,
+                attention=self.attention, seq_axis=self.seq_axis,
+                name="attn")(h, positions)
+        with jax.named_scope(scopes.NORM):
+            h = nn.LayerNorm(dtype=self.dtype, name="ln_mlp")(x)
+        with jax.named_scope(scopes.MLP):
+            h = nn.Dense(self.d_ff, dtype=self.dtype, name="mlp_in")(h)
+            h = nn.gelu(h)
+            return x + nn.Dense(x.shape[-1], dtype=self.dtype,
+                                name="mlp_out")(h)
 
 
 class TransformerLM(nn.Module):
@@ -127,10 +136,11 @@ class TransformerLM(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), tokens.shape)
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     name="tok_embed")(tokens)
-        x = x + nn.Embed(self.max_seq_len, self.d_model, dtype=self.dtype,
-                         name="pos_embed")(positions)
+        with jax.named_scope(scopes.EMBED):
+            x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                         name="tok_embed")(tokens)
+            x = x + nn.Embed(self.max_seq_len, self.d_model,
+                             dtype=self.dtype, name="pos_embed")(positions)
         block_cls = nn.remat(TransformerBlock) if self.remat \
             else TransformerBlock
         for i in range(self.num_layers):
@@ -138,15 +148,18 @@ class TransformerLM(nn.Module):
                 num_heads=self.num_heads, d_ff=self.d_ff, dtype=self.dtype,
                 attention=self.attention, seq_axis=self.seq_axis,
                 name=f"block_{i}")(x, positions)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
-        logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope(scopes.NORM):
+            x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
+        with jax.named_scope(scopes.HEAD):
+            logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
+                              name="lm_head")(x)
+            return logits.astype(jnp.float32)
 
 
 def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Next-token cross entropy (shift-by-one), mean over B and T-1."""
     import optax
 
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits[:, :-1], tokens[:, 1:]).mean()
+    with jax.named_scope(scopes.HEAD):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1], tokens[:, 1:]).mean()

@@ -10,6 +10,10 @@ edit changed the program the chip runs before spending chip time on it.
 ``input_output_alias`` stay. A Mosaic call's payload is MLIR bytecode with
 locations inside: it is replaced by the digest of its printed form without
 them. Nothing runs: equal text is equal programs, not equal times.
+
+``--strip-metadata`` drops every instruction's ``metadata={...}`` too, so
+that an edit which only renames or adds scopes (docs/tracing.md, "Scopes in
+a compiled step") gives equal text: the names changed and nothing else did.
 """
 
 import base64
@@ -20,6 +24,7 @@ import sys
 
 _LOCATION_TABLES = re.compile(
     r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(.+\n)*", re.M)
+_METADATA = re.compile(r',? metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
 
 
 def without_source_locations(hlo_text: str) -> str:
@@ -29,6 +34,12 @@ def without_source_locations(hlo_text: str) -> str:
     two traces of one program in one process."""
     return re.sub(r" stack_frame_id=\d+", "",
                   _LOCATION_TABLES.sub("", hlo_text))
+
+
+def without_metadata(hlo_text: str) -> str:
+    """The text less each instruction's ``metadata={...}``: ``op_name`` (the
+    scopes it was traced under), ``op_type`` and the source position."""
+    return _METADATA.sub("", hlo_text)
 
 
 def _payload_digest(match) -> str:
@@ -56,10 +67,13 @@ def main() -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     topology = topologies.get_topology_desc(platform="tpu",
                                             topology_name=aot.TOPOLOGY)
-    text = aot.compile_cell(cells.Spec().cell(sys.argv[1]),
-                            topology.devices).as_text()
+    cell = next(a for a in sys.argv[1:] if not a.startswith("--"))
+    text = without_source_locations(aot.compile_cell(
+        cells.Spec().cell(cell), topology.devices).as_text())
+    if "--strip-metadata" in sys.argv[1:]:
+        text = without_metadata(text)
     sys.stdout.write(re.sub(r'"body":"([A-Za-z0-9+/=]+)"', _payload_digest,
-                            without_source_locations(text)))
+                            text))
 
 
 if __name__ == "__main__":

@@ -293,6 +293,31 @@ class KimiBlock(nn.Module):
         return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
 
 
+def _keep_policy():
+    """The checkpoint policy of a recomputed block: it keeps its mixer
+    kernel's outputs — ``kda_fwd``'s ``o`` and chunk-starting states,
+    ``flash_mla_fwd``'s ``o`` and log-sum-exp, named where the two forward
+    rules make them — and recomputes everything else: norms, projections,
+    gate, MLP, expert layer. With its outputs kept the forward Mosaic call
+    is dead code in the recomputed block; the kernels' backward paths are
+    unchanged (``kda_bwd`` still takes the feed's run there). At
+    16,384 tokens x 32 heads a KDA layer holds ``o`` 134 MB + starts 268 MB
+    and the latent layer ``o`` 134 MB + lse 2 MB from its forward to its
+    backward pass, for one run of an 11 ms and a 29 ms kernel each.
+
+    Every block keeps: ``python3 -m chipbench.aot --workload
+    kimi_linear_16k_1chip`` totals 13.436 GB with the five of ``kda, kda,
+    kda, mla, kda`` keeping, under the 13.59 GB the chip leaves the step
+    (the latent block and the last 3 / 2 / 0 KDA blocks: 13.034 / 12.631 /
+    12.227; none, the default policy: 12.117). Where a stack did not fit,
+    the blocks nearest the output should keep first: the backward pass
+    frees their residuals first, so they are never dearer than one below."""
+    from ..ops import kda, pallas_attention
+
+    return jax.checkpoint_policies.save_only_these_names(
+        *kda.KEPT_NAMES, *pallas_attention.KEPT_NAMES)
+
+
 class KimiLinearLM(nn.Module):
     """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
     Layer ``i`` mixes with ``mixers[i]`` (``"kda"`` or ``"mla"``) and its
@@ -321,8 +346,10 @@ class KimiLinearLM(nn.Module):
     dtype: Any = jnp.bfloat16
     attention: str = "flash"    # "dense": the tests' written-out attention
     kda: str = "chunked"        # "recurrent": the tests' token-by-token scan
-    # jax.checkpoint each block: only the block-boundary activations are
-    # stored, a block's interior is recomputed in backward
+    # jax.checkpoint each block: only the block-boundary activations and the
+    # mixer kernel's outputs are stored (``_keep_policy``: 0.40 GB a KDA
+    # layer, 0.14 GB a latent one at 16,384 tokens x 32 heads); the rest of
+    # a block's interior is recomputed in backward
     remat: bool = False
 
     @classmethod
@@ -374,7 +401,8 @@ class KimiLinearLM(nn.Module):
         with jax.named_scope(scopes.EMBED):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                          embedding_init=_INIT, name="tok_embed")(tokens)
-        block_cls = nn.remat(KimiBlock) if self.remat else KimiBlock
+        block_cls = nn.remat(KimiBlock, policy=_keep_policy()) \
+            if self.remat else KimiBlock
         kda = dict(num_heads=self.num_heads, head_dim=self.kda_head_dim,
                    conv_size=self.conv_size, kda=self.kda)
         mla = dict(num_heads=self.num_heads, nope_dim=self.nope_dim,

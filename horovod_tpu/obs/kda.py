@@ -8,7 +8,7 @@ forgets or blows up. A training step does not carry the collection; a
 caller who wants the numbers applies the model with
 ``mutable=["kda_stats"]`` and hands the collection to :func:`publish`.
 
-And three gauges of a *compiled* step's text, set by
+And four gauges of a *compiled* step's text, set by
 :func:`record_scan_program` (whoever holds the compiled step calls it, as
 with ``obs.compiles.record_exchange_collectives``): whether the recurrence
 engaged its kernels. ``ops.kda`` forms a chunk's operands inside
@@ -17,7 +17,9 @@ engaged its kernels. ``ops.kda`` forms a chunk's operands inside
 And whether the layout held: the kernels read q, k, v, g as ``[B, T, H *
 d]`` and the layer keeps them so from its projections on; a tensor that is
 taken to ``[B, T, H, d]`` on the way is copied whole on the TPU, and shows
-here as a relayout.
+here as a relayout. And whether a recomputed block kept its mixer kernel's
+outputs (``models.kimi_linear``): a forward kernel the backward pass runs
+again shows here as a call beyond the one a layer needs.
 """
 
 from __future__ import annotations
@@ -57,7 +59,18 @@ _RELAYOUTS = _metrics().gauge(
     "layer keeps [B, T, H * d] from its projections to the kernels and back)",
     labels=("program",))
 
-_KERNEL_CALL = re.compile(r"%(kda_\w+?)(?:\.\d+)? = .*\bcustom-call\(")
+_RERUNS = _metrics().gauge(
+    "horovod_remat_forward_reruns",
+    "Calls of the mixers' forward kernels (kda_fwd, flash_mla_fwd) in a "
+    "compiled step's text beyond one a layer, a layer being one call of the "
+    "kernel's backward (0 where every recomputed block keeps its mixer "
+    "kernel's outputs for its backward pass)",
+    labels=("program",))
+
+# a mixer's forward kernel and the backward kernel that runs once a layer
+_MIXER_KERNELS = {"kda_fwd": "kda_bwd", "flash_mla_fwd": "flash_mla_bwd_dq"}
+_KERNEL_CALL = re.compile(
+    r"%((?:kda|flash_mla)_\w+?)(?:\.\d+)? = .*\bcustom-call\(")
 _OPERAND_SHAPES = re.compile(
     r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
 _INSTRUCTION = re.compile(
@@ -87,19 +100,22 @@ def _relayouts(entry_lines, q_shape, beta_shape) -> int:
 
 
 def record_scan_program(program: str, hlo_text: str) -> tuple:
-    """``(loops, {kernel: calls}, relayouts)`` of a compiled step's text
-    (``compiled.as_text()``), set on the three gauges under ``program``:
+    """``(loops, {kernel: calls}, relayouts, reruns)`` of a compiled step's
+    text (``compiled.as_text()``), set on the four gauges under ``program``:
     the ``while`` instructions whose ``op_name`` holds ``hvd.kda.scan``;
-    the ``tpu_custom_call``s named ``kda_*`` by kernel; and the entry
+    the ``tpu_custom_call``s named ``kda_*`` by kernel; the entry
     computation's ``copy``, ``reshape`` and ``transpose`` instructions (a
     ``bitcast`` moves nothing) whose result holds at least as many elements
     as the kernels' q and whose ``op_name`` holds ``hvd.kda`` or, where it
-    has none (the compiler's own copies), whose shape ends in ``[H, d]``.
-    On ``kimi_linear_16k_1chip`` that is 0, ``{"kda_fwd": 8, "kda_bwd":
-    4}`` (four layers, the forward run again where a block is recomputed)
-    and 0; before the kernels formed their operands it was 12 loops, and
-    while the layer held its tensors ``[B, T, H, d]`` 100 relayouts, 47 GB
-    moved a step (PERF.md §6, PR 31 and PR 36)."""
+    has none (the compiler's own copies), whose shape ends in ``[H, d]``;
+    and the calls of ``kda_fwd`` and ``flash_mla_fwd`` beyond one a layer
+    (as many as ``kda_bwd`` and ``flash_mla_bwd_dq`` have calls). On
+    ``kimi_linear_16k_1chip`` that is 0, ``{"kda_fwd": 4, "kda_bwd": 4}``,
+    0 and 0: every recomputed block keeps its mixer kernel's outputs. While
+    a recomputed block ran its forward kernel again it was 8 ``kda_fwd``
+    and 5 reruns (PR 38); before the kernels formed their operands 12
+    loops, and while the layer held its tensors ``[B, T, H, d]`` 100
+    relayouts, 47 GB moved a step (PERF.md §6, PR 31 and PR 36)."""
     loops = 0
     calls = collections.Counter()
     shapes = None
@@ -111,18 +127,24 @@ def record_scan_program(program: str, hlo_text: str) -> tuple:
             if m is not None:
                 calls[m.group(1)] += 1
                 given = _OPERAND_SHAPES.search(line)
-                if shapes is None and given is not None:
+                if shapes is None and given is not None \
+                        and m.group(1).startswith("kda_"):
                     shapes = [_dims(dims) for dims in re.findall(
                         r"\w+\[([\d,]*)\]", given.group(1))]
     entry = hlo_text.partition("\nENTRY ")[2].partition("\n}")[0]
     relayouts = 0 if shapes is None else _relayouts(
         entry.splitlines(), shapes[0], shapes[4])
+    reruns = sum(calls[forward] - calls[backward]
+                 for forward, backward in _MIXER_KERNELS.items())
+    kda_calls = {kernel: n for kernel, n in calls.items()
+                 if kernel.startswith("kda_")}
     _SCAN_LOOPS.labels(program=program).set(loops)
-    for kernel in {"kda_fwd", "kda_bwd", *calls}:
+    for kernel in {"kda_fwd", "kda_bwd", *kda_calls}:
         _KERNEL_CALLS.labels(program=program, kernel=kernel).set(
             calls[kernel])
     _RELAYOUTS.labels(program=program).set(relayouts)
-    return loops, dict(calls), relayouts
+    _RERUNS.labels(program=program).set(reruns)
+    return loops, kda_calls, relayouts, reruns
 
 
 def publish(kda_stats) -> dict:

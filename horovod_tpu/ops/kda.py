@@ -59,7 +59,12 @@ chunk-boundary states — 0.54 GB a layer at 16,384 tokens x 32 heads x 128^2
 — and nothing of a chunk's interior; the saved states are in the operands'
 type, which is all the backward's products take of them. ``kda_fed(feed,
 *args)`` keeps still less: the ``args`` of whatever makes q, k, v, g, beta
-(a layer's projections), which its backward runs again.
+(a layer's projections), which its backward runs again. Under a
+``jax.checkpoint`` that recomputes the layer even those are recomputed, and
+``kda_fwd`` with them — unless its policy saves ``KEPT_NAMES``, the names
+the forward rule gives the kernel's ``o`` (134 MB) and starting states (268
+MB, bfloat16): then the recomputation does not make the call
+(``models.kimi_linear._keep_policy``).
 
 The layout. The kernels read and write q, k, v, g and their gradients as
 ``[B, T, H * d]``, a head's channels side by side (``_specs``: blocks of
@@ -84,12 +89,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_attention import _sds
 
 CHUNK = 64
+# what ``kda_fwd`` leaves that a recomputed block would run it again for:
+# its output and the chunks' starting states, under these checkpoint names
+KEPT_NAMES = ("kda.o", "kda.starts")
 _SUB = 16            # rows of a sub-block of A and B
 _HEADS_A_STEP = 4    # independent chains a grid step interleaves
 _HI = jax.lax.Precision.HIGHEST
@@ -564,6 +573,7 @@ def _kda(feed, scale, chunk, interpret, *args):
 
 def _kda_fwd(feed, scale, chunk, interpret, *args):
     o, final, starts = _forward(*feed(*args), scale, chunk, interpret, True)
+    o, starts = map(checkpoint_name, (o, starts), KEPT_NAMES)
     return (o, final), (args, starts)
 
 
@@ -603,7 +613,14 @@ def kda_fed(feed, *args, scale: Optional[float] = None, chunk: int = CHUNK,
     again there, and its transpose after the chain's. At 16,384 tokens x 32
     heads x 128 that is 1.3 GB a layer less held between the forward and
     the backward pass. ``feed`` is a function of arrays alone,
-    differentiable in all of them."""
+    differentiable in all of them.
+
+    The forward rule names ``o`` and the chunks' starting states
+    (``KEPT_NAMES``) — an identity without a ``jax.checkpoint`` policy that
+    saves them; with one (``models.kimi_linear``) a recomputed layer holds
+    those 0.40 GB from its forward to its backward pass and does not run
+    ``kda_fwd`` a second time for them (``feed`` still runs in this
+    function's backward)."""
     if chunk % _SUB:
         raise ValueError(f"chunk must be a multiple of {_SUB}, got {chunk}")
     return _kda(feed, scale, chunk, interpret, *args)

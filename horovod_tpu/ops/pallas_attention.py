@@ -97,6 +97,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -104,6 +105,11 @@ from ..obs.registry import registry as _metrics
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _LANES = 128  # TPU vreg lane count: the trailing axis of column statistics
+# what the forward kernel leaves that a recomputed block would run it again
+# for: its output and the rows' log-sum-exp, under these checkpoint names. A
+# name is an identity that lowers to nothing; a ``jax.checkpoint`` whose
+# policy saves them (``models.kimi_linear``) keeps both for its backward pass
+KEPT_NAMES = ("flash.o", "flash.lse")
 # VMEM a kernel's resident major blocks may take (both pipeline buffers of
 # both operands, and the forward's scores): half of the 16 MiB a v5e kernel
 # gets by default, the rest is for the tiles' f32 temporaries
@@ -1053,6 +1059,7 @@ def _flash_fwd(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
                q_offset, window):
     o, lse = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret,
                        q_offset, window)
+    o, lse = map(checkpoint_name, (o, lse), KEPT_NAMES)
     return o, (q, k, v, o, lse)
 
 

@@ -200,7 +200,7 @@ def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
     text = jax.jit(jax.grad(lambda p, x: layer.apply(p, x).astype(
         jnp.float32).sum())).lower(params, x).compile().as_text()
     assert obs.kda.record_scan_program("toy", text) == (
-        0, {"kda_fwd": 1, "kda_bwd": 1}, 0)
+        0, {"kda_fwd": 1, "kda_bwd": 1}, 0, 0)
 
     def gauge(family, **labels):
         return next(s["value"]

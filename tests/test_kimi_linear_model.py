@@ -1,12 +1,16 @@
 """``models.kimi_linear``: the layout ``from_config`` gives the published
 pattern, the kernels against the written-out backends (the chunked delta
 rule against the token-by-token scan, flash against dense attention), the
-causal convolution against its definition, the seeded parameter tree pinned,
+causal convolution against its definition, a recomputed block keeping its
+mixer kernel's outputs and nothing else, the seeded parameter tree pinned,
 what feeds the delta rule and what normalises its output with the heads side
 by side against both written out by head, the 32 shares of an expert layer
 adding up to the uncut one at this model's router, the scopes in a compiled
 step, ``kda_stats`` as gauges, and the model through the data-parallel step
 on two devices."""
+
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +18,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import ExpertLayer, KimiLinearLM, lm_loss
-from horovod_tpu.models import kimi_linear, laguna
+from horovod_tpu.models import kimi_linear, laguna, scopes
 
 TOY = {
     "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
@@ -185,7 +189,7 @@ def test_kernels_and_written_out_backends_agree_and_remat_changes_nothing(
         toy):
     model, params, tokens = toy
 
-    def loss_and_grad(m):
+    def loss_and_grad(m, params=params):
         return jax.value_and_grad(lambda p: lm_loss(
             m.apply({"params": p}, tokens), tokens))(params)
 
@@ -198,9 +202,91 @@ def test_kernels_and_written_out_backends_agree_and_remat_changes_nothing(
         for a, b in zip(jax.tree_util.tree_leaves(grad),
                         jax.tree_util.tree_leaves(kernels[1])):
             np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-6)
+    # compiled whole, as a step is: what a recomputed block keeps of its
+    # mixer's kernel is what it would have recomputed, so nothing moves
+    plain, kept = (jax.jit(functools.partial(loss_and_grad, m))(params)
+                   for m in (model, model.clone(remat=True)))
+    for a, b in zip(*map(jax.tree_util.tree_leaves, (kept, plain))):
+        np.testing.assert_array_equal(a, b)
     for wrong in (dict(kda="scan"), dict(attention="ring")):
         with pytest.raises(ValueError, match="must be one of"):
             model.clone(**wrong).apply({"params": params}, tokens)
+
+
+def gradient_program_counts(model, params, tokens) -> collections.Counter:
+    """Of the jaxpr of the loss's gradient (``params`` may be shapes): the
+    ``pallas_call``s by name and, under ``"projections"``, the
+    ``dot_general``s traced under ``hvd.mixer.proj``. A ``cond``
+    (``lax.platform_dependent``: the kernel interpreted or compiled) counts
+    by its first branch."""
+    counts = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[eqn.params["name"]] += 1
+                continue
+            if eqn.primitive.name == "dot_general" and \
+                    scopes.MIXER_PROJ in str(eqn.source_info.name_stack):
+                counts["projections"] += 1
+            inner = list(jax.core.jaxprs_in_params(eqn.params))
+            for sub in inner[:1] if eqn.primitive.name == "cond" else inner:
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(lambda p: lm_loss(
+        model.apply({"params": p}, tokens), tokens)))(params).jaxpr)
+    return counts
+
+
+@pytest.mark.parametrize("mixer, kernel", [("kda", "kda_fwd"),
+                                           ("mla", "flash_mla_fwd")])
+def test_a_recomputed_block_keeps_its_mixer_kernels_outputs(
+        monkeypatch, mixer, kernel):
+    """Two layers of one mixer kind: the gradient program of the
+    ``remat=True`` model holds the mixer's forward kernel once a layer — as
+    the unrecomputed model's does — where ``nn.remat``'s default policy
+    runs it twice, and recomputes the rest of the block all the same: the
+    projections' products are as many as under the default policy."""
+    layers = {"kda": {"kda_layers": [1, 2], "full_attn_layers": []},
+              "mla": {"kda_layers": [], "full_attn_layers": [1, 2]}}[mixer]
+    model = KimiLinearLM.from_config(
+        dict(SMALL, num_hidden_layers=2, linear_attn_config=dict(
+            TOY["linear_attn_config"], **layers)),
+        dtype=jnp.float32, remat=True)
+    assert model.mixers == (mixer, mixer)
+    tokens = jnp.zeros((2, 96), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(1),
+                            tokens)["params"]
+    keeping = gradient_program_counts(model, params, tokens)
+    plain = gradient_program_counts(model.clone(remat=False), params, tokens)
+    monkeypatch.setattr(kimi_linear, "_keep_policy", lambda: None)
+    default = gradient_program_counts(model, params, tokens)
+    assert (plain[kernel], keeping[kernel], default[kernel]) == (2, 2, 4)
+    assert keeping["projections"] == default["projections"] \
+        > plain["projections"]
+    backward = {"kda": ["kda_bwd"],
+                "mla": ["flash_mla_bwd_dq", "flash_mla_bwd_dkv"]}[mixer]
+    assert all(c[name] == 2 for name in backward
+               for c in (plain, keeping, default))
+
+
+def test_laguna_recomputes_its_flash_forward_as_before():
+    """The fence: ``models.laguna`` has no room to keep a kernel's outputs
+    (PERF.md §7), so its ``nn.remat(LagunaBlock)`` stays with the default
+    policy and the names ``_flash_fwd`` gives its residuals save nothing:
+    two forward calls a layer, full and sliding alike."""
+    from test_laguna_model import TOY as LAGUNA_TOY
+
+    model = laguna.LagunaLM.from_config(
+        dict(LAGUNA_TOY, num_hidden_layers=2), dtype=jnp.float32, remat=True)
+    assert model.layer_types == ("full_attention", "sliding_attention")
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(1),
+                            tokens)["params"]
+    recomputed = gradient_program_counts(model, params, tokens)
+    plain = gradient_program_counts(model.clone(remat=False), params, tokens)
+    for kernel in ("flash_fwd", "flash_win_fwd"):
+        assert (plain[kernel], recomputed[kernel]) == (1, 2), kernel
 
 
 def test_nothing_sees_the_future(toy):
@@ -270,6 +356,39 @@ def test_kda_stats_become_gauges(toy):
     # a training step does not carry the collection
     assert "kda_stats" not in model.apply({"params": params}, tokens,
                                           mutable=["intermediates"])[1]
+
+
+def test_forward_kernels_run_again_become_a_gauge():
+    """``obs.kda.record_scan_program`` on a compiled step's text, cut to
+    its Mosaic calls: four KDA layers and a latent one whose recomputed
+    blocks run their forward kernel again (5 reruns), the same with the
+    first KDA block alone doing so (1), and with every block keeping (0)."""
+    from horovod_tpu import obs
+
+    def call(kernel, n):
+        return (f"  %{kernel}.{n} = (bf16[1,16384,4096]{{2,1,0}}) "
+                f"custom-call(%p.{n}), "
+                'custom_call_target="tpu_custom_call"\n')
+
+    def text(kda_fwd, flash_mla_fwd):
+        return "ENTRY %main {\n" + "".join(
+            call(kernel, n) for kernel, times in (
+                ("kda_fwd", kda_fwd), ("kda_bwd", 4),
+                ("flash_mla_fwd", flash_mla_fwd), ("flash_mla_bwd_dq", 1),
+                ("flash_mla_bwd_dkv", 1), ("expert_matmul_fwd", 48))
+            for n in range(times)) + "}\n"
+
+    for program, (kda_fwd, flash_mla_fwd), reruns in (
+            ("default", (8, 2), 5), ("all_but_one", (5, 1), 1),
+            ("keeping", (4, 1), 0)):
+        _, calls, _, counted = obs.kda.record_scan_program(
+            program, text(kda_fwd, flash_mla_fwd))
+        assert calls == {"kda_fwd": kda_fwd, "kda_bwd": 4}
+        assert counted == reruns
+    read = {s["labels"]["program"]: s["value"] for s in obs.registry()
+            .snapshot()["horovod_remat_forward_reruns"]["samples"]}
+    assert (read["default"], read["all_but_one"], read["keeping"]) \
+        == (5, 1, 0)
 
 
 def test_the_scopes_reach_the_compiled_step(toy):

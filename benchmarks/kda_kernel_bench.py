@@ -6,9 +6,13 @@
         --tree .parent --tree .
 
 Each ``--case`` is ``batch,seq,heads,head_dim`` with optional
-``,dtype=float32`` and ``,chunk=<n>``, or ``kimi``: a KDA layer's call on
+``,dtype=float32``, ``,chunk=<n>``, ``,d_v=<n>`` (values of their own
+width), ``,decay=head`` (one decay a head: the kernels are then ``gdn_fwd``
+/ ``gdn_bwd``) and ``,beta=<largest>``, or ``kimi``: a KDA layer's call on
 ``kimi_linear_16k_1chip`` (one sequence of 16,384 tokens, 32 heads of
-128). The kernels are handed q, k, v, g as ``[B, T, H * d]``, heads side by
+128), or ``olmo``: a delta-rule layer's on ``olmo_hybrid_8k_1chip`` (8,192
+tokens, 15 heads with keys 96 and values 192 wide, a decay a head, beta up
+to 2). The kernels are handed q, k, v, g as ``[B, T, H * d]``, heads side by
 side, as ``models.kimi_linear.KDAMixer`` hands them (``ops.kda.kda_fed``):
 the measured program is then the two kernels and nothing round them, and
 its per-call times are the cell's. A ``--tree`` from before PR 36 knows only
@@ -43,8 +47,9 @@ import sys
 import tempfile
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KERNELS = ("kda_fwd", "kda_bwd")
-CELLS = {"kimi": "1,16384,32,128"}
+KERNELS = ("kda_fwd", "kda_bwd", "gdn_fwd", "gdn_bwd")
+CELLS = {"kimi": "1,16384,32,128",
+         "olmo": "1,8192,15,96,d_v=192,decay=head,beta=2"}
 
 
 def parse_case(text: str) -> dict:
@@ -53,28 +58,36 @@ def parse_case(text: str) -> dict:
         text = CELLS[name] + ("," + more if more else "")
     batch, seq, heads, head_dim, *options = text.split(",")
     case = {"shape": (int(batch), int(seq), int(heads), int(head_dim)),
-            "dtype": "bfloat16", "chunk": 64}
+            "dtype": "bfloat16", "chunk": 64, "d_v": int(head_dim),
+            "decay": "channel", "beta": 1.0}
     for option in options:
         key, _, value = option.partition("=")
-        if key not in ("dtype", "chunk"):
+        if key not in ("dtype", "chunk", "d_v", "decay", "beta"):
             raise ValueError(f"case {text!r}: unknown option {key!r}")
-        case[key] = value if key == "dtype" else int(value)
+        case[key] = value if key in ("dtype", "decay") else \
+            float(value) if key == "beta" else int(value)
+    if case["decay"] not in ("channel", "head"):
+        raise ValueError(f"case {text!r}: decay is channel or head")
     return case
 
 
-def operands(shape, dtype):
-    """Unit q and k, normal v, a decay of about 0.8 a token and a sigmoid
-    write strength: what a KDA layer feeds its kernels at the start of
-    training."""
+def operands(case, dtype):
+    """Unit q and k, normal v (``d_v`` wide), a decay of about 0.8 a token —
+    a channel or a head — and a write strength of ``beta`` times a sigmoid:
+    what a delta-rule layer feeds its kernels at the start of training."""
     import jax
     import jax.numpy as jnp
 
+    shape = case["shape"]
+    values = (*shape[:3], case["d_v"])
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     q, k = (jax.random.normal(key, shape) for key in keys[:2])
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
-    v, cot = (jax.random.normal(key, shape) for key in keys[2:4])
-    g = -0.3 * jax.nn.softplus(jax.random.normal(keys[4], shape))
-    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3]))
+    v, cot = (jax.random.normal(key, values) for key in keys[2:4])
+    g = -0.3 * jax.nn.softplus(jax.random.normal(
+        keys[4], shape[:3] if case["decay"] == "head" else shape))
+    beta = case["beta"] * jax.nn.sigmoid(
+        jax.random.normal(keys[5], shape[:3]))
     return tuple(x.astype(dtype) for x in (q, k, v)), g, beta, cot
 
 
@@ -101,8 +114,7 @@ def measure(case: dict, iters: int, check: int, trace_root: str) -> dict:
     from horovod_tpu.ops import kda as ops_kda
 
     kda, kda_recurrent = ops_kda.kda, ops_kda.kda_recurrent
-    (q, k, v), g, beta, cot = operands(case["shape"],
-                                       jnp.dtype(case["dtype"]))
+    (q, k, v), g, beta, cot = operands(case, jnp.dtype(case["dtype"]))
 
     def loss(fn, cot, *args):
         return jnp.vdot(fn(*args)[0].astype(jnp.float32), cot)
@@ -118,7 +130,8 @@ def measure(case: dict, iters: int, check: int, trace_root: str) -> dict:
         as_timed, rule = (lambda x: x), chunked
     call = jax.jit(jax.value_and_grad(
         lambda *a: loss(rule, as_timed(cot), *a), argnums=(0, 1, 2, 3, 4)))
-    timed = (*map(as_timed, args[:4]), beta)
+    timed = (*map(as_timed, args[:3]),
+             g if case["decay"] == "head" else as_timed(g), beta)
     for _ in range(3):
         jax.block_until_ready(call(*timed))
     trace_dir = tempfile.mkdtemp(dir=trace_root)

@@ -71,8 +71,9 @@ def causal_conv(x, taps):
 
 # A head's channels lie side by side in the last axis, ``[B, T, heads * d]``,
 # from the projections to the delta rule's kernels and back: on the TPU that
-# form tiles (tokens, lanes) — with d = 128 a head of a token is the lanes of
-# one vreg — where ``[B, T, heads, d]`` tiles (heads, lanes), so a reshape
+# form tiles (tokens, lanes) — with this model's d = 128 a head of a token is
+# the lanes of one vreg; any d goes (``models.olmo_hybrid``: 96 and 192) —
+# where ``[B, T, heads, d]`` tiles (heads, lanes), so a reshape
 # between the two copies the whole tensor, and a reduction written over a
 # split last axis makes XLA move the heads into sublanes first. What a head
 # needs summed is therefore summed where it lies: a product with the 0/1
@@ -113,7 +114,9 @@ def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int, dtype):
     """What lies between a KDA layer's projections and its delta rule: the
     short convolutions and SiLU on q, k and v, q and k normalised a head,
     the per-channel log-decay ``g = -exp(rate) * softplus(raw + bias)`` in
-    float32 and ``beta = sigmoid(write)``. ``[B, T, heads * d]`` in,
+    float32 and ``beta = sigmoid(write)``, which this model keeps in [0, 1]
+    (the rule itself takes more: ``models.olmo_hybrid``). ``[B, T, heads *
+    d]`` in,
     ``(q, k, v, g [B, T, heads * d], beta [B, T, heads])`` out: what
     ``ops.kda.kda_fed`` takes, no tensor reshaped on the way."""
     whose = _whose(q.shape[-1], heads)

@@ -1,4 +1,4 @@
-"""The component scopes of the four models (``models/scopes.py``;
+"""The component scopes of the five models (``models/scopes.py``;
 docs/tracing.md, "Scopes in a compiled step") as they reach the train
 step's ``op_name``s, lowered on the CPU at toy sizes: every scope forward
 and backward, never one inside another, the shared expert under ``hvd.moe``,
@@ -14,7 +14,7 @@ import optax
 import pytest
 
 from benchmarks._dp_step import make_dp_train_step, make_lm_train_step
-from horovod_tpu.models import (KimiLinearLM, LagunaLM, ResNet,
+from horovod_tpu.models import (KimiLinearLM, LagunaLM, OlmoHybridLM, ResNet,
                                 TransformerLM, scopes)
 from horovod_tpu.models.resnet import BottleneckResNetBlock
 
@@ -69,14 +69,29 @@ def _kimi_linear(**fields):
         "experts_held": {"first": 4, "count": 4}}, **fields)
 
 
+def _olmo_hybrid(**fields):
+    """``tests/test_olmo_hybrid_model.py``'s toy cut to a delta-rule layer
+    and a full-attention one, 3 of 6 heads held."""
+    return OlmoHybridLM.from_config({
+        "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "rms_norm_eps": 1e-6, "head_dim": 16,
+        "num_attention_heads": 6, "num_key_value_heads": 6,
+        "linear_num_key_heads": 6, "linear_num_value_heads": 6,
+        "linear_key_head_dim": 16, "linear_value_head_dim": 32,
+        "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+        "layer_types": ["linear_attention", "full_attention"],
+        "heads_held": {"first": 0, "count": 3}}, **fields)
+
+
 def _resnet():
     return ResNet(stage_sizes=[1, 1], num_filters=8, num_classes=10,
                   block_cls=BottleneckResNetBlock)
 
 
 MODELS = {"transformer": _transformer, "laguna": _laguna,
-          "kimi_linear": _kimi_linear, "resnet": _resnet}
-LMS = ("transformer", "laguna", "kimi_linear")
+          "kimi_linear": _kimi_linear, "olmo_hybrid": _olmo_hybrid,
+          "resnet": _resnet}
+LMS = ("transformer", "laguna", "kimi_linear", "olmo_hybrid")
 TOKENS = jnp.zeros((2, 128), jnp.int32)
 IMAGES = jnp.zeros((2, 32, 32, 3), jnp.float32)
 
@@ -219,6 +234,18 @@ PATHS = {
            for n in ("ln_attn", "ln_mlp")]
         + [f"block_0/mlp/{leaf}" for leaf in _GATED]
         + [f"block_1/moe/{leaf}" for leaf in _MOE] + _LM_ENDS),
+    "olmo_hybrid": (
+        _dense("block_0/gdn", "query", "key", "value", "decay", "beta",
+               "gate", "out")
+        + [f"block_0/gdn/{leaf}" for leaf in (
+            "conv_q", "conv_k", "conv_v", "A_log", "dt_bias",
+            "out_norm/scale")]
+        + _dense("block_1/attn", "query", "key", "value", "out")
+        + ["block_1/attn/q_norm/scale", "block_1/attn/k_norm/scale"]
+        + [f"block_{i}/{n}/scale" for i in (0, 1)
+           for n in ("ln_attn", "ln_mlp")]
+        + [f"block_{i}/mlp/{leaf}" for i in (0, 1) for leaf in _GATED]
+        + _LM_ENDS),
     "resnet": (
         [f"BottleneckResNetBlock_{i}/{leaf}" for i in (0, 1)
          for leaf in _BOTTLENECK]
@@ -231,6 +258,9 @@ COLLECTIONS = {     # what ``init`` leaves beside ``params``
     "laguna": {"moe_stats": _MOE_STATS},
     "kimi_linear": {"moe_stats": _MOE_STATS, "kda_stats": [
         "block_0/kda/mean_decay/0", "block_0/kda/state_max/0"]},
+    "olmo_hybrid": {"gdn_stats": [
+        "block_0/gdn/beta_above_one/0", "block_0/gdn/mean_decay/0",
+        "block_0/gdn/state_max/0"]},
     "resnet": {"batch_stats": [
         f"BottleneckResNetBlock_{i}/{n}/{leaf}" for i in (0, 1)
         for n in ("BatchNorm_0", "BatchNorm_1", "BatchNorm_2", "norm_proj")

@@ -1,9 +1,12 @@
 """``ops.kda``: the chunked gated delta rule against the recurrence token
 by token — values, the final state and every gradient — at sequences that
 are no whole number of chunks, with a decay near 0 and near 1 and beta at
-both ends; what its kernels form in VMEM (the unit lower-triangular inverse,
-the decayed products) against the closed form of one chunk; and ``flash_attention`` with
-a v narrower than its q and k against attention written out."""
+both ends, under a decay a channel and a decay a head (keys and values of
+their own widths, head counts that are no multiple of a grid step's four,
+beta up to 2); what its kernels form in VMEM (the unit lower-triangular
+inverse, the decayed products) against the closed form of one chunk; and
+``flash_attention`` with a v narrower than its q and k against attention
+written out."""
 
 import math
 
@@ -18,17 +21,20 @@ from horovod_tpu.ops.kda import kda, kda_recurrent
 
 
 def operands(seed, batch=1, seq=150, heads=2, d_k=32, d_v=16, log_decay=0.1,
-             beta=None, dtype=jnp.float32):
-    """Unit q and k, normal v; ``g`` is ``-log_decay * softplus(normal)``
-    and ``beta`` a sigmoid of normals unless it is given."""
+             beta=None, dtype=jnp.float32, a_head=False, strongest=1.0):
+    """Unit q and k, normal v; ``g`` is ``-log_decay * softplus(normal)``,
+    one a channel or, with ``a_head``, one a head; ``beta`` is ``strongest``
+    times a sigmoid of normals unless it is given."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k = (jax.random.normal(key, (batch, seq, heads, d_k))
             for key in keys[:2])
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
     v = jax.random.normal(keys[2], (batch, seq, heads, d_v))
-    g = -log_decay * jax.nn.softplus(jax.random.normal(keys[3], q.shape))
+    g = -log_decay * jax.nn.softplus(jax.random.normal(
+        keys[3], q.shape[:3] if a_head else q.shape))
     if beta is None:
-        write = jax.nn.sigmoid(jax.random.normal(keys[4], q.shape[:3]))
+        write = strongest * jax.nn.sigmoid(
+            jax.random.normal(keys[4], q.shape[:3]))
     else:
         write = jnp.full(q.shape[:3], beta, jnp.float32)
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, write)
@@ -52,6 +58,17 @@ _CASES = {
     "never_writes": dict(beta=0.0),
     "always_overwrites": dict(beta=1.0),
     "values_wider_than_keys": dict(d_k=16, d_v=48, heads=3),
+    # the gated delta rule (arXiv:2412.06464): one decay a head, beta in
+    # (0, 2), keys half as wide as values; 3 and 5 heads are one group of
+    # four reaching past the heads there are, and one such after a whole one
+    "a_head_3_heads": dict(a_head=True, strongest=2.0, d_k=24, d_v=48,
+                           heads=3),
+    "a_head_5_heads_two_sequences": dict(
+        a_head=True, strongest=2.0, d_k=16, d_v=32, heads=5, batch=2,
+        seq=100),
+    "a_head_decay_near_zero": dict(a_head=True, strongest=2.0,
+                                   log_decay=20.0, seq=80),
+    "a_head_negative_eigenvalue": dict(a_head=True, beta=1.9, seq=70),
 }
 
 
@@ -62,9 +79,17 @@ def test_chunked_against_token_by_token(case):
     want_o, want_state = kda_recurrent(*args)
     scale = float(jnp.abs(want_o).max()) or 1.0
     np.testing.assert_allclose(o, want_o, atol=2e-6 * max(scale, 1.0))
-    np.testing.assert_allclose(state, want_state, atol=2e-6)
+    # a head's decays are differences of one cumulative sum: at e^-14 a
+    # token that sum reaches 900 in a chunk, and its float32 rounding is
+    # 5e-5 of an exponent near the chunk's end (a channel's sums start
+    # again every 16 rows)
+    np.testing.assert_allclose(
+        state, want_state,
+        atol=5e-6 if case == "a_head_decay_near_zero" else 2e-6)
     if case == "never_writes":
         assert float(jnp.abs(o).max()) == 0.0
+    if _CASES[case].get("strongest") == 2.0:
+        assert 0.2 < float((args[4] > 1.0).mean()) < 0.8
     weight = jax.random.normal(jax.random.PRNGKey(9), o.shape)
     for name, got, want in zip("q k v g beta".split(),
                                _gradients(kda, args, weight),
@@ -166,11 +191,47 @@ def test_bfloat16_operands_and_a_chunk_that_is_not_the_default():
         kda(*args, chunk=24)
 
 
-def test_the_chain_is_two_named_kernels():
-    args = operands(1, seq=64)
+@pytest.mark.parametrize("a_head,names", [
+    (False, ("kda_fwd", "kda_bwd")), (True, ("gdn_fwd", "gdn_bwd"))])
+def test_the_chain_is_two_named_kernels(a_head, names):
+    args = operands(1, seq=64, a_head=a_head)
     text = str(jax.make_jaxpr(jax.grad(lambda *a: kda(*a)[0].sum(),
                                        argnums=(0, 3)))(*args))
-    assert "kda_fwd" in text and "kda_bwd" in text
+    assert all(name in text for name in names)
+    assert not any(name in text for name in
+                   {"kda_fwd", "kda_bwd", "gdn_fwd", "gdn_bwd"} - set(names))
+
+
+@pytest.mark.parametrize("heads,widths,group", [
+    (32, (128, 128), 4),    # Kimi-Linear: whole groups of whole vregs
+    (2, (32, 16), 2),       # every head there is
+    (15, (96, 192), 4),     # Olmo-Hybrid's share: 3 groups and 3 heads
+    (30, (96, 192), 4), (5, (32, 64), 4), (3, (16, 48), 4)])
+def test_heads_a_grid_step_takes(heads, widths, group):
+    """``gcd(heads, 4)`` where that is every head or whole vregs of lanes;
+    else four, the last group reaching past the heads there are."""
+    assert kda_ops._heads_a_step(heads, *widths) == group
+
+
+@pytest.mark.parametrize("a_head", [False, True])
+def test_a_group_past_the_heads_reads_zeros(a_head):
+    """5 heads in groups of four: the second group holds three heads that
+    are not there. What a block reads past an array's end is unspecified —
+    the interpreter puts NaN there —, and the kernels take zeros in its
+    place: those heads' states, saved with the others' for the backward
+    pass, stay zero, and everything either kernel writes is finite."""
+    q, k, v, g, beta = operands(3, a_head=a_head, strongest=2.0, d_k=24,
+                                d_v=48, heads=5, batch=2, seq=150)
+    flat = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
+    ins = (flat(q), flat(k), flat(v), g if a_head else flat(g), beta)
+    o, final, starts = kda_ops._scan_fwd(*ins, 0.2, 64, True, True)
+    assert starts.shape == (3, 2 * 8, 48, 24)       # 8 heads a sequence
+    absent = starts.reshape(3, 2, 8, 48, 24)[:, :, 5:]
+    assert float(jnp.abs(absent).max()) == 0.0
+    assert bool(jnp.isfinite(starts).all() & jnp.isfinite(final).all())
+    grads = kda_ops._scan_bwd(*ins, starts, jnp.ones_like(o[:, :150]), 0.2,
+                              64, True)
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
 
 
 def test_no_loop_outside_the_two_kernels():
@@ -318,3 +379,29 @@ def test_heads_side_by_side_is_the_four_axis_form_bit_for_bit():
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert a.ndim == 3, name
         np.testing.assert_array_equal(a, b.reshape(a.shape), err_msg=name)
+
+
+@pytest.mark.parametrize("text,want", [
+    ("kimi", dict(shape=(1, 16384, 32, 128), d_v=128, decay="channel",
+                  beta=1.0)),
+    ("olmo", dict(shape=(1, 8192, 15, 96), d_v=192, decay="head", beta=2.0)),
+    ("olmo,chunk=32", dict(chunk=32, decay="head")),
+    ("2,256,3,16,d_v=48,dtype=float32", dict(shape=(2, 256, 3, 16), d_v=48,
+                                             dtype="float32"))])
+def test_the_kernel_bench_reads_its_cases(text, want):
+    """``benchmarks/kda_kernel_bench.py --case``: the two cells' calls by
+    name, and the options that give values, a decay and a beta of their
+    own."""
+    from benchmarks.kda_kernel_bench import operands, parse_case
+
+    case = parse_case(text)
+    assert {key: case[key] for key in want} == want
+    if case["shape"][1] <= 256:
+        (q, _, v), g, beta, cot = operands(case, jnp.float32)
+        assert v.shape == cot.shape == (*q.shape[:3], case["d_v"])
+        assert g.shape == (q.shape if case["decay"] == "channel"
+                           else q.shape[:3])
+        assert float(beta.max()) <= case["beta"]
+    with pytest.raises(ValueError, match="unknown option|channel or head"):
+        parse_case(text + ",decay=token")
+

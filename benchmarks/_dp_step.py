@@ -116,6 +116,18 @@ def make_dp_train_step(model, opt, mesh, axis_name: str = "data",
         donate_argnums=(0, 1, 2) if donate else ())
 
 
+def lm_step_loss(model, params, tokens):
+    """The language-model step's loss, under ``hvd.loss``: the model is
+    asked for the loss itself and forms head, loss and their gradients a
+    block of rows at a time (``models.transformer.lm_head_loss``): faster
+    than ``lm_loss`` over the whole float32 logits on four of the five LM
+    cells and level on the fifth (PERF.md, PR 41)."""
+    import jax
+
+    with jax.named_scope("hvd.loss"):
+        return model.apply({"params": params}, tokens, loss_tokens=tokens)
+
+
 def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     """Build the jitted DP language-model train step over ``mesh``.
 
@@ -127,15 +139,11 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.models import lm_loss
     from horovod_tpu.parallel import data_parallel_step
 
     def train_step(params, opt_state, tokens):
-        def loss_fn(p):
-            with jax.named_scope("hvd.loss"):
-                return lm_loss(model.apply({"params": p}, tokens), tokens)
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        loss, grads = jax.value_and_grad(
+            lambda p: lm_step_loss(model, p, tokens))(params)
         updates, opt_state = opt.update(grads, opt_state, params)
         with jax.named_scope("hvd.apply_updates"):
             params = optax.apply_updates(params, updates)

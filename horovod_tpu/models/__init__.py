@@ -13,9 +13,10 @@ from .laguna import ExpertLayer, LagunaLM
 from .mnist import MnistCNN
 from .olmo_hybrid import OlmoHybridLM
 from .resnet import ResNet, ResNet50, ResNet101
-from .transformer import TransformerLM, lm_loss
+from .transformer import TransformerLM, lm_head_loss, lm_loss
 from .vgg import VGG16, VGG19
 
 __all__ = ["MnistCNN", "ResNet", "ResNet50", "ResNet101",
-           "TransformerLM", "lm_loss", "VGG16", "VGG19", "InceptionV3",
+           "TransformerLM", "lm_loss", "lm_head_loss", "VGG16", "VGG19",
+           "InceptionV3",
            "LagunaLM", "ExpertLayer", "KimiLinearLM", "OlmoHybridLM"]

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from . import scopes
 from .laguna import (_INIT, ATTENTION_BACKENDS, ExpertLayer, GatedMLP,
                      dense_attention)
+from .transformer import LMHead
 
 KDA_BACKENDS = ("chunked", "recurrent")
 
@@ -322,7 +323,9 @@ def _keep_policy():
 
 
 class KimiLinearLM(nn.Module):
-    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
+    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``
+    (``model(tokens, loss_tokens=tokens)``: their ``lm_loss``, the logits
+    never whole, ``transformer.lm_head_loss``).
     Layer ``i`` mixes with ``mixers[i]`` (``"kda"`` or ``"mla"``) and its
     MLP is ``mlp_layer_types[i]`` (``"dense"`` or ``"sparse"``). No
     positional encoding of any kind."""
@@ -397,7 +400,7 @@ class KimiLinearLM(nn.Module):
         return cls(**fields)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, loss_tokens=None):
         if len(self.mixers) != len(self.mlp_layer_types):
             raise ValueError("mixers and mlp_layer_types must be equally "
                              "long")
@@ -426,8 +429,9 @@ class KimiLinearLM(nn.Module):
         with jax.named_scope(scopes.NORM):
             x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                            name="ln_final")(x)
+        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                      kernel_init=_INIT, name="lm_head")
+        if loss_tokens is not None:
+            return head.loss(x, loss_tokens)
         with jax.named_scope(scopes.HEAD):
-            logits = nn.Dense(self.vocab_size, use_bias=False,
-                              dtype=jnp.float32, kernel_init=_INIT,
-                              name="lm_head")(x)
-            return logits.astype(jnp.float32)
+            return head(x).astype(jnp.float32)

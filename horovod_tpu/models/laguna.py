@@ -4,8 +4,9 @@ their own, a head-wise output gate, gated SiLU MLPs and a routed expert
 layer that is told which experts it holds (docs/laguna.md).
 
 The second decoder beside ``transformer.TransformerLM``: the same call
-(``model(tokens) -> float32 logits``), so ``make_lm_train_step`` and
-``lm_loss`` take it unchanged, and the same kernel
+(``model(tokens) -> float32 logits``, or the loss itself given
+``loss_tokens``), so ``make_lm_train_step`` and ``lm_loss`` take it
+unchanged, and the same kernel
 (``ops.pallas_attention.flash_attention``, here with grouped heads and a
 window). ``LagunaLM.from_config`` reads the keys of the published
 ``config.json`` (poolside/Laguna-XS.2) plus ``experts_held``.
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
+from .transformer import LMHead
 
 ATTENTION_BACKENDS = ("flash", "dense")
 # rows the expert layer's first, unconditional pass has room for, over the
@@ -363,13 +365,22 @@ class ExpertLayer(nn.Module):
 
 class LagunaBlock(nn.Module):
     """Pre-RMSNorm residual block: grouped attention, then a dense gated
-    MLP (``dense_width``) or, where that is ``None``, the expert layer."""
+    MLP (``dense_width``) or, where that is ``None``, the expert layer.
+
+    With ``remat`` each half is a ``jax.checkpoint`` of its own: the block's
+    input and the attention half's output are stored, the halves' interiors
+    recomputed in backward, one at a time — while the MLP's (the expert
+    layer's) backward pass runs, nothing of the attention is held but what
+    ``_keep_policy`` keeps (a single checkpoint round the block compiles
+    to 0.8 GB more), and the attention's output projection, which only the
+    second half needed, is not recomputed at all."""
 
     attn: dict          # GroupedAttention's fields
     dense_width: Optional[int]
     experts: dict       # ExpertLayer's fields
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
+    remat: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -378,20 +389,56 @@ class LagunaBlock(nn.Module):
                 return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                                   name=name)(x)
 
-        h = norm("ln_attn", x)
-        with jax.named_scope(scopes.MIXER):
-            x = x + GroupedAttention(dtype=self.dtype, name="attn",
-                                     **self.attn)(h, positions)
-        h = norm("ln_mlp", x)
-        if self.dense_width is not None:
-            with jax.named_scope(scopes.MLP):
-                return x + GatedMLP(self.dense_width, self.dtype,
-                                    name="mlp")(h)
-        return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
+        # ``nn.remat`` hands a function the module as its first argument
+        def mix(block, x, positions):
+            h = norm("ln_attn", x)
+            with jax.named_scope(scopes.MIXER):
+                return x + GroupedAttention(dtype=self.dtype, name="attn",
+                                            **self.attn)(h, positions)
+
+        def feed(block, x):
+            h = norm("ln_mlp", x)
+            if self.dense_width is not None:
+                with jax.named_scope(scopes.MLP):
+                    return x + GatedMLP(self.dense_width, self.dtype,
+                                        name="mlp")(h)
+            return x + ExpertLayer(dtype=self.dtype, name="moe",
+                                   **self.experts)(h)
+
+        if self.remat:
+            mix = nn.remat(mix, policy=_keep_policy(self.attn["window"]))
+            feed = nn.remat(feed)
+        return feed(self, mix(self, x, positions))
+
+
+def _keep_policy(window):
+    """The checkpoint policy of a recomputed attention half. A full layer
+    keeps its flash kernel's output and log-sum-exp, named where the forward
+    rule makes them (``ops.pallas_attention.KEPT_NAMES``), and recomputes
+    everything else: with them kept the forward Mosaic call is dead code in
+    the recomputed half. A sliding layer (``window`` given) keeps nothing:
+    its forward kernel skips what the window hides, so running it again is
+    cheap for what keeping would hold.
+
+    The price list, at 2 x 8,192 tokens (docs/laguna.md): a full layer's 48
+    heads hold 0.20 GB for a 17.6 ms run of ``flash_fwd``, a sliding layer's
+    64 heads 0.27 GB for a 7.0 ms run of ``flash_win_fwd``. ``python3 -m
+    chipbench.aot --workload laguna_xs2_8k_1chip`` totals 12.82 GB with
+    the two full layers keeping, under the 13.234 GB the chip leaves the
+    step (none: 12.33; one sliding layer more: 13.08; two more: 13.35;
+    all five: 14.15)."""
+    if window is not None:
+        return None
+    from ..ops import pallas_attention
+
+    return jax.checkpoint_policies.save_only_these_names(
+        *pallas_attention.KEPT_NAMES)
 
 
 class LagunaLM(nn.Module):
-    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
+    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``
+    (``model(tokens, loss_tokens=tokens)``: their ``lm_loss``, the logits
+    never whole, ``transformer.lm_head_loss``).
     Layer ``i`` is ``layer_types[i]`` (``"full_attention"`` or
     ``"sliding_attention"``) with ``heads_per_layer[i]`` query heads, and
     its MLP ``mlp_layer_types[i]`` (``"dense"`` or ``"sparse"``)."""
@@ -416,8 +463,9 @@ class LagunaLM(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     attention: str = "flash"
-    # jax.checkpoint each block: only the block-boundary activations are
-    # stored, a block's interior is recomputed in backward
+    # jax.checkpoint each half of a block: only the halves' inputs and a
+    # full layer's flash kernel outputs (``_keep_policy``) are stored, the
+    # rest of a block's interior is recomputed in backward
     remat: bool = False
 
     @classmethod
@@ -463,7 +511,7 @@ class LagunaLM(nn.Module):
         return cls(**fields)
 
     @nn.compact
-    def __call__(self, tokens, positions=None):
+    def __call__(self, tokens, positions=None, loss_tokens=None):
         if not len(self.layer_types) == len(self.heads_per_layer) \
                 == len(self.mlp_layer_types):
             raise ValueError("layer_types, heads_per_layer and "
@@ -474,7 +522,6 @@ class LagunaLM(nn.Module):
         with jax.named_scope(scopes.EMBED):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                          embedding_init=_INIT, name="tok_embed")(tokens)
-        block_cls = nn.remat(LagunaBlock) if self.remat else LagunaBlock
         for i, (kind, heads, mlp) in enumerate(zip(
                 self.layer_types, self.heads_per_layer,
                 self.mlp_layer_types)):
@@ -489,15 +536,16 @@ class LagunaLM(nn.Module):
                 experts_per_token=self.experts_per_token,
                 experts_held=self.experts_held, width=self.expert_width,
                 shared_width=self.shared_width, scaling=self.routed_scaling)
-            x = block_cls(
+            x = LagunaBlock(
                 attn=attn, experts=experts, eps=self.eps, dtype=self.dtype,
                 dense_width=self.dense_width if mlp == "dense" else None,
-                name=f"block_{i}")(x, positions)
+                remat=self.remat, name=f"block_{i}")(x, positions)
         with jax.named_scope(scopes.NORM):
             x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                            name="ln_final")(x)
+        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                      kernel_init=_INIT, name="lm_head")
+        if loss_tokens is not None:
+            return head.loss(x, loss_tokens)
         with jax.named_scope(scopes.HEAD):
-            logits = nn.Dense(self.vocab_size, use_bias=False,
-                              dtype=jnp.float32, kernel_init=_INIT,
-                              name="lm_head")(x)
-            return logits.astype(jnp.float32)
+            return head(x).astype(jnp.float32)

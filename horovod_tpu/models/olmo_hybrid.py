@@ -43,6 +43,7 @@ from .kimi_linear import (KDA_BACKENDS, _decay_bias_init, _decay_rate_init,
                           _dense, _HeadRMSNorm, _keep_policy, _l2norm,
                           _taps_init, _whose, causal_conv)
 from .laguna import _INIT, ATTENTION_BACKENDS, GatedMLP, dense_attention
+from .transformer import LMHead
 
 LAYER_TYPES = ("linear_attention", "full_attention")
 
@@ -215,7 +216,9 @@ class OlmoHybridBlock(nn.Module):
 
 
 class OlmoHybridLM(nn.Module):
-    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``.
+    """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``
+    (``model(tokens, loss_tokens=tokens)``: their ``lm_loss``, the logits
+    never whole, ``transformer.lm_head_loss``).
     Layer ``i`` mixes with ``layer_types[i]``. The head counts are those
     held here. No positional encoding of any kind: the delta-rule layers
     carry order."""
@@ -278,7 +281,7 @@ class OlmoHybridLM(nn.Module):
         return cls(**fields)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, loss_tokens=None):
         unknown = set(self.layer_types) - set(LAYER_TYPES)
         if unknown:
             raise ValueError(f"layer_types must be of {LAYER_TYPES}, got "
@@ -300,8 +303,9 @@ class OlmoHybridLM(nn.Module):
         with jax.named_scope(scopes.NORM):
             x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                            name="ln_final")(x)
+        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
+                      kernel_init=_INIT, name="lm_head")
+        if loss_tokens is not None:
+            return head.loss(x, loss_tokens)
         with jax.named_scope(scopes.HEAD):
-            logits = nn.Dense(self.vocab_size, use_bias=False,
-                              dtype=jnp.float32, kernel_init=_INIT,
-                              name="lm_head")(x)
-            return logits.astype(jnp.float32)
+            return head(x).astype(jnp.float32)

@@ -88,15 +88,16 @@ _RELAYOUTS = _metrics().gauge(
 _RERUNS = _metrics().gauge(
     "horovod_remat_forward_reruns",
     "Calls of the mixers' forward kernels (kda_fwd, gdn_fwd, flash_mla_fwd, "
-    "flash_fwd) in a compiled step's text beyond one a layer, a layer being "
-    "one call of the kernel's backward (0 where every recomputed block "
-    "keeps its mixer kernel's outputs for its backward pass)",
+    "flash_fwd, flash_win_fwd) in a compiled step's text beyond one a layer, "
+    "a layer being one call of the kernel's backward (0 where every "
+    "recomputed block keeps its mixer kernel's outputs for its backward pass)",
     labels=("program",))
 
 # a mixer's forward kernel and the backward kernel that runs once a layer
 _MIXER_KERNELS = {"kda_fwd": "kda_bwd", "gdn_fwd": "gdn_bwd",
                   "flash_mla_fwd": "flash_mla_bwd_dq",
-                  "flash_fwd": "flash_bwd_dq"}
+                  "flash_fwd": "flash_bwd_dq",
+                  "flash_win_fwd": "flash_win_bwd_dq"}
 _RULE_KERNELS = ("kda_", "gdn_")
 _RULE_SCOPES = ("hvd.kda", "hvd.gdn")
 _KERNEL_CALL = re.compile(
@@ -143,7 +144,8 @@ def record_scan_program(program: str, hlo_text: str) -> tuple:
     holds ``hvd.kda`` or ``hvd.gdn`` or, where it has none (the compiler's
     own copies), whose shape ends in ``[H, d]``; and the calls of a mixer's
     forward kernel beyond one a layer (``_MIXER_KERNELS``: as many as its
-    backward kernel has calls). On
+    backward kernel has calls; 3 on ``laguna_xs2_8k_1chip``, whose sliding
+    layers run ``flash_win_fwd`` again, 5 before PR 41). On
     ``kimi_linear_16k_1chip`` that is 0, ``{"kda_fwd": 4, "kda_bwd": 4}``,
     0 and 0: every recomputed block keeps its mixer kernel's outputs. While
     a recomputed block ran its forward kernel again it was 8 ``kda_fwd``

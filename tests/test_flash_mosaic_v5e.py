@@ -15,6 +15,7 @@ Each case takes a second or two.
 """
 
 import functools
+import math
 import re
 
 import pytest
@@ -232,3 +233,52 @@ def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
         ) + text[end:]
     assert obs.kda.record_scan_program("moved", moved)[2] == 2
     assert gauge("horovod_kda_relayouts", program="moved") == 2
+
+
+def test_a_recomputed_laguna_step_as_its_gauges_read_it(
+        one_chip, no_compile_cache, monkeypatch):
+    """``obs.kda.record_scan_program`` on a toy Laguna step's loss gradient
+    (a full and a sliding layer, lowered for the v5e with the Mosaic
+    kernels): the recomputed full layer keeps ``flash_fwd``'s outputs, the
+    sliding one runs ``flash_win_fwd`` again — one rerun, counted since the
+    pair ``flash_win_fwd`` / ``flash_win_bwd_dq`` is known. And no float32
+    array of the compiled text whose last dimension is the vocabulary is
+    larger than a block of ``lm_head_loss``'s rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks._dp_step import lm_step_loss
+    from horovod_tpu import obs
+    from horovod_tpu.models import laguna, transformer
+    from horovod_tpu.ops import pallas_attention
+    from test_laguna_model import TOY
+
+    monkeypatch.setattr(transformer, "LOSS_ROWS", 128)
+    monkeypatch.setattr(
+        pallas_attention, "flash_attention",
+        functools.partial(pallas_attention.flash_attention, interpret=False))
+    vocab = 640
+    model = laguna.LagunaLM.from_config(
+        dict(TOY, num_hidden_layers=2, head_dim=128, vocab_size=vocab,
+             mlp_layer_types=["dense", "dense"]), remat=True)
+    assert model.layer_types == ("full_attention", "sliding_attention")
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((2, 8), jnp.int32))["params"])
+    text = jax.jit(jax.grad(functools.partial(lm_step_loss, model))).lower(
+        params, tokens).compile().as_text()
+    calls = {kernel: len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text))
+             for kernel in ("flash_fwd", "flash_bwd_dq", "flash_win_fwd",
+                            "flash_win_bwd_dq")}
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_win_fwd": 2,
+                     "flash_win_bwd_dq": 1}
+    assert obs.kda.record_scan_program("laguna_toy", text)[3] == 1
+    sizes = {math.prod(map(int, dims.split(",")))
+             for dims in re.findall(rf"\bf32\[((?:\d+,)*{vocab})\]", text)}
+    assert max(sizes) == 128 * vocab < tokens.size * vocab
+    assert {s["labels"]["program"]: s["value"] for s in
+            obs.registry().snapshot()["horovod_remat_forward_reruns"][
+                "samples"] if s["labels"]["program"].startswith("laguna_")} \
+        == {"laguna_toy": 1}

@@ -270,11 +270,14 @@ def test_a_recomputed_block_keeps_its_mixer_kernels_outputs(
                for c in (plain, keeping, default))
 
 
-def test_laguna_recomputes_its_flash_forward_as_before():
-    """The fence: ``models.laguna`` has no room to keep a kernel's outputs
-    (PERF.md §7), so its ``nn.remat(LagunaBlock)`` stays with the default
-    policy and the names ``_flash_fwd`` gives its residuals save nothing:
-    two forward calls a layer, full and sliding alike."""
+def test_laguna_keeps_its_full_layers_flash_outputs():
+    """``models.laguna`` takes the same names: a recomputed full layer keeps
+    ``flash_fwd``'s outputs and runs it once, as the unrecomputed model
+    does; a sliding layer, whose window kernel is cheap for what keeping
+    would hold, recomputes ``flash_win_fwd`` (``laguna._keep_policy``). Each
+    half of a block is a checkpoint of its own, and the attention's output
+    projection is not recomputed: one product of the five under
+    ``hvd.mixer.proj`` fewer a layer than a second forward pass has."""
     from test_laguna_model import TOY as LAGUNA_TOY
 
     model = laguna.LagunaLM.from_config(
@@ -285,8 +288,12 @@ def test_laguna_recomputes_its_flash_forward_as_before():
                             tokens)["params"]
     recomputed = gradient_program_counts(model, params, tokens)
     plain = gradient_program_counts(model.clone(remat=False), params, tokens)
-    for kernel in ("flash_fwd", "flash_win_fwd"):
-        assert (plain[kernel], recomputed[kernel]) == (1, 2), kernel
+    assert (plain["flash_fwd"], recomputed["flash_fwd"]) == (1, 1)
+    assert (plain["flash_win_fwd"], recomputed["flash_win_fwd"]) == (1, 2)
+    # query, key, value, gate, out: forward and two transposes each, and the
+    # first four once more in the recomputed half
+    assert (plain["projections"], recomputed["projections"]) \
+        == (2 * 15, 2 * 19)
 
 
 def test_nothing_sees_the_future(toy):

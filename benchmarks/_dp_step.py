@@ -128,6 +128,32 @@ def lm_step_loss(model, params, tokens):
         return model.apply({"params": params}, tokens, loss_tokens=tokens)
 
 
+def bd_step_loss(model, params, clean, noisy, weights):
+    """The block-diffusion step's loss, under ``hvd.loss``: the model
+    (``models.sdar.SdarMoeLM``) scores the noisy rows against the clean ids
+    at the masked positions, each by its weight."""
+    import jax
+
+    with jax.named_scope("hvd.loss"):
+        return model.apply({"params": params}, clean, noisy, weights=weights)
+
+
+def _lm_update(opt, axis_name, params, opt_state, loss_of):
+    """What a language-model step does once it has its loss as a function
+    of the parameters: gradient, ``DistributedOptimizer`` update, the loss
+    averaged over the replicas."""
+    import jax
+    import optax
+
+    loss, grads = jax.value_and_grad(loss_of)(params)
+    updates, opt_state = opt.update(grads, opt_state, params)
+    with jax.named_scope("hvd.apply_updates"):
+        params = optax.apply_updates(params, updates)
+    with jax.named_scope("hvd.sync_stats"):
+        loss = jax.lax.pmean(loss, axis_name)
+    return params, opt_state, loss
+
+
 def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     """Build the jitted DP language-model train step over ``mesh``.
 
@@ -135,23 +161,37 @@ def make_lm_train_step(model, opt, mesh, axis_name: str = "data"):
     loss)`` with tokens ``[global_batch, seq]`` sharded on the data axis,
     params and optimizer state replicated and donated, and ``loss`` the
     cross-replica mean next-token cross entropy."""
-    import jax
-    import optax
     from jax.sharding import PartitionSpec as P
 
     from horovod_tpu.parallel import data_parallel_step
 
     def train_step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_step_loss(model, p, tokens))(params)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        with jax.named_scope("hvd.apply_updates"):
-            params = optax.apply_updates(params, updates)
-        with jax.named_scope("hvd.sync_stats"):
-            loss = jax.lax.pmean(loss, axis_name)
-        return params, opt_state, loss
+        return _lm_update(opt, axis_name, params, opt_state,
+                          lambda p: lm_step_loss(model, p, tokens))
 
     return data_parallel_step(
         train_step, opt, mesh,
         in_specs=(P(), P(), P(axis_name)), out_specs=(P(), P(), P()),
         donate_argnums=(0, 1))
+
+
+def make_bd_train_step(model, opt, mesh, axis_name: str = "data"):
+    """The block-diffusion train step over ``mesh``: ``step(params,
+    opt_state, clean, noisy, weights) -> (params, opt_state, loss)``, the
+    three data arrays ``[global_batch, seq]`` each sharded on the data axis,
+    the rest as ``make_lm_train_step``'s; the loss is
+    ``models.sdar.SdarMoeLM``'s, the noisy rows scored against the clean ids
+    at the masked positions, each by its weight."""
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.parallel import data_parallel_step
+
+    def train_step(params, opt_state, clean, noisy, weights):
+        return _lm_update(
+            opt, axis_name, params, opt_state,
+            lambda p: bd_step_loss(model, p, clean, noisy, weights))
+
+    return data_parallel_step(
+        train_step, opt, mesh,
+        in_specs=(P(), P(), P(axis_name), P(axis_name), P(axis_name)),
+        out_specs=(P(), P(), P()), donate_argnums=(0, 1))

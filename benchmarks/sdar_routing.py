@@ -1,0 +1,94 @@
+"""Where the rows of a block-diffusion cell go, on the chip and at the
+cell's size.
+
+    python3 benchmarks/sdar_routing.py --workload sdar_moe_8k_1chip \
+        --seeds 7,8 --out chiprun_out/sdar_routing.json
+
+For every seed and every batch of its pool, on the seeded weights, what the
+benchmark's runs do not print: the gauges of the noise (``horovod_bd_masked_
+share``, ``horovod_bd_mean_weight``: ``obs.bd``), the routing gauges layer
+by layer (``horovod_moe_held_assignment_share``, ``horovod_moe_expert_load_
+max_over_mean``: ``obs.moe``) and, from the same counts, the slots the held
+experts' rows take in whole tiles of the grouped-product kernel against the
+slots the expert layer's first, unconditional pass has
+(``models.laguna.held_expert_sum``): a batch that needs more runs a further
+pass, and its step takes longer than its neighbours'.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--workload", default="sdar_moe_8k_1chip")
+    parser.add_argument("--benchmark", default=None)
+    parser.add_argument("--seeds", default="7")
+    parser.add_argument("--out", default=None)
+    parser.add_argument("--rehearse-cpu", action="store_true")
+    args = parser.parse_args(argv)
+
+    from chipbench import cell as cells
+    from chipbench import run
+
+    cell = cells.Spec(args.benchmark).cell(args.workload)
+    run.take_devices(cell, args.rehearse_cpu)
+
+    import jax
+    import numpy as np
+
+    from horovod_tpu import obs
+    from horovod_tpu.models.laguna import SLICE_OF_EVEN
+    from horovod_tpu.ops.grouped_matmul import ROW_TILE
+
+    family, config, traffic = cell.family, cell.config, cell.traffic
+    model = family.build(config)
+    first, held = model.experts_held
+    stats = jax.jit(lambda p, clean, noisy, weights: model.apply(
+        {"params": p}, clean, noisy, weights=weights,
+        mutable=["moe_stats", "bd_stats"])[1])
+    rows = []
+    for seed in (int(s) for s in args.seeds.split(",") if s):
+        keys = cells.seed_keys(seed, 2)
+        (params,) = jax.jit(
+            lambda key: family.init_model_state(config, key))(keys[0])
+        pool = jax.jit(
+            lambda key: family.make_pool(config, traffic, key))(keys[1])
+        for i, batch in enumerate(pool):
+            sown = stats(params, *batch)
+            row = {"seed": seed, "batch": i, **obs.bd.publish(sown["bd_stats"])}
+            routed = obs.moe.publish(sown["moe_stats"])
+            row["held_share"] = [v["held_share"]
+                                 for _, v in sorted(routed.items())]
+            row["load_max_over_mean"] = [
+                v["load_max_over_mean"] for _, v in sorted(routed.items())]
+            row["slots_used_over_first_pass"] = []
+            for layer in sorted(routed):
+                block, moe = layer.split("/")
+                counts = np.asarray(
+                    sown["moe_stats"][block][moe]["assignments"][-1])
+                room = SLICE_OF_EVEN * counts.sum() * held // counts.size
+                used = (-(-counts[first:first + held] // ROW_TILE)
+                        * ROW_TILE).sum()
+                row["slots_used_over_first_pass"].append(float(used / room))
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+        del params, pool
+    worst = max(max(r["slots_used_over_first_pass"]) for r in rows)
+    print(f"most slots a layer used of its first pass's: {worst:.3f} "
+          f"({'a further pass ran' if worst > 1 else 'no further pass'})")
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w", encoding="utf-8") as fh:
+            json.dump({"cell": cell.name, "rows": rows}, fh, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

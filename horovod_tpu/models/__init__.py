@@ -13,10 +13,12 @@ from .laguna import ExpertLayer, LagunaLM
 from .mnist import MnistCNN
 from .olmo_hybrid import OlmoHybridLM
 from .resnet import ResNet, ResNet50, ResNet101
+from .sdar import SdarMoeLM
 from .transformer import TransformerLM, lm_head_loss, lm_loss
 from .vgg import VGG16, VGG19
 
 __all__ = ["MnistCNN", "ResNet", "ResNet50", "ResNet101",
            "TransformerLM", "lm_loss", "lm_head_loss", "VGG16", "VGG19",
            "InceptionV3",
-           "LagunaLM", "ExpertLayer", "KimiLinearLM", "OlmoHybridLM"]
+           "LagunaLM", "ExpertLayer", "KimiLinearLM", "OlmoHybridLM",
+           "SdarMoeLM"]

@@ -179,10 +179,15 @@ class GroupedAttention(nn.Module):
 # -- the expert layer ---------------------------------------------------------
 
 
+SCORINGS = {"sigmoid": nn.sigmoid,
+            "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 def route(scores, experts_per_token: int, scaling: float):
     """``(ids, weights)`` [N, k]: the ``experts_per_token`` largest of the
-    sigmoid ``scores`` [N, E], their weights normalised to sum 1 over the
-    selected and scaled."""
+    ``scores`` [N, E] (a sigmoid's, or a softmax's over all ``E``:
+    ``SCORINGS``), their weights normalised to sum 1 over the selected and
+    scaled."""
     top, ids = jax.lax.top_k(scores, experts_per_token)
     return ids, scaling * top / jnp.sum(top, axis=-1, keepdims=True)
 
@@ -310,9 +315,11 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
 
 class ExpertLayer(nn.Module):
     """Routed experts, of which this chip holds ``experts_held = (first,
-    count)``, plus one shared expert. Routes over all ``num_experts`` in
-    float32, keeps ``experts_per_token``, adds ``shared(x)`` and the held
-    experts' weighted outputs; what absent experts would add is left out.
+    count)``, plus one shared expert (none where ``shared_width`` is 0: no
+    parameter, no product). Routes over all ``num_experts`` in float32 by
+    ``scoring`` (one of ``SCORINGS``), keeps ``experts_per_token``, adds
+    ``shared(x)`` and the held experts' weighted outputs; what absent
+    experts would add is left out.
 
     Sows into the collection ``moe_stats`` (when the caller makes it
     mutable): ``assignments`` [num_experts], how many of the ``N * k``
@@ -326,6 +333,7 @@ class ExpertLayer(nn.Module):
     shared_width: int
     scaling: float = 1.0
     dtype: Any = jnp.bfloat16
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
@@ -333,13 +341,16 @@ class ExpertLayer(nn.Module):
         if not 0 <= first <= first + held <= self.num_experts or held < 1:
             raise ValueError(f"experts_held {self.experts_held} is no part "
                              f"of {self.num_experts} experts")
+        if self.scoring not in SCORINGS:
+            raise ValueError(f"scoring must be one of {sorted(SCORINGS)}, "
+                             f"got {self.scoring!r}")
         d = x.shape[-1]
         tokens = x.reshape(-1, d)
         with jax.named_scope("hvd.moe"):
             with jax.named_scope("hvd.moe.route"):
                 # float32 in earnest: without ``highest`` the TPU multiplies
                 # float32 operands in one bfloat16 pass
-                scores = nn.sigmoid(nn.Dense(
+                scores = SCORINGS[self.scoring](nn.Dense(
                     self.num_experts, use_bias=False, dtype=jnp.float32,
                     precision=jax.lax.Precision.HIGHEST, kernel_init=_INIT,
                     name="router")(tokens.astype(jnp.float32)))
@@ -357,9 +368,12 @@ class ExpertLayer(nn.Module):
                 routed = held_expert_sum(tokens, ids, weights, w1, w3, w2,
                                          first, self.num_experts)
                 shared = GatedMLP(self.shared_width, self.dtype,
-                                  name="shared")(tokens)
+                                  name="shared")(tokens) \
+                    if self.shared_width else None
             with jax.named_scope("hvd.moe.combine"):
-                out = shared + routed.astype(self.dtype)
+                out = routed.astype(self.dtype)
+                if shared is not None:
+                    out = shared + out
         return out.reshape(x.shape)
 
 

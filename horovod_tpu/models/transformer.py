@@ -209,7 +209,15 @@ def _head_loss_fwd(x, kernel, bias, tokens):
     targets = jnp.roll(tokens, -1, axis=-1).reshape(rows)
     counted = jnp.broadcast_to(jnp.arange(seq) < seq - 1,
                                tokens.shape).reshape(rows).astype(jnp.float32)
-    x = x.reshape(rows, d)
+    return _visit_blocks(kernel, bias, scale, x.reshape(rows, d), targets,
+                         counted, batch)
+
+
+def _visit_blocks(kernel, bias, scale, x, targets, counted, batch):
+    """``_loss_block`` over the rows ``x [rows, d]``, ``LOSS_ROWS`` at a
+    time: the loss and what the backward rule scales, ``dx`` shaped
+    ``[*batch, d]``."""
+    rows, d = x.shape
     # the blocks written out one after another, not a ``lax.scan``: the
     # compiler orders them by the sums they feed, and a loop's carried
     # [d, V] sum cost Kimi-Linear's step 0.3 GB more than this does
@@ -235,12 +243,38 @@ def _head_loss_bwd(grads, g):
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
+def _weighted_loss_fwd(x, kernel, bias, targets, weights):
+    *batch, d = x.shape
+    rows = math.prod(batch)
+    return _visit_blocks(kernel, bias, 1.0 / rows, x.reshape(rows, d),
+                         targets.reshape(rows),
+                         weights.reshape(rows).astype(jnp.float32), batch)
+
+
+@jax.custom_vjp
+def _weighted_loss(x, kernel, bias, targets, weights):
+    return _weighted_loss_fwd(x, kernel, bias, targets, weights)[0]
+
+
+_weighted_loss.defvjp(
+    _weighted_loss_fwd, lambda grads, g: (*_head_loss_bwd(grads, g), None))
+
+
 def lm_head_loss(x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array],
-                 tokens: jax.Array) -> jax.Array:
+                 tokens: Optional[jax.Array] = None, *,
+                 targets: Optional[jax.Array] = None,
+                 weights: Optional[jax.Array] = None) -> jax.Array:
     """``lm_loss(x @ kernel + bias, tokens)`` for final hidden states ``x
     [B, T, d]``, without the float32 logits ``[B, T, V]`` or their gradient
     ever existing whole: the head and its loss a block of ``LOSS_ROWS`` rows
     at a time.
+
+    Given ``targets`` and ``weights`` (both ``[B, T]``) in place of
+    ``tokens``, row ``(b, t)`` is scored against ``targets[b, t]`` itself,
+    no shift, its cross entropy times ``weights[b, t]``, and the sum is
+    divided by the ``B * T`` rows: the loss of a masked-diffusion objective,
+    whose weights are zero off the masked positions
+    (``models.sdar.block_diffusion_noise``). Neither gets a gradient.
 
     A block's visit forms its float32 logits, their log-sum-exp and the
     block's share of the loss and, while it has them, their gradient
@@ -255,6 +289,9 @@ def lm_head_loss(x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array],
     memory (PERF.md, PR 41), so every LM's step takes it."""
     from ..ops.spmd import vary_like
 
+    if (tokens is None) == (targets is None) \
+            or (targets is None) != (weights is None):
+        raise ValueError("lm_head_loss takes tokens, or targets and weights")
     with jax.named_scope(scopes.HEAD):
         # replicated parameters beside sharded rows: typed alike inside the
         # rule, their gradient summed over the mesh axis once, outside it
@@ -262,6 +299,8 @@ def lm_head_loss(x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array],
             (kernel,) = vary_like(x, kernel)
         else:
             kernel, bias = vary_like(x, kernel, bias)
+        if targets is not None:
+            return _weighted_loss(x, kernel, bias, targets, weights)
         return _head_loss(x, kernel, bias, tokens)
 
 
@@ -270,8 +309,9 @@ class LMHead(nn.Dense):
     ``head.loss(x, tokens)`` the same parameters under
     :func:`lm_head_loss`."""
 
-    def loss(self, x, tokens):
+    def loss(self, x, tokens=None, **weighted):
         if self.is_initializing():
             self(x[..., :1, :])     # ``nn.Dense`` declares the parameters
         params = self.variables["params"]
-        return lm_head_loss(x, params["kernel"], params.get("bias"), tokens)
+        return lm_head_loss(x, params["kernel"], params.get("bias"), tokens,
+                            **weighted)

@@ -22,6 +22,8 @@ The subsystem docs live in docs/metrics.md; the pieces:
   collectives can run beside compute);
 * :mod:`.moe` — the expert layer's routing gauges, from the flax
   collection it sows (docs/laguna.md);
+* :mod:`.bd` — a block-diffusion step's masked share and mean loss weight,
+  from the flax collection ``bd_stats`` that ``models.sdar.SdarMoeLM`` sows.
 * :mod:`.kda` — the delta-rule layers' decay and state gauges, likewise
   (docs/kimi-linear.md);
 * :func:`metrics_snapshot` — the Python API: this process's families, or
@@ -42,6 +44,7 @@ from .registry import (  # noqa: F401 - public surface
     registry,
 )
 from .bridge import TimelineBridge  # noqa: F401
+from . import bd  # noqa: F401 - public surface (docs/sdar.md)
 from . import compiles  # noqa: F401
 from .compiles import (CompileEvent, compile_events,  # noqa: F401
                        record_exchange_collectives)

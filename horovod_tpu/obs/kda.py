@@ -88,7 +88,7 @@ _RELAYOUTS = _metrics().gauge(
 _RERUNS = _metrics().gauge(
     "horovod_remat_forward_reruns",
     "Calls of the mixers' forward kernels (kda_fwd, gdn_fwd, flash_mla_fwd, "
-    "flash_fwd, flash_win_fwd) in a compiled step's text beyond one a layer, "
+    "flash_fwd, flash_win_fwd, flash_bd_fwd) in a compiled step's text beyond one a layer, "
     "a layer being one call of the kernel's backward (0 where every "
     "recomputed block keeps its mixer kernel's outputs for its backward pass)",
     labels=("program",))
@@ -97,6 +97,7 @@ _RERUNS = _metrics().gauge(
 _MIXER_KERNELS = {"kda_fwd": "kda_bwd", "gdn_fwd": "gdn_bwd",
                   "flash_mla_fwd": "flash_mla_bwd_dq",
                   "flash_fwd": "flash_bwd_dq",
+                  "flash_bd_fwd": "flash_bd_bwd_dq",
                   "flash_win_fwd": "flash_win_bwd_dq"}
 _RULE_KERNELS = ("kda_", "gdn_")
 _RULE_SCOPES = ("hvd.kda", "hvd.gdn")

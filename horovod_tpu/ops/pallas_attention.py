@@ -57,6 +57,28 @@ The dK/dV grid moves to another query head every step, so its q-side block
 is fetched every step: under a window it is no longer than a k tile's
 window reaches (``_major``'s ``reach``).
 
+Block diffusion (``block_diffusion=B``; docs/sdar.md). A block-diffusion
+model trains on a sequence's clean copy and a noisy copy side by side,
+``2 L`` rows ``[clean ; noisy]``, under a mask of three parts: a clean row
+sees the clean rows of its block and of those before it; a noisy row sees
+the *clean* rows of the blocks before its own and the *noisy* rows of its
+own; no row sees a noisy row of another block. Read row by row that is one
+thing twice over: every row, clean or noisy, sees the clean rows of the
+blocks strictly before its own — the causal walk over the clean half's k
+tiles from the tile's place in its own half, the diagonal tile masked by
+block (``k < B * (q // B)``) in the same 128-row strips — and the rows of
+its own block *in its own half*, which lie on the diagonal of the K/V tile
+at the q tile's own index. So the three kernels take that tile as two more
+operands and the accumulators *start* from it, one ``strip x strip`` square
+a strip (``_own_mask``), before the walk: a row of a sequence's first block,
+which sees no clean key at all, has a finite maximum from the first step,
+and everything is one softmax a row. The dK/dV grid runs over both halves'
+k tiles and takes each query head twice, its clean half and its noisy one:
+a clean k tile walks both, a noisy one neither, and either adds its own
+squares once a query head. Executed over needed pairs is ``(L + 3 * 128) /
+(L + B)``, 1.046 at ``L`` 8192 (a schedule that stopped at the causal bound
+of the ``2 L`` rows would execute 2.0).
+
 The forward's softmax is two loops a major block: scores into a VMEM
 buffer with their lane-wise maximum, one cross-lane reduction a row, then
 ``exp``, lane-wise sums and P V — the statistics and the accumulators'
@@ -81,7 +103,8 @@ The three ``pallas_call``s are named ``flash_fwd``, ``flash_bwd_dq`` and
 ``flash_bwd_dkv`` (``flash_win_fwd``, ``flash_win_bwd_dq``,
 ``flash_win_bwd_dkv`` for a call with a window; ``flash_mla_fwd``,
 ``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv`` for one whose v is of another
-width than its q and k): the names a compiled program's custom calls and a
+width than its q and k; ``flash_bd_fwd``, ``flash_bd_bwd_dq``,
+``flash_bd_bwd_dkv`` for one under block diffusion's mask): the names a compiled program's custom calls and a
 profiler trace show them under (docs/tracing.md).
 
 ``interpret=True`` (automatic on the CPU backend only) runs the same
@@ -291,7 +314,8 @@ def _pieces(transposed: bool = False, *, tile_q: int, tile_k: int,
 
 def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
                     tile_k: int, causal: bool,
-                    window: Optional[int] = None) -> dict:
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None) -> dict:
     """What each kernel executes at these shapes: ``tiles`` (compute tiles
     run), ``diagonal`` (how many of them hold masked pairs), ``trimmed``
     (how many of those run as strips, ``_strips``; 0 where the masked body
@@ -299,7 +323,16 @@ def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
     strip, over the pairs the mask keeps). ``flash_fwd`` and
     ``flash_bwd_dq`` walk k tiles for each q tile, ``flash_bwd_dkv`` walks
     q tiles for each k tile; a call with a ``window`` runs the same three
-    under their ``flash_win_*`` names."""
+    under their ``flash_win_*`` names.
+
+    Under ``block_diffusion`` (``seq_q = seq_k = 2 L`` rows, the
+    ``flash_bd_*`` calls) each half's q tiles make the causal walk over the
+    clean half's k tiles, and every tile adds the squares of its rows' own
+    blocks — a strip's square on its own diagonal, counted among ``tiles``
+    and ``diagonal`` as one tile more a q tile; the mask keeps ``L (L +
+    block)`` pairs."""
+    halves = 1 if block_diffusion is None else 2
+    seq_q, seq_k = seq_q // halves, seq_k // halves
     num_q_tiles, num_k_tiles = seq_q // tile_q, seq_k // tile_k
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
                     causal=causal, window=window)
@@ -317,17 +350,22 @@ def causal_schedule(seq_q: int, seq_k: int, q_offset: int, tile_q: int,
         return max(0, last - first)
 
     needed = seq_q * seq_k if not causal else sum(map(visible, range(seq_q)))
+    if block_diffusion is not None:
+        needed = seq_q * (seq_q + block_diffusion)
 
     def walk(walks):
-        edge, clear, diagonal = (sum(max(0, n) for n in kind)
+        edge, clear, diagonal = (halves * sum(max(0, n) for n in kind)
                                  for kind in zip(*walks))
         pairs, trimmed = clear * tile_q * tile_k, 0
         for count, parts in zip((diagonal, edge), _pieces(**schedule)[1:]):
             pairs += count * sum((rows.stop - rows.start) * cols
                                  for rows, _, cols, _ in parts)
             trimmed += count if len(parts) > 1 else 0
-        return {"tiles": edge + clear + diagonal,
-                "diagonal": edge + diagonal, "trimmed": trimmed,
+        own = 0 if block_diffusion is None else 2 * num_q_tiles
+        pairs += own * sum((rows.stop - rows.start) ** 2
+                           for rows, *_ in _pieces(**schedule)[1])
+        return {"tiles": edge + clear + diagonal + own,
+                "diagonal": edge + diagonal + own, "trimmed": trimmed,
                 "pair_ratio": pairs / needed}
 
     over_k, over_q = walk(by_q), walk(by_k)
@@ -399,22 +437,27 @@ def _major_index(step, first_tile, tiles_per_major: int, grid_majors: int,
     return first_tile // tiles_per_major + step
 
 
-def _causal_mask(s, q_pos0, k_pos0, q_axis=0, window=None):
+def _causal_mask(s, q_pos0, k_pos0, q_axis=0, window=None, block=None):
     """Mask future positions of a score block to the _NEG_INF sentinel, and
     with a ``window`` the positions it has left behind (``q - k >=
     window``). q positions run along ``q_axis`` of ``s`` and k positions
     along the other axis (``q_axis=1`` is the dK/dV kernel's transposed
     block). Shared by forward and backward so the two can never disagree
-    on what was masked."""
+    on what was masked. Under block diffusion (``block``, a power of two) a
+    row sees the clean keys of the blocks *before* its own: those under its
+    block's first position."""
     q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    if block is not None:
+        return jnp.where(k_pos < (q_pos & -block), s, _NEG_INF)
     keep = q_pos >= k_pos
     if window is not None:
         keep = keep & (q_pos - k_pos < window)
     return jnp.where(keep, s, _NEG_INF)
 
 
-def _mask_columns(s, mask, q_pos0, k_pos0, q_axis=0, window=None):
+def _mask_columns(s, mask, q_pos0, k_pos0, q_axis=0, window=None,
+                  block=None):
     """``_causal_mask`` on the ``mask = (first, count)`` columns of the
     score block ``s`` alone (``_parts``); the positions are those of the
     block's first row and column."""
@@ -422,15 +465,27 @@ def _mask_columns(s, mask, q_pos0, k_pos0, q_axis=0, window=None):
         return s
     at, count = mask
     if count == s.shape[1]:
-        return _causal_mask(s, q_pos0, k_pos0, q_axis, window)
+        return _causal_mask(s, q_pos0, k_pos0, q_axis, window, block)
     if q_axis:
         q_pos0 += at
     else:
         k_pos0 += at
-    block = _causal_mask(s[:, at:at + count], q_pos0, k_pos0, q_axis, window)
+    masked = _causal_mask(s[:, at:at + count], q_pos0, k_pos0, q_axis, window,
+                          block)
     return jnp.concatenate(
-        [x for x in (s[:, :at], block, s[:, at + count:]) if x.shape[1]],
+        [x for x in (s[:, :at], masked, s[:, at + count:]) if x.shape[1]],
         axis=1)
+
+
+def _own_mask(s, block: int):
+    """A row's own block under block diffusion: ``s`` is a square of scores
+    whose q rows and k columns start at the same multiple of ``block``, and
+    a row keeps the columns of its own block (two positions share a block
+    when they differ in its low bits alone). Symmetric, so the dK/dV
+    kernel's transposed squares take it as it is."""
+    rows, cols = (jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+                  for axis in (0, 1))
+    return jnp.where((rows ^ cols) < block, s, _NEG_INF)
 
 
 def _col(stat):
@@ -446,11 +501,10 @@ def _lane_fold(x, lanes: int, op):
                                  for c in range(x.shape[1] // lanes)])
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
-                s_buf, m_lane, l_lane, *, scale: float, causal: bool,
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
                 q_offset: int, tile_q: int, tile_k: int, major_k: int,
                 num_k_tiles: int, window: Optional[int], grid_majors: int,
-                pieces):
+                pieces, block: Optional[int] = None):
     """One q tile against the resident K/V major block ``kk``. Grid (bh,
     q-tile, k-major), the last sequential; ``o_acc``, ``m_acc`` and
     ``l_acc`` persist across it.
@@ -475,7 +529,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
     still find every pair of an *early* major block masked; its statistics
     stay at the sentinel there, what it accumulates is finite, and the
     first block with a visible pair rescales it by ``exp(sentinel - m)``,
-    which is 0. So ``m`` is finite wherever the result depends on it."""
+    which is 0. So ``m`` is finite wherever the result depends on it.
+
+    Block diffusion (``block``; ``refs`` then lead with the K and V tile at
+    the q tile's own index, module docstring): the accumulators *start* from
+    the rows' own blocks, a square a strip on that tile's diagonal, so a row
+    that sees no clean key (its sequence's first block) has a finite ``m``
+    from step 0 on; the walk is the causal one over the ``num_k_tiles``
+    clean tiles at the tile's place in its half, masked by block."""
+    if block is not None:
+        k_own, v_own, *refs = refs
+    o_ref, lse_ref, o_acc, m_acc, l_acc, s_buf, m_lane, l_lane = refs
     # program_id must be read at kernel top level: inside a pl.when body it
     # escapes the interpreter's scope (breaks interpret=True on CPU)
     step = pl.program_id(2)
@@ -485,10 +549,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
 
     @pl.when(step == 0)
     def _init():
-        o_acc[...] = jnp.zeros_like(o_acc)
-        m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
-        l_acc[...] = jnp.zeros_like(l_acc)
+        if block is None:
+            o_acc[...] = jnp.zeros_like(o_acc)
+            m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+            l_acc[...] = jnp.zeros_like(l_acc)
+            return
+        q_block = q_ref[0].astype(jnp.float32) * scale
+        for rows, *_ in pieces[1]:
+            s = _own_mask(jax.lax.dot_general(
+                q_block[rows], k_own[0, rows, :].astype(jnp.float32),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32), block)
+            m = s.max(axis=1, keepdims=True)
+            p = jnp.exp(s - m)
+            o_acc[rows, :] = jax.lax.dot_general(
+                p, v_own[0, rows, :].astype(jnp.float32),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_acc[rows, :] = jnp.broadcast_to(m, (s.shape[0], _LANES))
+            l_acc[rows, :] = jnp.broadcast_to(
+                p.sum(axis=1, keepdims=True), (s.shape[0], _LANES))
 
+    if block is not None:
+        q_idx = q_idx % num_k_tiles     # the tile's place in its half
     lo, a, b, end = _k_walk(
         q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
         num_k_tiles=num_k_tiles, causal=causal, window=window)
@@ -516,7 +598,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
                     preferred_element_type=jnp.float32)
                 s = _mask_columns(
                     s, mask, q_pos0 + rows.start,
-                    kk * major_k + j * tile_k + first_col, window=window)
+                    kk * major_k + j * tile_k + first_col, window=window,
+                    block=block)
                 s_buf[rows, cols] = s
                 m_lane[rows, :] = jnp.maximum(
                     m_lane[rows, :], _lane_fold(s, lanes, jnp.maximum))
@@ -572,10 +655,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
 
 
 def _recompute_p(q_blk, k_blk, lse, *, mask, q_pos0, k_pos0,
-                 transposed=False, window=None):
+                 transposed=False, window=None, block=None):
     """Recompute the normalized probability block P = exp(S - lse), with
-    S's mask on the piece's ``mask`` columns (``_parts``); shared by both
-    backward kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
+    S's mask on the piece's ``mask`` columns (``_parts``; ``"own"``: the
+    square of a row's own block under block diffusion, ``_own_mask``);
+    shared by both backward kernels. ``q_blk`` comes scaled. All f32, MXU matmul.
 
     ``transposed=False``: P is [tile_q, tile_k] and ``lse`` its
     [tile_q, 1] column. ``transposed=True``: P^T is [tile_k, tile_q],
@@ -584,30 +668,56 @@ def _recompute_p(q_blk, k_blk, lse, *, mask, q_pos0, k_pos0,
     s = jax.lax.dot_general(
         lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    s = _mask_columns(s, mask, q_pos0, k_pos0,
-                      q_axis=1 if transposed else 0, window=window)
+    if mask == "own":
+        s = _own_mask(s, block)
+    else:
+        s = _mask_columns(s, mask, q_pos0, k_pos0,
+                          q_axis=1 if transposed else 0, window=window,
+                          block=block)
     # no row is empty (``_fwd_kernel``), so lse is finite and a masked
     # pair's exp(sentinel - lse) is the 0 it should be
     return jnp.exp(s - lse)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale: float, causal: bool, q_offset: int,
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                   scale: float, causal: bool, q_offset: int,
                    tile_q: int, tile_k: int, major_k: int, num_k_tiles: int,
-                   window: Optional[int], grid_majors: int, pieces):
+                   window: Optional[int], grid_majors: int, pieces,
+                   block: Optional[int] = None):
     """dQ = (P * (dO V^T - delta)) K * scale, accumulated over the k tiles
     from the window's edge up to the diagonal. Grid (bh, q-tile, k-major)
     as the forward's, and a tile in the forward's ``pieces``: a strip of q
     rows forms P, dP and dS on its columns and adds to its rows of
-    ``dq_acc``."""
+    ``dq_acc``. Block diffusion as the forward's: ``dq_acc`` starts from the
+    rows' own blocks, on the K and V tile that leads ``refs``."""
+    if block is not None:
+        k_own, v_own, *refs = refs
+    dq_ref, dq_acc = refs
     step = pl.program_id(2)
     q_idx = pl.program_id(1)
     tiles_per_major = major_k // tile_k
 
     @pl.when(step == 0)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        if block is None:
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+            return
+        q_scaled = q_ref[0].astype(jnp.float32) * scale
+        do_blk = do_ref[0].astype(jnp.float32)
+        lse, delta = _col(lse_ref[0]), _col(delta_ref[0])
+        for rows, *_ in pieces[1]:
+            k_blk = k_own[0, rows, :].astype(jnp.float32)
+            p = _recompute_p(q_scaled[rows], k_blk, lse[rows], mask="own",
+                             q_pos0=0, k_pos0=0, block=block)
+            dp = jax.lax.dot_general(  # dO V^T on the rows' own square
+                do_blk[rows], v_own[0, rows, :].astype(jnp.float32),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            dq_acc[rows, :] = jax.lax.dot_general(
+                p * (dp - delta[rows]) * scale, k_blk,
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
+    if block is not None:
+        q_idx = q_idx % num_k_tiles     # the tile's place in its half
     lo, a, b, end = _k_walk(
         q_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
         num_k_tiles=num_k_tiles, causal=causal, window=window)
@@ -631,7 +741,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                     q_scaled[rows], k_blk, lse[rows], mask=mask,
                     q_pos0=q_pos0 + rows.start,
                     k_pos0=kk * major_k + j * tile_k + first_col,
-                    window=window)
+                    window=window, block=block)
                 dp = jax.lax.dot_general(  # dO V^T  [rows, count]
                     do_blk[rows], v_blk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -655,11 +765,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0, ...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                    scale: float,
                     causal: bool, q_offset: int, tile_q: int, tile_k: int,
                     major_q: int, num_q_tiles: int, window: Optional[int],
-                    group: int, grid_majors: int, pieces):
+                    group: int, grid_majors: int, pieces,
+                    block: Optional[int] = None):
     """dV = P^T dO and dK = (P * (dP - delta))^T Q for one k tile of one
     K/V head, accumulated over the q tiles of the resident major block from
     the diagonal to the window's far edge, and over the query heads of the
@@ -671,7 +782,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     (``_pieces(transposed=True)``): a strip of *k* rows against the q
     columns it can see — from its own on in the diagonal tile, up to its
     own in the window's edge tile — adding to its rows of ``dk_acc`` and
-    ``dv_acc``."""
+    ``dv_acc``.
+
+    Block diffusion (``block``): the k tiles run over both halves,
+    ``num_q_tiles`` counts one half's q tiles, and the sequential axis takes
+    each query head twice, its clean half and then its noisy one
+    (``_dkv_step`` over ``2 * group`` members). A *clean* k tile walks each
+    half's q tiles from its own place on, masked by block; a noisy one walks
+    none. Either adds, once a query head, what the rows of the q tile at its
+    own index give their own blocks (``refs`` lead with that tile of Q and
+    dO and its statistics)."""
+    if block is not None:
+        q_own, do_own, lse_own, delta_own, *refs = refs
+    dk_ref, dv_ref, dk_acc, dv_acc = refs
     step = pl.program_id(2)
     k_idx = pl.program_id(1)
     tiles_per_major = major_q // tile_q
@@ -684,7 +807,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     start, a, b, stop = _q_walk(
         k_idx, q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
         num_q_tiles=num_q_tiles, causal=causal, window=window)
-    _, major_step = _dkv_step(step, group, grid_majors)
+    member, major_step = _dkv_step(step, group if block is None
+                                   else 2 * group, grid_majors)
     iq = _major_index(major_step, start, tiles_per_major, grid_majors,
                       num_q_tiles // tiles_per_major)
     first = _first_tile(iq, tiles_per_major, num_q_tiles)
@@ -693,6 +817,30 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     needed = start < first + tiles_per_major
     if window is not None:
         needed = needed & (first < stop)
+    if block is not None:
+        needed = needed & (k_idx < num_q_tiles)     # a clean k tile
+
+    if block is not None:
+        @pl.when((member % 2 == 0) & (major_step == 0))
+        def _own():
+            for rows, *_ in pieces[1]:
+                q_blk = q_own[0, rows, :].astype(jnp.float32)
+                do_blk = do_own[0, rows, :].astype(jnp.float32)
+                p_t = _recompute_p(
+                    q_blk * scale, k_ref[0, rows, :].astype(jnp.float32),
+                    lse_own[0, :, rows], mask="own", q_pos0=0, k_pos0=0,
+                    transposed=True, block=block)
+                dv_acc[rows, :] += jax.lax.dot_general(  # P^T dO [rows, d]
+                    p_t, do_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp_t = jax.lax.dot_general(  # V dO^T  [rows, rows]
+                    v_ref[0, rows, :].astype(jnp.float32), do_blk,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds_t = p_t * (dp_t - delta_own[0, :, rows]) * scale
+                dk_acc[rows, :] += jax.lax.dot_general(  # dS^T Q [rows, d]
+                    ds_t, q_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     @pl.when(needed)
     def _run():
@@ -710,7 +858,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     mask=mask,
                     q_pos0=q_offset + iq * major_q + i * tile_q + first_col,
                     k_pos0=k_pos0 + rows.start, transposed=True,
-                    window=window)
+                    window=window, block=block)
                 dv_acc[rows, :] += jax.lax.dot_general(  # P^T dO [rows, d]
                     p_t, do_blk, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -838,14 +986,18 @@ def _operand_row_bytes(head_dim: int, dtype,
 
 
 def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, group: int,
-                   grid_majors: int, **schedule):
+                   grid_majors: int, wrap: bool = False, **schedule):
     """BlockSpec of K or V on a (q head, q-tile, k-major) grid: the block
     of the K/V head that the q head's group shares. A major block wholly in
     a q tile's future is not fetched: the index stays on the last one the
-    tile needs."""
+    tile needs. ``wrap`` (block diffusion): the q tiles run over two halves
+    and walk the ``num_k_tiles`` of the first from their place in their
+    own."""
     tiles_per_major = major_k // schedule["tile_k"]
 
     def index(bh, i, step):
+        if wrap:
+            i = i % num_k_tiles
         lo, _, _, end = _k_walk(i, num_k_tiles=num_k_tiles, **schedule)
         kk = _major_index(step, lo, tiles_per_major, grid_majors,
                           num_k_tiles // tiles_per_major)
@@ -855,25 +1007,39 @@ def _kv_major_spec(major_k: int, head_dim: int, num_k_tiles: int, group: int,
     return pl.BlockSpec((1, major_k, head_dim), index)
 
 
+def _own_kv_specs(tile: int, group: int, head_dim: int, v_dim: int) -> list:
+    """BlockSpecs of the K and the V tile at a q tile's own index, on a (q
+    head, q-tile, k-major) grid: where block diffusion's rows find their own
+    blocks."""
+    return [pl.BlockSpec((1, tile, d), lambda bh, i, kk: (bh // group, i, 0))
+            for d in (head_dim, v_dim)]
+
+
 def _compiler_params(interpret: bool):
     return None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _kernel_names(window: Optional[int], split: bool = False) -> dict:
+def _kernel_names(window: Optional[int], split: bool = False,
+                  block: Optional[int] = None) -> dict:
     """The ``pallas_call`` names by ``causal_schedule``'s keys: a call with
-    a window, and one whose v is of another width than its q (``split``:
-    latent attention), are named apart, so that a trace tells them apart."""
-    prefix = "flash_mla" if split else \
+    a window, one whose v is of another width than its q (``split``:
+    latent attention) and one under block diffusion's mask are named apart,
+    so that a trace tells them apart."""
+    prefix = "flash_bd" if block is not None else \
+        "flash_mla" if split else \
         "flash" if window is None else "flash_win"
     return {name: prefix + name[len("flash"):] for name in
             ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
 
 
-def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
+def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window,
+              block=None):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
     seq_k, kv_heads, v_dim = k.shape[1], k.shape[2], v.shape[-1]
+    if block is not None:
+        seq_k //= 2     # the clean half: what the walk streams
     num_k_tiles = seq_k // tile_k
     # beside K and V, a row of the major block holds its f32 scores
     major_k = _major(seq_k, tile_k, _operand_row_bytes(
@@ -881,25 +1047,29 @@ def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
     grid_majors = _grid_majors(seq_k // major_k, major_k, tile_q, window)
     lanes = _LANES if tile_k % _LANES == 0 else tile_k
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
-    name = _kernel_names(window, v_dim != head_dim)["flash_fwd"]
+    name = _kernel_names(window, v_dim != head_dim, block)["flash_fwd"]
     _PAIR_RATIO.labels(kernel=name).set(causal_schedule(
-        seq_q, seq_k, q_offset, tile_q, tile_k, causal, window)
+        seq_q, k.shape[1], q_offset, tile_q, tile_k, causal, window, block)
         ["flash_fwd"]["pair_ratio"])
     schedule = dict(q_offset=q_offset, tile_q=tile_q, tile_k=tile_k,
                     causal=causal, window=window)
+    group = heads // kv_heads
 
     q_spec, o_spec = (pl.BlockSpec((1, tile_q, d), lambda bh, i, kk: (bh, i, 0))
                       for d in (head_dim, v_dim))
-    k_spec, v_spec = (_kv_major_spec(major_k, d, num_k_tiles,
-                                     heads // kv_heads, grid_majors,
+    k_spec, v_spec = (_kv_major_spec(major_k, d, num_k_tiles, group,
+                                     grid_majors, block is not None,
                                      **schedule) for d in (head_dim, v_dim))
+    own_specs, own = [], ()
+    if block is not None:
+        own_specs, own = _own_kv_specs(tile_q, group, head_dim, v_dim), (kb, vb)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, major_k=major_k,
                           num_k_tiles=num_k_tiles, grid_majors=grid_majors,
-                          pieces=_pieces(**schedule),
+                          pieces=_pieces(**schedule), block=block,
                           **schedule),
         grid=(batch * heads, seq_q // tile_q, grid_majors),
-        in_specs=[q_spec, k_spec, v_spec],
+        in_specs=[q_spec, k_spec, v_spec, *own_specs],
         out_specs=[
             o_spec,
             pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0)),
@@ -919,18 +1089,21 @@ def _fwd_impl(q, k, v, causal, scale, tiles, interpret, q_offset, window):
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name=name,
-    )(qb, kb, vb)
+    )(qb, kb, vb, *own)
     # the kernel repeats each row's value along the lane axis; the residual
     # kept for the backward pass is the O(T) vector
     return _from_bh(o, batch, heads), lse[..., 0]
 
 
 def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
-              q_offset, window):
+              q_offset, window, block=None):
     tile_q, tile_k = tiles
     batch, seq_q, heads, head_dim = q.shape
     seq_k, kv_heads, v_dim = k.shape[1], k.shape[2], v.shape[-1]
     group = heads // kv_heads
+    halves = 1 if block is None else 2
+    # under block diffusion the walks are over one half's tiles
+    seq_q, seq_k = seq_q // halves, seq_k // halves
     num_q_tiles = seq_q // tile_q
     num_k_tiles = seq_k // tile_k
     row_bytes = _operand_row_bytes(head_dim, k.dtype, v_dim)
@@ -943,9 +1116,9 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
                      None if window is None else tile_k + window - 1)
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     ob, dob = _to_bh(o), _to_bh(do)
-    names = _kernel_names(window, v_dim != head_dim)
-    executed = causal_schedule(seq_q, seq_k, q_offset, tile_q, tile_k,
-                               causal, window)
+    names = _kernel_names(window, v_dim != head_dim, block)
+    executed = causal_schedule(halves * seq_q, halves * seq_k, q_offset,
+                               tile_q, tile_k, causal, window, block)
     for key in ("flash_bwd_dq", "flash_bwd_dkv"):
         _PAIR_RATIO.labels(kernel=names[key]).set(
             executed[key]["pair_ratio"])
@@ -967,23 +1140,28 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
                                     lambda bh, i, kk: (bh, i, 0))
                        for d in (head_dim, v_dim))
     k_spec, v_spec = (_kv_major_spec(major_k, d, num_k_tiles, group, k_majors,
-                                     **schedule) for d in (head_dim, v_dim))
+                                     block is not None, **schedule)
+                      for d in (head_dim, v_dim))
     col_spec = pl.BlockSpec((1, tile_q, _LANES), lambda bh, i, kk: (bh, i, 0))
+    own_specs, own = [], ()
+    if block is not None:
+        own_specs, own = _own_kv_specs(tile_q, group, head_dim, v_dim), (kb, vb)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, major_k=major_k,
                           num_k_tiles=num_k_tiles, grid_majors=k_majors,
-                          pieces=_pieces(**schedule),
+                          pieces=_pieces(**schedule), block=block,
                           **schedule),
-        grid=(batch * heads, num_q_tiles, k_majors),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, col_spec, col_spec],
+        grid=(batch * heads, halves * num_q_tiles, k_majors),
+        in_specs=[q_spec, k_spec, v_spec, do_spec, col_spec, col_spec,
+                  *own_specs],
         out_specs=q_spec,
-        out_shape=_sds((batch * heads, seq_q, head_dim), q.dtype,
+        out_shape=_sds((batch * heads, halves * seq_q, head_dim), q.dtype,
                        q, k, v, do),
         scratch_shapes=[pltpu.VMEM((tile_q, head_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name=names["flash_bwd_dq"],
-    )(qb, kb, vb, dob, lse_cols, delta_cols)
+    )(qb, kb, vb, dob, lse_cols, delta_cols, *own)
 
     # dK/dV grid: (kv head, k-tile, group x q-major) — the streamed q-side
     # operands re-index by the LAST grid axis here: the query heads of the
@@ -992,13 +1170,23 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
     q_majors = _grid_majors(num_q_majors, major_q, tile_k, window)
 
     def q_head(bh, step):
-        member, _ = _dkv_step(step, group, q_majors)
+        member, _ = _dkv_step(step, halves * group, q_majors)
+        if block is not None:
+            member = member // 2
         return bh if group == 1 else bh * group + member
 
     def q_major(kk, step):
-        _, major_step = _dkv_step(step, group, q_majors)
+        member, major_step = _dkv_step(step, halves * group, q_majors)
         start, _, _, stop = _q_walk(kk, num_q_tiles=num_q_tiles, **schedule)
         tiles_per_major = major_q // tile_q
+        if block is not None:
+            # each query head's clean half, then its noisy one; a noisy k
+            # tile walks neither and waits where the last clean one ended
+            waited = jnp.maximum(major_step, jnp.minimum(
+                start // tiles_per_major, num_q_majors - 1))
+            return jnp.where(kk < num_k_tiles,
+                             member % 2 * num_q_majors + waited,
+                             2 * num_q_majors - 1)
         if window is None:
             # a major block wholly in this k tile's past is not fetched:
             # the index waits on the first one the tile needs
@@ -1011,63 +1199,83 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale, tiles, interpret,
         last = jnp.maximum((stop - 1) // tiles_per_major, first)
         return jnp.clip(iq, first, last)
 
+    def streamed_head(bh, kk, step):
+        if block is None:
+            return q_head(bh, step)
+        return jnp.where(kk < num_k_tiles, q_head(bh, step),
+                         bh * group + group - 1)
+
     kv_q_spec, kv_do_spec = (pl.BlockSpec(
         (1, major_q, d),
-        lambda bh, kk, step: (q_head(bh, step), q_major(kk, step), 0))
+        lambda bh, kk, step: (streamed_head(bh, kk, step),
+                              q_major(kk, step), 0))
         for d in (head_dim, v_dim))
     kv_k_spec, kv_v_spec = (pl.BlockSpec((1, tile_k, d),
                                          lambda bh, kk, step: (bh, kk, 0))
                             for d in (head_dim, v_dim))
     kv_row_spec = pl.BlockSpec(
         (1, 1, major_q),
-        lambda bh, kk, step: (q_head(bh, step), 0, q_major(kk, step)))
+        lambda bh, kk, step: (streamed_head(bh, kk, step), 0,
+                              q_major(kk, step)))
+    own_specs, own = [], ()
+    if block is not None:
+        # the q tile at the k tile's own index, of the step's query head
+        own_specs = [pl.BlockSpec(
+            (1, tile_k, d), lambda bh, kk, step: (q_head(bh, step), kk, 0))
+            for d in (head_dim, v_dim)] + 2 * [pl.BlockSpec(
+                (1, 1, tile_k),
+                lambda bh, kk, step: (q_head(bh, step), 0, kk))]
+        own = (qb, dob, lse_rows, delta_rows)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, major_q=major_q,
                           num_q_tiles=num_q_tiles, group=group,
                           grid_majors=q_majors,
                           pieces=_pieces(transposed=True, **schedule),
-                          **schedule),
-        grid=(batch * kv_heads, num_k_tiles, group * q_majors),
+                          block=block, **schedule),
+        grid=(batch * kv_heads, halves * num_k_tiles,
+              halves * group * q_majors),
         in_specs=[kv_q_spec, kv_k_spec, kv_v_spec, kv_do_spec,
-                  kv_row_spec, kv_row_spec],
+                  kv_row_spec, kv_row_spec, *own_specs],
         out_specs=[kv_k_spec, kv_v_spec],
         out_shape=[
-            _sds((batch * kv_heads, seq_k, head_dim), k.dtype, q, k, v, do),
-            _sds((batch * kv_heads, seq_k, v_dim), v.dtype, q, k, v, do),
+            _sds((batch * kv_heads, halves * seq_k, head_dim), k.dtype,
+                 q, k, v, do),
+            _sds((batch * kv_heads, halves * seq_k, v_dim), v.dtype,
+                 q, k, v, do),
         ],
         scratch_shapes=[pltpu.VMEM((tile_k, head_dim), jnp.float32),
                         pltpu.VMEM((tile_k, v_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name=names["flash_bwd_dkv"],
-    )(qb, kb, vb, dob, lse_rows, delta_rows)
+    )(qb, kb, vb, dob, lse_rows, delta_rows, *own)
 
     return (_from_bh(dq, batch, heads), _from_bh(dk, batch, kv_heads),
             _from_bh(dv, batch, kv_heads))
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-           q_offset, window):
+           q_offset, window, block):
     o, _ = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret, q_offset,
-                     window)
+                     window, block)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-               q_offset, window):
+               q_offset, window, block):
     o, lse = _fwd_impl(q, k, v, causal, scale, fwd_tiles, interpret,
-                       q_offset, window)
+                       q_offset, window, block)
     o, lse = map(checkpoint_name, (o, lse), KEPT_NAMES)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, scale, fwd_tiles, bwd_tiles, interpret, q_offset,
-               window, res, do):
+               window, block, res, do):
     q, k, v, o, lse = res
     return _bwd_impl(q, k, v, o, lse, do, causal, scale, bwd_tiles,
-                     interpret, q_offset, window)
+                     interpret, q_offset, window, block)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1075,14 +1283,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret", "q_offset",
-    "window"))
+    "window", "block_diffusion"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     q_offset: int = 0,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None) -> jax.Array:
     """Fused attention, q ``[batch, seq, heads, head_dim]``, k ``[batch,
     seq_k, kv_heads, head_dim]`` and v ``[batch, seq_k, kv_heads, v_dim]``;
     the result is ``[batch, seq, heads, v_dim]``. Differentiable (custom VJP
@@ -1106,6 +1315,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     tiles, which the shapes, ``causal`` and ``window`` choose (``_tiles``);
     sequence lengths must be multiples of the tiles (pad upstream; a tile
     is the whole sequence when that is shorter).
+
+    ``block_diffusion`` (causal only, no window or offset; a length that
+    divides 128) is the mask a block-diffusion model trains under: the rows
+    are the clean copy of each sequence and then its noisy copy, ``seq =
+    seq_k = 2 L``; a clean row sees the clean rows of its block and of those
+    before it, a noisy row the *clean* rows of the blocks before its own and
+    the *noisy* rows of its own, under one softmax. The tiles are chosen for
+    ``L`` and must be square; the three calls are named ``flash_bd_*``.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -1126,6 +1343,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             "window needs causal=True, window >= 1 and every query's own "
             f"position among the keys (q_offset {q_offset} + {seq_q} "
             f"queries > {seq_k} keys)")
+    if block_diffusion is not None:
+        if not causal or window is not None or q_offset or seq_q != seq_k \
+                or seq_q % 2 or block_diffusion < 1 \
+                or _LANES % block_diffusion:
+            raise ValueError(
+                "block_diffusion needs causal=True, no window or q_offset, "
+                "a block length that divides 128 and as many q as k rows, "
+                f"each sequence's clean and then its noisy copy (got "
+                f"{block_diffusion}, {seq_q} and {seq_k} rows)")
+        seq_q = seq_k = seq_q // 2      # the tiles and the walks are a half's
     fwd_tiles, bwd_tiles = _tiles(seq_q, seq_k, q.shape[-1], k.dtype,
                                   block_q, block_k, window, v.shape[-1])
     for tile_q, tile_k in (fwd_tiles, bwd_tiles):
@@ -1133,5 +1360,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError(
                 f"sequence lengths ({seq_q}, {seq_k}) must be multiples of "
                 f"the block sizes ({tile_q}, {tile_k}); pad inputs first.")
+        if block_diffusion is not None and (
+                tile_q != tile_k or tile_q % block_diffusion):
+            raise ValueError(
+                f"block_diffusion needs square tiles of whole blocks, got "
+                f"({tile_q}, {tile_k}) for blocks of {block_diffusion}")
     return _flash(q, k, v, causal, scale, fwd_tiles, bwd_tiles, interpret,
-                  q_offset, window)
+                  q_offset, window, block_diffusion)

@@ -123,6 +123,37 @@ def test_kernels_compile_for_v5e(one_chip, no_compile_cache, case):
         assert pa._major(seq_k, bwd[1], rows) < seq_k
 
 
+def test_block_diffusion_kernels_compile_for_v5e(one_chip, no_compile_cache):
+    """The ``flash_bd_*`` calls at the shape of ``sdar_moe_8k_1chip`` — one
+    sequence's 8,192 clean and 8,192 noisy rows, 32 query heads on 4 K/V
+    heads of 128, blocks of 4 — with the K/V tile at the q tile's own index
+    as two more operands: in strips, within the kernels' VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), "bfloat16",
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), "bfloat16",
+                              sharding=one_chip)
+    attention = functools.partial(pa.flash_attention, causal=True,
+                                  block_diffusion=4, interpret=False)
+    hlo = jax.jit(jax.grad(
+        lambda q, k, v: attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    for name in pa._kernel_names(None, block=4).values():
+        assert f"%{name}" in hlo, name
+    fwd, bwd = pa._tiles(8192, 8192, 128, "bfloat16", None, None)
+    assert (fwd, bwd) == ((1024, 1024), (512, 512))
+    for kernel, tiles in (("flash_fwd", fwd), ("flash_bwd_dq", bwd),
+                          ("flash_bwd_dkv", bwd)):
+        executed = pa.causal_schedule(16384, 16384, 0, *tiles, True,
+                                      block_diffusion=4)[kernel]
+        assert executed["trimmed"] and executed["pair_ratio"] < 1.05
+
+
 # the kernels of kimi_linear_16k_1chip at the cell's shapes: latent
 # attention's flash calls with q and k 192 wide and v 128 (nothing padded,
 # the calls named apart), and the delta rule's chain, forward and transposed

@@ -177,3 +177,72 @@ def test_initialising_through_the_loss_makes_the_same_parameters():
         == jax.tree_util.tree_structure(through)
     for a, b in zip(*map(jax.tree_util.tree_leaves, (plain, through))):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("batch, seq", [(2, 2048 + 100), (3, 50)],
+                         ids=["blocks_and_a_tail", "a_tail"])
+def test_targets_and_weights_against_whole_logits(batch, seq):
+    """A row scored against its own target, no shift, times its weight
+    (zero on half of the rows, up to hundreds on others), the sum over the
+    ``B * T`` rows: the loss and the gradients to the hidden states and the
+    kernel; targets and weights get none."""
+    d, vocab = 16, 40
+    keys = jax.random.split(jax.random.PRNGKey(seq), 5)
+    x = jax.random.normal(keys[0], (batch, seq, d)).astype(jnp.bfloat16)
+    kernel = 0.3 * jax.random.normal(keys[1], (d, vocab))
+    targets = jax.random.randint(keys[2], (batch, seq), 0, vocab)
+    rate = jax.random.uniform(keys[3], (batch, seq), minval=1e-3)
+    weights = jnp.where(jax.random.uniform(keys[4], (batch, seq)) < rate,
+                        1.0 / rate, 0.0)
+
+    def whole(x, kernel):
+        logp = jax.nn.log_softmax(x.astype(jnp.float32) @ kernel, -1)
+        picked = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+        return -3.0 * jnp.sum(weights * picked) / targets.size
+
+    def blocked(x, kernel):
+        return 3.0 * lm_head_loss(x, kernel, None, targets=targets,
+                                  weights=weights)
+
+    want = jax.jit(jax.value_and_grad(whole, (0, 1)))(x, kernel)
+    got = jax.jit(jax.value_and_grad(blocked, (0, 1)))(x, kernel)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=2e-6)
+    for a, b in zip(got[1], want[1]):
+        a, b = (np.asarray(g, np.float32) for g in (a, b))
+        np.testing.assert_allclose(
+            a, b, rtol=1e-2 if a.shape == x.shape else 2e-5,
+            atol=2e-6 * np.abs(b).max())
+    with pytest.raises(ValueError, match="tokens, or targets and weights"):
+        lm_head_loss(x, kernel, None, targets, targets=targets,
+                     weights=weights)
+    with pytest.raises(ValueError, match="tokens, or targets and weights"):
+        lm_head_loss(x, kernel, None, targets=targets)
+
+
+def test_weight_one_and_shifted_targets_are_the_next_token_loss():
+    """The weighted form given what the next-token form makes for itself —
+    each row's next token, every position but a sequence's last counted,
+    ``T / (T - 1)`` each since it divides by all ``B * T`` rows — against
+    the next-token form: the same loss and gradients."""
+    batch, seq, d, vocab = 2, 2048 + 100, 16, 40
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(keys[0], (batch, seq, d)).astype(jnp.bfloat16)
+    kernel = 0.3 * jax.random.normal(keys[1], (d, vocab))
+    bias = 0.1 * jax.random.normal(keys[2], (vocab,))
+    tokens = jax.random.randint(keys[3], (batch, seq), 0, vocab)
+    weights = jnp.broadcast_to(
+        jnp.where(jnp.arange(seq) < seq - 1, seq / (seq - 1.0), 0.0),
+        tokens.shape)
+
+    def weighted(x, kernel, bias):
+        return lm_head_loss(x, kernel, bias, targets=jnp.roll(tokens, -1, -1),
+                            weights=weights)
+
+    want = jax.jit(jax.value_and_grad(
+        lambda *a: lm_head_loss(*a, tokens), (0, 1, 2)))(x, kernel, bias)
+    got = jax.jit(jax.value_and_grad(weighted, (0, 1, 2)))(x, kernel, bias)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=2e-6)
+    for a, b in zip(got[1], want[1]):
+        a, b = (np.asarray(g, np.float32) for g in (a, b))
+        np.testing.assert_allclose(a, b, rtol=1e-2 if a.shape == x.shape
+                                   else 2e-5, atol=2e-6 * np.abs(b).max())

@@ -1,0 +1,197 @@
+#!/usr/bin/env python
+"""One expert layer alone: device time of a forward + backward call of
+``models.laguna.ExpertLayer`` under ``jax.checkpoint``, at a cell's shape.
+
+    chiprun -- python benchmarks/expert_layer_bench.py --case laguna \\
+        --case sdar --case kimi --tree .pr_trees/parent --tree .
+
+Each ``--case`` is a cell's expert layer — ``laguna`` (16,384 rows of 2048,
+32 of 256 experts 512 wide held, a shared expert), ``sdar`` (16 of 128
+experts 768 wide, a softmax router, no shared expert), ``kimi`` (2304 wide,
+8 of 256 experts of 1024) — with an optional ``,slice=<slots>``: the slots
+the loop of ``held_expert_sum`` takes at a time in place of the layer's own
+rule (what one slice costs, and what an iteration's fixed cost is; a tree
+from before that loop ignores it). The
+router is seeded and the rows are normal, so about ``held / num_experts``
+of the assignments come here, as on the cells' seeded weights. As a block
+runs it: the layer's output recomputed in the backward pass, gradients for
+the rows and every parameter.
+
+``layer_ms`` is the whole device program a call, ``kernels_ms`` the three
+``expert_matmul_*`` calls in it, both read from a profiler trace of
+``--iters`` calls, ``ops_ms`` its twelve longest operations as a cell's
+``breakdown.device_ops`` names them (a loop is one of them, and spans its
+body's); ``slices_run`` and ``slot_fill`` are
+what the layer sows (null on a tree that sows neither). Each ``--tree``
+measures that checkout's ``horovod_tpu`` (a ``git archive`` of the parent
+beside this one): give the option more than once to compare in one chip
+call.
+
+One process, on the device it measures: exits non-zero without a TPU
+unless ``--rehearse-cpu`` asks for a CPU run at 512 rows (the Pallas
+interpreter: a check of the script, never a time). Prints one JSON line
+per case and tree, stamped with ``platform`` / ``device_kind``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import importlib
+import json
+import os
+import re
+import sys
+import tempfile
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KERNELS = ("expert_matmul_fwd", "expert_matmul_bwd_dx", "expert_matmul_bwd_dw")
+CELLS = {
+    "laguna": dict(rows=16384, d=2048, num_experts=256, experts_per_token=8,
+                   held=32, width=512, shared_width=512, scaling=2.5,
+                   scoring="sigmoid"),
+    "sdar": dict(rows=16384, d=2048, num_experts=128, experts_per_token=8,
+                 held=16, width=768, shared_width=0, scaling=1.0,
+                 scoring="softmax"),
+    "kimi": dict(rows=16384, d=2304, num_experts=256, experts_per_token=8,
+                 held=8, width=1024, shared_width=1024, scaling=2.446,
+                 scoring="sigmoid"),
+}
+
+
+def parse_case(text: str) -> dict:
+    name, *options = text.split(",")
+    if name not in CELLS:
+        raise ValueError(f"case {text!r}: one of {sorted(CELLS)}")
+    case = {"case": name, **CELLS[name], "slice": None}
+    for option in options:
+        key, _, value = option.partition("=")
+        if key != "slice":
+            raise ValueError(f"case {text!r}: unknown option {key!r}")
+        case[key] = int(value)
+    return case
+
+
+def slices_of(size: int):
+    """``models.laguna.slice_slots`` answering ``size`` whatever the shapes
+    (in tiles of the kernel's, or of 8 slots below one)."""
+    def rule(capacity, held, num_experts):
+        from horovod_tpu.ops.grouped_matmul import ROW_TILE
+
+        tile = ROW_TILE if size >= ROW_TILE else 8
+        return max(size // tile, 1) * tile, tile
+    return rule
+
+
+def measure(case: dict, iters: int, trace_root: str) -> dict:
+    """One case's line, under the case's slice where it names one."""
+    from horovod_tpu.models import laguna
+
+    own_rule = getattr(laguna, "slice_slots", None)
+    if case["slice"] and own_rule:
+        laguna.slice_slots = slices_of(case["slice"])
+    try:
+        return _measure(laguna, case, iters, trace_root)
+    finally:
+        if own_rule:
+            laguna.slice_slots = own_rule
+
+
+def _measure(laguna, case: dict, iters: int, trace_root: str) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import trace_reduce
+    from horovod_tpu import obs
+
+    layer = laguna.ExpertLayer(
+        num_experts=case["num_experts"],
+        experts_per_token=case["experts_per_token"],
+        experts_held=(0, case["held"]), width=case["width"],
+        shared_width=case["shared_width"], scaling=case["scaling"],
+        scoring=case["scoring"])
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    x, cot = (jax.random.normal(key, (1, case["rows"], case["d"]),
+                                jnp.bfloat16) for key in keys[:2])
+    params = jax.jit(layer.init)(keys[2], x)["params"]
+
+    @jax.jit
+    def call(params, x):
+        run = jax.checkpoint(lambda p, x: layer.apply({"params": p}, x))
+        return jax.value_and_grad(lambda p, x: jnp.vdot(
+            run(p, x).astype(jnp.float32), cot), argnums=(0, 1))(params, x)
+
+    for _ in range(3):
+        jax.block_until_ready(call(params, x))
+    trace_dir = tempfile.mkdtemp(dir=trace_root)
+    with jax.profiler.trace(trace_dir):
+        for _ in range(iters):
+            result = call(params, x)
+        jax.block_until_ready(result)
+
+    sown = obs.moe.publish({"layer": jax.jit(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=["moe_stats"])[1]["moe_stats"])(params, x)})
+    line = {**case, "iters": iters,
+            **{name: sown["layer"].get(name) for name in (
+                "held_share", "slices_run", "slot_fill")}}
+    device = next((lines for name, lines in
+                   trace_reduce.load(trace_dir).items()
+                   if trace_reduce.DEVICE_PLANE.match(name)), None)
+    if device is None:  # the CPU backend traces no device plane
+        return line
+    seconds, groups = collections.Counter(), collections.Counter()
+    for event in device.get(trace_reduce.OPS_LINE, []):
+        stem = re.sub(r"\.\d+$", "", trace_reduce.parse_op(event.name)[0])
+        seconds[stem] += event.dur_ns * 1e-9
+        groups[trace_reduce.group_of(event.name)] += event.dur_ns * 1e-9
+    for name in KERNELS:
+        line[f"{name}_ms"] = 1e3 * seconds[name] / iters
+    line["kernels_ms"] = 1e3 * sum(seconds[name] for name in KERNELS) / iters
+    # the longest operations as a cell's breakdown names them (name, opcode
+    # and output type), loops (which span their bodies) too
+    line["ops_ms"] = [[name, round(1e3 * total / iters, 3)]
+                      for name, total in groups.most_common(12)]
+    modules = device.get(trace_reduce.MODULES_LINE, [])
+    line["layer_ms"] = 1e-6 * sum(e.dur_ns for e in modules) / iters
+    return line
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(
+        description=__doc__, formatter_class=argparse.RawTextHelpFormatter)
+    parser.add_argument("--case", action="append", required=True,
+                        type=parse_case)
+    parser.add_argument("--iters", type=int, default=5)
+    parser.add_argument("--tree", action="append",
+                        help="a checkout whose horovod_tpu is measured")
+    parser.add_argument("--rehearse-cpu", action="store_true")
+    args = parser.parse_args(argv)
+    sys.path.insert(0, _ROOT)
+    if args.rehearse_cpu:
+        os.environ["HOROVOD_BENCH_PLATFORM"] = "cpu"
+        args.case = [{**case, "rows": min(case["rows"], 512)}
+                     for case in args.case]
+
+    from bench import _bench_device, _device_stamp
+
+    device = _bench_device()
+    trace_root = os.path.join(_ROOT, ".chipbench_trace")
+    os.makedirs(trace_root, exist_ok=True)
+    for tree in args.tree or [_ROOT]:
+        # this tree's package in place of the last one's
+        for name in [m for m in sys.modules
+                     if m.split(".")[0] == "horovod_tpu"]:
+            del sys.modules[name]
+        sys.path.insert(0, os.path.abspath(tree))
+        importlib.invalidate_caches()
+        for case in args.case:
+            line = measure(case, args.iters, trace_root)
+            print(json.dumps({
+                **line, "tree": os.path.relpath(tree, _ROOT),
+                **_device_stamp(device, 1)}), flush=True)
+        sys.path.pop(0)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

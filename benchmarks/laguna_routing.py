@@ -10,11 +10,13 @@ Two readings that the benchmark's runs do not print:
   router's own products are float32 in both; its input is not) — the
   discontinuity that ``correct``'s limits have to live with;
 * the share of assignments that go to the experts held here
-  (``horovod_moe_held_assignment_share``) and the busiest expert's load
-  (``horovod_moe_expert_load_max_over_mean``) as the cell's own trainer
-  steps through its pool, with each step's time beside them: a chip's share
-  of the experts is the only part of the routed sum the loss sees, so
-  training moves the router.
+  (``horovod_moe_held_assignment_share``), the busiest expert's load
+  (``horovod_moe_expert_load_max_over_mean``) and the slices the held
+  experts' loop ran and how full they were (``horovod_moe_slices_run``,
+  ``horovod_moe_slot_fill``) as the cell's own trainer steps through its
+  pool, with each step's time beside them: a chip's share of the experts
+  is the only part of the routed sum the loss sees, so training moves the
+  router, and a step costs the rows routed here.
 """
 
 from __future__ import annotations
@@ -90,10 +92,9 @@ def main(argv=None) -> int:
             if step % args.every == 0:
                 batch = trainer.pool[step % len(trainer.pool)][0]
                 routed = hvd.obs.moe.publish(stats(trainer.state[0], batch))
-                row["held_share"] = [v["held_share"]
-                                     for _, v in sorted(routed.items())]
-                row["load_max_over_mean"] = [
-                    v["load_max_over_mean"] for _, v in sorted(routed.items())]
+                for name in ("held_share", "load_max_over_mean",
+                             "slices_run", "slot_fill"):
+                    row[name] = [v[name] for _, v in sorted(routed.items())]
             t = time.perf_counter()
             row["loss"] = float(trainer.step())
             row["step_ms"] = 1e3 * (time.perf_counter() - t)

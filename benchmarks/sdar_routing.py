@@ -8,11 +8,11 @@ For every seed and every batch of its pool, on the seeded weights, what the
 benchmark's runs do not print: the gauges of the noise (``horovod_bd_masked_
 share``, ``horovod_bd_mean_weight``: ``obs.bd``), the routing gauges layer
 by layer (``horovod_moe_held_assignment_share``, ``horovod_moe_expert_load_
-max_over_mean``: ``obs.moe``) and, from the same counts, the slots the held
-experts' rows take in whole tiles of the grouped-product kernel against the
-slots the expert layer's first, unconditional pass has
-(``models.laguna.held_expert_sum``): a batch that needs more runs a further
-pass, and its step takes longer than its neighbours'.
+max_over_mean``: ``obs.moe``) and of the loop that multiplies the held
+experts' rows (``models.laguna.held_expert_sum``) the slices it ran and
+how full they were (``horovod_moe_slices_run``, ``horovod_moe_slot_fill``):
+a batch that routes more rows here runs more slices, and its step takes as
+much longer than its neighbours'.
 """
 
 from __future__ import annotations
@@ -41,15 +41,11 @@ def main(argv=None) -> int:
     run.take_devices(cell, args.rehearse_cpu)
 
     import jax
-    import numpy as np
 
     from horovod_tpu import obs
-    from horovod_tpu.models.laguna import SLICE_OF_EVEN
-    from horovod_tpu.ops.grouped_matmul import ROW_TILE
 
     family, config, traffic = cell.family, cell.config, cell.traffic
     model = family.build(config)
-    first, held = model.experts_held
     stats = jax.jit(lambda p, clean, noisy, weights: model.apply(
         {"params": p}, clean, noisy, weights=weights,
         mutable=["moe_stats", "bd_stats"])[1])
@@ -64,25 +60,15 @@ def main(argv=None) -> int:
             sown = stats(params, *batch)
             row = {"seed": seed, "batch": i, **obs.bd.publish(sown["bd_stats"])}
             routed = obs.moe.publish(sown["moe_stats"])
-            row["held_share"] = [v["held_share"]
-                                 for _, v in sorted(routed.items())]
-            row["load_max_over_mean"] = [
-                v["load_max_over_mean"] for _, v in sorted(routed.items())]
-            row["slots_used_over_first_pass"] = []
-            for layer in sorted(routed):
-                block, moe = layer.split("/")
-                counts = np.asarray(
-                    sown["moe_stats"][block][moe]["assignments"][-1])
-                room = SLICE_OF_EVEN * counts.sum() * held // counts.size
-                used = (-(-counts[first:first + held] // ROW_TILE)
-                        * ROW_TILE).sum()
-                row["slots_used_over_first_pass"].append(float(used / room))
+            for name in ("held_share", "load_max_over_mean", "slices_run",
+                         "slot_fill"):
+                row[name] = [v[name] for _, v in sorted(routed.items())]
             rows.append(row)
             print(json.dumps(row), flush=True)
         del params, pool
-    worst = max(max(r["slots_used_over_first_pass"]) for r in rows)
-    print(f"most slots a layer used of its first pass's: {worst:.3f} "
-          f"({'a further pass ran' if worst > 1 else 'no further pass'})")
+    print(f"slices a layer ran: {min(min(r['slices_run']) for r in rows)} to "
+          f"{max(max(r['slices_run']) for r in rows)}; the emptiest were "
+          f"{min(min(r['slot_fill']) for r in rows):.3f} full")
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w", encoding="utf-8") as fh:

@@ -18,9 +18,8 @@ the experts it holds give, plus the shared expert. What the absent experts
 would add is left out, and nothing stands in for their chips or their
 traffic. No token is dropped under any imbalance: the rows routed to held
 experts are sorted by expert and multiplied as grouped matrix products
-(``ops.grouped_matmul``) over a buffer sized for a few times their expected
-number (``SLICE_OF_EVEN``), and further passes, a loop whose trip count is
-the routing's, take whatever goes beyond that, up to every row.
+(``ops.grouped_matmul``), a small slice at a time in one loop whose trip
+count is the routing's: a pass costs its rows, up to every row.
 """
 
 from __future__ import annotations
@@ -38,16 +37,6 @@ from . import scopes
 from .transformer import LMHead
 
 ATTENTION_BACKENDS = ("flash", "dense")
-# rows the expert layer's first, unconditional pass has room for, over the
-# rows an even router would send to the held experts. The gather, the masks
-# and the scatter-add of a pass cost its slots, not the rows in them (the
-# kernels alone follow the rows), and an overflow costs a whole further
-# pass: the room buys a level step time with slots that stay empty, 37 ms
-# and 0.45 GB a step at the Laguna-XS.2 cell's size for 4 against 2 (my chip
-# run, PR 26). What filled 2 there was no trained model's routing but a
-# router frozen as seeded while the other weights trained on a cycled pool
-# (PERF.md section 6 and Open questions: make a pass follow its rows)
-SLICE_OF_EVEN = 4
 _INIT = nn.initializers.normal(0.02)
 
 
@@ -192,73 +181,93 @@ def route(scores, experts_per_token: int, scaling: float):
     return ids, scaling * top / jnp.sum(top, axis=-1, keepdims=True)
 
 
-def _expert_pass(lo, x, weights, w1, w3, w2, slots, tile_ends, size: int,
-                 tile: int):
-    """The held experts' weighted outputs for the ``size`` slots from ``lo``
-    on, added up by token: float32 [N, d]. ``slots`` holds, expert after
-    expert and each expert's rows padded to whole tiles, the index of an
-    assignment (token ``index // k``) or -1; expert ``e``'s tiles (of
-    ``tile`` rows) end at tile ``tile_ends[e]``. ``weights`` [N, k]."""
+def _slice(p, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
+    """Slice ``p`` of the slots through the experts: ``(rows, token, weight,
+    at, a, h, gated, y)``. ``slots`` holds, expert after expert, each one's
+    rows padded to whole tiles, the index of an assignment (``rows``, of
+    ``token``) or ``N * k``, past the arrays: a read there is clipped to the
+    last row (finite; no gradient takes it), an update dropped. Expert
+    ``e``'s tiles end at ``tile_ends[e]``, whence ``at``: each tile's group,
+    the active tiles, ``tile``. ``h = silu(a w1) * (a w3)``, ``y = h w2``."""
     from ..ops.grouped_matmul import grouped_matmul
 
-    k = weights.shape[1]
-    rows = jax.lax.dynamic_slice_in_dim(slots, lo, size)
-    valid = (rows >= 0)[:, None]
-    token = jnp.maximum(rows, 0) // k
-    tiles = lo // tile + jnp.arange(size // tile)
-    group = jnp.minimum(jnp.searchsorted(tile_ends, tiles, side="right"),
+    rows = jax.lax.dynamic_slice_in_dim(slots, p * size, size)
+    tiles = p * (size // tile) + jnp.arange(size // tile)
+    group = jnp.minimum(jnp.sum(tiles[:, None] >= tile_ends, axis=1),
                         w1.shape[0] - 1)
-    active = jnp.clip(tile_ends[-1] - lo // tile, 0, tiles.size)
-
-    def grouped(a, w):
-        # the tiles past the last active one belong to no product: whatever
-        # the kernel leaves there is replaced, forward and backward
-        return jnp.where(valid, grouped_matmul(a, w, group, active, tile), 0)
-
-    a = jnp.where(valid, x[token], 0)
-    y = grouped(nn.silu(grouped(a, w1)) * grouped(a, w3), w2)
-    y = y.astype(jnp.float32) * jnp.where(
-        valid, weights.reshape(-1)[jnp.maximum(rows, 0)][:, None], 0.0)
-    return jnp.zeros((x.shape[0], x.shape[1]), jnp.float32).at[token].add(y)
+    at = (group, jnp.clip(tile_ends[-1] - tiles[0], 0, tiles.size), tile)
+    token = rows // weights.shape[1]
+    a = x.at[token].get(mode="clip")
+    h, gated = jax.vjp(lambda h1, h3: nn.silu(h1) * h3,
+                       grouped_matmul(a, w1, *at), grouped_matmul(a, w3, *at))
+    weight = weights.reshape(-1).at[rows].get(mode="clip")[:, None]
+    return rows, token, weight, at, a, h, gated, grouped_matmul(h, w2, *at)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _with_further_passes(first, x, weights, w1, w3, w2, slots, tile_ends,
-                         size: int, tile: int):
-    """``first`` (the pass over the leading ``size`` slots) plus the passes
-    over as many further slices as the slots in use reach: a loop whose
-    trip count is the routing's, none at all under an even router. Its
-    backward pass runs the same loop, a pass recomputed and transposed at
-    a time, so that an overflow costs time and no memory."""
+def _loop(tile_ends, size, tile, one, like):
+    """``one(p, carry)`` over the slices in use, from f32 zeros ``like``."""
+    from ..ops.spmd import vary_like
+
     return jax.lax.fori_loop(
-        1, -(-tile_ends[-1] * tile // size),
-        lambda p, out: out + _expert_pass(
-            p * size, x, weights, w1, w3, w2, slots, tile_ends, size, tile),
-        first)
+        0, -(-tile_ends[-1] * tile // size), one,
+        vary_like(like[0], *(jnp.zeros(a.shape, jnp.float32) for a in like)))
 
 
-def _further_fwd(first, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
-    out = _with_further_passes(first, x, weights, w1, w3, w2, slots,
-                               tile_ends, size, tile)
-    return out, (x, weights, w1, w3, w2, slots, tile_ends)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _expert_loop(x, weights, w1, w3, w2, slots, tile_ends, size, tile):
+    """The held experts' weighted outputs added up by token, float32 [N, d],
+    a slice at a time into the carried sum; backward the same loop, a slice
+    recomputed and transposed at a time into gradients added to in place."""
+    w1, w3, w2 = (w.astype(x.dtype) for w in (w1, w3, w2))
+
+    def one(p, carry):
+        _, token, weight, *_, y = _slice(p, x, weights, w1, w3, w2, slots,
+                                         tile_ends, size, tile)
+        y = y.astype(jnp.float32) * weight
+        return (carry[0].at[token].add(y, mode="drop"),)
+
+    return _loop(tile_ends, size, tile, one, (x,))[0]
 
 
-def _further_bwd(size, tile, res, g):
-    *operands, slots, tile_ends = res
+def _loop_bwd(size, tile, res, g):
+    from ..ops.grouped_matmul import grouped_matmul_transposed
+
+    x, weights, *matrices, slots, tile_ends = res
+    w1, w3, w2 = (w.astype(x.dtype) for w in matrices)
 
     def one(p, grads):
-        _, transpose = jax.vjp(lambda *a: _expert_pass(
-            p * size, *a, slots, tile_ends, size, tile), *operands)
-        return jax.tree_util.tree_map(jnp.add, grads, transpose(g))
+        dx, dweights, dw1, dw3, dw2 = grads
+        rows, token, weight, at, a, h, gated, y = _slice(
+            p, x, weights, w1, w3, w2, slots, tile_ends, size, tile)
+        # zero for an empty slot, whose row and weight are some token's
+        gy = g.at[token].get(mode="fill", fill_value=0)
+        dweights = dweights.at[rows].add(
+            jnp.sum(gy * y.astype(jnp.float32), axis=-1), mode="drop")
+        dy = (gy * weight).astype(y.dtype)
+        dh, dw2 = grouped_matmul_transposed(h, dy, w2, dw2, *at)
+        dh1, dh3 = gated(dh)
+        da1, dw1 = grouped_matmul_transposed(a, dh1, w1, dw1, *at)
+        da3, dw3 = grouped_matmul_transposed(a, dh3, w3, dw3, *at)
+        da = da1.astype(jnp.float32) + da3
+        return dx.at[token].add(da, mode="drop"), dweights, dw1, dw3, dw2
 
-    # zeros that vary over mesh axes as their operands do (shard_map)
-    zeros = tuple(jax.lax.select(jnp.zeros(a.shape, bool), a,
-                                 jnp.zeros_like(a)) for a in operands)
-    grads = jax.lax.fori_loop(1, -(-tile_ends[-1] * tile // size), one, zeros)
-    return (g, *grads, None, None)
+    grads = _loop(tile_ends, size, tile, one,
+                  (x, weights.reshape(-1), *matrices))
+    return (*(d.reshape(a.shape).astype(a.dtype) for d, a in zip(grads, res)),
+            None, None)
 
 
-_with_further_passes.defvjp(_further_fwd, _further_bwd)
+_expert_loop.defvjp(lambda *a: (_expert_loop(*a), a[:7]), _loop_bwd)
+
+
+def slice_slots(capacity: int, held: int, num_experts: int):
+    """``(slots, tile)``: a slice, the tiles that hold an eighth of the rows
+    an even router sends here, and a tile's rows (8 below a kernel tile)."""
+    from ..ops.grouped_matmul import ROW_TILE
+
+    eighth = capacity * held // (8 * num_experts)
+    tile = ROW_TILE if eighth >= ROW_TILE else 8
+    return max(eighth // tile, 1) * tile, tile
 
 
 def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
@@ -266,31 +275,23 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     """``sum over the held e among a token's experts of weight_e *
     expert_e(x)`` for tokens ``x`` [N, d], routed to ``ids`` [N, k] with
     ``weights`` [N, k]; the experts held are ``first .. first + len(w1)``,
-    each ``(silu(x w1) * (x w3)) w2``. Float32 [N, d].
+    each ``(silu(x w1) * (x w3)) w2``. Returns that sum, float32 [N, d],
+    and the loop's ``slices`` run, ``slots`` in use and slots it ``ran``.
 
-    The assignments to held experts are sorted by expert and laid out in
-    slots, each expert's rows padded to whole tiles of the grouped-product
-    kernel (``ops.grouped_matmul``). A pass multiplies the rows of a slice
-    of the slots. A slice holds ``SLICE_OF_EVEN`` times the rows an even
-    router would send here, and the first pass is unconditional; further
-    ones run only as far as the slots in use reach, so the kernels' work
+    The assignments to held experts are sorted by expert into slots, each
+    expert's rows padded to whole tiles of the grouped-product kernel
+    (``ops.grouped_matmul``). One loop from slot 0 takes a slice
+    (``slice_slots``) at a time as far as the slots in use reach: the work
     follows the rows routed here and no row is ever dropped."""
-    from ..ops.grouped_matmul import ROW_TILE
     from ..ops.spmd import vary_like
 
-    n, k = ids.shape
-    held = w1.shape[0]
-    capacity = n * k
+    held, capacity = w1.shape[0], ids.size
     local = ids.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True)  # held rows first, by expert
     counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
     ends = jnp.cumsum(counts)
-    # a slice: SLICE_OF_EVEN times the rows an even router sends here, in
-    # whole tiles (tiles of 8 rows where that is less than one kernel tile)
-    even = -(-SLICE_OF_EVEN * capacity * held // num_experts)
-    tile = ROW_TILE if even >= ROW_TILE else 8
-    size = min(-(-capacity // tile), -(-even // tile)) * tile
+    size, tile = slice_slots(capacity, held, num_experts)
     tiles_of = -(-counts // tile)
     tile_ends = jnp.cumsum(tiles_of)
     # the slot of the p-th sorted assignment: its expert's first slot plus
@@ -299,18 +300,15 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     slot = (tile_ends - tiles_of)[expert] * tile \
         + jnp.arange(capacity) - (ends - counts)[expert]
     room = -(-(capacity + held * tile) // size) * size
-    slots = jnp.full((room,), -1, jnp.int32).at[
+    slots = jnp.full((room,), capacity, jnp.int32).at[
         jnp.where(jnp.arange(capacity) < ends[-1], slot, room)].set(
             order.astype(jnp.int32), mode="drop")
-    operands = (x, weights, *(w.astype(x.dtype) for w in (w1, w3, w2)),
-                slots, tile_ends)
-    out = _expert_pass(0, *operands, size, tile)
-    if size < room:
-        # the loop's trip count is this device's own: its operands are typed
-        # as varying like the tokens, so that the sum of the replicated
-        # weights' gradient over the mesh axis happens once, outside it
-        out = _with_further_passes(out, *vary_like(x, *operands), size, tile)
-    return out
+    # the trip count is a device's own: typed as varying like the tokens, the
+    # replicated weights get their gradient summed over the axis outside it
+    operands = vary_like(x, x, weights, w1, w3, w2, slots, tile_ends)
+    slices = -(-tile_ends[-1] * tile // size)
+    return _expert_loop(*operands, size, tile), {
+        "slices": slices, "slots": tile_ends[-1] * tile, "ran": slices * size}
 
 
 class ExpertLayer(nn.Module):
@@ -322,9 +320,9 @@ class ExpertLayer(nn.Module):
     experts would add is left out.
 
     Sows into the collection ``moe_stats`` (when the caller makes it
-    mutable): ``assignments`` [num_experts], how many of the ``N * k``
-    assignments each expert got, and ``absent``, how many went to experts
-    not held (``obs.moe.publish`` turns them into gauges)."""
+    mutable) what ``obs.moe.publish`` turns into gauges: ``assignments``
+    [num_experts], how many of the ``N * k`` assignments each expert got,
+    ``absent``, how many went to experts not held, and the loop's numbers."""
 
     num_experts: int
     experts_per_token: int
@@ -365,8 +363,10 @@ class ExpertLayer(nn.Module):
                 w1, w3 = (self.param(name, _INIT, (held, d, self.width))
                           for name in ("experts_w1", "experts_w3"))
                 w2 = self.param("experts_w2", _INIT, (held, self.width, d))
-                routed = held_expert_sum(tokens, ids, weights, w1, w3, w2,
-                                         first, self.num_experts)
+                routed, loop = held_expert_sum(
+                    tokens, ids, weights, w1, w3, w2, first, self.num_experts)
+                for name, value in loop.items():
+                    self.sow("moe_stats", name, value)
                 shared = GatedMLP(self.shared_width, self.dtype,
                                   name="shared")(tokens) \
                     if self.shared_width else None

@@ -1,9 +1,11 @@
 """Gauges of the expert layer's routing (docs/laguna.md).
 
 ``models.laguna.ExpertLayer`` sows, into the flax collection ``moe_stats``,
-how many assignments each expert got (``assignments``) and how many went to
-experts this chip does not hold (``absent``). A training step does not
-carry the collection; a caller who wants the numbers applies the model with
+how many assignments each expert got (``assignments``), how many went to
+experts this chip does not hold (``absent``) and, of the loop that
+multiplies the held experts' rows, the ``slices`` it ran, the ``slots`` in
+use and the slots it ``ran`` over. A training step does not carry the
+collection; a caller who wants the numbers applies the model with
 ``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
 """
 
@@ -21,12 +23,24 @@ _HELD = _metrics().gauge(
     "Share of a layer's token-to-expert assignments that went to experts "
     "this chip holds (held / num_experts under an even router)",
     labels=("layer",))
+_SLICES = _metrics().gauge(
+    "horovod_moe_slices_run",
+    "Slices of slots the held experts' loop ran, by expert layer: the "
+    "slots in use over a slice's, rounded up (0: no row was routed here)",
+    labels=("layer",))
+_FILL = _metrics().gauge(
+    "horovod_moe_slot_fill",
+    "Slots in use (the rows routed here, each expert's padded to whole "
+    "kernel tiles) over the slots the loop ran over, by expert layer (1.0 "
+    "where it ran none)",
+    labels=("layer",))
 
 
 def publish(moe_stats) -> dict:
     """Set the gauges from a ``moe_stats`` collection and return what was
-    set, ``{layer: {"load_max_over_mean": .., "held_share": ..}}``; a
-    layer is the path of its module, ``block_3/moe``."""
+    set, ``{layer: {"load_max_over_mean": .., "held_share": ..,
+    "slices_run": .., "slot_fill": ..}}``; a layer is the path of its
+    module, ``block_3/moe``."""
     import numpy as np
     from flax.traverse_util import flatten_dict
 
@@ -36,15 +50,21 @@ def publish(moe_stats) -> dict:
         sown.setdefault("/".join(module), {})[name] = np.asarray(values[-1])
     out = {}
     for layer, stats in sorted(sown.items()):
-        if not {"assignments", "absent"} <= set(stats):
+        if not {"assignments", "absent", "slices", "slots", "ran"} \
+                <= set(stats):
             continue
         counts = stats["assignments"].astype(np.float64)
         total = counts.sum()
         if not total:
             continue
+        ran = int(stats["ran"])
         out[layer] = {
             "load_max_over_mean": float(counts.max() / counts.mean()),
-            "held_share": float(1.0 - stats["absent"] / total)}
-        _LOAD.labels(layer=layer).set(out[layer]["load_max_over_mean"])
-        _HELD.labels(layer=layer).set(out[layer]["held_share"])
+            "held_share": float(1.0 - stats["absent"] / total),
+            "slices_run": int(stats["slices"]),
+            "slot_fill": float(stats["slots"] / ran) if ran else 1.0}
+        for gauge, name in ((_LOAD, "load_max_over_mean"),
+                            (_HELD, "held_share"), (_SLICES, "slices_run"),
+                            (_FILL, "slot_fill")):
+            gauge.labels(layer=layer).set(out[layer][name])
     return out

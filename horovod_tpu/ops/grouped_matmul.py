@@ -17,8 +17,10 @@ index map stays on that tile, so an idle grid step moves no data. Three
 calls, named as a trace shows them: ``expert_matmul_fwd`` (rows x W),
 ``expert_matmul_bwd_dx`` (the rows' gradient, dY x W^T, the same kernel
 with the matrix's other axis contracted) and ``expert_matmul_bwd_dw`` (the
-weights' gradient, rows^T x dY summed over a group's tiles in a float32
-accumulator). Float32 accumulation throughout.
+weights' gradient, rows^T x dY summed over a group's tiles onto a float32
+running sum that the call takes and returns in place: a caller who walks
+the rows a slice at a time, ``grouped_matmul_transposed``, touches only the
+matrices of the groups a slice holds). Float32 accumulation throughout.
 
 ``jax.lax.ragged_dot`` computes the same products, and the TPU compiler
 turns it into kernels of its own — but under the name ``ragged-dot-none``
@@ -75,23 +77,25 @@ def _rows_kernel(group_ref, active_ref, a_ref, w_ref, o_ref, *,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _weights_kernel(group_ref, active_ref, a_ref, g_ref, o_ref, acc):
-    """``rows^T x dY`` of one tile, added to its group's accumulator: the
-    grid is sequential, a group's tiles are consecutive, and its block is
-    written back when the walk leaves it."""
+def _weights_kernel(group_ref, active_ref, a_ref, g_ref, sum_ref, o_ref):
+    """``rows^T x dY`` of one tile, added to its group's block of the
+    running sum: the grid is sequential, a group's tiles are consecutive,
+    its block starts from ``sum_ref``'s (the same buffer) and is written
+    back when the walk leaves it. A block no tile reaches is never
+    touched; with no active tile at all the first one passes through."""
     i = pl.program_id(0)
-    first = (i == 0) | (group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])
+    live = i < active_ref[0]
 
-    @pl.when(i < active_ref[0])
-    def _run():
-        @pl.when(first)
-        def _init():
-            acc[...] = jnp.zeros_like(acc)
+    @pl.when((i == 0) | (
+        live & (group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])))
+    def _start():
+        o_ref[...] = sum_ref[...]
 
-        acc[...] += jax.lax.dot_general(
+    @pl.when(live)
+    def _add():
+        o_ref[0] += jax.lax.dot_general(
             a_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        o_ref[0] = acc[...].astype(o_ref.dtype)
 
 
 def _check(rows, weights, contracted: int, tile: int):
@@ -111,8 +115,9 @@ def _check(rows, weights, contracted: int, tile: int):
 def _compiler_params(interpret: bool, weights):
     # sequential: the idle steps past the last active tile revisit its
     # blocks, and the weights' gradient accumulates over a group's tiles.
-    # VMEM: the matrix twice (pipeline buffers), once more in float32 for
-    # the weights' gradient, and 16 MiB for the row tiles and temporaries
+    # VMEM: the matrix twice (pipeline buffers), in float32 twice coming
+    # and twice going for the weights' gradient's running sum, and 16 MiB
+    # for the row tiles and temporaries
     matrix = weights[0].size * max(weights.dtype.itemsize, 4)
     return None if interpret else pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
@@ -140,28 +145,28 @@ def _rows_call(rows, weights, tile_group, active, transpose, tile, interpret):
     )(tile_group, active, rows, weights)
 
 
-def _weights_call(rows, grads, like, tile_group, active, tile, interpret):
-    tiles = _check(rows, like, 1, tile)
+def _weights_call(rows, grads, sums, tile_group, active, tile, interpret):
+    """``sums`` [groups, k, n] float32 plus each group's ``rows^T x
+    grads`` over its active tiles, in ``sums``' own buffer."""
+    tiles = _check(rows, sums, 1, tile)
     row_block = lambda width: pl.BlockSpec(  # noqa: E731
         (tile, width), lambda i, group, active: (_last(i, active), 0))
-    out = pl.pallas_call(
+    matrix = pl.BlockSpec(
+        (1, *sums.shape[1:]),
+        lambda i, group, active: (group[_last(i, active)], 0, 0))
+    return pl.pallas_call(
         _weights_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(tiles,),
-            in_specs=[row_block(rows.shape[1]), row_block(grads.shape[1])],
-            out_specs=pl.BlockSpec(
-                (1, *like.shape[1:]),
-                lambda i, group, active: (group[_last(i, active)], 0, 0)),
-            scratch_shapes=[pltpu.VMEM(like.shape[1:], jnp.float32)]),
-        out_shape=_sds(like.shape, like.dtype, rows, grads),
-        compiler_params=_compiler_params(interpret, like),
+            in_specs=[row_block(rows.shape[1]), row_block(grads.shape[1]),
+                      matrix],
+            out_specs=matrix),
+        out_shape=_sds(sums.shape, sums.dtype, rows, grads, sums),
+        input_output_aliases={4: 0},
+        compiler_params=_compiler_params(interpret, sums),
         interpret=interpret,
         name="expert_matmul_bwd_dw",
-    )(tile_group, active, rows, grads)
-    # a group that no active tile belongs to was never written
-    visited = jnp.zeros((like.shape[0],), bool).at[tile_group].max(
-        jnp.arange(tiles) < active[0])
-    return jnp.where(visited[:, None, None], out, 0)
+    )(tile_group, active, rows, grads, sums)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -176,14 +181,44 @@ def _grouped_fwd(rows, weights, tile_group, active, tile, interpret):
 
 
 def _grouped_bwd(tile, interpret, res, g):
+    # the models write their backward pass themselves
+    # (``grouped_matmul_transposed``); this rule stays for callers that
+    # differentiate ``grouped_matmul``: tests/chipbench's grouped-product
+    # test and the tests here, so a benchmark PR can retire it
     rows, weights, tile_group, active = res
+    zeros = vary_like(rows, jnp.zeros(weights.shape, jnp.float32))[0]
     return (_rows_call(g, weights, tile_group, active, True, tile, interpret),
-            _weights_call(rows, g, weights, tile_group, active, tile,
-                          interpret),
+            _weights_call(rows, g, zeros, tile_group, active, tile,
+                          interpret).astype(weights.dtype),
             None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _operands(like, tile_group, active_tiles, *arrays):
+    """``arrays`` and the two scalar-prefetched operands, typed as ``like``
+    is: inside a vma-tracking shard_map the rows vary over the data axis
+    and replicated weights do not."""
+    return vary_like(like, *arrays, tile_group.astype(jnp.int32),
+                     jnp.reshape(active_tiles, (1,)).astype(jnp.int32))
+
+
+def _on_platform(interpret, stand_in, kernels, *operands):
+    """``kernels(interpret, *operands)``, or where those cannot be
+    interpreted — the Pallas interpreter refuses to index a
+    scalar-prefetched operand that varies over a mesh axis (a vma-tracking
+    ``shard_map`` lowered for the CPU) — ``stand_in``, the same in
+    ``jax.numpy``, for a program lowered for the CPU alone: one lowered for
+    a TPU from a CPU box (``chipbench.aot``) gets the kernels the chip
+    will run."""
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    if interpret and operand_vma(operands[0]):
+        return jax.lax.platform_dependent(
+            *operands, cpu=stand_in,
+            default=functools.partial(kernels, False))
+    return kernels(interpret, *operands)
 
 
 # jitted, as ``flash_attention`` is: behind the boundary the calls keep
@@ -199,29 +234,43 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     ``t < active_tiles``; later rows unspecified. Differentiable in
     ``rows`` (whose gradient is unspecified in the same rows) and
     ``weights``; what the caller puts into inactive tiles is never read."""
-    if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
-    operands = (weights, tile_group.astype(jnp.int32),
-                jnp.reshape(active_tiles, (1,)).astype(jnp.int32))
-    # inside a vma-tracking shard_map the rows vary over the data axis and
-    # replicated weights do not: the kernels' operands are typed alike
-    operands = vary_like(rows, *operands)
-    if interpret and operand_vma(rows):
-        # the stand-in is for a program lowered for the CPU alone: one
-        # lowered for a TPU from a CPU box (``chipbench.aot``) gets the
-        # kernels the chip will run
-        return jax.lax.platform_dependent(
-            rows, *operands,
-            cpu=functools.partial(_tile_by_tile, tile=row_tile),
-            default=lambda *a: _grouped(*a, row_tile, False))
-    return _grouped(rows, *operands, row_tile, interpret)
+    return _on_platform(
+        interpret, functools.partial(_tile_by_tile, tile=row_tile),
+        lambda interpret, *a: _grouped(*a, row_tile, interpret),
+        rows, *_operands(rows, tile_group, active_tiles, weights))
+
+
+@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
+def grouped_matmul_transposed(rows, grads, weights, sums, tile_group,
+                              active_tiles, row_tile: int = ROW_TILE,
+                              interpret: Optional[bool] = None):
+    """``grouped_matmul``'s transpose at ``grads`` [m, n], for a caller who
+    writes its own backward pass: the rows' gradient [m, k] (unspecified
+    past the active tiles) and ``sums`` [groups, k, n] float32 plus the
+    weights' gradient — in ``sums``' buffer, of which only the matrices of
+    groups with an active tile are read or written."""
+    def kernels(interpret, grads, rows, sums, weights, tile_group, active):
+        return (_rows_call(grads, weights, tile_group, active, True,
+                           row_tile, interpret),
+                _weights_call(rows, grads, sums, tile_group, active,
+                              row_tile, interpret))
+
+    def stand_in(grads, rows, sums, weights, tile_group, active):
+        tiled = lambda a: a.reshape(tile_group.size, row_tile, -1)  # noqa
+        live = (jnp.arange(tile_group.size) < active[0])[:, None, None]
+        per_tile = jnp.einsum("tmk,tmn->tkn", tiled(rows), tiled(grads),
+                              preferred_element_type=jnp.float32)
+        return (_tile_by_tile(grads, jnp.swapaxes(weights, 1, 2), tile_group,
+                              active, row_tile),
+                sums.at[tile_group].add(jnp.where(live, per_tile, 0)))
+
+    return _on_platform(
+        interpret, stand_in, kernels, grads,
+        *_operands(grads, tile_group, active_tiles, rows, sums, weights))
 
 
 def _tile_by_tile(rows, weights, tile_group, active, tile: int):
-    """The same product in ``jax.numpy``, for the one place the kernels
-    cannot be interpreted: the Pallas interpreter refuses to index a
-    scalar-prefetched operand that varies over a mesh axis (a
-    vma-tracking ``shard_map`` lowered for the CPU)."""
+    """``grouped_matmul`` in ``jax.numpy`` (``_on_platform``)."""
     tiles = rows.shape[0] // tile
     out = jnp.einsum("tmk,tkn->tmn", rows.reshape(tiles, tile, -1),
                      weights[tile_group],

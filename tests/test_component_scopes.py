@@ -253,7 +253,8 @@ PATHS = {
         + ["bn_init/bias", "bn_init/scale", "conv_init/kernel",
            "Dense_0/kernel", "Dense_0/bias"]),
 }
-_MOE_STATS = ["block_1/moe/absent/0", "block_1/moe/assignments/0"]
+_MOE_STATS = [f"block_1/moe/{name}/0" for name in (
+    "absent", "assignments", "ran", "slices", "slots")]
 COLLECTIONS = {     # what ``init`` leaves beside ``params``
     "transformer": {},
     "laguna": {"moe_stats": _MOE_STATS},

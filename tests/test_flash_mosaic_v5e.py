@@ -210,6 +210,38 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, no_compile_cache):
     assert not re.search(r"f32\[[\d,]*4,16,16,128\]", hlo)
 
 
+@pytest.mark.parametrize("rows,d,width,held", [
+    (2048, 2048, 512, 32), (2048, 2048, 768, 16), (512, 2304, 1024, 8)],
+    ids=["laguna", "sdar", "kimi"])
+def test_a_slice_of_the_expert_loop_transposes_for_v5e(
+        one_chip, no_compile_cache, rows, d, width, held):
+    """``grouped_matmul_transposed`` at a slice of the three expert cells:
+    the weights' gradient takes its float32 running sum and returns it in
+    the same buffer (four float32 matrices of a group in VMEM at once: 38
+    MiB at Kimi-Linear's 2304 x 1024)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.grouped_matmul import (ROW_TILE,
+                                                grouped_matmul_transposed)
+
+    def placed(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = jax.jit(functools.partial(
+        grouped_matmul_transposed, interpret=False), donate_argnums=3).lower(
+            placed((rows, d), jnp.bfloat16),
+            placed((rows, width), jnp.bfloat16),
+            placed((held, d, width), jnp.bfloat16),
+            placed((held, d, width), jnp.float32),
+            placed((rows // ROW_TILE,), jnp.int32),
+            placed((), jnp.int32)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    dw = re.search(r"%expert_matmul_bwd_dw\S* = .*", hlo).group(0)
+    assert "output_to_operand_aliasing={{}: (4, {})}" in dw, dw
+    assert "%expert_matmul_bwd_dx" in hlo
+
+
 def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
     """``obs.kda.record_scan_program`` on a compiled toy step (one KDA
     layer's gradient, lowered for the v5e with no ``interpret`` given): no

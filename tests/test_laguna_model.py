@@ -114,66 +114,86 @@ def expert_operands(seed, tokens, d, width, held, k, num_experts,
     return x, ids, weights, w1, w3, w2
 
 
-_IMBALANCES = {
-    "even": None,
-    # every token routed to one expert (and three it does not matter which)
-    "all_to_one_held": lambda t: np.tile([5, 0, 1, 2], (t, 1)),
-    "all_to_held_only": lambda t: np.tile([4, 5, 6, 7], (t, 1)),
-    "none_held": lambda t: np.tile([0, 1, 2, 3], (t, 1)),
-    "one_token_here": lambda t: np.concatenate(
-        [[[7, 0, 1, 2]], np.tile([0, 1, 2, 3], (t - 1, 1))]),
+def ids_with(counts, tokens, k, first, num_experts):
+    """``ids`` [tokens, k] that send ``counts[e]`` rows to held expert
+    ``first + e`` — the tokens after those of the experts before it, round
+    the batch — and every other assignment to experts not held."""
+    held = [[] for _ in range(tokens)]
+    start = 0
+    for e, count in enumerate(counts):
+        for t in range(start, start + count):
+            held[t % tokens].append(first + e)
+        start += count
+    absent = [e for e in range(num_experts)
+              if not first <= e < first + len(counts)]
+    return np.asarray([(h + absent)[:k] for h in held], np.int32)
+
+
+# (tokens, d, width, k, num_experts, first, rows to each held expert or
+# None for a seeded router, slots of a slice, slices the loop must run)
+_ROUTINGS = {
+    # 4 of 32 held, 4 a token: a slice is one tile of 8 slots
+    "even": (64, 16, 8, 4, 32, 4, None, 8, None),
+    "all_to_one_held": (64, 16, 8, 4, 32, 4, (0, 64, 0, 0), 8, 8),
+    "all_to_held_only": (64, 16, 8, 4, 32, 4, (64, 64, 64, 64), 8, 32),
+    "none_held": (64, 16, 8, 4, 32, 4, (0, 0, 0, 0), 8, 0),
+    "one_token_here": (64, 16, 8, 4, 32, 4, (0, 0, 0, 1), 8, 1),
+    # 2 of 256 held, 8 a token: every token chooses both
+    "many_slices_of_a_small_slice": (32, 16, 8, 8, 256, 10, (32, 32), 8, 8),
+    # 4 of 16 held, 128 tokens: a slice is two tiles; the slots in use end
+    # on a slice's boundary, a tile short of it and a tile past it
+    "ends_on_a_boundary": (128, 16, 8, 4, 16, 4, (8, 8, 8, 8), 16, 2),
+    "ends_a_tile_short": (128, 16, 8, 4, 16, 4, (8, 8, 0, 5), 16, 2),
+    "ends_a_tile_past": (128, 16, 8, 4, 16, 4, (9, 8, 8, 8), 16, 3),
+    # one group's tiles across four slices: the weights' gradient adds up
+    # in place, the other three matrices' never touched
+    "one_expert_holds_every_row": (128, 16, 8, 4, 16, 4, (0, 0, 64, 0), 16, 4),
+    # 8 of 16 held, a slice of four tiles: each holds another expert's few
+    # rows, and an expert without a row lies between them
+    "less_than_a_tile_each": (128, 16, 8, 4, 16, 2,
+                              (3, 2, 5, 1, 0, 4, 2, 7), 32, 2),
 }
 
 
-@pytest.mark.parametrize("imbalance", _IMBALANCES)
-def test_no_token_is_dropped_under_any_imbalance(imbalance):
-    """Forward and every gradient against the loop over experts. With 4 of
-    32 experts held and 4 a token, a pass takes 128 of the 256 assignments:
-    ``all_to_held_only`` needs both passes (the second is the loop that an
-    even router never enters), ``all_to_one_held`` puts every token in one
-    group."""
-    make = _IMBALANCES[imbalance]
-    ids = None if make is None else jnp.asarray(make(64), jnp.int32)
+@pytest.mark.parametrize("routing", _ROUTINGS)
+def test_the_loop_follows_the_rows_and_drops_none(routing):
+    """Forward and every gradient against the loop over experts, and the
+    trip count against the slots in use, under any imbalance."""
+    tokens, d, width, k, num_experts, first, counts, size, slices = \
+        _ROUTINGS[routing]
+    held = 4 if counts is None else len(counts)
+    ids = None if counts is None else jnp.asarray(
+        ids_with(counts, tokens, k, first, num_experts))
     x, ids, weights, w1, w3, w2 = expert_operands(
-        1, 64, 16, 8, 4, 4, 32, ids)
+        1, tokens, d, width, held, k, num_experts, ids)
     cot = jnp.asarray(np.random.default_rng(2).standard_normal(x.shape),
                       jnp.float32)
 
     def run(fn):
-        out, vjp = jax.vjp(lambda *a: fn(a[0], ids, *a[1:]), x, weights, w1,
-                           w3, w2)
-        return (out, *vjp(cot))
+        out, vjp, loop = jax.vjp(lambda *a: fn(a[0], ids, *a[1:]), x,
+                                 weights, w1, w3, w2, has_aux=True)
+        return loop, (out, *vjp(cot))
 
     with jax.default_matmul_precision("highest"):
-        got = run(lambda x, ids, *a: laguna.held_expert_sum(
-            x, ids, *a, first=4, num_experts=32))
-        want = run(lambda x, ids, *a: expert_loop(x, ids, *a, first=4))
-    held = int(np.sum((np.asarray(ids) >= 4) & (np.asarray(ids) < 8)))
-    assert (held > 128) == (imbalance == "all_to_held_only")
+        loop, got = run(lambda x, ids, *a: laguna.held_expert_sum(
+            x, ids, *a, first=first, num_experts=num_experts))
+        _, want = run(lambda x, ids, *a: (
+            expert_loop(x, ids, *a, first=first), None))
     for g, w, name in zip(got, want, ("out", "dx", "dweights", "dw1", "dw3",
                                       "dw2")):
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
-    if imbalance == "none_held":
+    here = np.bincount(np.asarray(ids).reshape(-1) - first + num_experts,
+                       minlength=2 * num_experts)[num_experts:][:held]
+    if counts is not None:
+        assert here.tolist() == list(counts)
+    in_use = int((-(-here // 8) * 8).sum())
+    assert {name: int(v) for name, v in loop.items()} == {
+        "slices": -(-in_use // size), "slots": in_use,
+        "ran": -(-in_use // size) * size}
+    if slices is not None:
+        assert int(loop["slices"]) == slices
+    if not in_use:
         assert not np.asarray(got[0]).any()
-
-
-def test_many_passes_when_the_slice_is_small():
-    """2 of 256 experts held, 8 a token: a pass takes ``SLICE_OF_EVEN`` /
-    128 of the assignments, and every token choosing both held ones needs
-    several."""
-    assert 64 * laguna.SLICE_OF_EVEN < 32 * 8 * 2  # rows a pass < rows held
-    ids = jnp.asarray(np.tile([10, 11, 0, 1, 2, 3, 4, 5], (32, 1)), jnp.int32)
-    x, ids, weights, w1, w3, w2 = expert_operands(3, 32, 16, 8, 2, 8, 256,
-                                                  ids)
-    with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda x, w1: laguna.held_expert_sum(
-            x, ids, weights, w1, w3, w2, first=10, num_experts=256).sum(),
-            argnums=(0, 1))(x, w1)
-        want = jax.grad(lambda x, w1: expert_loop(
-            x, ids, weights, w1, w3, w2, first=10).sum(),
-            argnums=(0, 1))(x, w1)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("active", [0, 3, 5, 8])
@@ -206,6 +226,45 @@ def test_grouped_matmul_tile_by_tile(active):
         for g, v in zip(got_vjp(cot), want_vjp(cot)):
             np.testing.assert_allclose(g, v, rtol=1e-5, atol=1e-5)
     assert not np.asarray(got_vjp(cot)[1][1]).any()   # group 1: no tile
+
+
+@pytest.mark.parametrize("active", [0, 3, 8])
+def test_the_weights_gradient_adds_to_a_running_sum(active):
+    """``grouped_matmul_transposed`` (interpreted): the rows' gradient of
+    the active tiles, and ``sums`` plus each group's ``rows^T x grads`` —
+    a group without an active tile keeps its sum as it was."""
+    from horovod_tpu.ops.grouped_matmul import grouped_matmul_transposed
+
+    rng = np.random.default_rng(active)
+    rows, grads, w, sums = (
+        jnp.asarray(rng.standard_normal(shape), jnp.float32) for shape in (
+            (64, 16), (64, 24), (3, 16, 24), (3, 16, 24)))
+    group = jnp.asarray([0, 0, 0, 2, 2, 2, 2, 2], jnp.int32)
+    live = (np.arange(8) < active)[:, None, None]
+    with jax.default_matmul_precision("highest"):
+        dx, total = grouped_matmul_transposed(
+            rows, grads, w, sums, group, jnp.int32(active), row_tile=8)
+        per_tile = np.where(live, np.einsum(
+            "tmk,tmn->tkn", rows.reshape(8, 8, 16), grads.reshape(8, 8, 24)),
+            0.0)
+        want_dx = np.einsum("tmn,tkn->tmk", grads.reshape(8, 8, 24), w[group])
+    want = np.asarray(sums).copy()
+    np.add.at(want, np.asarray(group), per_tile)
+    np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(total[1], sums[1])   # group 1: no tile
+    np.testing.assert_allclose(
+        np.asarray(dx)[:8 * active], want_dx.reshape(64, 16)[:8 * active],
+        rtol=1e-5, atol=1e-5)
+
+
+def test_a_slice_is_an_eighth_of_an_even_routers_rows():
+    """The three cells' shapes: 16,384 rows, 8 a token; and the tile of 8
+    rows where a slice is less than one kernel tile."""
+    assert laguna.slice_slots(16384 * 8, 32, 256) == (2048, 256)   # Laguna
+    assert laguna.slice_slots(16384 * 8, 16, 128) == (2048, 256)   # SDAR
+    assert laguna.slice_slots(16384 * 8, 8, 256) == (512, 256)     # Kimi
+    assert laguna.slice_slots(128 * 4, 4, 16) == (16, 8)
+    assert laguna.slice_slots(32 * 8, 2, 256) == (8, 8)
 
 
 def test_grouped_matmul_refuses_what_does_not_fit():
@@ -343,19 +402,51 @@ def test_moe_stats_become_gauges(toy):
     counts = np.asarray(
         state["moe_stats"]["block_1"]["moe"]["assignments"][0])
     assert counts.shape == (16,) and counts.sum() == 2 * 64 * 4
+    # a slice here is 16 slots: an eighth of 2 * 64 * 4 * 4 / 16 rows, in
+    # two tiles of 8
+    in_use = (-(-counts[4:8] // 8) * 8).sum()
     want = {"load_max_over_mean": counts.max() / counts.mean(),
-            "held_share": counts[4:8].sum() / counts.sum()}
+            "held_share": counts[4:8].sum() / counts.sum(),
+            "slices_run": -(-in_use // 16),
+            "slot_fill": in_use / (-(-in_use // 16) * 16)}
     assert published["block_1/moe"] == pytest.approx(want)
+    assert 0.5 < want["slot_fill"] <= 1.0
     snapshot = obs.registry().snapshot()
     for family, key in (
             ("horovod_moe_expert_load_max_over_mean", "load_max_over_mean"),
-            ("horovod_moe_held_assignment_share", "held_share")):
+            ("horovod_moe_held_assignment_share", "held_share"),
+            ("horovod_moe_slices_run", "slices_run"),
+            ("horovod_moe_slot_fill", "slot_fill")):
         read = {s["labels"]["layer"]: s["value"]
                 for s in snapshot[family]["samples"]}
         assert read["block_1/moe"] == pytest.approx(want[key])
     # a training step does not carry the collection
     assert "moe_stats" not in model.apply({"params": params}, tokens,
                                           mutable=["intermediates"])[1]
+
+
+def test_publish_reads_the_loop_off_a_collection():
+    """Hand-made: 40 slots in use of 3 slices of 16; a layer without a row
+    here ran no slice, and wasted none."""
+    from horovod_tpu import obs
+
+    def layer(assignments, absent, slices, slots):
+        return {name: (np.asarray(value),) for name, value in dict(
+            assignments=assignments, absent=absent, slices=slices,
+            slots=slots, ran=16 * slices).items()}
+
+    published = obs.moe.publish({
+        "block_1": {"moe": layer([10, 30, 0, 0], 10, 3, 40)},
+        "block_2": {"moe": layer([0, 0, 20, 20], 40, 0, 0)},
+        "block_3": {"other": {"assignments": (np.ones(4),)}}})
+    assert published == {
+        "block_1/moe": {"load_max_over_mean": 3.0, "held_share": 0.75,
+                        "slices_run": 3, "slot_fill": 40 / 48},
+        "block_2/moe": {"load_max_over_mean": 2.0, "held_share": 0.0,
+                        "slices_run": 0, "slot_fill": 1.0}}
+    fill = {s["labels"]["layer"]: s["value"] for s in
+            obs.registry().snapshot()["horovod_moe_slot_fill"]["samples"]}
+    assert fill["block_2/moe"] == 1.0
 
 
 def test_the_scopes_reach_the_compiled_step(toy):
@@ -372,17 +463,20 @@ def test_two_devices_train_as_one(toy):
     """Through ``make_lm_train_step`` and ``hvd.DistributedOptimizer`` on a
     data mesh of two: the loss and the updated parameters are those of one
     device on the whole batch (routing, the sort and the grouped products
-    under a vma-checking ``shard_map``). With 4 of 16 experts held a
-    device's padded slots overflow the first pass, so the loop of further
-    passes runs, its trip count the device's own: the replicated weights'
-    gradient must be summed over the axis outside it, or the devices wait
-    on different all-reduces."""
+    under a vma-checking ``shard_map``). The expert layer's loop runs as
+    many slices as a device's own rows take, another number on each: the
+    replicated weights' gradient must be summed over the axis outside it,
+    or the devices wait on different all-reduces."""
     import optax
 
     import horovod_tpu as hvd
     from benchmarks._dp_step import make_lm_train_step
 
     model, params, tokens = toy
+    slices = [[int(layer["moe"]["slices"][0]) for layer in model.apply(
+        {"params": params}, tokens[i:i + 1],
+        mutable=["moe_stats"])[1]["moe_stats"].values()] for i in range(2)]
+    assert slices[0] != slices[1] and min(min(slices)) > 0
     results = []
     for n in (1, 2):
         mesh = hvd.parallel.data_parallel_mesh(jax.devices()[:n])

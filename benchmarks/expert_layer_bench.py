@@ -21,11 +21,30 @@ the rows and every parameter.
 ``expert_matmul_*`` calls in it, both read from a profiler trace of
 ``--iters`` calls, ``ops_ms`` its twelve longest operations as a cell's
 ``breakdown.device_ops`` names them (a loop is one of them, and spans its
-body's); ``slices_run`` and ``slot_fill`` are
-what the layer sows (null on a tree that sows neither). Each ``--tree``
+body's), ``sums_ms`` the operations outside the loops' containers whose name
+holds ``moe_rows_add``, the token-sized float32 type (the scatter-adds the
+call replaced) or the assignment-sized one (the router weights' scalar
+scatter-add); ``slices_run``, ``slot_fill`` and ``sum_kernel_share`` are
+what the layer sows (null on a tree that does not). Each ``--tree``
 measures that checkout's ``horovod_tpu`` (a ``git archive`` of the parent
 beside this one): give the option more than once to compare in one chip
 call.
+
+``--sum-only`` times, in place of the layer, what a slice's end costs: its
+rows added by token into the carried float32 sum, once as the loop's
+forward pass does it (bfloat16 rows times a float32 weight a row) and once
+as its backward pass does (float32 rows), by ``ops.grouped_matmul.
+moe_rows_add`` on the sum carried as ``[N, d / 128, 128]`` (``kernel_ms`` a
+call, ``kernel_us_a_row``; null on a tree without the call) and by the
+scatter-add ``sum.at[token].add(rows * scale, mode="drop")`` it replaced
+(``scatter_ms``, ``scatter_us_a_row``: its whole device program, the
+product by the weights included; the kernel's line is the call's own
+operation), from a trace of ``--iters`` calls that hand the sum on, after
+the largest difference between the two results and the elements that
+differ (0 and 0 on the v5e: the kernel adds in the scatter-add's order, and
+the chip rounds the product before the sum as XLA's own fusion does). A
+slice's tokens are drawn as the layer lays them out: distinct within a
+tile, anew for every tile, each tile's last slots empty.
 
 One process, on the device it measures: exits non-zero without a TPU
 unless ``--rehearse-cpu`` asks for a CPU run at 512 rows (the Pallas
@@ -83,6 +102,24 @@ def slices_of(size: int):
     return rule
 
 
+def device_lines(trace_dir: str):
+    """The lines of a trace's device plane; ``None`` from the CPU backend,
+    which traces none."""
+    from chipbench import trace_reduce
+
+    return next((lines for name, lines in
+                 trace_reduce.load(trace_dir).items()
+                 if trace_reduce.DEVICE_PLANE.match(name)), None)
+
+
+def programs_ms(device, iters: int) -> float:
+    """ms a call of the device programs in a trace of ``iters`` calls."""
+    from chipbench import trace_reduce
+
+    return 1e-6 * sum(e.dur_ns for e in device.get(
+        trace_reduce.MODULES_LINE, [])) / iters
+
+
 def measure(case: dict, iters: int, trace_root: str) -> dict:
     """One case's line, under the case's slice where it names one."""
     from horovod_tpu.models import laguna
@@ -133,11 +170,10 @@ def _measure(laguna, case: dict, iters: int, trace_root: str) -> dict:
         {"params": p}, x, mutable=["moe_stats"])[1]["moe_stats"])(params, x)})
     line = {**case, "iters": iters,
             **{name: sown["layer"].get(name) for name in (
-                "held_share", "slices_run", "slot_fill")}}
-    device = next((lines for name, lines in
-                   trace_reduce.load(trace_dir).items()
-                   if trace_reduce.DEVICE_PLANE.match(name)), None)
-    if device is None:  # the CPU backend traces no device plane
+                "held_share", "slices_run", "slot_fill",
+                "sum_kernel_share")}}
+    device = device_lines(trace_dir)
+    if device is None:
         return line
     seconds, groups = collections.Counter(), collections.Counter()
     for event in device.get(trace_reduce.OPS_LINE, []):
@@ -151,8 +187,90 @@ def _measure(laguna, case: dict, iters: int, trace_root: str) -> dict:
     # and output type), loops (which span their bodies) too
     line["ops_ms"] = [[name, round(1e3 * total / iters, 3)]
                       for name, total in groups.most_common(12)]
-    modules = device.get(trace_reduce.MODULES_LINE, [])
-    line["layer_ms"] = 1e-6 * sum(e.dur_ns for e in modules) / iters
+    # the sums by token outside the loops' own rows: the kernel, the
+    # scatter-add it replaced, and the router weights' scalar scatter-add
+    line["sums_ms"] = {
+        key: round(1e3 * sum(total for name, total in groups.items() if
+                             key in name and not name.startswith("while"))
+                   / iters, 3)
+        for key in ("moe_rows_add", f"f32[{case['rows']},{case['d']}]",
+                    f"f32[{case['rows'] * case['experts_per_token']}]")}
+    line["layer_ms"] = programs_ms(device, iters)
+    return line
+
+
+def measure_sum(case: dict, iters: int, trace_root: str) -> dict:
+    """One case's ``--sum-only`` line: a slice's sum by token alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import trace_reduce
+    from horovod_tpu.models import laguna
+    from horovod_tpu.ops import grouped_matmul
+
+    n, d = case["rows"], case["d"]
+    size, tile = slices_of(case["slice"])(0, 0, 0) if case["slice"] else \
+        laguna.slice_slots(n * case["experts_per_token"], case["held"],
+                           case["num_experts"])
+    rng = np.random.default_rng(0)
+    token = np.stack([rng.permutation(n)[:tile] for _ in range(size // tile)])
+    token[:, tile - tile // 16:] = n
+    token = jnp.asarray(token.reshape(-1), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    total = jax.random.normal(keys[0], (n, d), jnp.float32)
+    scale = jax.random.uniform(keys[1], (size, 1), jnp.float32, 0.1, 1.0)
+    kernel = getattr(grouped_matmul, "moe_rows_add", None)
+
+    def scatter(total, rows, scale):
+        rows = rows.astype(jnp.float32)
+        return total.at[token].add(rows if scale is None else rows * scale,
+                                   mode="drop")
+
+    def device_ms(call, total, *operands, op=None):
+        """ms a call of the device programs of ``iters`` calls, or of their
+        operations named ``op`` alone (a program handed ``[N, 18, 128]``
+        from outside a loop also copies it to the kernel's layout and
+        back, which no loop does)."""
+        call = jax.jit(call, donate_argnums=0)
+        for _ in range(3):
+            total = call(total, *operands)
+        jax.block_until_ready(total)
+        trace_dir = tempfile.mkdtemp(dir=trace_root)
+        with jax.profiler.trace(trace_dir):
+            for _ in range(iters):
+                total = call(total, *operands)
+            jax.block_until_ready(total)
+        device = device_lines(trace_dir)
+        if device is None:
+            return None
+        if op is None:
+            return programs_ms(device, iters)
+        return 1e-6 * sum(
+            e.dur_ns for e in device.get(trace_reduce.OPS_LINE, [])
+            if trace_reduce.parse_op(e.name)[0].startswith(op)) / iters
+
+    line = {**case, "iters": iters, "slice": size, "tile": tile}
+    for form, dtype, scale in (("fwd", jnp.bfloat16, scale),
+                               ("bwd", jnp.float32, None)):
+        rows = jax.random.normal(keys[2], (size, d), dtype)
+        want = jax.jit(scatter)(total, rows, scale)
+        ms = {"scatter": device_ms(scatter, total + 0, rows, scale)}
+        if kernel is not None:
+            add = lambda total, rows, scale: kernel(  # noqa: E731
+                total, rows, token, scale, jnp.int32(size // tile),
+                row_tile=tile)
+            cut = (n, d // 128, 128)
+            apart = jnp.abs(jax.jit(add)(total.reshape(cut), rows, scale)
+                            .reshape(n, d) - want)
+            line[f"{form}_max_abs_diff"] = float(jnp.max(apart))
+            line[f"{form}_elements_apart"] = int(jnp.sum(apart != 0))
+            ms["kernel"] = device_ms(add, total.reshape(cut) + 0, rows, scale,
+                                     op="moe_rows_add")
+        for name, value in ms.items():
+            line[f"{form}_{name}_ms"] = value
+            line[f"{form}_{name}_us_a_row"] = \
+                None if value is None else 1e3 * value / size
     return line
 
 
@@ -164,6 +282,9 @@ def main(argv=None) -> int:
     parser.add_argument("--iters", type=int, default=5)
     parser.add_argument("--tree", action="append",
                         help="a checkout whose horovod_tpu is measured")
+    parser.add_argument("--sum-only", action="store_true",
+                        help="a slice's sum by token alone, kernel and "
+                             "scatter-add")
     parser.add_argument("--rehearse-cpu", action="store_true")
     args = parser.parse_args(argv)
     sys.path.insert(0, _ROOT)
@@ -185,7 +306,8 @@ def main(argv=None) -> int:
         sys.path.insert(0, os.path.abspath(tree))
         importlib.invalidate_caches()
         for case in args.case:
-            line = measure(case, args.iters, trace_root)
+            line = (measure_sum if args.sum_only else measure)(
+                case, args.iters, trace_root)
             print(json.dumps({
                 **line, "tree": os.path.relpath(tree, _ROOT),
                 **_device_stamp(device, 1)}), flush=True)

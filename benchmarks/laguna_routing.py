@@ -12,9 +12,11 @@ Two readings that the benchmark's runs do not print:
 * the share of assignments that go to the experts held here
   (``horovod_moe_held_assignment_share``), the busiest expert's load
   (``horovod_moe_expert_load_max_over_mean``) and the slices the held
-  experts' loop ran and how full they were (``horovod_moe_slices_run``,
-  ``horovod_moe_slot_fill``) as the cell's own trainer steps through its
-  pool, with each step's time beside them: a chip's share of the experts
+  experts' loop ran, how full they were and whether their sums by token
+  went through the kernel (``horovod_moe_slices_run``,
+  ``horovod_moe_slot_fill``, ``horovod_moe_sum_kernel_share``) as the
+  cell's own trainer steps through its pool, with each step's time
+  beside them: a chip's share of the experts
   is the only part of the routed sum the loss sees, so training moves the
   router, and a step costs the rows routed here.
 """
@@ -93,7 +95,7 @@ def main(argv=None) -> int:
                 batch = trainer.pool[step % len(trainer.pool)][0]
                 routed = hvd.obs.moe.publish(stats(trainer.state[0], batch))
                 for name in ("held_share", "load_max_over_mean",
-                             "slices_run", "slot_fill"):
+                             "slices_run", "slot_fill", "sum_kernel_share"):
                     row[name] = [v[name] for _, v in sorted(routed.items())]
             t = time.perf_counter()
             row["loss"] = float(trainer.step())

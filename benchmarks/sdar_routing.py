@@ -10,7 +10,9 @@ share``, ``horovod_bd_mean_weight``: ``obs.bd``), the routing gauges layer
 by layer (``horovod_moe_held_assignment_share``, ``horovod_moe_expert_load_
 max_over_mean``: ``obs.moe``) and of the loop that multiplies the held
 experts' rows (``models.laguna.held_expert_sum``) the slices it ran and
-how full they were (``horovod_moe_slices_run``, ``horovod_moe_slot_fill``):
+how full they were (``horovod_moe_slices_run``, ``horovod_moe_slot_fill``)
+and the share of its slots summed by token in ``moe_rows_add``
+(``horovod_moe_sum_kernel_share``, 1.0 on the cell):
 a batch that routes more rows here runs more slices, and its step takes as
 much longer than its neighbours'.
 """
@@ -61,7 +63,7 @@ def main(argv=None) -> int:
             row = {"seed": seed, "batch": i, **obs.bd.publish(sown["bd_stats"])}
             routed = obs.moe.publish(sown["moe_stats"])
             for name in ("held_share", "load_max_over_mean", "slices_run",
-                         "slot_fill"):
+                         "slot_fill", "sum_kernel_share"):
                 row[name] = [v[name] for _, v in sorted(routed.items())]
             rows.append(row)
             print(json.dumps(row), flush=True)

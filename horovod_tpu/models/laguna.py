@@ -204,29 +204,52 @@ def _slice(p, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
     return rows, token, weight, at, a, h, gated, grouped_matmul(h, w2, *at)
 
 
-def _loop(tile_ends, size, tile, one, like):
-    """``one(p, carry)`` over the slices in use, from f32 zeros ``like``."""
+def _by_token(x):
+    """The shape in which a float32 sum over the rows of ``x`` [N, d] is
+    carried: a row cut into pieces of 128 where ``d`` allows it, which is
+    what ``ops.grouped_matmul.moe_rows_add`` adds to; else ``x``'s own."""
+    n, d = x.shape
+    return (n, d // 128, 128) if d % 128 == 0 else (n, d)
+
+
+def _add_by_token(total, rows, token, scale, at):
+    """``total`` plus a slice's ``rows`` (times ``scale`` [size, 1] where
+    given), each added in float32 to the row ``token`` names; a slot past
+    an expert's rows (``token == N``) adds nothing."""
+    from ..ops.grouped_matmul import moe_rows_add
+
+    if total.ndim == 3:
+        return moe_rows_add(total, rows, token, scale, *at[1:])
+    rows = rows.astype(jnp.float32)
+    return total.at[token].add(rows if scale is None else rows * scale,
+                               mode="drop")
+
+
+def _loop(tile_ends, size, tile, one, like, shapes):
+    """``one(p, carry)`` over the slices in use, from f32 zeros of
+    ``shapes``, typed as ``like`` varies."""
     from ..ops.spmd import vary_like
 
     return jax.lax.fori_loop(
         0, -(-tile_ends[-1] * tile // size), one,
-        vary_like(like[0], *(jnp.zeros(a.shape, jnp.float32) for a in like)))
+        vary_like(like, *(jnp.zeros(shape, jnp.float32) for shape in shapes)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _expert_loop(x, weights, w1, w3, w2, slots, tile_ends, size, tile):
     """The held experts' weighted outputs added up by token, float32 [N, d],
-    a slice at a time into the carried sum; backward the same loop, a slice
-    recomputed and transposed at a time into gradients added to in place."""
+    a slice at a time into the carried sum (``_by_token``); backward the
+    same loop, a slice recomputed and transposed at a time into gradients
+    added to in place."""
     w1, w3, w2 = (w.astype(x.dtype) for w in (w1, w3, w2))
 
     def one(p, carry):
-        _, token, weight, *_, y = _slice(p, x, weights, w1, w3, w2, slots,
-                                         tile_ends, size, tile)
-        y = y.astype(jnp.float32) * weight
-        return (carry[0].at[token].add(y, mode="drop"),)
+        _, token, weight, at, *_, y = _slice(p, x, weights, w1, w3, w2, slots,
+                                             tile_ends, size, tile)
+        return (_add_by_token(carry[0], y, token, weight, at),)
 
-    return _loop(tile_ends, size, tile, one, (x,))[0]
+    return _loop(tile_ends, size, tile, one, x,
+                 (_by_token(x),))[0].reshape(x.shape)
 
 
 def _loop_bwd(size, tile, res, g):
@@ -249,10 +272,11 @@ def _loop_bwd(size, tile, res, g):
         da1, dw1 = grouped_matmul_transposed(a, dh1, w1, dw1, *at)
         da3, dw3 = grouped_matmul_transposed(a, dh3, w3, dw3, *at)
         da = da1.astype(jnp.float32) + da3
-        return dx.at[token].add(da, mode="drop"), dweights, dw1, dw3, dw2
+        return (_add_by_token(dx, da, token, None, at), dweights, dw1, dw3,
+                dw2)
 
-    grads = _loop(tile_ends, size, tile, one,
-                  (x, weights.reshape(-1), *matrices))
+    grads = _loop(tile_ends, size, tile, one, x, (
+        _by_token(x), (weights.size,), *(w.shape for w in matrices)))
     return (*(d.reshape(a.shape).astype(a.dtype) for d, a in zip(grads, res)),
             None, None)
 
@@ -276,7 +300,8 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     expert_e(x)`` for tokens ``x`` [N, d], routed to ``ids`` [N, k] with
     ``weights`` [N, k]; the experts held are ``first .. first + len(w1)``,
     each ``(silu(x w1) * (x w3)) w2``. Returns that sum, float32 [N, d],
-    and the loop's ``slices`` run, ``slots`` in use and slots it ``ran``.
+    and the loop's ``slices`` run, ``slots`` in use, slots it ``ran`` and,
+    of those, the slots ``summed`` by token in ``moe_rows_add``.
 
     The assignments to held experts are sorted by expert into slots, each
     expert's rows padded to whole tiles of the grouped-product kernel
@@ -307,8 +332,10 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     # replicated weights get their gradient summed over the axis outside it
     operands = vary_like(x, x, weights, w1, w3, w2, slots, tile_ends)
     slices = -(-tile_ends[-1] * tile // size)
+    ran = slices * size
     return _expert_loop(*operands, size, tile), {
-        "slices": slices, "slots": tile_ends[-1] * tile, "ran": slices * size}
+        "slices": slices, "slots": tile_ends[-1] * tile, "ran": ran,
+        "summed": ran * (len(_by_token(x)) == 3)}
 
 
 class ExpertLayer(nn.Module):

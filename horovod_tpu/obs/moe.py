@@ -4,9 +4,10 @@
 how many assignments each expert got (``assignments``), how many went to
 experts this chip does not hold (``absent``) and, of the loop that
 multiplies the held experts' rows, the ``slices`` it ran, the ``slots`` in
-use and the slots it ``ran`` over. A training step does not carry the
-collection; a caller who wants the numbers applies the model with
-``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
+use, the slots it ``ran`` over and those whose sum by token went through
+``ops.grouped_matmul.moe_rows_add`` (``summed``). A training step does
+not carry the collection; a caller who wants the numbers applies the model
+with ``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
 """
 
 from __future__ import annotations
@@ -34,13 +35,20 @@ _FILL = _metrics().gauge(
     "kernel tiles) over the slots the loop ran over, by expert layer (1.0 "
     "where it ran none)",
     labels=("layer",))
+_SUMMED = _metrics().gauge(
+    "horovod_moe_sum_kernel_share",
+    "Slots whose rows were added to their tokens' sum by the moe_rows_add "
+    "kernel over the slots the loop ran over, by expert layer (0.0 where "
+    "the width is no multiple of 128 and XLA's scatter-add runs; 1.0 where "
+    "the loop ran none)",
+    labels=("layer",))
 
 
 def publish(moe_stats) -> dict:
     """Set the gauges from a ``moe_stats`` collection and return what was
     set, ``{layer: {"load_max_over_mean": .., "held_share": ..,
-    "slices_run": .., "slot_fill": ..}}``; a layer is the path of its
-    module, ``block_3/moe``."""
+    "slices_run": .., "slot_fill": .., "sum_kernel_share": ..}}``; a layer
+    is the path of its module, ``block_3/moe``."""
     import numpy as np
     from flax.traverse_util import flatten_dict
 
@@ -50,8 +58,8 @@ def publish(moe_stats) -> dict:
         sown.setdefault("/".join(module), {})[name] = np.asarray(values[-1])
     out = {}
     for layer, stats in sorted(sown.items()):
-        if not {"assignments", "absent", "slices", "slots", "ran"} \
-                <= set(stats):
+        if not {"assignments", "absent", "slices", "slots", "ran",
+                "summed"} <= set(stats):
             continue
         counts = stats["assignments"].astype(np.float64)
         total = counts.sum()
@@ -62,9 +70,11 @@ def publish(moe_stats) -> dict:
             "load_max_over_mean": float(counts.max() / counts.mean()),
             "held_share": float(1.0 - stats["absent"] / total),
             "slices_run": int(stats["slices"]),
-            "slot_fill": float(stats["slots"] / ran) if ran else 1.0}
+            "slot_fill": float(stats["slots"] / ran) if ran else 1.0,
+            "sum_kernel_share": float(stats["summed"] / ran) if ran else 1.0}
         for gauge, name in ((_LOAD, "load_max_over_mean"),
                             (_HELD, "held_share"), (_SLICES, "slices_run"),
-                            (_FILL, "slot_fill")):
+                            (_FILL, "slot_fill"),
+                            (_SUMMED, "sum_kernel_share")):
             gauge.labels(layer=layer).set(out[layer][name])
     return out

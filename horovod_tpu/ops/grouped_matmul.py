@@ -1,4 +1,5 @@
-"""Pallas grouped matrix products for the experts a chip holds.
+"""Pallas grouped matrix products for the experts a chip holds, and the
+sum by token of what they give.
 
 ``grouped_matmul(rows, weights, tile_group, active_tiles)`` multiplies each
 tile of ``row_tile`` consecutive ``rows`` [m, k] by the matrix of
@@ -13,7 +14,7 @@ Design: the grid walks the row tiles; a group's whole matrix is one block,
 so that consecutive tiles of a group fetch it once (the index map repeats
 the block index, and Pallas skips the copy); ``tile_group`` and
 ``active_tiles`` are scalar-prefetched, and past the last active tile every
-index map stays on that tile, so an idle grid step moves no data. Three
+index map stays on that tile, so an idle grid step moves no data. Four
 calls, named as a trace shows them: ``expert_matmul_fwd`` (rows x W),
 ``expert_matmul_bwd_dx`` (the rows' gradient, dY x W^T, the same kernel
 with the matrix's other axis contracted) and ``expert_matmul_bwd_dw`` (the
@@ -21,6 +22,25 @@ weights' gradient, rows^T x dY summed over a group's tiles onto a float32
 running sum that the call takes and returns in place: a caller who walks
 the rows a slice at a time, ``grouped_matmul_transposed``, touches only the
 matrices of the groups a slice holds). Float32 accumulation throughout.
+
+The fourth, ``moe_rows_add``, is no product: it adds a slice's rows, each
+to the row of a float32 sum that its token names — what
+``sum.at[token].add(rows * scale, mode="drop")`` does, which XLA compiles
+for the v5e to sixteen rows at a time, each batch read, added to and
+written back before the next starts, because two updates may name one row
+(0.24 us a row at 2,048 rows of 2048, eight times what their bytes take:
+PERF.md section 6, PR 44). The layout knows better: a tile is one group's,
+and a token is routed to a group at most once, so within a tile no token
+occurs twice. The call walks the tiles in order and, for one tile, starts
+every row's read from the sum, waits for all, adds the tile's rows in one
+pass, starts every row's write, waits for all: two round trips a tile of
+256 rows. The sum stays in HBM (``pl.ANY``) and is the call's output
+(``input_output_aliases``); it is carried as ``[N, d / 128, 128]``, a row's
+``d`` cut into pieces of 128, because Mosaic moves whole ``(8, 128)`` tiles
+of HBM and one row of a tiled ``[N, d]`` is an eighth of each of its.
+An empty slot (``token == N``) moves nothing, tiles past the active ones do
+nothing, and a token's additions keep the scatter-add's order (tile after
+tile, slot order), so the float32 result is the same to the bit.
 
 ``jax.lax.ragged_dot`` computes the same products, and the TPU compiler
 turns it into kernels of its own — but under the name ``ragged-dot-none``
@@ -56,6 +76,10 @@ _MIB = 1024 * 1024
 # ask for room for its two pipeline buffers, a float32 accumulator of its
 # size and the row tiles (``_vmem_limit``), within a v5e core's 128 MiB
 _MATRIX_BYTES = 16 * _MIB
+# the rows whose copies ``moe_rows_add`` issues, or waits for, in one trip
+# of its loops: side by side their address arithmetic shares bundles (25
+# bundles a row over a tile's four loops against 42 one at a time)
+_ROWS_AT_ONCE = 8
 
 
 def _last(i, active_ref):
@@ -96,6 +120,86 @@ def _weights_kernel(group_ref, active_ref, a_ref, g_ref, sum_ref, o_ref):
         o_ref[0] += jax.lax.dot_general(
             a_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+
+def _rows_add_kernel(token_ref, active_ref, rows_ref, *refs, scaled: bool):
+    """One tile's rows added to the rows of the sum their tokens name: every
+    row's read in flight at once, one add, every row's write in flight at
+    once. No token occurs twice in a tile (the caller's layout); the grid
+    is sequential, so a token of the next tile finds this one's sum."""
+    scale_ref = refs[0] if scaled else None
+    out_ref, held, sem = refs[-3:]  # ``refs[-4]``, the sum, is ``out_ref``
+    tile = rows_ref.shape[0]
+    tokens, pieces, lanes = out_ref.shape
+    first = pl.program_id(0) * tile
+
+    def every_row(move):
+        """``move`` on the copy between a slot's row of the sum and its
+        rows of ``held``; a slot past an expert's rows has no token (nor
+        has one below 0: the copies' bounds are checked here alone)."""
+        def some(r, _):
+            for slot in range(_ROWS_AT_ONCE):
+                slot += r * _ROWS_AT_ONCE
+                token = token_ref[first + slot]
+
+                @pl.when(token.astype(jnp.uint32) < tokens)
+                def _held():
+                    move(out_ref.at[token],
+                         held.at[pl.ds(slot * pieces, pieces)])
+        jax.lax.fori_loop(0, tile // _ROWS_AT_ONCE, some, None)
+
+    def copy_every_row(back: bool):
+        """Every slot's copy started, then every one waited for."""
+        def copy(row, held_rows):
+            return pltpu.make_async_copy(
+                *((held_rows, row) if back else (row, held_rows)), sem)
+        every_row(lambda *ends: copy(*ends).start())
+        every_row(lambda *ends: copy(*ends).wait())
+
+    @pl.when(pl.program_id(0) < active_ref[0])
+    def _run():
+        copy_every_row(back=False)
+        for q in range(pieces):
+            # piece ``q`` of every row: ``held`` holds a row's pieces on
+            # consecutive sublanes, as the sum does
+            piece = rows_ref[:, q * lanes:(q + 1) * lanes].astype(jnp.float32)
+            if scaled:
+                piece = piece * scale_ref[...]
+            at = pl.ds(q, tile, stride=pieces)
+            held[at, :] = held[at, :] + piece
+        copy_every_row(back=True)
+
+
+def _rows_add_call(sums, rows, token, scale, active, tile, interpret):
+    """``sums`` [N, d / 128, 128] float32 plus the ``rows`` [size, d] (times
+    ``scale`` [size, 1] where given) of the active tiles, each added to the
+    row ``token`` names, in ``sums``' own buffer."""
+    size, d = rows.shape
+    tokens, pieces, lanes = sums.shape
+    if size % tile or pieces * lanes != d or token.shape != (size,):
+        raise ValueError(f"rows {rows.shape} of tokens {token.shape} do not "
+                         f"add to {sums.shape} in tiles of {tile} rows")
+    block = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda i, token, active: (_last(i, active), 0))
+    scaled = scale is not None
+    return pl.pallas_call(
+        functools.partial(_rows_add_kernel, scaled=scaled),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(size // tile,),
+            in_specs=[block(d), *([block(1)] if scaled else []),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tile * pieces, lanes), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=_sds(sums.shape, sums.dtype, sums, rows),
+        input_output_aliases={3 + scaled: 0},
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True,
+            vmem_limit_bytes=32 * _MIB),
+        interpret=interpret,
+        name="moe_rows_add",
+    )(token, active, rows, *([scale] if scaled else []), sums)
 
 
 def _check(rows, weights, contracted: int, tile: int):
@@ -267,6 +371,39 @@ def grouped_matmul_transposed(rows, grads, weights, sums, tile_group,
     return _on_platform(
         interpret, stand_in, kernels, grads,
         *_operands(grads, tile_group, active_tiles, rows, sums, weights))
+
+
+@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
+def moe_rows_add(sums, rows, token, scale, active_tiles,
+                 row_tile: int = ROW_TILE, interpret: Optional[bool] = None):
+    """``sums`` [N, d / 128, 128] float32 — ``[N, d]`` with a row's ``d``
+    cut into pieces of 128, so that a row is whole tiles of the chip's
+    memory and moves in one transfer — plus, for every slot ``s`` of the
+    first ``active_tiles`` tiles (``row_tile`` slots each) with ``0 <=
+    token[s] < N``, ``rows[s]`` [d] (times ``scale[s]`` [size, 1] unless
+    that is ``None``) in float32, added to row ``token[s]`` in slot order: what
+    ``sums.at[token].add(rows * scale, mode="drop")`` gives, bit for bit,
+    in ``sums``' buffer. The caller guarantees that no token occurs twice
+    within a tile; rows of other slots are never read."""
+    scaled = scale is not None
+
+    def kernels(interpret, rows, sums, token, active, *scale):
+        return _rows_add_call(sums, rows, token, *(scale or [None]), active,
+                              row_tile, interpret)
+
+    def stand_in(rows, sums, token, active, *scale):
+        live = jnp.arange(token.size) < active[0] * row_tile
+        rows = rows.astype(jnp.float32)
+        if scaled:
+            rows = rows * scale[0]
+        return sums.reshape(sums.shape[0], -1).at[
+            jnp.where(live, token, sums.shape[0])].add(
+                rows, mode="drop").reshape(sums.shape)
+
+    return _on_platform(
+        interpret, stand_in, kernels, rows,
+        *_operands(rows, token, active_tiles, sums),
+        *(vary_like(rows, scale) if scaled else ()))
 
 
 def _tile_by_tile(rows, weights, tile_group, active, tile: int):

@@ -254,7 +254,7 @@ PATHS = {
            "Dense_0/kernel", "Dense_0/bias"]),
 }
 _MOE_STATS = [f"block_1/moe/{name}/0" for name in (
-    "absent", "assignments", "ran", "slices", "slots")]
+    "absent", "assignments", "ran", "slices", "slots", "summed")]
 COLLECTIONS = {     # what ``init`` leaves beside ``params``
     "transformer": {},
     "laguna": {"moe_stats": _MOE_STATS},

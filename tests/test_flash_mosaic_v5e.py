@@ -242,6 +242,85 @@ def test_a_slice_of_the_expert_loop_transposes_for_v5e(
     assert "%expert_matmul_bwd_dx" in hlo
 
 
+@pytest.mark.parametrize("rows,d", [(2048, 2048), (2048, 2048), (512, 2304)],
+                         ids=["laguna", "sdar", "kimi"])
+def test_a_slice_of_the_expert_loop_sums_by_token_for_v5e(
+        one_chip, no_compile_cache, rows, d):
+    """``moe_rows_add`` at a slice of the three expert cells, as the loop's
+    forward pass calls it (bfloat16 rows, a weight a row) and as its
+    backward pass does (float32 rows): one Mosaic call each, the carried
+    sum ``[N, d / 128, 128]`` returned in its own buffer."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.grouped_matmul import moe_rows_add
+
+    def placed(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    total, token = placed((16384, d // 128, 128), jnp.float32), \
+        placed((rows,), jnp.int32)
+    for operands in ((placed((rows, d), jnp.bfloat16),
+                      placed((rows, 1), jnp.float32)),
+                     (placed((rows, d), jnp.float32), None)):
+        hlo = jax.jit(
+            lambda total, token, active, rows, scale: moe_rows_add(
+                total, rows, token, scale, active, interpret=False),
+            donate_argnums=0).lower(total, token, placed((), jnp.int32),
+                                    *operands).compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+        call = re.search(r"%moe_rows_add\S* = .*", hlo).group(0)
+        last = 3 + (operands[1] is not None)   # the sum is the last operand
+        assert f"output_to_operand_aliasing={{{{}}: ({last}, {{}})}}" \
+            in call, call
+        assert f"f32[16384,{d // 128},128]" in call.split(" custom-call(")[0]
+
+
+def test_an_expert_layer_carries_its_sums_in_place_for_v5e(
+        one_chip, no_compile_cache):
+    """One ``ExpertLayer`` forward and backward at Laguna's shape, compiled
+    for the v5e inside a ``shard_map`` over that one chip, as a cell's step
+    is (there the calls follow the platform the program is lowered for,
+    not the CPU that lowers it): the sums by token are ``moe_rows_add``'s,
+    one in each loop — no scatter into a token-sized float32 operand is
+    left — and neither loop's body copies a carried sum."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.models.laguna import ExpertLayer
+
+    layer = ExpertLayer(num_experts=256, experts_per_token=8,
+                        experts_held=(0, 32), width=512, shared_width=512,
+                        scaling=2.5)
+    mesh = Mesh(list(one_chip.device_set), ("data",))
+
+    def placed(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    x = placed((1, 16384, 2048), jnp.bfloat16, P("data"))
+    params = jax.tree_util.tree_map(
+        lambda p: placed(p.shape, p.dtype, P()),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"])
+    grads = jax.grad(lambda p, x: jnp.square(layer.apply(
+        {"params": p}, x).astype(jnp.float32)).sum(), argnums=(0, 1))
+    hlo = jax.jit(jax.shard_map(
+        grads, mesh=mesh, in_specs=(P(), P("data")),
+        out_specs=(P(), P("data")))).lower(params, x).compile().as_text()
+    assert len(re.findall(r"%moe_rows_add\S* = ", hlo)) == 2
+    assert not re.search(r" scatter\(f32\[16384,", hlo)
+    assert not re.search(r"= f32\[16384,\S* scatter\(", hlo)
+    bodies = set(re.findall(r"body=(%[\w.\-]+)", hlo))
+    assert len(bodies) == 2, bodies
+    for name in bodies:
+        body = hlo[hlo.index(f"\n{name} ("):]
+        body = body[:body.index("\n}\n")]
+        assert "%moe_rows_add" in body
+        assert not re.search(r"= f32\[16384,\S* copy\(", body), body
+
+
 def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):
     """``obs.kda.record_scan_program`` on a compiled toy step (one KDA
     layer's gradient, lowered for the v5e with no ``interpret`` given): no

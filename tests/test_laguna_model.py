@@ -102,8 +102,9 @@ def expert_operands(seed, tokens, d, width, held, k, num_experts,
                     ids=None):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
-    w1, w3 = (jnp.asarray(0.3 * rng.standard_normal((held, d, width)),
-                          jnp.float32) for _ in range(2))
+    # products over ``d`` of one size whatever ``d`` (0.3 at the toy's 16)
+    w1, w3 = (jnp.asarray(1.2 / math.sqrt(d) * rng.standard_normal(
+        (held, d, width)), jnp.float32) for _ in range(2))
     w2 = jnp.asarray(0.3 * rng.standard_normal((held, width, d)),
                      jnp.float32)
     if ids is None:
@@ -152,6 +153,12 @@ _ROUTINGS = {
     # rows, and an expert without a row lies between them
     "less_than_a_tile_each": (128, 16, 8, 4, 16, 2,
                               (3, 2, 5, 1, 0, 4, 2, 7), 32, 2),
+    # rows of two pieces of 128: the sums by token go through
+    # ``moe_rows_add`` — a seeded router, and a token in consecutive tiles
+    # (every token chooses both held experts) over slices of two tiles
+    "pieces_of_128": (64, 256, 8, 4, 32, 4, None, 8, None),
+    "pieces_of_128_a_token_in_the_next_tile": (
+        128, 256, 8, 8, 16, 3, (128, 120), 16, 16),
 }
 
 
@@ -189,11 +196,79 @@ def test_the_loop_follows_the_rows_and_drops_none(routing):
     in_use = int((-(-here // 8) * 8).sum())
     assert {name: int(v) for name, v in loop.items()} == {
         "slices": -(-in_use // size), "slots": in_use,
-        "ran": -(-in_use // size) * size}
+        "ran": -(-in_use // size) * size,
+        "summed": -(-in_use // size) * size if d % 128 == 0 else 0}
     if slices is not None:
         assert int(loop["slices"]) == slices
     if not in_use:
         assert not np.asarray(got[0]).any()
+
+
+# (rows of the sum, d, a tile's slots, tiles, active tiles, rows' dtype,
+# scaled)
+_SUMS = {
+    "two_pieces": (64, 256, 8, 4, 4, "float32", True),
+    "unscaled": (64, 256, 8, 4, 4, "float32", False),
+    "no_active_tile": (64, 256, 8, 4, 0, "float32", True),
+    "fewer_active_than_the_grid": (64, 256, 8, 4, 3, "bfloat16", True),
+    "a_kernel_tile": (300, 256, 256, 2, 2, "bfloat16", True),
+    "a_kernel_tile_one_active": (300, 256, 256, 2, 1, "float32", False),
+    "eighteen_pieces": (40, 2304, 8, 3, 3, "bfloat16", True),
+    "eighteen_pieces_unscaled": (40, 2304, 8, 3, 2, "float32", False),
+}
+
+
+@pytest.mark.parametrize("case", _SUMS)
+def test_rows_add_to_their_tokens_bit_for_bit(case):
+    """``moe_rows_add`` (interpreted) against ``sum.at[token].add(rows *
+    scale, mode="drop")``: every tile's tokens distinct and drawn anew, so
+    that tokens recur in consecutive tiles and a row's additions keep
+    their order; each tile's last slots empty (``token == N``) with NaN
+    rows; tiles past ``active`` all NaN. Bit for bit: scaled rows and
+    their scales are bfloat16 values, whose product float32 holds exactly
+    — the CPU compiler contracts the interpreted kernel's ``rows * scale +
+    sum`` into one rounding, which the chip does not
+    (benchmarks/expert_layer_bench.py ``--sum-only`` reads the difference
+    there on float32 scales)."""
+    from horovod_tpu.ops.grouped_matmul import moe_rows_add
+
+    n, d, tile, tiles, active, dtype, scaled = _SUMS[case]
+    rng = np.random.default_rng(len(case))
+    token = np.stack([np.concatenate([   # tokens 0 and 1 in every tile
+        [0, 1], 2 + rng.permutation(n - 2)[:tile - 2]]) for _ in range(tiles)])
+    token[:, tile - tile // 4:] = n
+    token = token.reshape(-1).astype(np.int32)
+    live = (np.arange(token.size) < active * tile) & (token < n)
+    rows = jnp.asarray(np.where(
+        live[:, None], rng.standard_normal((token.size, d)), np.nan),
+        jnp.bfloat16 if scaled else dtype).astype(dtype)
+    scale = jnp.asarray(rng.uniform(0.1, 1.0, (token.size, 1)),
+                        jnp.bfloat16).astype(jnp.float32) if scaled else None
+    total = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    got = moe_rows_add(total.reshape(n, d // 128, 128), rows,
+                       jnp.asarray(token), scale, jnp.int32(active),
+                       row_tile=tile)
+    update = rows.astype(jnp.float32) * (scale if scaled else 1.0)
+    want = total.at[jnp.where(live, token, n)].add(update, mode="drop")
+    assert got.shape == (n, d // 128, 128) and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got).reshape(n, d), want)
+    assert np.isfinite(np.asarray(got)).all()
+    touched = np.zeros(n, bool)
+    touched[token[live]] = True
+    assert touched.any() == bool(active)
+    np.testing.assert_array_equal(np.asarray(got).reshape(n, d)[~touched],
+                                  np.asarray(total)[~touched])
+
+
+def test_rows_add_refuses_what_does_not_fit():
+    from horovod_tpu.ops.grouped_matmul import moe_rows_add
+
+    total, token = jnp.zeros((16, 2, 128)), jnp.zeros((16,), jnp.int32)
+    for rows, tokens in ((jnp.zeros((16, 128)), token),    # another width
+                         (jnp.zeros((12, 256)), token[:12]),  # no whole tile
+                         (jnp.zeros((16, 256)), token[:8])):
+        with pytest.raises(ValueError, match="do not add to"):
+            moe_rows_add(total, rows, tokens, None, jnp.int32(1), row_tile=8)
 
 
 @pytest.mark.parametrize("active", [0, 3, 5, 8])
@@ -408,7 +483,8 @@ def test_moe_stats_become_gauges(toy):
     want = {"load_max_over_mean": counts.max() / counts.mean(),
             "held_share": counts[4:8].sum() / counts.sum(),
             "slices_run": -(-in_use // 16),
-            "slot_fill": in_use / (-(-in_use // 16) * 16)}
+            "slot_fill": in_use / (-(-in_use // 16) * 16),
+            "sum_kernel_share": 0.0}   # 64 wide: XLA's scatter-add
     assert published["block_1/moe"] == pytest.approx(want)
     assert 0.5 < want["slot_fill"] <= 1.0
     snapshot = obs.registry().snapshot()
@@ -416,7 +492,8 @@ def test_moe_stats_become_gauges(toy):
             ("horovod_moe_expert_load_max_over_mean", "load_max_over_mean"),
             ("horovod_moe_held_assignment_share", "held_share"),
             ("horovod_moe_slices_run", "slices_run"),
-            ("horovod_moe_slot_fill", "slot_fill")):
+            ("horovod_moe_slot_fill", "slot_fill"),
+            ("horovod_moe_sum_kernel_share", "sum_kernel_share")):
         read = {s["labels"]["layer"]: s["value"]
                 for s in snapshot[family]["samples"]}
         assert read["block_1/moe"] == pytest.approx(want[key])
@@ -426,14 +503,14 @@ def test_moe_stats_become_gauges(toy):
 
 
 def test_publish_reads_the_loop_off_a_collection():
-    """Hand-made: 40 slots in use of 3 slices of 16; a layer without a row
-    here ran no slice, and wasted none."""
+    """Hand-made: 40 slots in use of 3 slices of 16, all summed by the
+    kernel; a layer without a row here ran no slice, and wasted none."""
     from horovod_tpu import obs
 
     def layer(assignments, absent, slices, slots):
         return {name: (np.asarray(value),) for name, value in dict(
             assignments=assignments, absent=absent, slices=slices,
-            slots=slots, ran=16 * slices).items()}
+            slots=slots, ran=16 * slices, summed=16 * slices).items()}
 
     published = obs.moe.publish({
         "block_1": {"moe": layer([10, 30, 0, 0], 10, 3, 40)},
@@ -441,9 +518,11 @@ def test_publish_reads_the_loop_off_a_collection():
         "block_3": {"other": {"assignments": (np.ones(4),)}}})
     assert published == {
         "block_1/moe": {"load_max_over_mean": 3.0, "held_share": 0.75,
-                        "slices_run": 3, "slot_fill": 40 / 48},
+                        "slices_run": 3, "slot_fill": 40 / 48,
+                        "sum_kernel_share": 1.0},
         "block_2/moe": {"load_max_over_mean": 2.0, "held_share": 0.0,
-                        "slices_run": 0, "slot_fill": 1.0}}
+                        "slices_run": 0, "slot_fill": 1.0,
+                        "sum_kernel_share": 1.0}}
     fill = {s["labels"]["layer"]: s["value"] for s in
             obs.registry().snapshot()["horovod_moe_slot_fill"]["samples"]}
     assert fill["block_2/moe"] == 1.0

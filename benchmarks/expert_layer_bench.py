@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """One expert layer alone: device time of a forward + backward call of
-``models.laguna.ExpertLayer`` under ``jax.checkpoint``, at a cell's shape.
+``models.experts.ExpertLayer`` under ``jax.checkpoint``, at a cell's shape.
 
     chiprun -- python benchmarks/expert_layer_bench.py --case laguna \\
         --case sdar --case kimi --tree .pr_trees/parent --tree .
@@ -92,7 +92,7 @@ def parse_case(text: str) -> dict:
 
 
 def slices_of(size: int):
-    """``models.laguna.slice_slots`` answering ``size`` whatever the shapes
+    """``models.experts.slice_slots`` answering ``size`` whatever the shapes
     (in tiles of the kernel's, or of 8 slots below one)."""
     def rule(capacity, held, num_experts):
         from horovod_tpu.ops.grouped_matmul import ROW_TILE
@@ -120,28 +120,37 @@ def programs_ms(device, iters: int) -> float:
         trace_reduce.MODULES_LINE, [])) / iters
 
 
+def expert_module():
+    """The tree's ``models.experts``: ``models.laguna`` in a tree from
+    before the expert layer had a module of its own."""
+    try:
+        from horovod_tpu.models import experts
+    except ImportError:
+        from horovod_tpu.models import laguna as experts
+    return experts
+
+
 def measure(case: dict, iters: int, trace_root: str) -> dict:
     """One case's line, under the case's slice where it names one."""
-    from horovod_tpu.models import laguna
-
-    own_rule = getattr(laguna, "slice_slots", None)
+    experts = expert_module()
+    own_rule = getattr(experts, "slice_slots", None)
     if case["slice"] and own_rule:
-        laguna.slice_slots = slices_of(case["slice"])
+        experts.slice_slots = slices_of(case["slice"])
     try:
-        return _measure(laguna, case, iters, trace_root)
+        return _measure(experts, case, iters, trace_root)
     finally:
         if own_rule:
-            laguna.slice_slots = own_rule
+            experts.slice_slots = own_rule
 
 
-def _measure(laguna, case: dict, iters: int, trace_root: str) -> dict:
+def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
     import jax
     import jax.numpy as jnp
 
     from chipbench import trace_reduce
     from horovod_tpu import obs
 
-    layer = laguna.ExpertLayer(
+    layer = experts.ExpertLayer(
         num_experts=case["num_experts"],
         experts_per_token=case["experts_per_token"],
         experts_held=(0, case["held"]), width=case["width"],
@@ -206,13 +215,12 @@ def measure_sum(case: dict, iters: int, trace_root: str) -> dict:
     import numpy as np
 
     from chipbench import trace_reduce
-    from horovod_tpu.models import laguna
     from horovod_tpu.ops import grouped_matmul
 
     n, d = case["rows"], case["d"]
     size, tile = slices_of(case["slice"])(0, 0, 0) if case["slice"] else \
-        laguna.slice_slots(n * case["experts_per_token"], case["held"],
-                           case["num_experts"])
+        expert_module().slice_slots(
+            n * case["experts_per_token"], case["held"], case["num_experts"])
     rng = np.random.default_rng(0)
     token = np.stack([rng.permutation(n)[:tile] for _ in range(size // tile)])
     token[:, tile - tile // 16:] = n

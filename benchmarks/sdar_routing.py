@@ -9,7 +9,7 @@ benchmark's runs do not print: the gauges of the noise (``horovod_bd_masked_
 share``, ``horovod_bd_mean_weight``: ``obs.bd``), the routing gauges layer
 by layer (``horovod_moe_held_assignment_share``, ``horovod_moe_expert_load_
 max_over_mean``: ``obs.moe``) and of the loop that multiplies the held
-experts' rows (``models.laguna.held_expert_sum``) the slices it ran and
+experts' rows (``models.experts.held_expert_sum``) the slices it ran and
 how full they were (``horovod_moe_slices_run``, ``horovod_moe_slot_fill``)
 and the share of its slots summed by token in ``moe_rows_add``
 (``horovod_moe_sum_kernel_share``, 1.0 on the cell):

@@ -1,20 +1,29 @@
-"""Benchmark / example model zoo.
+"""The models the benchmarks and examples train, in flax (the reference
+ships none: its examples import torchvision / Keras applications, SURVEY
+§2.8): five decoder-only LMs (``transformer``, ``laguna``, ``kimi_linear``,
+``olmo_hybrid``, ``sdar``) and the image models of the reference's benchmark
+protocol (``resnet``, ``vgg``, ``inception``, ``mnist``).
 
-The reference ships no model code — its examples import torchvision / Keras
-applications (SURVEY §2.8). This environment has no TPU-side model zoo, so
-the models the benchmarks need (ResNet-50/101, a small MNIST convnet) are
-implemented here in flax, sized and configured to match the reference
-benchmark protocol (``examples/pytorch_synthetic_benchmark.py``).
+Imports point one way and no decoder imports another (tests/
+test_models_layout.py), ``a <- b`` reading "b imports a":
+
+    scopes <- parts <- head, experts;  delta imports none of them
+    scopes, head                        <- transformer
+    scopes, head, parts, experts        <- laguna, sdar
+    scopes, head, parts, experts, delta <- kimi_linear
+    scopes, head, parts, delta          <- olmo_hybrid
 """
 
+from .experts import ExpertLayer
+from .head import lm_head_loss, lm_loss
 from .inception import InceptionV3
 from .kimi_linear import KimiLinearLM
-from .laguna import ExpertLayer, LagunaLM
+from .laguna import LagunaLM
 from .mnist import MnistCNN
 from .olmo_hybrid import OlmoHybridLM
 from .resnet import ResNet, ResNet50, ResNet101
 from .sdar import SdarMoeLM
-from .transformer import TransformerLM, lm_head_loss, lm_loss
+from .transformer import TransformerLM
 from .vgg import VGG16, VGG19
 
 __all__ = ["MnistCNN", "ResNet", "ResNet50", "ResNet101",

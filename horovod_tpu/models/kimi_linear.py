@@ -1,7 +1,7 @@
 """Kimi-Linear-style decoder: layers of Kimi Delta Attention (KDA, a gated
 delta-rule linear attention over a short causal convolution) mixed 3 : 1
 with latent attention (MLA, no positional encoding), gated SiLU MLPs and,
-after a leading dense layer, the routed expert layer of ``models.laguna``
+after a leading dense layer, the routed expert layer ``experts.ExpertLayer``
 (docs/kimi-linear.md).
 
 The third decoder beside ``transformer.TransformerLM`` and
@@ -22,7 +22,6 @@ end, sown into the collection ``kda_stats`` (``obs.kda.publish``).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -30,123 +29,11 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .laguna import (_INIT, ATTENTION_BACKENDS, ExpertLayer, GatedMLP,
-                     dense_attention)
-from .transformer import LMHead
-
-KDA_BACKENDS = ("chunked", "recurrent")
-
-
-def _dense(features, name, dtype, axis=-1):
-    return nn.DenseGeneral(features, axis=axis, use_bias=False, dtype=dtype,
-                           kernel_init=_INIT, name=name)
-
-
-def _taps_init(key, shape, dtype=jnp.float32):
-    """Uniform in +-1/sqrt(taps): a depthwise convolution's usual start."""
-    bound = 1.0 / math.sqrt(shape[0])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-def _decay_rate_init(key, shape, dtype=jnp.float32):
-    """``A``: the log of a rate drawn uniformly from [1, 16], a head."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _decay_bias_init(key, shape, dtype=jnp.float32):
-    """``b``: softplus(b) is a step drawn log-uniformly from [0.001, 0.1]."""
-    step = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
-                                      math.log(1e-1)))
-    return step + jnp.log(-jnp.expm1(-step))
-
-
-def causal_conv(x, taps):
-    """Depthwise causal convolution along the sequence: ``y_t = sum_j
-    taps[j] * x_{t - (n - 1) + j}`` for x ``[B, T, C]`` and taps ``[n, C]``,
-    the last tap on the token itself, zeros before the sequence."""
-    n = taps.shape[0]
-    padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + x.shape[1]] * taps[j].astype(x.dtype)
-               for j in range(n))
-
-
-# A head's channels lie side by side in the last axis, ``[B, T, heads * d]``,
-# from the projections to the delta rule's kernels and back: on the TPU that
-# form tiles (tokens, lanes) — with this model's d = 128 a head of a token is
-# the lanes of one vreg; any d goes (``models.olmo_hybrid``: 96 and 192) —
-# where ``[B, T, heads, d]`` tiles (heads, lanes), so a reshape
-# between the two copies the whole tensor, and a reduction written over a
-# split last axis makes XLA move the heads into sublanes first. What a head
-# needs summed is therefore summed where it lies: a product with the 0/1
-# matrix of which channel is whose (the MXU adds a head's lanes), and the
-# same matrix transposed hands a head's number back to its channels. The
-# matrix is exact in bfloat16, so the six passes of ``Precision.HIGHEST``
-# are a float32 sum (8.9e-8 from the sum by head on the chip; ``HIGH``'s
-# three keep sixteen bits, 5.6e-6) and, the products reading their tensor
-# from HBM once either way, cost 0.2 ms a layer over ``HIGH`` (PERF.md §6).
-_BY_HEAD = jax.lax.Precision.HIGHEST
-
-
-def _whose(width: int, heads: int):
-    """``[width, heads]`` float32: 1 where a channel is of that head."""
-    return (jnp.arange(width)[:, None] // (width // heads)
-            == jnp.arange(heads)).astype(jnp.float32)
-
-
-def _head_sums(x, whose):
-    """Each head's sum of ``x [..., heads * d]``, float32: ``[..., heads]``."""
-    return jnp.dot(x, whose, precision=_BY_HEAD)
-
-
-def _to_channels(x, whose):
-    """``x [..., heads]`` repeated over each head's channels."""
-    return jnp.dot(x, whose.T, precision=_BY_HEAD)
-
-
-def _l2norm(x, whose):
-    """``x [..., heads * d]`` with each head's channels scaled to unit
-    length, float32."""
-    x = x.astype(jnp.float32)
-    return x * _to_channels(jax.lax.rsqrt(
-        _head_sums(jnp.square(x), whose) + 1e-6), whose)
-
-
-def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int, dtype):
-    """What lies between a KDA layer's projections and its delta rule: the
-    short convolutions and SiLU on q, k and v, q and k normalised a head,
-    the per-channel log-decay ``g = -exp(rate) * softplus(raw + bias)`` in
-    float32 and ``beta = sigmoid(write)``, which this model keeps in [0, 1]
-    (the rule itself takes more: ``models.olmo_hybrid``). ``[B, T, heads *
-    d]`` in,
-    ``(q, k, v, g [B, T, heads * d], beta [B, T, heads])`` out: what
-    ``ops.kda.kda_fed`` takes, no tensor reshaped on the way."""
-    whose = _whose(q.shape[-1], heads)
-    with jax.named_scope("hvd.kda.conv"):
-        q, k, v = (nn.silu(causal_conv(a, t)) for a, t in zip((q, k, v), taps))
-    g = -jnp.repeat(jnp.exp(rate), raw.shape[-1] // heads) * jax.nn.softplus(
-        raw.astype(jnp.float32) + bias)
-    q, k = (_l2norm(a, whose).astype(dtype) for a in (q, k))
-    return q, k, v, g, nn.sigmoid(write.astype(jnp.float32))
-
-
-class _HeadRMSNorm(nn.Module):
-    """``nn.RMSNorm`` over each head of ``x [B, T, heads * d]`` with one
-    learned ``scale [d]`` shared by the heads: the mean square in float32,
-    ``x * (rsqrt(. + epsilon) * scale)`` cast to ``dtype``."""
-
-    heads: int
-    epsilon: float
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        d = x.shape[-1] // self.heads
-        scale = self.param("scale", nn.initializers.ones, (d,), jnp.float32)
-        whose = _whose(x.shape[-1], self.heads)
-        mean_square = _head_sums(
-            jnp.square(x.astype(jnp.float32)), whose) / d
-        by = _to_channels(jax.lax.rsqrt(mean_square + self.epsilon), whose)
-        return (x * (by * jnp.tile(scale, self.heads))).astype(self.dtype)
+from .delta import (HeadRMSNorm, conditioned, decay_bias_init,
+                    decay_rate_init, delta_rule, taps_init)
+from .experts import ExpertLayer, held_of
+from .head import norm_and_head
+from .parts import INIT, GatedMLP, attend, dense, keep_policy, rms_norm
 
 
 class KDAMixer(nn.Module):
@@ -164,50 +51,37 @@ class KDAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if self.kda not in KDA_BACKENDS:
-            raise ValueError(f"kda must be one of {KDA_BACKENDS}, got "
-                             f"{self.kda!r}")
-        from ..ops.kda import kda_fed, kda_recurrent
-
         heads, dh = self.num_heads, self.head_dim
         width = heads * dh
         with jax.named_scope("hvd.kda"):
             with jax.named_scope(scopes.MIXER_PROJ):
-                q, k, v = (_dense(width, name, self.dtype)(x)
+                q, k, v = (dense(width, name, self.dtype)(x)
                            for name in ("query", "key", "value"))
-            taps = tuple(self.param(name, _taps_init, (self.conv_size, width))
+            taps = tuple(self.param(name, taps_init, (self.conv_size, width))
                          for name in ("conv_q", "conv_k", "conv_v"))
-            rate = self.param("decay_rate", _decay_rate_init, (heads,))
-            bias = self.param("decay_bias", _decay_bias_init, (width,))
+            rate = self.param("decay_rate", decay_rate_init, (heads,))
+            bias = self.param("decay_bias", decay_bias_init, (width,))
             with jax.named_scope(scopes.MIXER_PROJ):
-                raw = _dense(width, "decay_b", self.dtype)(
-                    _dense(dh, "decay_a", self.dtype)(x))
-                write = _dense(heads, "beta", self.dtype)(x)
-            feed = functools.partial(_conditioned, heads=heads,
-                                     dtype=self.dtype)
+                raw = dense(width, "decay_b", self.dtype)(
+                    dense(dh, "decay_a", self.dtype)(x))
+                write = dense(heads, "beta", self.dtype)(x)
+            feed = functools.partial(conditioned, heads=heads,
+                                     dtype=self.dtype,
+                                     conv_scope="hvd.kda.conv")
             projected = (q, k, v, raw, write, taps, rate, bias)
             with jax.named_scope("hvd.kda.scan"):
-                # the kernel's backward keeps the projections and forms
-                # what ``feed`` makes of them again (``ops.kda.kda_fed``)
-                if self.kda == "chunked":
-                    o, state = kda_fed(feed, *projected)
-                else:   # the definition takes heads on an axis of their own
-                    *fed, beta = feed(*projected)
-                    o, state = kda_recurrent(*(
-                        a.reshape(*a.shape[:2], heads, dh) for a in fed),
-                        beta)
-                    o = o.reshape(*o.shape[:2], width)
+                o, state = delta_rule(feed, projected, heads, self.kda)
             if self.is_mutable_collection("kda_stats"):
                 self.sow("kda_stats", "mean_decay",
                          jnp.mean(jnp.exp(feed(*projected)[3])))
                 self.sow("kda_stats", "state_max", jnp.max(jnp.abs(state)))
             with jax.named_scope(scopes.MIXER_PROJ):
-                gate = _dense(width, "gate_b", self.dtype)(
-                    _dense(dh, "gate_a", self.dtype)(x))
-            o = _HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
+                gate = dense(width, "gate_b", self.dtype)(
+                    dense(dh, "gate_a", self.dtype)(x))
+            o = HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
                 o.astype(self.dtype)) * nn.sigmoid(gate)
             with jax.named_scope(scopes.MIXER_PROJ):
-                return _dense(x.shape[-1], "out", self.dtype)(o)
+                return dense(x.shape[-1], "out", self.dtype)(o)
 
 
 class LatentAttention(nn.Module):
@@ -228,43 +102,55 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if self.attention not in ATTENTION_BACKENDS:
-            raise ValueError(f"attention must be one of {ATTENTION_BACKENDS},"
-                             f" got {self.attention!r}")
         heads = self.num_heads
         with jax.named_scope("hvd.mla"):
             with jax.named_scope(scopes.MIXER_PROJ):
-                q = _dense((heads, self.nope_dim + self.rope_dim), "query",
+                q = dense((heads, self.nope_dim + self.rope_dim), "query",
                            self.dtype)(x)
-                down = _dense(self.kv_rank + self.rope_dim, "kv_a",
+                down = dense(self.kv_rank + self.rope_dim, "kv_a",
                               self.dtype)(x)
             latent, k_pe = jnp.split(down, [self.kv_rank], axis=-1)
             latent = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                                 name="kv_norm")(latent)
             with jax.named_scope(scopes.MIXER_PROJ):
-                up = _dense((heads, self.nope_dim + self.v_dim), "kv_b",
+                up = dense((heads, self.nope_dim + self.v_dim), "kv_b",
                             self.dtype)(latent)
             k_nope, v = jnp.split(up, [self.nope_dim], axis=-1)
             k = jnp.concatenate([k_nope, jnp.broadcast_to(
                 k_pe[:, :, None, :], (*k_nope.shape[:3], self.rope_dim))],
                 axis=-1)
             with jax.named_scope("hvd.mla.attn"):
-                if self.attention == "flash":
-                    from ..ops.pallas_attention import flash_attention
-
-                    out = flash_attention(q, k, v, causal=True)
-                else:
-                    out = dense_attention(q, k, v)
+                out = attend(q, k, v, self.attention)
             out = out.astype(self.dtype)
             with jax.named_scope(scopes.MIXER_PROJ):
-                return _dense(x.shape[-1], "out", self.dtype,
+                return dense(x.shape[-1], "out", self.dtype,
                               axis=(-2, -1))(out)
 
 
 class KimiBlock(nn.Module):
     """Pre-RMSNorm residual block: a KDA or a latent-attention mixer, then
     a dense gated MLP (``dense_width``) or, where that is ``None``, the
-    expert layer."""
+    expert layer.
+
+    What a recomputed block keeps (``parts.keep_policy``, as
+    ``KimiLinearLM`` asks): its mixer kernel's outputs — ``kda_fwd``'s ``o``
+    and chunk-starting states, ``flash_mla_fwd``'s ``o`` and log-sum-exp,
+    named where the two forward rules make them — and recomputes everything
+    else: norms, projections, gate, MLP, expert layer. With its outputs kept
+    the forward Mosaic call is dead code in the recomputed block; the
+    kernels' backward paths are unchanged (``kda_bwd`` still takes the
+    feed's run there). At 16,384 tokens x 32 heads a KDA layer holds ``o``
+    134 MB + starts 268 MB and the latent layer ``o`` 134 MB + lse 2 MB from
+    its forward to its backward pass, for one run of an 11 ms and a 29 ms
+    kernel each.
+
+    Every block keeps: ``python3 -m chipbench.aot --workload
+    kimi_linear_16k_1chip`` totals 13.436 GB with the five of ``kda, kda,
+    kda, mla, kda`` keeping, under the 13.59 GB the chip leaves the step
+    (the latent block and the last 3 / 2 / 0 KDA blocks: 13.034 / 12.631 /
+    12.227; none, the default policy: 12.117). Where a stack did not fit,
+    the blocks nearest the output should keep first: the backward pass
+    frees their residuals first, so they are never dearer than one below."""
 
     mixer: str          # "kda" | "mla"
     kda: dict           # KDAMixer's fields
@@ -276,12 +162,7 @@ class KimiBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        def norm(name, x):
-            with jax.named_scope(scopes.NORM):
-                return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                                  name=name)(x)
-
-        h = norm("ln_attn", x)
+        h = rms_norm(x, "ln_attn", self.eps, self.dtype)
         with jax.named_scope(scopes.MIXER):
             if self.mixer == "kda":
                 x = x + KDAMixer(eps=self.eps, dtype=self.dtype, name="kda",
@@ -289,7 +170,7 @@ class KimiBlock(nn.Module):
             else:
                 x = x + LatentAttention(eps=self.eps, dtype=self.dtype,
                                         name="mla", **self.mla)(h)
-        h = norm("ln_mlp", x)
+        h = rms_norm(x, "ln_mlp", self.eps, self.dtype)
         if self.dense_width is not None:
             with jax.named_scope(scopes.MLP):
                 return x + GatedMLP(self.dense_width, self.dtype,
@@ -297,35 +178,10 @@ class KimiBlock(nn.Module):
         return x + ExpertLayer(dtype=self.dtype, name="moe", **self.experts)(h)
 
 
-def _keep_policy():
-    """The checkpoint policy of a recomputed block: it keeps its mixer
-    kernel's outputs — ``kda_fwd``'s ``o`` and chunk-starting states,
-    ``flash_mla_fwd``'s ``o`` and log-sum-exp, named where the two forward
-    rules make them — and recomputes everything else: norms, projections,
-    gate, MLP, expert layer. With its outputs kept the forward Mosaic call
-    is dead code in the recomputed block; the kernels' backward paths are
-    unchanged (``kda_bwd`` still takes the feed's run there). At
-    16,384 tokens x 32 heads a KDA layer holds ``o`` 134 MB + starts 268 MB
-    and the latent layer ``o`` 134 MB + lse 2 MB from its forward to its
-    backward pass, for one run of an 11 ms and a 29 ms kernel each.
-
-    Every block keeps: ``python3 -m chipbench.aot --workload
-    kimi_linear_16k_1chip`` totals 13.436 GB with the five of ``kda, kda,
-    kda, mla, kda`` keeping, under the 13.59 GB the chip leaves the step
-    (the latent block and the last 3 / 2 / 0 KDA blocks: 13.034 / 12.631 /
-    12.227; none, the default policy: 12.117). Where a stack did not fit,
-    the blocks nearest the output should keep first: the backward pass
-    frees their residuals first, so they are never dearer than one below."""
-    from ..ops import kda, pallas_attention
-
-    return jax.checkpoint_policies.save_only_these_names(
-        *kda.KEPT_NAMES, *pallas_attention.KEPT_NAMES)
-
-
 class KimiLinearLM(nn.Module):
     """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``
     (``model(tokens, loss_tokens=tokens)``: their ``lm_loss``, the logits
-    never whole, ``transformer.lm_head_loss``).
+    never whole, ``head.lm_head_loss``).
     Layer ``i`` mixes with ``mixers[i]`` (``"kda"`` or ``"mla"``) and its
     MLP is ``mlp_layer_types[i]`` (``"dense"`` or ``"sparse"``). No
     positional encoding of any kind."""
@@ -353,7 +209,7 @@ class KimiLinearLM(nn.Module):
     attention: str = "flash"    # "dense": the tests' written-out attention
     kda: str = "chunked"        # "recurrent": the tests' token-by-token scan
     # jax.checkpoint each block: only the block-boundary activations and the
-    # mixer kernel's outputs are stored (``_keep_policy``: 0.40 GB a KDA
+    # mixer kernel's outputs are stored (``KimiBlock``: 0.40 GB a KDA
     # layer, 0.14 GB a latent one at 16,384 tokens x 32 heads); the rest of
     # a block's interior is recomputed in backward
     remat: bool = False
@@ -370,8 +226,6 @@ class KimiLinearLM(nn.Module):
         linear = config["linear_attn_config"]
         kinds = {**{i: "kda" for i in linear["kda_layers"]},
                  **{i: "mla" for i in linear["full_attn_layers"]}}
-        held = config.get("experts_held",
-                          {"first": 0, "count": config["num_experts"]})
         fields = dict(
             vocab_size=config["vocab_size"], d_model=config["hidden_size"],
             mixers=tuple(kinds[i] for i in range(1, depth + 1)),
@@ -390,7 +244,7 @@ class KimiLinearLM(nn.Module):
             * config["num_shared_experts"],
             num_experts=config["num_experts"],
             experts_per_token=config["num_experts_per_token"],
-            experts_held=(held["first"], held["count"]),
+            experts_held=held_of(config),
             routed_scaling=config["routed_scaling_factor"],
             eps=config["rms_norm_eps"])
         if linear["num_heads"] != fields["num_heads"]:
@@ -406,9 +260,9 @@ class KimiLinearLM(nn.Module):
                              "long")
         with jax.named_scope(scopes.EMBED):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                         embedding_init=_INIT, name="tok_embed")(tokens)
-        block_cls = nn.remat(KimiBlock, policy=_keep_policy()) \
-            if self.remat else KimiBlock
+                         embedding_init=INIT, name="tok_embed")(tokens)
+        block_cls = nn.remat(KimiBlock, policy=keep_policy(
+            "kda", "pallas_attention")) if self.remat else KimiBlock
         kda = dict(num_heads=self.num_heads, head_dim=self.kda_head_dim,
                    conv_size=self.conv_size, kda=self.kda)
         mla = dict(num_heads=self.num_heads, nope_dim=self.nope_dim,
@@ -426,12 +280,5 @@ class KimiLinearLM(nn.Module):
                 dtype=self.dtype,
                 dense_width=self.dense_width if mlp == "dense" else None,
                 name=f"block_{i}")(x)
-        with jax.named_scope(scopes.NORM):
-            x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                           name="ln_final")(x)
-        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                      kernel_init=_INIT, name="lm_head")
-        if loss_tokens is not None:
-            return head.loss(x, loss_tokens)
-        with jax.named_scope(scopes.HEAD):
-            return head(x).astype(jnp.float32)
+        return norm_and_head(x, self.vocab_size, self.eps, self.dtype,
+                             loss_tokens)

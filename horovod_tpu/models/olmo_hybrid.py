@@ -10,7 +10,8 @@ and ``kimi_linear.KimiLinearLM``: the same call (``model(tokens) -> float32
 logits``), so ``make_lm_train_step`` and ``lm_loss`` take it unchanged.
 Two kernels carry it: ``ops.kda.kda_fed`` with a decay ``[B, T, heads]``
 (its ``gdn_fwd`` / ``gdn_bwd``; the tensors ``[B, T, heads * d]`` as the
-projections make them, as in ``kimi_linear``) and
+projections make them, as in ``kimi_linear``; ``models.delta`` holds what
+the two share) and
 ``ops.pallas_attention.flash_attention``.
 
 The model stands for one chip of a deployment that divides each layer's
@@ -39,30 +40,12 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .kimi_linear import (KDA_BACKENDS, _decay_bias_init, _decay_rate_init,
-                          _dense, _HeadRMSNorm, _keep_policy, _l2norm,
-                          _taps_init, _whose, causal_conv)
-from .laguna import _INIT, ATTENTION_BACKENDS, GatedMLP, dense_attention
-from .transformer import LMHead
+from .delta import (HeadRMSNorm, conditioned, decay_bias_init,
+                    decay_rate_init, delta_rule, taps_init)
+from .head import norm_and_head
+from .parts import INIT, GatedMLP, attend, dense, keep_policy, rms_norm
 
 LAYER_TYPES = ("linear_attention", "full_attention")
-
-
-def _conditioned(q, k, v, raw, write, taps, rate, bias, *, heads: int,
-                 strongest: float, dtype):
-    """What lies between a delta-rule layer's projections and its rule: the
-    short convolutions and SiLU on q, k and v, q and k normalised a head,
-    the log-decay ``g = -exp(rate) * softplus(raw + bias)``, one a head and
-    token in float32, and ``beta = strongest * sigmoid(write)``. q, k ``[B,
-    T, heads * d_k]``, v ``[B, T, heads * d_v]``, raw and write ``[B, T,
-    heads]`` in; ``(q, k, v, g, beta)`` out, g and beta ``[B, T, heads]``:
-    what ``ops.kda.kda_fed`` takes, no tensor reshaped on the way."""
-    whose = _whose(q.shape[-1], heads)
-    with jax.named_scope("hvd.gdn.conv"):
-        q, k, v = (nn.silu(causal_conv(a, t)) for a, t in zip((q, k, v), taps))
-    g = -jnp.exp(rate) * jax.nn.softplus(raw.astype(jnp.float32) + bias)
-    q, k = (_l2norm(a, whose).astype(dtype) for a in (q, k))
-    return q, k, v, g, strongest * nn.sigmoid(write.astype(jnp.float32))
 
 
 class GatedDeltaMixer(nn.Module):
@@ -84,16 +67,11 @@ class GatedDeltaMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if self.rule not in KDA_BACKENDS:
-            raise ValueError(f"rule must be one of {KDA_BACKENDS}, got "
-                             f"{self.rule!r}")
-        from ..ops.kda import kda_fed, kda_recurrent
-
         heads = self.num_heads
         keys, values = heads * self.key_dim, heads * self.value_dim
         with jax.named_scope("hvd.gdn"):
             with jax.named_scope(scopes.MIXER_PROJ):
-                q, k, v = (_dense(width, name, self.dtype)(x)
+                q, k, v = (dense(width, name, self.dtype)(x)
                            for name, width in (("query", keys), ("key", keys),
                                                ("value", values)))
                 # from zero: the step starts at softplus(dt_bias), as
@@ -104,28 +82,20 @@ class GatedDeltaMixer(nn.Module):
                 raw = nn.DenseGeneral(
                     heads, use_bias=False, dtype=self.dtype, name="decay",
                     kernel_init=nn.initializers.zeros_init())(x)
-                write = _dense(heads, "beta", self.dtype)(x)
-            taps = tuple(self.param(name, _taps_init, (self.conv_size, width))
+                write = dense(heads, "beta", self.dtype)(x)
+            taps = tuple(self.param(name, taps_init, (self.conv_size, width))
                          for name, width in (("conv_q", keys),
                                              ("conv_k", keys),
                                              ("conv_v", values)))
-            rate = self.param("A_log", _decay_rate_init, (heads,))
-            bias = self.param("dt_bias", _decay_bias_init, (heads,))
+            rate = self.param("A_log", decay_rate_init, (heads,))
+            bias = self.param("dt_bias", decay_bias_init, (heads,))
             feed = functools.partial(
-                _conditioned, heads=heads, dtype=self.dtype,
+                conditioned, heads=heads, dtype=self.dtype,
+                conv_scope="hvd.gdn.conv",
                 strongest=2.0 if self.allow_neg_eigval else 1.0)
             projected = (q, k, v, raw, write, taps, rate, bias)
             with jax.named_scope("hvd.gdn.scan"):
-                # the kernel's backward keeps the projections and forms
-                # what ``feed`` makes of them again (``ops.kda.kda_fed``)
-                if self.rule == "chunked":
-                    o, state = kda_fed(feed, *projected)
-                else:   # the definition takes heads on an axis of their own
-                    q, k, v, g, beta = feed(*projected)
-                    o, state = kda_recurrent(*(
-                        a.reshape(*a.shape[:2], heads, -1)
-                        for a in (q, k, v)), g, beta)
-                    o = o.reshape(*o.shape[:2], values)
+                o, state = delta_rule(feed, projected, heads, self.rule)
             if self.is_mutable_collection("gdn_stats"):
                 g, beta = feed(*projected)[3:]
                 self.sow("gdn_stats", "mean_decay", jnp.mean(jnp.exp(g)))
@@ -133,11 +103,11 @@ class GatedDeltaMixer(nn.Module):
                 self.sow("gdn_stats", "beta_above_one",
                          jnp.mean((beta > 1.0).astype(jnp.float32)))
             with jax.named_scope(scopes.MIXER_PROJ):
-                gate = _dense(values, "gate", self.dtype)(x)
-            o = _HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
+                gate = dense(values, "gate", self.dtype)(x)
+            o = HeadRMSNorm(heads, self.eps, self.dtype, name="out_norm")(
                 o.astype(self.dtype)) * nn.silu(gate)
             with jax.named_scope(scopes.MIXER_PROJ):
-                return _dense(x.shape[-1], "out", self.dtype)(o)
+                return dense(x.shape[-1], "out", self.dtype)(o)
 
 
 class NormedAttention(nn.Module):
@@ -154,13 +124,9 @@ class NormedAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if self.attention not in ATTENTION_BACKENDS:
-            raise ValueError(f"attention must be one of {ATTENTION_BACKENDS},"
-                             f" got {self.attention!r}")
-
         def heads(n, name, norm=None):
             with jax.named_scope(scopes.MIXER_PROJ):
-                y = _dense(n * self.head_dim, name, self.dtype)(x)
+                y = dense(n * self.head_dim, name, self.dtype)(x)
             if norm is not None:
                 y = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                                name=norm)(y)
@@ -169,15 +135,10 @@ class NormedAttention(nn.Module):
         q = heads(self.num_heads, "query", "q_norm")
         k = heads(self.num_kv_heads, "key", "k_norm")
         v = heads(self.num_kv_heads, "value")
-        if self.attention == "flash":
-            from ..ops.pallas_attention import flash_attention
-
-            out = flash_attention(q, k, v, causal=True)
-        else:
-            out = dense_attention(q, k, v)
+        out = attend(q, k, v, self.attention)
         out = out.astype(self.dtype).reshape(*x.shape[:2], -1)
         with jax.named_scope(scopes.MIXER_PROJ):
-            return _dense(x.shape[-1], "out", self.dtype)(out)
+            return dense(x.shape[-1], "out", self.dtype)(out)
 
 
 class OlmoHybridBlock(nn.Module):
@@ -196,9 +157,7 @@ class OlmoHybridBlock(nn.Module):
         def add_normed(owner, name, x, y):
             # the norm is hvd.norm's, the residual add its part's, as in
             # the pre-norm decoders
-            with jax.named_scope(scopes.NORM):
-                y = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                               name=name)(y)
+            y = rms_norm(y, name, self.eps, self.dtype)
             with jax.named_scope(owner):
                 return x + y
 
@@ -218,7 +177,7 @@ class OlmoHybridBlock(nn.Module):
 class OlmoHybridLM(nn.Module):
     """Decoder-only LM, ``model(tokens) -> float32 logits [B, T, vocab]``
     (``model(tokens, loss_tokens=tokens)``: their ``lm_loss``, the logits
-    never whole, ``transformer.lm_head_loss``).
+    never whole, ``head.lm_head_loss``).
     Layer ``i`` mixes with ``layer_types[i]``. The head counts are those
     held here. No positional encoding of any kind: the delta-rule layers
     carry order."""
@@ -240,7 +199,7 @@ class OlmoHybridLM(nn.Module):
     attention: str = "flash"    # "dense": the tests' written-out attention
     rule: str = "chunked"       # "recurrent": the tests' token-by-token scan
     # jax.checkpoint each block: the block-boundary activations and the
-    # mixer kernel's outputs are stored (``kimi_linear._keep_policy``), the
+    # mixer kernel's outputs are stored (``parts.keep_policy``), the
     # rest of a block's interior is recomputed in backward
     remat: bool = False
 
@@ -288,9 +247,9 @@ class OlmoHybridLM(nn.Module):
                              f"{sorted(unknown)}")
         with jax.named_scope(scopes.EMBED):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                         embedding_init=_INIT, name="tok_embed")(tokens)
-        block_cls = nn.remat(OlmoHybridBlock, policy=_keep_policy()) \
-            if self.remat else OlmoHybridBlock
+                         embedding_init=INIT, name="tok_embed")(tokens)
+        block_cls = nn.remat(OlmoHybridBlock, policy=keep_policy(
+            "kda", "pallas_attention")) if self.remat else OlmoHybridBlock
         gdn = dict(num_heads=self.linear_heads, key_dim=self.linear_key_dim,
                    value_dim=self.linear_value_dim, conv_size=self.conv_size,
                    allow_neg_eigval=self.allow_neg_eigval, rule=self.rule)
@@ -300,12 +259,5 @@ class OlmoHybridLM(nn.Module):
             x = block_cls(mixer=mixer, gdn=gdn, attn=attn,
                           mlp_width=self.mlp_width, eps=self.eps,
                           dtype=self.dtype, name=f"block_{i}")(x)
-        with jax.named_scope(scopes.NORM):
-            x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                           name="ln_final")(x)
-        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                      kernel_init=_INIT, name="lm_head")
-        if loss_tokens is not None:
-            return head.loss(x, loss_tokens)
-        with jax.named_scope(scopes.HEAD):
-            return head(x).astype(jnp.float32)
+        return norm_and_head(x, self.vocab_size, self.eps, self.dtype,
+                             loss_tokens)

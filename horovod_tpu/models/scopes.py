@@ -9,7 +9,7 @@ refactor keeps the names, because readers find device time by them
 (``chipbench/components.py``).
 
 Set where a block calls its part and not inside the part, the six never
-nest in one another; ``hvd.moe`` (``laguna.ExpertLayer``) is the seventh
+nest in one another; ``hvd.moe`` (``experts.ExpertLayer``) is the seventh
 owner, and the shared expert's ``GatedMLP`` stays its.
 """
 

@@ -17,7 +17,7 @@ own and the *noisy* rows of its own — which
 ``flash_bd_*`` kernels. The loss is the cross entropy of the noisy rows
 against their own clean ids, no shift, each row times its weight (``1 /
 rate`` where it was masked, else 0), over the ``G * L`` rows
-(``transformer.lm_head_loss`` with ``targets`` and ``weights``): ``model(
+(``head.lm_head_loss`` with ``targets`` and ``weights``): ``model(
 clean, noisy, weights=weights)``; without ``weights`` the call returns the
 noisy rows' float32 logits ``[G, L, vocab]``.
 
@@ -25,7 +25,7 @@ In the last layer the clean half is needed for its keys and values alone:
 after that layer's attention only the noisy rows go on, through its expert
 layer, the final norm and the head.
 
-The expert layer is ``laguna.ExpertLayer`` told ``experts_held`` (one chip
+The expert layer is ``experts.ExpertLayer`` told ``experts_held`` (one chip
 of an expert-parallel deployment), ``scoring="softmax"`` and no shared
 expert; its routing gauges are Laguna's. The loss sows, into the collection
 ``bd_stats``, the share of rows that carry a weight and the mean weight
@@ -34,7 +34,6 @@ over them (``obs.bd.publish``).
 
 from __future__ import annotations
 
-import math
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -42,9 +41,9 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .laguna import (ATTENTION_BACKENDS, ExpertLayer, Rotary, _INIT,
-                     _keep_policy)
-from .transformer import LMHead
+from .experts import ExpertLayer, held_of
+from .head import norm_and_head
+from .parts import INIT, Rotary, attend, dense, keep_policy, rms_norm
 
 
 def block_diffusion_noise(key, tokens, block_length: int, mask_id: int,
@@ -67,31 +66,6 @@ def block_diffusion_noise(key, tokens, block_length: int, mask_id: int,
             jnp.where(masked, 1.0 / rate, 0.0))
 
 
-def block_diffusion_mask(seq: int, block_length: int):
-    """``[2 seq, 2 seq]`` bool, the mask from its definition: rows and
-    columns are a sequence's clean copy and then its noisy one."""
-    at = jnp.arange(2 * seq)
-    noisy, block = at >= seq, at % seq // block_length
-    q_noisy, k_noisy = noisy[:, None], noisy[None, :]
-    q_block, k_block = block[:, None], block[None, :]
-    return jnp.where(q_noisy,
-                     jnp.where(k_noisy, k_block == q_block,
-                               k_block < q_block),
-                     ~k_noisy & (k_block <= q_block))
-
-
-def dense_attention(q, k, v, block_length: int):
-    """Attention under ``block_diffusion_mask`` written out, grouped heads
-    as ``flash_attention`` takes them; float32 softmax."""
-    group = q.shape[2] // k.shape[2]
-    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
-        / math.sqrt(q.shape[-1])
-    keep = block_diffusion_mask(q.shape[1] // 2, block_length)
-    weights = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v)
-
-
 class BlockDiffusionAttention(nn.Module):
     """Self-attention of ``[clean ; noisy]`` rows under the block-diffusion
     mask: ``num_heads`` query heads on ``num_kv_heads`` key/value heads, q
@@ -111,15 +85,9 @@ class BlockDiffusionAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        if self.attention not in ATTENTION_BACKENDS:
-            raise ValueError(f"attention must be one of {ATTENTION_BACKENDS},"
-                             f" got {self.attention!r}")
-
         def heads(n, name, norm=None):
             with jax.named_scope(scopes.MIXER_PROJ):
-                y = nn.DenseGeneral((n, self.head_dim), use_bias=False,
-                                    dtype=self.dtype, kernel_init=_INIT,
-                                    name=name)(x)
+                y = dense((n, self.head_dim), name, self.dtype)(x)
             if norm is None:
                 return y
             return self.rotary(nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
@@ -130,20 +98,13 @@ class BlockDiffusionAttention(nn.Module):
             k = heads(self.num_kv_heads, "key", "k_norm")
             v = heads(self.num_kv_heads, "value")
             with jax.named_scope("hvd.bd.attn"):
-                if self.attention == "flash":
-                    from ..ops.pallas_attention import flash_attention
-
-                    out = flash_attention(q, k, v, causal=True,
-                                          block_diffusion=self.block_length)
-                else:
-                    out = dense_attention(q, k, v, self.block_length)
+                out = attend(q, k, v, self.attention,
+                             block_diffusion=self.block_length)
             if self.noisy_only:
                 out = out[:, out.shape[1] // 2:]
             with jax.named_scope(scopes.MIXER_PROJ):
-                return nn.DenseGeneral(
-                    x.shape[-1], axis=(-2, -1), use_bias=False,
-                    dtype=self.dtype, kernel_init=_INIT,
-                    name="out")(out.astype(self.dtype))
+                return dense(x.shape[-1], "out", self.dtype,
+                             axis=(-2, -1))(out.astype(self.dtype))
 
 
 class SdarBlock(nn.Module):
@@ -153,7 +114,7 @@ class SdarBlock(nn.Module):
     With ``remat`` each half is a ``jax.checkpoint`` of its own, as
     ``laguna.LagunaBlock``'s; the attention half keeps its flash kernel's
     output and log-sum-exp as a full layer of Laguna's does
-    (``laguna._keep_policy``), so that ``flash_bd_fwd`` runs once a layer
+    (``parts.keep_policy``), so that ``flash_bd_fwd`` runs once a layer
     (docs/sdar.md has the price list)."""
 
     attn: dict          # BlockDiffusionAttention's fields
@@ -165,14 +126,9 @@ class SdarBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        def norm(name, x):
-            with jax.named_scope(scopes.NORM):
-                return nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                                  name=name)(x)
-
         # ``nn.remat`` hands a function the module as its first argument
         def mix(block, x, positions):
-            h = norm("ln_attn", x)
+            h = rms_norm(x, "ln_attn", self.eps, self.dtype)
             with jax.named_scope(scopes.MIXER):
                 if self.last:
                     x = x[:, x.shape[1] // 2:]
@@ -182,10 +138,11 @@ class SdarBlock(nn.Module):
 
         def feed(block, x):
             return x + ExpertLayer(dtype=self.dtype, name="moe",
-                                   **self.experts)(norm("ln_mlp", x))
+                                   **self.experts)(
+                rms_norm(x, "ln_mlp", self.eps, self.dtype))
 
         if self.remat:
-            mix = nn.remat(mix, policy=_keep_policy(window=None))
+            mix = nn.remat(mix, policy=keep_policy("pallas_attention"))
             feed = nn.remat(feed)
         return feed(self, mix(self, x, positions))
 
@@ -227,8 +184,6 @@ class SdarMoeLM(nn.Module):
                              "supported")
         if not config.get("norm_topk_prob", True):
             raise ValueError("norm_topk_prob false is not supported")
-        held = config.get("experts_held",
-                          {"first": 0, "count": config["num_experts"]})
         fields = dict(
             vocab_size=config["vocab_size"], d_model=config["hidden_size"],
             num_layers=config["num_hidden_layers"],
@@ -238,7 +193,7 @@ class SdarMoeLM(nn.Module):
             expert_width=config["moe_intermediate_size"],
             num_experts=config["num_experts"],
             experts_per_token=config["num_experts_per_tok"],
-            experts_held=(held["first"], held["count"]),
+            experts_held=held_of(config),
             rope_theta=config["rope_theta"],
             block_length=config.get("block_length", 4),
             eps=config["rms_norm_eps"])
@@ -253,7 +208,7 @@ class SdarMoeLM(nn.Module):
                                      tokens.shape)
         with jax.named_scope(scopes.EMBED):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                         embedding_init=_INIT, name="tok_embed")(tokens)
+                         embedding_init=INIT, name="tok_embed")(tokens)
         attn = dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim, attention=self.attention,
                     block_length=self.block_length,
@@ -268,16 +223,11 @@ class SdarMoeLM(nn.Module):
                           dtype=self.dtype, remat=self.remat,
                           last=i == self.num_layers - 1,
                           name=f"block_{i}")(x, positions)
-        with jax.named_scope(scopes.NORM):
-            x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                           name="ln_final")(x)
-        head = LMHead(self.vocab_size, use_bias=False, dtype=jnp.float32,
-                      kernel_init=_INIT, name="lm_head")
         if weights is None:
-            with jax.named_scope(scopes.HEAD):
-                return head(x).astype(jnp.float32)
+            return norm_and_head(x, self.vocab_size, self.eps, self.dtype)
         counted = jnp.sum(weights > 0)
         self.sow("bd_stats", "masked_share", counted / weights.size)
         self.sow("bd_stats", "mean_weight",
                  jnp.sum(weights) / jnp.maximum(counted, 1))
-        return head.loss(x, targets=clean, weights=weights)
+        return norm_and_head(x, self.vocab_size, self.eps, self.dtype,
+                             targets=clean, weights=weights)

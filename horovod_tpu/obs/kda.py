@@ -22,7 +22,7 @@ And whether the layout held: the kernels read q, k, v, g as ``[B, T, H *
 d]`` and the layer keeps them so from its projections on; a tensor that is
 taken to ``[B, T, H, d]`` on the way is copied whole on the TPU, and shows
 here as a relayout. And whether a recomputed block kept its mixer kernel's
-outputs (``models.kimi_linear``): a forward kernel the backward pass runs
+outputs (``models.parts.keep_policy``): a forward kernel the backward pass runs
 again shows here as a call beyond the one a layer needs.
 
 ``models.olmo_hybrid.GatedDeltaMixer`` sows ``gdn_stats`` — the same two

@@ -1,6 +1,6 @@
 """Gauges of the expert layer's routing (docs/laguna.md).
 
-``models.laguna.ExpertLayer`` sows, into the flax collection ``moe_stats``,
+``models.experts.ExpertLayer`` sows, into the flax collection ``moe_stats``,
 how many assignments each expert got (``assignments``), how many went to
 experts this chip does not hold (``absent``) and, of the loop that
 multiplies the held experts' rows, the ``slices`` it ran, the ``slots`` in

@@ -5,7 +5,7 @@ sum by token of what they give.
 tile of ``row_tile`` consecutive ``rows`` [m, k] by the matrix of
 ``weights`` [groups, k, n] that ``tile_group`` names for it. The caller
 lays the rows out so that a tile belongs to one group (a group's rows
-padded to whole tiles, ``models.laguna.held_expert_sum``); only the first
+padded to whole tiles, ``models.experts.held_expert_sum``); only the first
 ``active_tiles`` tiles are computed, so the work follows the rows that were
 routed here, not the buffer that could hold all of them. Rows of later
 tiles come back unspecified (the caller masks them).

@@ -77,7 +77,7 @@ them. ``kda_fed(feed,
 ``kda_fwd`` with them — unless its policy saves ``KEPT_NAMES``, the names
 the forward rule gives the kernel's ``o`` (134 MB) and starting states (268
 MB, bfloat16): then the recomputation does not make the call
-(``models.kimi_linear._keep_policy``).
+(``models.parts.keep_policy``, as ``models.kimi_linear`` asks it).
 
 The layout. The kernels read and write q, k, v, g and their gradients as
 ``[B, T, H * d]``, a head's channels side by side (``_specs``: blocks of
@@ -792,9 +792,9 @@ def kda_fed(feed, *args, scale: Optional[float] = None, chunk: int = CHUNK,
 
     The forward rule names ``o`` and the chunks' starting states
     (``KEPT_NAMES``) — an identity without a ``jax.checkpoint`` policy that
-    saves them; with one (``models.kimi_linear``) a recomputed layer holds
-    those 0.40 GB from its forward to its backward pass and does not run
-    ``kda_fwd`` a second time for them (``feed`` still runs in this
+    saves them; with one (``models.parts.keep_policy``) a recomputed layer
+    holds those 0.40 GB from its forward to its backward pass and does not
+    run ``kda_fwd`` a second time for them (``feed`` still runs in this
     function's backward)."""
     if chunk % _SUB:
         raise ValueError(f"chunk must be a multiple of {_SUB}, got {chunk}")

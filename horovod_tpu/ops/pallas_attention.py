@@ -131,7 +131,8 @@ _LANES = 128  # TPU vreg lane count: the trailing axis of column statistics
 # what the forward kernel leaves that a recomputed block would run it again
 # for: its output and the rows' log-sum-exp, under these checkpoint names. A
 # name is an identity that lowers to nothing; a ``jax.checkpoint`` whose
-# policy saves them (``models.kimi_linear``) keeps both for its backward pass
+# policy saves them (``models.parts.keep_policy``) keeps both for its backward
+# pass
 KEPT_NAMES = ("flash.o", "flash.lse")
 # VMEM a kernel's resident major blocks may take (both pipeline buffers of
 # both operands, and the forward's scores): half of the 16 MiB a v5e kernel

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models.sdar import block_diffusion_mask, dense_attention
+from horovod_tpu.models.parts import block_diffusion_mask, dense_attention
 from horovod_tpu.ops import pallas_attention as pa
 
 # id: (L, block, heads, kv heads, head_dim, batch, tile bound, resident bytes)
@@ -60,7 +60,8 @@ def test_kernels_against_dense_attention(monkeypatch, case):
 
     with jax.default_matmul_precision("highest"):
         want, pull = jax.vjp(
-            lambda q, k, v: dense_attention(q, k, v, block), q, k, v)
+            lambda q, k, v: dense_attention(
+                q, k, v, block_diffusion=block), q, k, v)
         want = (want, *pull(do))
     got, pull = jax.vjp(flash, q, k, v)
     got = (got, *pull(do))

@@ -289,7 +289,7 @@ def test_an_expert_layer_carries_its_sums_in_place_for_v5e(
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.models.laguna import ExpertLayer
+    from horovod_tpu.models.experts import ExpertLayer
 
     layer = ExpertLayer(num_experts=256, experts_per_token=8,
                         experts_held=(0, 32), width=512, shared_width=512,
@@ -391,16 +391,16 @@ def test_a_recomputed_laguna_step_as_its_gauges_read_it(
 
     from benchmarks._dp_step import lm_step_loss
     from horovod_tpu import obs
-    from horovod_tpu.models import laguna, transformer
+    from horovod_tpu.models import LagunaLM, head
     from horovod_tpu.ops import pallas_attention
     from test_laguna_model import TOY
 
-    monkeypatch.setattr(transformer, "LOSS_ROWS", 128)
+    monkeypatch.setattr(head, "LOSS_ROWS", 128)
     monkeypatch.setattr(
         pallas_attention, "flash_attention",
         functools.partial(pallas_attention.flash_attention, interpret=False))
     vocab = 640
-    model = laguna.LagunaLM.from_config(
+    model = LagunaLM.from_config(
         dict(TOY, num_hidden_layers=2, head_dim=128, vocab_size=vocab,
              mlp_layer_types=["dense", "dense"]), remat=True)
     assert model.layer_types == ("full_attention", "sliding_attention")

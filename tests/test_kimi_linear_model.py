@@ -17,8 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import ExpertLayer, KimiLinearLM, lm_loss
-from horovod_tpu.models import kimi_linear, laguna, scopes
+from horovod_tpu.models import ExpertLayer, KimiLinearLM, LagunaLM, lm_loss
+from horovod_tpu.models import delta, kimi_linear, parts, scopes
 
 TOY = {
     "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
@@ -100,7 +100,7 @@ def test_causal_conv_against_its_definition():
                     jnp.float32)
     taps = jnp.asarray([[1.0, 0.0, 2.0], [0.0, 1.0, 0.0],
                         [0.0, 0.0, 0.0], [0.5, 3.0, 1.0]])
-    out = np.asarray(kimi_linear.causal_conv(x, taps))
+    out = np.asarray(delta.causal_conv(x, taps))
     x = np.asarray(x)
     # the last tap is the token's own; nothing before the sequence
     np.testing.assert_allclose(out[0, 0], [0.5, 3.0, 1.0] * x[0, 0],
@@ -109,7 +109,7 @@ def test_causal_conv_against_its_definition():
         out[0, 5, 0], 1.0 * x[0, 2, 0] + 0.5 * x[0, 5, 0], rtol=1e-6)
     np.testing.assert_allclose(
         out[0, 5, 1], 1.0 * x[0, 3, 1] + 3.0 * x[0, 5, 1], rtol=1e-6)
-    moved = np.asarray(kimi_linear.causal_conv(
+    moved = np.asarray(delta.causal_conv(
         jnp.asarray(x).at[0, 6].add(1.0), taps))
     assert np.abs(moved - out)[0, :6].max() == 0.0
 
@@ -138,7 +138,7 @@ def test_the_parameter_tree_is_what_it_was(toy):
 
 
 def test_heads_side_by_side_against_heads_on_an_axis():
-    """``_conditioned`` and the output norm keep ``[B, T, H * d]`` and sum
+    """``conditioned`` and the output norm keep ``[B, T, H * d]`` and sum
     a head's channels where they lie; written out with the heads on an axis
     of their own, as the model had them, the numbers are the same in
     float32 — values and the gradient of every argument."""
@@ -157,14 +157,14 @@ def test_heads_side_by_side_against_heads_on_an_axis():
     def by_head(q, k, v, raw, write, taps, rate, bias):
         unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
             jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-        q, k, v = (split(nn.silu(kimi_linear.causal_conv(a, t)))
+        q, k, v = (split(nn.silu(delta.causal_conv(a, t)))
                    for a, t in zip((q, k, v), taps))
         g = -jnp.exp(rate)[:, None] * split(jax.nn.softplus(raw + bias))
         return unit(q), unit(k), v, g, nn.sigmoid(write)
 
     def flat(*a):
-        *fed, beta = kimi_linear._conditioned(*a, heads=heads,
-                                              dtype=jnp.float32)
+        *fed, beta = delta.conditioned(*a, heads=heads, dtype=jnp.float32,
+                                       conv_scope="hvd.kda.conv")
         assert all(x.shape == (2, seq, heads * d) for x in fed)
         return (*map(split, fed), beta)
 
@@ -180,7 +180,7 @@ def test_heads_side_by_side_against_heads_on_an_axis():
     # the output norm against flax's over the last axis, one scale shared
     o, scale = normal(2, seq, heads * d), {"scale": 1.0 + normal(d) / 4}
     want = nn.RMSNorm(epsilon=1e-5).apply({"params": scale}, split(o))
-    got = kimi_linear._HeadRMSNorm(heads, 1e-5, jnp.float32).apply(
+    got = delta.HeadRMSNorm(heads, 1e-5, jnp.float32).apply(
         {"params": scale}, o)
     np.testing.assert_allclose(split(got), want, atol=1e-6)
 
@@ -259,7 +259,7 @@ def test_a_recomputed_block_keeps_its_mixer_kernels_outputs(
                             tokens)["params"]
     keeping = gradient_program_counts(model, params, tokens)
     plain = gradient_program_counts(model.clone(remat=False), params, tokens)
-    monkeypatch.setattr(kimi_linear, "_keep_policy", lambda: None)
+    monkeypatch.setattr(kimi_linear, "keep_policy", lambda *kernels: None)
     default = gradient_program_counts(model, params, tokens)
     assert (plain[kernel], keeping[kernel], default[kernel]) == (2, 2, 4)
     assert keeping["projections"] == default["projections"] \
@@ -274,13 +274,13 @@ def test_laguna_keeps_its_full_layers_flash_outputs():
     """``models.laguna`` takes the same names: a recomputed full layer keeps
     ``flash_fwd``'s outputs and runs it once, as the unrecomputed model
     does; a sliding layer, whose window kernel is cheap for what keeping
-    would hold, recomputes ``flash_win_fwd`` (``laguna._keep_policy``). Each
+    would hold, recomputes ``flash_win_fwd`` (``laguna.LagunaBlock``). Each
     half of a block is a checkpoint of its own, and the attention's output
     projection is not recomputed: one product of the five under
     ``hvd.mixer.proj`` fewer a layer than a second forward pass has."""
     from test_laguna_model import TOY as LAGUNA_TOY
 
-    model = laguna.LagunaLM.from_config(
+    model = LagunaLM.from_config(
         dict(LAGUNA_TOY, num_hidden_layers=2), dtype=jnp.float32, remat=True)
     assert model.layer_types == ("full_attention", "sliding_attention")
     tokens = jnp.zeros((2, 64), jnp.int32)
@@ -322,7 +322,7 @@ def test_the_32_shares_add_up_to_the_whole_layer():
     params = jax.tree_util.tree_map(lambda p: 10.0 * p, params)
     with jax.default_matmul_precision("highest"):
         want = whole.apply({"params": params}, x)
-        shared_alone = laguna.GatedMLP(8, jnp.float32).apply(
+        shared_alone = parts.GatedMLP(8, jnp.float32).apply(
             {"params": params["shared"]}, x)
         total = shared_alone
         for share in range(32):

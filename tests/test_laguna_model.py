@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import ExpertLayer, LagunaLM, lm_loss
-from horovod_tpu.models import laguna
+from horovod_tpu.models import experts, parts
 
 TOY = {
     "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
@@ -39,7 +39,7 @@ TOY = {
 
 
 def test_plain_rotary_frequencies():
-    freq = np.asarray(laguna.Rotary(theta=10000.0, dim=128).inv_freq())
+    freq = np.asarray(parts.Rotary(theta=10000.0, dim=128).inv_freq())
     assert freq.shape == (64,)
     assert freq[0] == 1.0
     assert freq[1] == pytest.approx(10000 ** (-2 / 128), rel=1e-6)
@@ -53,7 +53,7 @@ def test_yarn_frequencies_against_hand_worked_values():
     for one, so ``low`` 5 and ``high`` 16: frequencies 0..5 are
     extrapolated (unchanged), 16..31 interpolated (divided by 64), and
     between them the ramp ``(i - 5) / 11`` blends the two."""
-    rotary = laguna.Rotary(theta=500000.0, dim=64, factor=64.0,
+    rotary = parts.Rotary(theta=500000.0, dim=64, factor=64.0,
                            original_max_position=4096, beta_fast=64.0,
                            beta_slow=1.0,
                            attention_factor=1.4158883083359672)
@@ -73,7 +73,7 @@ def test_yarn_frequencies_against_hand_worked_values():
 
 
 def test_rotation_turns_the_leading_dims_and_keeps_the_rest():
-    rotary = laguna.Rotary(theta=10000.0, dim=8, attention_factor=2.0)
+    rotary = parts.Rotary(theta=10000.0, dim=8, attention_factor=2.0)
     x = jnp.ones((1, 3, 2, 16), jnp.float32)
     out = np.asarray(rotary(x, jnp.arange(3)[None]))
     np.testing.assert_array_equal(out[..., 8:], 1.0)
@@ -182,7 +182,7 @@ def test_the_loop_follows_the_rows_and_drops_none(routing):
         return loop, (out, *vjp(cot))
 
     with jax.default_matmul_precision("highest"):
-        loop, got = run(lambda x, ids, *a: laguna.held_expert_sum(
+        loop, got = run(lambda x, ids, *a: experts.held_expert_sum(
             x, ids, *a, first=first, num_experts=num_experts))
         _, want = run(lambda x, ids, *a: (
             expert_loop(x, ids, *a, first=first), None))
@@ -335,11 +335,11 @@ def test_the_weights_gradient_adds_to_a_running_sum(active):
 def test_a_slice_is_an_eighth_of_an_even_routers_rows():
     """The three cells' shapes: 16,384 rows, 8 a token; and the tile of 8
     rows where a slice is less than one kernel tile."""
-    assert laguna.slice_slots(16384 * 8, 32, 256) == (2048, 256)   # Laguna
-    assert laguna.slice_slots(16384 * 8, 16, 128) == (2048, 256)   # SDAR
-    assert laguna.slice_slots(16384 * 8, 8, 256) == (512, 256)     # Kimi
-    assert laguna.slice_slots(128 * 4, 4, 16) == (16, 8)
-    assert laguna.slice_slots(32 * 8, 2, 256) == (8, 8)
+    assert experts.slice_slots(16384 * 8, 32, 256) == (2048, 256)   # Laguna
+    assert experts.slice_slots(16384 * 8, 16, 128) == (2048, 256)   # SDAR
+    assert experts.slice_slots(16384 * 8, 8, 256) == (512, 256)     # Kimi
+    assert experts.slice_slots(128 * 4, 4, 16) == (16, 8)
+    assert experts.slice_slots(32 * 8, 2, 256) == (8, 8)
 
 
 def test_grouped_matmul_refuses_what_does_not_fit():
@@ -372,7 +372,7 @@ def test_the_shares_add_up_to_the_whole_layer():
     params = jax.tree_util.tree_map(lambda p: 10.0 * p, params)
     with jax.default_matmul_precision("highest"):
         want = whole.apply({"params": params}, x)
-        shared_alone = laguna.GatedMLP(8, jnp.float32).apply(
+        shared_alone = parts.GatedMLP(8, jnp.float32).apply(
             {"params": params["shared"]}, x)
         total = shared_alone
         for share in range(8):
@@ -387,7 +387,7 @@ def test_the_shares_add_up_to_the_whole_layer():
 
 def test_router_keeps_the_largest_and_normalises_them():
     scores = jnp.asarray([[0.1, 0.9, 0.5, 0.3, 0.7, 0.2]], jnp.float32)
-    ids, weights = laguna.route(scores, 3, 2.5)
+    ids, weights = experts.route(scores, 3, 2.5)
     assert ids.tolist() == [[1, 4, 2]]
     np.testing.assert_allclose(
         weights, [[2.5 * 0.9 / 2.1, 2.5 * 0.7 / 2.1, 2.5 * 0.5 / 2.1]],

@@ -1,4 +1,4 @@
-"""``models.transformer.lm_head_loss``: the head and its loss a block of
+"""``models.head.lm_head_loss``: the head and its loss a block of
 rows at a time against ``lm_loss`` over whole logits (the loss and the
 gradients to the hidden states, the kernel and the bias), the four LMs
 through the step body's loss against ``lm_loss`` of their logits (equal
@@ -13,8 +13,7 @@ import pytest
 
 from benchmarks._dp_step import lm_step_loss, make_lm_train_step
 from horovod_tpu.models import (KimiLinearLM, LagunaLM, OlmoHybridLM,
-                                TransformerLM, lm_head_loss, lm_loss,
-                                transformer)
+                                TransformerLM, head, lm_head_loss, lm_loss)
 
 
 @pytest.mark.parametrize("batch, seq", [(1, 4096), (2, 2048 + 100), (3, 50)],
@@ -105,7 +104,7 @@ def test_the_step_never_holds_its_logits_whole(monkeypatch, family, remat):
     parameter gradients; the first's gradient program holds float32 arrays
     ``[..., V]`` of a block's rows at most, the second's the whole ``B * T
     * V`` (less the last positions, where the slice fused)."""
-    monkeypatch.setattr(transformer, "LOSS_ROWS", 32)
+    monkeypatch.setattr(head, "LOSS_ROWS", 32)
     model, vocab = _toy_model(family)
     model = model.clone(remat=remat)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 48), 0, vocab)
@@ -141,7 +140,7 @@ def test_two_devices_step_as_one(monkeypatch):
 
     import horovod_tpu as hvd
 
-    monkeypatch.setattr(transformer, "LOSS_ROWS", 32)
+    monkeypatch.setattr(head, "LOSS_ROWS", 32)
     model = TransformerLM(vocab_size=384, num_layers=1, num_heads=2,
                           d_model=32, d_ff=64, max_seq_len=128,
                           dtype=jnp.float32)

@@ -184,7 +184,7 @@ def test_the_two_shares_add_up_to_the_whole_mixer(kind):
 
 def test_a_recomputed_block_keeps_its_mixer_kernels_outputs():
     """Every block recomputed, the forward kernels still run once a
-    layer (``kimi_linear._keep_policy`` over the names the two forward
+    layer (``parts.keep_policy`` over the names the two forward
     rules set); under the default policy they would run twice."""
     model = OlmoHybridLM.from_config(TOY, dtype=jnp.float32, remat=True)
     tokens = jnp.zeros((1, 64), jnp.int32)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu import obs
-from horovod_tpu.models import ExpertLayer, SdarMoeLM, laguna, sdar
+from horovod_tpu.models import ExpertLayer, SdarMoeLM, experts, sdar
 
 CONFIG = {
     "vocab_size": 96, "hidden_size": 32, "num_hidden_layers": 2,
@@ -118,7 +118,7 @@ def test_the_shares_add_up_to_the_whole_layer(monkeypatch):
 
 def test_softmax_router_keeps_the_largest_and_renormalises_them():
     logits = jnp.log(jnp.asarray([[1.0, 9.0, 5.0, 3.0, 7.0, 2.0]]))
-    ids, weights = laguna.route(laguna.SCORINGS["softmax"](logits), 3, 1.0)
+    ids, weights = experts.route(experts.SCORINGS["softmax"](logits), 3, 1.0)
     assert ids.tolist() == [[1, 4, 2]]
     np.testing.assert_allclose(weights, [[9 / 21, 7 / 21, 5 / 21]], rtol=1e-6)
     with pytest.raises(ValueError, match="scoring must be one of"):

@@ -164,7 +164,7 @@ def test_remat_matches_plain():
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models.transformer import TransformerLM, lm_loss
+    from horovod_tpu.models import TransformerLM, lm_loss
 
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0, 64)
     kw = dict(vocab_size=64, num_layers=2, num_heads=2, d_model=32,

@@ -75,6 +75,10 @@ CELLS = {
     "kimi": dict(rows=16384, d=2304, num_experts=256, experts_per_token=8,
                  held=8, width=1024, shared_width=1024, scaling=2.446,
                  scoring="sigmoid"),
+    "smallthinker": dict(rows=16384, d=2560, num_experts=64,
+                         experts_per_token=6, held=16, width=768,
+                         shared_width=0, scaling=1.0, scoring="softmax",
+                         gate="relu"),
 }
 
 
@@ -155,7 +159,9 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
         experts_per_token=case["experts_per_token"],
         experts_held=(0, case["held"]), width=case["width"],
         shared_width=case["shared_width"], scaling=case["scaling"],
-        scoring=case["scoring"])
+        scoring=case["scoring"],
+        # a tree from before the gate was a field knows silu alone
+        **({"gate": case["gate"]} if "gate" in case else {}))
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     x, cot = (jax.random.normal(key, (1, case["rows"], case["d"]),
                                 jnp.bfloat16) for key in keys[:2])
@@ -180,7 +186,7 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
     line = {**case, "iters": iters,
             **{name: sown["layer"].get(name) for name in (
                 "held_share", "slices_run", "slot_fill",
-                "sum_kernel_share")}}
+                "sum_kernel_share", "gate_zero_share")}}
     device = device_lines(trace_dir)
     if device is None:
         return line

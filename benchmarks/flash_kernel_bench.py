@@ -42,11 +42,14 @@ import tempfile
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 # one layer's attention as the benchmark's cells call it: gpt2m_1chip (and
-# a chip of gpt2m_4chip), laguna_xs2_8k_1chip's full and sliding layers
+# a chip of gpt2m_4chip), laguna_xs2_8k_1chip's full and sliding layers,
+# smallthinker_16k_1chip's full and window layers (a group of 7)
 CELLS = {
     "gpt2m": "4,1024,16,64,causal",
     "laguna_full": "2,8192,48,128,causal,kv_heads=8",
     "laguna_window": "2,8192,64,128,causal,kv_heads=8,window=512",
+    "smallthinker_full": "1,16384,28,128,causal,kv_heads=4",
+    "smallthinker_window": "1,16384,28,128,causal,kv_heads=4,window=4096",
 }
 
 

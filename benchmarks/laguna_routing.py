@@ -1,4 +1,5 @@
-"""Where the tokens of a Laguna cell go, on the chip and at the cell's size.
+"""Where the tokens of an expert cell of an LM family (``laguna``,
+``smallthinker``) go, on the chip and at the cell's size.
 
     python3 benchmarks/laguna_routing.py --workload laguna_xs2_8k_1chip \
         --seed 7 --steps 80 --out chiprun_out/routing.json
@@ -14,7 +15,9 @@ Two readings that the benchmark's runs do not print:
   (``horovod_moe_expert_load_max_over_mean``) and the slices the held
   experts' loop ran, how full they were and whether their sums by token
   went through the kernel (``horovod_moe_slices_run``,
-  ``horovod_moe_slot_fill``, ``horovod_moe_sum_kernel_share``) as the
+  ``horovod_moe_slot_fill``, ``horovod_moe_sum_kernel_share``), and the
+  share of exact zeros behind the gate
+  (``horovod_moe_gate_zero_share``), as the
   cell's own trainer steps through its pool, with each step's time
   beside them: a chip's share of the experts
   is the only part of the routed sum the loss sees, so training moves the
@@ -57,7 +60,7 @@ def main(argv=None) -> int:
     family, config, traffic = cell.family, cell.config, cell.traffic
     keys = cells.seed_keys(args.seed, 2)
     model = family.build(config)
-    k = config["num_experts_per_tok"]
+    k = model.experts_per_token
     report = {"cell": cell.name, "seed": args.seed}
 
     def router_logits(m):
@@ -95,7 +98,8 @@ def main(argv=None) -> int:
                 batch = trainer.pool[step % len(trainer.pool)][0]
                 routed = hvd.obs.moe.publish(stats(trainer.state[0], batch))
                 for name in ("held_share", "load_max_over_mean",
-                             "slices_run", "slot_fill", "sum_kernel_share"):
+                             "slices_run", "slot_fill", "sum_kernel_share",
+                             "gate_zero_share"):
                     row[name] = [v[name] for _, v in sorted(routed.items())]
             t = time.perf_counter()
             row["loss"] = float(trainer.step())

@@ -24,6 +24,7 @@ from .parts import INIT, GatedMLP
 
 SCORINGS = {"sigmoid": nn.sigmoid,
             "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+GATES = {"silu": nn.silu, "relu": nn.relu}
 
 
 def route(scores, experts_per_token: int, scaling: float):
@@ -35,14 +36,15 @@ def route(scores, experts_per_token: int, scaling: float):
     return ids, scaling * top / jnp.sum(top, axis=-1, keepdims=True)
 
 
-def _slice(p, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
+def _slice(p, x, weights, w1, w3, w2, slots, tile_ends, size, tile, gate):
     """Slice ``p`` of the slots through the experts: ``(rows, token, weight,
     at, a, h, gated, y)``. ``slots`` holds, expert after expert, each one's
     rows padded to whole tiles, the index of an assignment (``rows``, of
     ``token``) or ``N * k``, past the arrays: a read there is clipped to the
     last row (finite; no gradient takes it), an update dropped. Expert
     ``e``'s tiles end at ``tile_ends[e]``, whence ``at``: each tile's group,
-    the active tiles, ``tile``. ``h = silu(a w1) * (a w3)``, ``y = h w2``."""
+    the active tiles, ``tile``. ``h = gate(a w1) * (a w3)``, ``gate`` one of
+    ``GATES``; ``y = h w2``."""
     from ..ops.grouped_matmul import grouped_matmul
 
     rows = jax.lax.dynamic_slice_in_dim(slots, p * size, size)
@@ -52,7 +54,7 @@ def _slice(p, x, weights, w1, w3, w2, slots, tile_ends, size, tile):
     at = (group, jnp.clip(tile_ends[-1] - tiles[0], 0, tiles.size), tile)
     token = rows // weights.shape[1]
     a = x.at[token].get(mode="clip")
-    h, gated = jax.vjp(lambda h1, h3: nn.silu(h1) * h3,
+    h, gated = jax.vjp(lambda h1, h3: GATES[gate](h1) * h3,
                        grouped_matmul(a, w1, *at), grouped_matmul(a, w3, *at))
     weight = weights.reshape(-1).at[rows].get(mode="clip")[:, None]
     return rows, token, weight, at, a, h, gated, grouped_matmul(h, w2, *at)
@@ -89,8 +91,8 @@ def _loop(tile_ends, size, tile, one, like, shapes):
         vary_like(like, *(jnp.zeros(shape, jnp.float32) for shape in shapes)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _expert_loop(x, weights, w1, w3, w2, slots, tile_ends, size, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _expert_loop(x, weights, w1, w3, w2, slots, tile_ends, size, tile, gate):
     """The held experts' weighted outputs added up by token, float32 [N, d],
     a slice at a time into the carried sum (``_by_token``); backward the
     same loop, a slice recomputed and transposed at a time into gradients
@@ -99,14 +101,14 @@ def _expert_loop(x, weights, w1, w3, w2, slots, tile_ends, size, tile):
 
     def one(p, carry):
         _, token, weight, at, *_, y = _slice(p, x, weights, w1, w3, w2, slots,
-                                             tile_ends, size, tile)
+                                             tile_ends, size, tile, gate)
         return (_add_by_token(carry[0], y, token, weight, at),)
 
     return _loop(tile_ends, size, tile, one, x,
                  (_by_token(x),))[0].reshape(x.shape)
 
 
-def _loop_bwd(size, tile, res, g):
+def _loop_bwd(size, tile, gate, res, g):
     from ..ops.grouped_matmul import grouped_matmul_transposed
 
     x, weights, *matrices, slots, tile_ends = res
@@ -115,7 +117,7 @@ def _loop_bwd(size, tile, res, g):
     def one(p, grads):
         dx, dweights, dw1, dw3, dw2 = grads
         rows, token, weight, at, a, h, gated, y = _slice(
-            p, x, weights, w1, w3, w2, slots, tile_ends, size, tile)
+            p, x, weights, w1, w3, w2, slots, tile_ends, size, tile, gate)
         # zero for an empty slot, whose row and weight are some token's
         gy = g.at[token].get(mode="fill", fill_value=0)
         dweights = dweights.at[rows].add(
@@ -138,6 +140,21 @@ def _loop_bwd(size, tile, res, g):
 _expert_loop.defvjp(lambda *a: (_expert_loop(*a), a[:7]), _loop_bwd)
 
 
+def _gate_zeros(x, weights, w1, w3, w2, slots, tile_ends, size, tile, gate):
+    """How many elements of the gated hidden rows ``h`` are exactly zero,
+    over the slots that hold an assignment: the loop's own slices, counted
+    and not multiplied on (a gauge's number, traced only where asked for)."""
+    w1, w3, w2 = (w.astype(x.dtype) for w in (w1, w3, w2))
+
+    def one(p, carry):
+        rows, *_, h, _, _ = _slice(p, x, weights, w1, w3, w2, slots,
+                                   tile_ends, size, tile, gate)
+        return (carry[0] + jnp.sum((h == 0) & (rows < weights.size)[:, None],
+                                   dtype=jnp.float32),)
+
+    return _loop(tile_ends, size, tile, one, x, ((),))[0]
+
+
 def slice_slots(capacity: int, held: int, num_experts: int):
     """``(slots, tile)``: a slice, the tiles that hold an eighth of the rows
     an even router sends here, and a tile's rows (8 below a kernel tile)."""
@@ -149,13 +166,17 @@ def slice_slots(capacity: int, held: int, num_experts: int):
 
 
 def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
-                    num_experts: int):
+                    num_experts: int, gate: str = "silu",
+                    count_zeros: bool = False):
     """``sum over the held e among a token's experts of weight_e *
     expert_e(x)`` for tokens ``x`` [N, d], routed to ``ids`` [N, k] with
     ``weights`` [N, k]; the experts held are ``first .. first + len(w1)``,
-    each ``(silu(x w1) * (x w3)) w2``. Returns that sum, float32 [N, d],
-    and the loop's ``slices`` run, ``slots`` in use, slots it ``ran`` and,
-    of those, the slots ``summed`` by token in ``moe_rows_add``.
+    each ``(gate(x w1) * (x w3)) w2``, ``gate`` one of ``GATES``. Returns
+    that sum, float32 [N, d], and the loop's ``slices`` run, ``slots`` in
+    use, slots it ``ran`` and, of those, the slots ``summed`` by token in
+    ``moe_rows_add``; with ``count_zeros`` also ``gate_zero_share``, the
+    share of the elements of ``gate(x w1) * (x w3)`` that are exactly zero
+    over the rows routed here (a pass of its own over the slices).
 
     The assignments to held experts are sorted by expert into slots, each
     expert's rows padded to whole tiles of the grouped-product kernel
@@ -187,9 +208,13 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     operands = vary_like(x, x, weights, w1, w3, w2, slots, tile_ends)
     slices = -(-tile_ends[-1] * tile // size)
     ran = slices * size
-    return _expert_loop(*operands, size, tile), {
-        "slices": slices, "slots": tile_ends[-1] * tile, "ran": ran,
-        "summed": ran * (len(_by_token(x)) == 3)}
+    loop = {"slices": slices, "slots": tile_ends[-1] * tile, "ran": ran,
+            "summed": ran * (len(_by_token(x)) == 3)}
+    if count_zeros:
+        loop["gate_zero_share"] = _gate_zeros(
+            *operands, size, tile, gate) / jnp.maximum(
+                ends[-1] * w1.shape[-1], 1)
+    return _expert_loop(*operands, size, tile, gate), loop
 
 
 class ExpertLayer(nn.Module):
@@ -198,12 +223,19 @@ class ExpertLayer(nn.Module):
     parameter, no product). Routes over all ``num_experts`` in float32 by
     ``scoring`` (one of ``SCORINGS``), keeps ``experts_per_token``, adds
     ``shared(x)`` and the held experts' weighted outputs; what absent
-    experts would add is left out.
+    experts would add is left out. An expert is ``(gate(x w1) * (x w3))
+    w2``, ``gate`` one of ``GATES`` (the shared expert is ``parts.GatedMLP``,
+    SiLU-gated whatever ``gate``). The router reads the tensor the experts
+    multiply, or ``routed_by`` where the call gives one (a block's input
+    from before its attention, say): its operations are under
+    ``hvd.moe.route`` either way.
 
     Sows into the collection ``moe_stats`` (when the caller makes it
     mutable) what ``obs.moe.publish`` turns into gauges: ``assignments``
     [num_experts], how many of the ``N * k`` assignments each expert got,
-    ``absent``, how many went to experts not held, and the loop's numbers."""
+    ``absent``, how many went to experts not held, and the loop's numbers
+    (``gate_zero_share`` among them outside ``init``: a training step, which
+    does not carry the collection, holds no operation for it)."""
 
     num_experts: int
     experts_per_token: int
@@ -213,9 +245,10 @@ class ExpertLayer(nn.Module):
     scaling: float = 1.0
     dtype: Any = jnp.bfloat16
     scoring: str = "sigmoid"
+    gate: str = "silu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routed_by=None):
         first, held = self.experts_held
         if not 0 <= first <= first + held <= self.num_experts or held < 1:
             raise ValueError(f"experts_held {self.experts_held} is no part "
@@ -223,8 +256,12 @@ class ExpertLayer(nn.Module):
         if self.scoring not in SCORINGS:
             raise ValueError(f"scoring must be one of {sorted(SCORINGS)}, "
                              f"got {self.scoring!r}")
+        if self.gate not in GATES:
+            raise ValueError(f"gate must be one of {sorted(GATES)}, got "
+                             f"{self.gate!r}")
         d = x.shape[-1]
         tokens = x.reshape(-1, d)
+        read = tokens if routed_by is None else routed_by.reshape(-1, d)
         with jax.named_scope("hvd.moe"):
             with jax.named_scope("hvd.moe.route"):
                 # float32 in earnest: without ``highest`` the TPU multiplies
@@ -232,7 +269,7 @@ class ExpertLayer(nn.Module):
                 scores = SCORINGS[self.scoring](nn.Dense(
                     self.num_experts, use_bias=False, dtype=jnp.float32,
                     precision=jax.lax.Precision.HIGHEST, kernel_init=INIT,
-                    name="router")(tokens.astype(jnp.float32)))
+                    name="router")(read.astype(jnp.float32)))
                 ids, weights = route(scores, self.experts_per_token,
                                      self.scaling)
             counts = jnp.zeros((self.num_experts,), jnp.int32).at[
@@ -245,7 +282,9 @@ class ExpertLayer(nn.Module):
                           for name in ("experts_w1", "experts_w3"))
                 w2 = self.param("experts_w2", INIT, (held, self.width, d))
                 routed, loop = held_expert_sum(
-                    tokens, ids, weights, w1, w3, w2, first, self.num_experts)
+                    tokens, ids, weights, w1, w3, w2, first, self.num_experts,
+                    self.gate, self.is_mutable_collection("moe_stats")
+                    and not self.is_initializing())
                 for name, value in loop.items():
                     self.sow("moe_stats", name, value)
                 shared = GatedMLP(self.shared_width, self.dtype,

@@ -5,7 +5,9 @@ how many assignments each expert got (``assignments``), how many went to
 experts this chip does not hold (``absent``) and, of the loop that
 multiplies the held experts' rows, the ``slices`` it ran, the ``slots`` in
 use, the slots it ``ran`` over and those whose sum by token went through
-``ops.grouped_matmul.moe_rows_add`` (``summed``). A training step does
+``ops.grouped_matmul.moe_rows_add`` (``summed``) and the share of the gated
+hidden rows' elements that are exactly zero (``gate_zero_share``, a pass of
+its own that only a caller of the collection pays for). A training step does
 not carry the collection; a caller who wants the numbers applies the model
 with ``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
 """
@@ -42,13 +44,21 @@ _SUMMED = _metrics().gauge(
     "the width is no multiple of 128 and XLA's scatter-add runs; 1.0 where "
     "the loop ran none)",
     labels=("layer",))
+_ZEROS = _metrics().gauge(
+    "horovod_moe_gate_zero_share",
+    "Share of the elements of the held experts' gated hidden rows, gate(x "
+    "w1) * (x w3) over the rows routed here, that are exactly zero, by "
+    "expert layer (about a half under a relu gate on seeded weights, what "
+    "'sparse ReGLU' means on this chip; 0 under silu)",
+    labels=("layer",))
 
 
 def publish(moe_stats) -> dict:
     """Set the gauges from a ``moe_stats`` collection and return what was
     set, ``{layer: {"load_max_over_mean": .., "held_share": ..,
-    "slices_run": .., "slot_fill": .., "sum_kernel_share": ..}}``; a layer
-    is the path of its module, ``block_3/moe``."""
+    "slices_run": .., "slot_fill": .., "sum_kernel_share": ..,
+    "gate_zero_share": ..}}`` (the last where the layer sowed it); a layer is
+    the path of its module, ``block_3/moe``."""
     import numpy as np
     from flax.traverse_util import flatten_dict
 
@@ -77,4 +87,7 @@ def publish(moe_stats) -> dict:
                             (_FILL, "slot_fill"),
                             (_SUMMED, "sum_kernel_share")):
             gauge.labels(layer=layer).set(out[layer][name])
+        if "gate_zero_share" in stats:
+            out[layer]["gate_zero_share"] = float(stats["gate_zero_share"])
+            _ZEROS.labels(layer=layer).set(out[layer]["gate_zero_share"])
     return out

@@ -80,6 +80,13 @@ _CASES = {
         (1, 4096, 8, 128), 4096, "bfloat16", True, 0, 2, 1536),
     "strips_later_q_shard_f32": ((1, 1024, 4, 128), 2048, "float32", True,
                                  1024),
+    # smallthinker_16k_1chip's full and window layers: a group of 7 query
+    # heads a key/value head, and a window of four forward tiles that spans
+    # several majors at 16,384 positions
+    "strips_smallthinker_full": ((1, 16384, 28, 128), 16384, "bfloat16",
+                                 True, 0, 4, None),
+    "strips_smallthinker_window": ((1, 16384, 28, 128), 16384, "bfloat16",
+                                   True, 0, 4, 4096),
 }
 
 
@@ -211,11 +218,12 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, no_compile_cache):
 
 
 @pytest.mark.parametrize("rows,d,width,held", [
-    (2048, 2048, 512, 32), (2048, 2048, 768, 16), (512, 2304, 1024, 8)],
-    ids=["laguna", "sdar", "kimi"])
+    (2048, 2048, 512, 32), (2048, 2048, 768, 16), (512, 2304, 1024, 8),
+    (3072, 2560, 768, 16)], ids=["laguna", "sdar", "kimi", "smallthinker"])
 def test_a_slice_of_the_expert_loop_transposes_for_v5e(
         one_chip, no_compile_cache, rows, d, width, held):
-    """``grouped_matmul_transposed`` at a slice of the three expert cells:
+    """``grouped_matmul_transposed`` at a slice of the four expert cells
+    (SmallThinker's is ``slice_slots(98304, 16, 64)``, 3,072 slots):
     the weights' gradient takes its float32 running sum and returns it in
     the same buffer (four float32 matrices of a group in VMEM at once: 38
     MiB at Kimi-Linear's 2304 x 1024)."""
@@ -242,11 +250,12 @@ def test_a_slice_of_the_expert_loop_transposes_for_v5e(
     assert "%expert_matmul_bwd_dx" in hlo
 
 
-@pytest.mark.parametrize("rows,d", [(2048, 2048), (2048, 2048), (512, 2304)],
-                         ids=["laguna", "sdar", "kimi"])
+@pytest.mark.parametrize("rows,d", [(2048, 2048), (2048, 2048), (512, 2304),
+                                    (3072, 2560)],
+                         ids=["laguna", "sdar", "kimi", "smallthinker"])
 def test_a_slice_of_the_expert_loop_sums_by_token_for_v5e(
         one_chip, no_compile_cache, rows, d):
-    """``moe_rows_add`` at a slice of the three expert cells, as the loop's
+    """``moe_rows_add`` at a slice of the four expert cells, as the loop's
     forward pass calls it (bfloat16 rows, a weight a row) and as its
     backward pass does (float32 rows): one Mosaic call each, the carried
     sum ``[N, d / 128, 128]`` returned in its own buffer."""
@@ -276,39 +285,47 @@ def test_a_slice_of_the_expert_loop_sums_by_token_for_v5e(
         assert f"f32[16384,{d // 128},128]" in call.split(" custom-call(")[0]
 
 
-def test_an_expert_layer_carries_its_sums_in_place_for_v5e(
-        one_chip, no_compile_cache):
-    """One ``ExpertLayer`` forward and backward at Laguna's shape, compiled
-    for the v5e inside a ``shard_map`` over that one chip, as a cell's step
-    is (there the calls follow the platform the program is lowered for,
-    not the CPU that lowers it): the sums by token are ``moe_rows_add``'s,
-    one in each loop — no scatter into a token-sized float32 operand is
-    left — and neither loop's body copies a carried sum."""
+def _expert_layer_hlo(one_chip, layer, d, routed: bool = False):
+    """The compiled text of one ``ExpertLayer`` forward and backward on
+    16,384 tokens of width ``d``, lowered for the v5e inside a ``shard_map``
+    over that one chip, as a cell's step is (there the calls follow the
+    platform the program is lowered for, not the CPU that lowers it);
+    ``routed``: the router reads a tensor of its own."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.models.experts import ExpertLayer
-
-    layer = ExpertLayer(num_experts=256, experts_per_token=8,
-                        experts_held=(0, 32), width=512, shared_width=512,
-                        scaling=2.5)
     mesh = Mesh(list(one_chip.device_set), ("data",))
 
     def placed(shape, dtype, spec):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    x = placed((1, 16384, 2048), jnp.bfloat16, P("data"))
+    x = placed((1, 16384, d), jnp.bfloat16, P("data"))
     params = jax.tree_util.tree_map(
         lambda p: placed(p.shape, p.dtype, P()),
         jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"])
-    grads = jax.grad(lambda p, x: jnp.square(layer.apply(
-        {"params": p}, x).astype(jnp.float32)).sum(), argnums=(0, 1))
-    hlo = jax.jit(jax.shard_map(
-        grads, mesh=mesh, in_specs=(P(), P("data")),
-        out_specs=(P(), P("data")))).lower(params, x).compile().as_text()
+    grads = jax.grad(lambda p, x, r: jnp.square(layer.apply(
+        {"params": p}, x, routed_by=r if routed else None).astype(
+            jnp.float32)).sum(), argnums=(0, 1, 2))
+    return jax.jit(jax.shard_map(
+        grads, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+        out_specs=(P(), P("data"), P("data")))).lower(
+            params, x, x).compile().as_text()
+
+
+def test_an_expert_layer_carries_its_sums_in_place_for_v5e(
+        one_chip, no_compile_cache):
+    """One ``ExpertLayer`` forward and backward at Laguna's shape: the sums
+    by token are ``moe_rows_add``'s, one in each loop — no scatter into a
+    token-sized float32 operand is left — and neither loop's body copies a
+    carried sum."""
+    from horovod_tpu.models.experts import ExpertLayer
+
+    hlo = _expert_layer_hlo(one_chip, ExpertLayer(
+        num_experts=256, experts_per_token=8, experts_held=(0, 32),
+        width=512, shared_width=512, scaling=2.5), 2048)
     assert len(re.findall(r"%moe_rows_add\S* = ", hlo)) == 2
     assert not re.search(r" scatter\(f32\[16384,", hlo)
     assert not re.search(r"= f32\[16384,\S* scatter\(", hlo)
@@ -319,6 +336,28 @@ def test_an_expert_layer_carries_its_sums_in_place_for_v5e(
         body = body[:body.index("\n}\n")]
         assert "%moe_rows_add" in body
         assert not re.search(r"= f32\[16384,\S* copy\(", body), body
+
+
+def test_a_relu_gated_layer_routed_by_its_own_tensor_compiles_for_v5e(
+        one_chip, no_compile_cache):
+    """The expert layer as ``smallthinker_16k_1chip`` calls it — 16 of 64
+    experts of 2560 x 768 held, 6 a token, a ReLU gate, the router reading
+    another tensor than the experts — in slices of ``slice_slots(98304, 16,
+    64)``: the same two loops and kernels, a ``maximum`` where the SiLU's
+    ``logistic`` was, and a gradient for the router's own input."""
+    from horovod_tpu.models.experts import ExpertLayer, slice_slots
+
+    assert slice_slots(16384 * 6, 16, 64) == (3072, 256)
+    hlo = _expert_layer_hlo(one_chip, ExpertLayer(
+        num_experts=64, experts_per_token=6, experts_held=(0, 16),
+        width=768, shared_width=0, scoring="softmax", gate="relu"), 2560,
+        routed=True)
+    assert len(re.findall(r"%moe_rows_add\S* = ", hlo)) == 2
+    assert len(set(re.findall(r"body=(%[\w.\-]+)", hlo))) == 2
+    for name in ("expert_matmul_fwd", "expert_matmul_bwd_dx",
+                 "expert_matmul_bwd_dw"):
+        assert f"%{name}" in hlo, name
+    assert "bf16[3072,768]" in hlo and "logistic" not in hlo
 
 
 def test_a_delta_rule_layer_engages_its_kernels(one_chip, no_compile_cache):

@@ -484,7 +484,8 @@ def test_moe_stats_become_gauges(toy):
             "held_share": counts[4:8].sum() / counts.sum(),
             "slices_run": -(-in_use // 16),
             "slot_fill": in_use / (-(-in_use // 16) * 16),
-            "sum_kernel_share": 0.0}   # 64 wide: XLA's scatter-add
+            "sum_kernel_share": 0.0,   # 64 wide: XLA's scatter-add
+            "gate_zero_share": 0.0}    # a silu gate gives no exact zero
     assert published["block_1/moe"] == pytest.approx(want)
     assert 0.5 < want["slot_fill"] <= 1.0
     snapshot = obs.registry().snapshot()

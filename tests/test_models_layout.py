@@ -11,7 +11,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 MODELS = ROOT / "horovod_tpu" / "models"
 DECODERS = ("transformer", "laguna", "kimi_linear", "olmo_hybrid", "sdar",
-            "resnet", "vgg", "inception", "mnist")
+            "smallthinker", "resnet", "vgg", "inception", "mnist")
 SHARED = ("scopes", "head", "parts", "experts", "delta")
 # who reads the package: its own modules, and the trees of its users
 READERS = ("horovod_tpu", "benchmarks", "tools", "tests")

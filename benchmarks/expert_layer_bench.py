@@ -11,11 +11,16 @@ experts 768 wide, a softmax router, no shared expert), ``kimi`` (2304 wide,
 8 of 256 experts of 1024) — with an optional ``,slice=<slots>``: the slots
 the loop of ``held_expert_sum`` takes at a time in place of the layer's own
 rule (what one slice costs, and what an iteration's fixed cost is; a tree
-from before that loop ignores it). The
+from before that loop ignores it), and an optional ``,keep=0``: the
+checkpoint's default policy in place of the one that saves the layer's
+``KEPT_NAMES``, so that the recomputed half selects and sorts again (what
+keeping the routing is worth; a tree from before the names keeps nothing
+either way). The
 router is seeded and the rows are normal, so about ``held / num_experts``
 of the assignments come here, as on the cells' seeded weights. As a block
-runs it: the layer's output recomputed in the backward pass, gradients for
-the rows and every parameter.
+runs it: the layer's output recomputed in the backward pass under the
+policy the decoders give an expert half, gradients for the rows and every
+parameter.
 
 ``layer_ms`` is the whole device program a call, ``kernels_ms`` the three
 ``expert_matmul_*`` calls in it, both read from a profiler trace of
@@ -24,7 +29,13 @@ the rows and every parameter.
 body's), ``sums_ms`` the operations outside the loops' containers whose name
 holds ``moe_rows_add``, the token-sized float32 type (the scatter-adds the
 call replaced) or the assignment-sized one (the router weights' scalar
-scatter-add); ``slices_run``, ``slot_fill`` and ``sum_kernel_share`` are
+scatter-add), ``layout_ms`` the layer's bookkeeping: every operation the
+compiled text puts under ``hvd.moe`` but the two loops and their bodies
+(the kernels among them) and the shared expert — the router's product and
+scoring, the selection, the slot layout, their transposes, the sum with
+the shared expert; ``layout_reruns`` is ``obs.moe.record_layout_program``'s
+count on that text (null on a tree without it); ``slices_run``,
+``slot_fill`` and ``sum_kernel_share`` are
 what the layer sows (null on a tree that does not). Each ``--tree``
 measures that checkout's ``horovod_tpu`` (a ``git archive`` of the parent
 beside this one): give the option more than once to compare in one chip
@@ -86,10 +97,10 @@ def parse_case(text: str) -> dict:
     name, *options = text.split(",")
     if name not in CELLS:
         raise ValueError(f"case {text!r}: one of {sorted(CELLS)}")
-    case = {"case": name, **CELLS[name], "slice": None}
+    case = {"case": name, **CELLS[name], "slice": None, "keep": 1}
     for option in options:
         key, _, value = option.partition("=")
-        if key != "slice":
+        if key not in ("slice", "keep"):
             raise ValueError(f"case {text!r}: unknown option {key!r}")
         case[key] = int(value)
     return case
@@ -151,7 +162,7 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from chipbench import trace_reduce
+    from chipbench import scopes, trace_reduce
     from horovod_tpu import obs
 
     layer = experts.ExpertLayer(
@@ -167,12 +178,19 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
                                 jnp.bfloat16) for key in keys[:2])
     params = jax.jit(layer.init)(keys[2], x)["params"]
 
+    # what a decoder's recomputed expert half keeps of the layer
+    kept = getattr(experts, "KEPT_NAMES", ()) if case["keep"] else ()
+
     @jax.jit
     def call(params, x):
-        run = jax.checkpoint(lambda p, x: layer.apply({"params": p}, x))
+        run = jax.checkpoint(
+            lambda p, x: layer.apply({"params": p}, x),
+            policy=jax.checkpoint_policies.save_only_these_names(*kept)
+            if kept else None)
         return jax.value_and_grad(lambda p, x: jnp.vdot(
             run(p, x).astype(jnp.float32), cot), argnums=(0, 1))(params, x)
 
+    hlo = call.lower(params, x).compile().as_text()
     for _ in range(3):
         jax.block_until_ready(call(params, x))
     trace_dir = tempfile.mkdtemp(dir=trace_root)
@@ -183,7 +201,9 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
 
     sown = obs.moe.publish({"layer": jax.jit(lambda p, x: layer.apply(
         {"params": p}, x, mutable=["moe_stats"])[1]["moe_stats"])(params, x)})
-    line = {**case, "iters": iters,
+    record = getattr(obs.moe, "record_layout_program", None)
+    line = {**case, "iters": iters, "kept": list(kept),
+            "layout_reruns": record and record(case["case"], hlo)[3],
             **{name: sown["layer"].get(name) for name in (
                 "held_share", "slices_run", "slot_fill",
                 "sum_kernel_share", "gate_zero_share")}}
@@ -191,10 +211,16 @@ def _measure(experts, case: dict, iters: int, trace_root: str) -> dict:
     if device is None:
         return line
     seconds, groups = collections.Counter(), collections.Counter()
+    known, layout = scopes.instructions(hlo), 0.0
     for event in device.get(trace_reduce.OPS_LINE, []):
-        stem = re.sub(r"\.\d+$", "", trace_reduce.parse_op(event.name)[0])
-        seconds[stem] += event.dur_ns * 1e-9
+        name = trace_reduce.parse_op(event.name)[0]
+        seconds[re.sub(r"\.\d+$", "", name)] += event.dur_ns * 1e-9
         groups[trace_reduce.group_of(event.name)] += event.dur_ns * 1e-9
+        scope = known.get(name, (None, ""))[1]
+        if "hvd.moe" in scope and "hvd.moe.experts/while" not in scope \
+                and "/shared/" not in scope:
+            layout += event.dur_ns * 1e-9
+    line["layout_ms"] = 1e3 * layout / iters
     for name in KERNELS:
         line[f"{name}_ms"] = 1e3 * seconds[name] / iters
     line["kernels_ms"] = 1e3 * sum(seconds[name] for name in KERNELS) / iters

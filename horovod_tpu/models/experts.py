@@ -19,20 +19,49 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .parts import INIT, GatedMLP
 
 SCORINGS = {"sigmoid": nn.sigmoid,
             "softmax": functools.partial(jax.nn.softmax, axis=-1)}
 GATES = {"silu": nn.silu, "relu": nn.relu}
+# what the layer's backward pass takes of its routing — the experts selected
+# and their scores, the slots and where each expert's tiles end (``route``,
+# ``slot_layout``): a recomputed layer whose policy saves them
+# (``parts.keep_policy``) sorts nothing again
+KEPT_NAMES = ("moe.ids", "moe.top", "moe.slots", "moe.tile_ends")
+
+
+@jax.custom_jvp
+def _read(scores, top, ids):
+    """``jnp.take_along_axis(scores, ids, axis=-1)`` where that is known to
+    be ``top``: nothing is read, and the derivative is taken at ``ids``."""
+    return top
+
+
+@_read.defjvp
+def _read_jvp(primals, tangents):
+    _, top, ids = primals
+    # a sum over all the experts in which one term is not zero: its
+    # transpose is element-wise, where a gather's is a scatter into the
+    # scores' gradient that the TPU applies an update at a time
+    chosen = ids[..., None] == jnp.arange(tangents[0].shape[-1])
+    return top, jnp.sum(
+        jnp.where(chosen, tangents[0][..., None, :], 0), axis=-1)
 
 
 def route(scores, experts_per_token: int, scaling: float):
     """``(ids, weights)`` [N, k]: the ``experts_per_token`` largest of the
     ``scores`` [N, E] (a sigmoid's, or a softmax's over all ``E``:
     ``SCORINGS``), their weights normalised to sum 1 over the selected and
-    scaled."""
-    top, ids = jax.lax.top_k(scores, experts_per_token)
+    scaled. The selection's two results are named (``KEPT_NAMES``) and its
+    derivative goes through the named ``ids``, so that what the backward
+    pass needs of it is what a policy can save, not a second ``top_k``'s
+    own result."""
+    top, ids = map(checkpoint_name, jax.lax.top_k(
+        jax.lax.stop_gradient(scores), experts_per_token), KEPT_NAMES[1::-1])
+    top = _read(scores, top, ids)
     return ids, scaling * top / jnp.sum(top, axis=-1, keepdims=True)
 
 
@@ -165,6 +194,33 @@ def slice_slots(capacity: int, held: int, num_experts: int):
     return max(eighth // tile, 1) * tile, tile
 
 
+def slot_layout(key, held: int, size: int, tile: int):
+    """``(slots, tile_ends, rows)`` of assignments ``key`` [capacity], each
+    the held expert it goes to or ``held`` for none: ``slots``, the
+    assignments sorted by expert, each expert's padded with ``capacity`` to
+    whole tiles, on to a whole number of slices of ``size``; ``tile_ends``
+    [held], the tile each expert's end at; ``rows``, how many are held.
+
+    One stable sort lays the slots out: an expert's count is a comparison
+    summed, so the slots its last tile has past its rows are known before
+    the sort and go into it behind the assignments, under the expert's key
+    and holding ``capacity``, as an assignment to no held expert does."""
+    capacity = key.size
+    counts = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                     dtype=jnp.int32)
+    tiles_of = -(-counts // tile)
+    spare = jnp.arange(-(-(capacity + held * tile) // size) * size - capacity)
+    spare_key = jnp.sum(
+        spare[:, None] >= jnp.cumsum(tiles_of * tile - counts), axis=1,
+        dtype=jnp.int32)
+    _, slots = jax.lax.sort(
+        (jnp.concatenate([key, spare_key]), jnp.concatenate([
+            jnp.where(key < held, jnp.arange(capacity, dtype=jnp.int32),
+                      capacity),
+            jnp.full(spare.shape, capacity, jnp.int32)])), num_keys=1)
+    return slots, jnp.cumsum(tiles_of), jnp.sum(counts)
+
+
 def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
                     num_experts: int, gate: str = "silu",
                     count_zeros: bool = False):
@@ -185,24 +241,14 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     follows the rows routed here and no row is ever dropped."""
     from ..ops.spmd import vary_like
 
-    held, capacity = w1.shape[0], ids.size
+    held = w1.shape[0]
     local = ids.reshape(-1) - first
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True)  # held rows first, by expert
-    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-    ends = jnp.cumsum(counts)
-    size, tile = slice_slots(capacity, held, num_experts)
-    tiles_of = -(-counts // tile)
-    tile_ends = jnp.cumsum(tiles_of)
-    # the slot of the p-th sorted assignment: its expert's first slot plus
-    # its rank among the expert's rows
-    expert = jnp.minimum(key[order], held - 1)
-    slot = (tile_ends - tiles_of)[expert] * tile \
-        + jnp.arange(capacity) - (ends - counts)[expert]
-    room = -(-(capacity + held * tile) // size) * size
-    slots = jnp.full((room,), capacity, jnp.int32).at[
-        jnp.where(jnp.arange(capacity) < ends[-1], slot, room)].set(
-            order.astype(jnp.int32), mode="drop")
+    size, tile = slice_slots(ids.size, held, num_experts)
+    slots, tile_ends, rows = slot_layout(
+        jnp.where((local >= 0) & (local < held), local, held), held, size,
+        tile)
+    slots, tile_ends = map(checkpoint_name, (slots, tile_ends),
+                           KEPT_NAMES[2:])
     # the trip count is a device's own: typed as varying like the tokens, the
     # replicated weights get their gradient summed over the axis outside it
     operands = vary_like(x, x, weights, w1, w3, w2, slots, tile_ends)
@@ -213,7 +259,7 @@ def held_expert_sum(x, ids, weights, w1, w3, w2, first: int,
     if count_zeros:
         loop["gate_zero_share"] = _gate_zeros(
             *operands, size, tile, gate) / jnp.maximum(
-                ends[-1] * w1.shape[-1], 1)
+                rows * w1.shape[-1], 1)
     return _expert_loop(*operands, size, tile, gate), loop
 
 

@@ -135,8 +135,10 @@ class KimiBlock(nn.Module):
     What a recomputed block keeps (``parts.keep_policy``, as
     ``KimiLinearLM`` asks): its mixer kernel's outputs — ``kda_fwd``'s ``o``
     and chunk-starting states, ``flash_mla_fwd``'s ``o`` and log-sum-exp,
-    named where the two forward rules make them — and recomputes everything
-    else: norms, projections, gate, MLP, expert layer. With its outputs kept
+    named where the two forward rules make them — and, of a sparse block,
+    the expert layer's routing and slot layout (``experts.KEPT_NAMES``: 1.6
+    MB), and recomputes everything else: norms, projections, gate, MLP, the
+    router's product and the experts' rows. With its outputs kept
     the forward Mosaic call is dead code in the recomputed block; the
     kernels' backward paths are unchanged (``kda_bwd`` still takes the
     feed's run there). At 16,384 tokens x 32 heads a KDA layer holds ``o``
@@ -262,7 +264,8 @@ class KimiLinearLM(nn.Module):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                          embedding_init=INIT, name="tok_embed")(tokens)
         block_cls = nn.remat(KimiBlock, policy=keep_policy(
-            "kda", "pallas_attention")) if self.remat else KimiBlock
+            "ops.kda", "ops.pallas_attention", "models.experts")) \
+            if self.remat else KimiBlock
         kda = dict(num_heads=self.num_heads, head_dim=self.kda_head_dim,
                    conv_size=self.conv_size, kda=self.kda)
         mla = dict(num_heads=self.num_heads, nope_dim=self.nope_dim,

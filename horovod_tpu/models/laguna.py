@@ -82,7 +82,11 @@ class LagunaBlock(nn.Module):
     chipbench.aot --workload laguna_xs2_8k_1chip`` totals 12.82 GB with
     the two full layers keeping, under the 13.234 GB the chip leaves the
     step (none: 12.33; one sliding layer more: 13.08; two more: 13.35;
-    all five: 14.15)."""
+    all five: 14.15).
+
+    What a recomputed MLP half keeps: the expert layer's routing and slot
+    layout (``experts.KEPT_NAMES``: 1.6 MB a layer), so that it selects and
+    sorts nothing again; a dense MLP names nothing and keeps nothing."""
 
     attn: dict          # GroupedAttention's fields
     dense_width: Optional[int]
@@ -111,9 +115,9 @@ class LagunaBlock(nn.Module):
 
         if self.remat:
             kept = () if self.attn["window"] is not None \
-                else ("pallas_attention",)
+                else ("ops.pallas_attention",)
             mix = nn.remat(mix, policy=keep_policy(*kept))
-            feed = nn.remat(feed)
+            feed = nn.remat(feed, policy=keep_policy("models.experts"))
         return feed(self, mix(self, x, positions))
 
 

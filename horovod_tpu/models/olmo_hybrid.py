@@ -249,7 +249,8 @@ class OlmoHybridLM(nn.Module):
             x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                          embedding_init=INIT, name="tok_embed")(tokens)
         block_cls = nn.remat(OlmoHybridBlock, policy=keep_policy(
-            "kda", "pallas_attention")) if self.remat else OlmoHybridBlock
+            "ops.kda", "ops.pallas_attention")) \
+            if self.remat else OlmoHybridBlock
         gdn = dict(num_heads=self.linear_heads, key_dim=self.linear_key_dim,
                    value_dim=self.linear_value_dim, conv_size=self.conv_size,
                    allow_neg_eigval=self.allow_neg_eigval, rule=self.rule)

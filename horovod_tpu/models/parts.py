@@ -143,14 +143,16 @@ def attend(q, k, v, backend: str, **mask):
     return dense_attention(q, k, v, **mask)
 
 
-def keep_policy(*kernels: str):
-    """The ``jax.checkpoint`` policy of a recomputed layer that keeps its
-    kernels' outputs and recomputes the rest: ``save_only_these_names``
-    over the ``KEPT_NAMES`` that the forward rules of the modules of
-    ``horovod_tpu.ops`` named (``"pallas_attention"``, ``"kda"``) set.
-    ``None``, the default policy, for a layer that keeps nothing."""
-    if not kernels:
+def keep_policy(*modules: str):
+    """The ``jax.checkpoint`` policy of a recomputed layer that keeps what
+    ``modules`` of this package name and recomputes the rest:
+    ``save_only_these_names`` over the ``KEPT_NAMES`` of each — the outputs
+    of their kernels that the forward rules of ``"ops.pallas_attention"``
+    and ``"ops.kda"`` name, the routing and slot layout of
+    ``"models.experts"``. ``None``, the default policy, for a layer that
+    keeps nothing."""
+    if not modules:
         return None
     return jax.checkpoint_policies.save_only_these_names(*(
-        name for kernel in kernels for name in importlib.import_module(
-            f"..ops.{kernel}", __package__).KEPT_NAMES))
+        name for module in modules for name in importlib.import_module(
+            f"..{module}", __package__).KEPT_NAMES))

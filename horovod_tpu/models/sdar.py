@@ -114,7 +114,9 @@ class SdarBlock(nn.Module):
     With ``remat`` each half is a ``jax.checkpoint`` of its own, as
     ``laguna.LagunaBlock``'s; the attention half keeps its flash kernel's
     output and log-sum-exp as a full layer of Laguna's does
-    (``parts.keep_policy``), so that ``flash_bd_fwd`` runs once a layer
+    (``parts.keep_policy``), so that ``flash_bd_fwd`` runs once a layer,
+    and the expert half its routing and slot layout
+    (``experts.KEPT_NAMES``), so that a layer selects and sorts once
     (docs/sdar.md has the price list)."""
 
     attn: dict          # BlockDiffusionAttention's fields
@@ -142,8 +144,8 @@ class SdarBlock(nn.Module):
                 rms_norm(x, "ln_mlp", self.eps, self.dtype))
 
         if self.remat:
-            mix = nn.remat(mix, policy=keep_policy("pallas_attention"))
-            feed = nn.remat(feed)
+            mix = nn.remat(mix, policy=keep_policy("ops.pallas_attention"))
+            feed = nn.remat(feed, policy=keep_policy("models.experts"))
         return feed(self, mix(self, x, positions))
 
 

@@ -79,17 +79,16 @@ class SmallThinkerBlock(nn.Module):
 
     Where the routing is computed: inside the second half, with the expert
     layer, from the block's input — which is the first half's input and so
-    stored anyway; ids and weights are not stored (0.8 MB a layer at 16,384
-    tokens would do it, but a third checkpoint or a split layer would have
-    to carry them) and are recomputed in backward with the rest of that
-    half: no byte, and a third of the 3.9 ms a step that routing takes in
-    all four layers of ``smallthinker_16k_1chip``, forward, recomputed and
-    backward together. Its operations stay under ``hvd.moe.route`` and
-    outside ``hvd.mixer``. The price list at 1 x 16,384
-    (docs/smallthinker.md): a layer's kept ``o`` and ``lse`` are 0.12 GB,
-    for an 18.8 ms run of ``flash_fwd`` or an 8.9 ms run of
-    ``flash_win_fwd``; ``python3 -m chipbench.aot --workload
-    smallthinker_16k_1chip`` totals 12.25 GB with all four keeping."""
+    stored anyway. That half keeps what its backward pass takes of the
+    routing (``experts.KEPT_NAMES``: the selected experts, their scores, the
+    slots and the tile ends, 1.2 MB a layer at 16,384 tokens, named where
+    the layer makes them), so the recomputed half runs the router's product
+    and softmax and neither ``top_k`` nor the slots' sort again. Its
+    operations stay under ``hvd.moe.route`` and outside ``hvd.mixer``. The
+    price list at 1 x 16,384 (docs/smallthinker.md): a layer's kept ``o``
+    and ``lse`` are 0.12 GB, for an 18.8 ms run of ``flash_fwd`` or an 8.9
+    ms run of ``flash_win_fwd``; ``python3 -m chipbench.aot --workload
+    smallthinker_16k_1chip`` totals 12.20 GB with all four keeping."""
 
     attn: dict          # GroupedCausalAttention's fields
     experts: dict       # ExpertLayer's fields
@@ -112,8 +111,8 @@ class SmallThinkerBlock(nn.Module):
                 rms_norm(a, "ln_mlp", self.eps, self.dtype), routed_by=x)
 
         if self.remat:
-            mix = nn.remat(mix, policy=keep_policy("pallas_attention"))
-            feed = nn.remat(feed)
+            mix = nn.remat(mix, policy=keep_policy("ops.pallas_attention"))
+            feed = nn.remat(feed, policy=keep_policy("models.experts"))
         return feed(self, x, mix(self, x, positions))
 
 

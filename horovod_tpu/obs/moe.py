@@ -10,9 +10,19 @@ hidden rows' elements that are exactly zero (``gate_zero_share``, a pass of
 its own that only a caller of the collection pays for). A training step does
 not carry the collection; a caller who wants the numbers applies the model
 with ``mutable=["moe_stats"]`` and hands the collection to :func:`publish`.
+
+And one gauge of a *compiled* step's text, set by
+:func:`record_layout_program` (whoever holds the compiled step calls it, as
+with ``obs.kda.record_scan_program``): whether a recomputed layer kept its
+routing and slot layout (``models.experts.KEPT_NAMES``, saved by
+``models.parts.keep_policy("models.experts")``). One that does not selects
+and sorts again in its backward pass, and shows here as sorts beyond the
+ones a layer needs.
 """
 
 from __future__ import annotations
+
+import re
 
 from .registry import registry as _metrics
 
@@ -51,6 +61,44 @@ _ZEROS = _metrics().gauge(
     "expert layer (about a half under a relu gate on seeded weights, what "
     "'sparse ReGLU' means on this chip; 0 under silu)",
     labels=("layer",))
+_RERUNS = _metrics().gauge(
+    "horovod_moe_layout_reruns",
+    "Times a compiled step's text selects a layer's experts (top_k, under "
+    "hvd.moe.route) or sorts its assignments into slots (under "
+    "hvd.moe.experts) beyond once a layer, a layer being one backward loop "
+    "over the held experts' slices (0 where every recomputed expert layer "
+    "keeps its routing and slot layout for its backward pass)",
+    labels=("program",))
+
+_SORT = re.compile(r' (?:sort|topk)\(|custom_call_target="TopK"')
+
+
+def record_layout_program(program: str, hlo_text: str) -> tuple:
+    """``(selections, sorts, layers, reruns)`` of a compiled step's text
+    (``compiled.as_text()``), the last set on the gauge under ``program``:
+    the ``sort`` instructions (``topk``, or a ``TopK`` call, where the
+    backend has one) whose ``op_name`` holds ``hvd.moe.route``, those under
+    ``hvd.moe`` otherwise (``models.experts.slot_layout``'s one), the
+    backward loops over the slices (a ``while`` named ``hvd.moe.experts/
+    while`` under a ``transpose``), and the larger of the first two beyond
+    the third. On the four expert cells that is 0 — 4, 6, 4 and 4 layers
+    (``laguna_xs2_8k_1chip``, ``sdar_moe_8k_1chip``,
+    ``kimi_linear_16k_1chip``, ``smallthinker_16k_1chip``), each selected
+    and sorted once; as many reruns as layers while a recomputed half kept
+    nothing of its routing (before PR 47)."""
+    selections = sorts = layers = 0
+    for line in hlo_text.splitlines():
+        if "hvd.moe" not in line:
+            continue
+        if _SORT.search(line):
+            selections += "hvd.moe.route" in line
+            sorts += "hvd.moe.route" not in line
+        elif " while(" in line and "transpose(" in line \
+                and 'hvd.moe.experts/while"' in line:
+            layers += 1
+    reruns = max(selections, sorts) - layers if layers else 0
+    _RERUNS.labels(program=program).set(reruns)
+    return selections, sorts, layers, reruns
 
 
 def publish(moe_stats) -> dict:
